@@ -90,6 +90,7 @@ class _PipelinedEncode:
         try:
             path, (parity, crcs) = self._fut.result(timeout)
         except FuturesTimeout:
+            ec_pipeline.get().note_result_timeout()
             chan = self._codec._encode_channel(self._stripes.shape[2])
             parity, crcs = chan.host_fn(self._stripes)
             path = "host"
@@ -123,7 +124,9 @@ class _PipelinedDecode:
         try:
             _path, (out,) = self._fut.result(timeout)
         except FuturesTimeout:
-            out = self._host()     # wedged pipeline: host self-serve
+            # wedged pipeline: host self-serve
+            ec_pipeline.get().note_result_timeout()
+            out = self._host()
         return np.asarray(out)
 
 

@@ -29,8 +29,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import compile_cache
 from . import crc32c as crc_mod
 from . import gf
+
+compile_cache.place()
 
 # Accumulation dtype pairs: int8 inputs with int32 accumulation hits the
 # MXU's integer path on TPU; bf16/f32 is a fallback knob for platforms
@@ -137,16 +140,20 @@ def gf2_matmul_bytes_packed(g_bits: jnp.ndarray, data: jnp.ndarray,
     return packed.transpose(0, 2, 1, 3).reshape(B, m, L)
 
 
-@functools.lru_cache(maxsize=256)
-def _encode_fn(g_bits_key: bytes, shape_key: tuple, compute: str):
-    """Jitted (B, k, L) uint8 -> (B, m, L) uint8 parity."""
-    rows, cols = shape_key
-    g_bits = np.frombuffer(g_bits_key, dtype=np.uint8).reshape(rows, cols)
-    g_const = jnp.asarray(g_bits)
+@functools.lru_cache(maxsize=None)
+def _apply_fn(compute: str):
+    """Jitted (g_bits (R, C), data (B, k, L) uint8) -> (B, R/8, L).
+
+    The bit-matrix is an OPERAND, not a baked constant: every matrix
+    of one shape shares one executable per data shape.  A degraded
+    read's decode matrix depends on which shards were lost AND on
+    which k survivors answered first, so with per-matrix executables
+    every new (want, present) pattern paid its own compile and its
+    first batches were host-served while it warmed."""
 
     @jax.jit
-    def run(data):
-        return gf2_matmul_bytes_packed(g_const, data, compute)
+    def run(g_bits, data):
+        return gf2_matmul_bytes_packed(g_bits, data, compute)
 
     return run
 
@@ -166,14 +173,15 @@ def make_codec_fn(matrix: np.ndarray, w: int = 8,
         assert bits.shape[0] % 8 == 0 and bits.shape[1] % 8 == 0
     else:
         raise ValueError(f"unsupported w={w}")
-    fn = _encode_fn(bits.tobytes(), bits.shape, compute)
+    fn = _apply_fn(compute)
+    bits = np.ascontiguousarray(bits)
 
     def call(data):
         data = jnp.asarray(data, dtype=jnp.uint8)
         squeeze = data.ndim == 2
         if squeeze:
             data = data[None]
-        out = fn(data)
+        out = fn(bits, data)
         return out[0] if squeeze else out
 
     return call
@@ -453,12 +461,8 @@ def mesh_geometry(nbytes: int, n_ls: int) -> tuple[int, int, int]:
 def _mesh_context(devices, n_dp: int, n_ls: int):
     """Build the dp x ls jax Mesh plus the sharding/shard_map imports
     shared by the mesh kernel builders."""
-    import jax
+    from jax import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    try:
-        from jax import shard_map          # jax >= 0.8
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     devs = np.array(list(devices)).reshape(n_dp, n_ls)
     return jax, Mesh(devs, ("dp", "ls")), NamedSharding, P, shard_map
 
@@ -483,16 +487,6 @@ def _combine_local_crcs(jax, c, comb_c, in_dtype, acc_dtype):
     full = (tot & 1).astype(jnp.uint32)
     weights32 = jnp.asarray([1 << i for i in range(32)], dtype=jnp.uint32)
     return jnp.sum(full * weights32, axis=-1, dtype=jnp.uint32)
-
-
-def _donated_call(fn, *args):
-    """Call a possibly-donating jitted fn; backends without donation
-    support (CPU in older jax) warn instead of failing — silence it,
-    the arena lifecycle upstream is identical either way."""
-    import warnings
-    with warnings.catch_warnings():
-        warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
-        return fn(*args)
 
 
 def make_mesh_encode_crc_fn(matrix: np.ndarray, nbytes: int, devices,
@@ -562,7 +556,7 @@ def make_mesh_encode_crc_fn(matrix: np.ndarray, nbytes: int, devices,
             from ..utils import copyaudit
             copyaudit.note("ec.mesh_pad", batch.nbytes)
         dev = jax_mod.device_put(arr, data_sharding)
-        parity_dev, crcs_dev = _donated_call(jitted, dev)
+        parity_dev, crcs_dev = jitted(dev)
         crcs = np.asarray(crcs_dev)[:S]
         parity = np.asarray(parity_dev)
         if pad or S_pad != S:
@@ -574,6 +568,10 @@ def make_mesh_encode_crc_fn(matrix: np.ndarray, nbytes: int, devices,
 
     run.chunk_pad = pad
     run.mesh_devices = devices
+    # the jitted program and its input sharding, for ahead-of-time
+    # compiles against a described (not attached) topology
+    run.jitted = jitted
+    run.data_sharding = data_sharding
     return run
 
 

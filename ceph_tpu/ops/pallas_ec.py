@@ -29,7 +29,9 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from . import gf
+from . import compile_cache, gf
+
+compile_cache.place()
 
 # lanes per grid cell; 8 bit-planes of a TL-byte tile = TL*8k int8 in
 # VMEM (k=8, TL=16384 -> 8 MB peak intermediates), inside ~16 MB VMEM.
@@ -274,7 +276,30 @@ def make_encode_crc_fn(matrix: np.ndarray, L: int,
     HBM between the two kernels; the scrub CRCs cover data and parity
     chunks (HashInfo semantics, osd/ECUtil.cc:140).
     """
-    m, k = np.asarray(matrix).shape
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    matrix = np.ascontiguousarray(matrix, dtype=np.uint8)
+    run = _encode_crc_call(matrix.tobytes(), matrix.shape, L, interpret)
+
+    def call(data):
+        data = jnp.asarray(data, dtype=jnp.uint8)
+        squeeze = data.ndim == 2
+        if squeeze:
+            data = data[None]
+        parity, crcs = run(data)
+        return (parity[0], crcs[0]) if squeeze else (parity, crcs)
+
+    return call
+
+
+@functools.lru_cache(maxsize=64)
+def _encode_crc_call(m_key: bytes, mk: tuple[int, int], L: int,
+                     interpret: bool):
+    """One jitted composition per (matrix, L): every codec in the
+    process (13 OSDs share one chip) reuses it instead of tracing and
+    compiling its own copy of the same program."""
+    m, k = mk
+    matrix = np.frombuffer(m_key, dtype=np.uint8).reshape(m, k)
     enc = make_encode_fn(matrix, L, interpret=interpret)
     crc = make_crc_fn(L, interpret=interpret)
 
@@ -289,12 +314,4 @@ def make_encode_crc_fn(matrix: np.ndarray, L: int,
         crcs = jnp.concatenate([dcrc, pcrc], axis=1)
         return parity, crcs
 
-    def call(data):
-        data = jnp.asarray(data, dtype=jnp.uint8)
-        squeeze = data.ndim == 2
-        if squeeze:
-            data = data[None]
-        parity, crcs = run(data)
-        return (parity[0], crcs[0]) if squeeze else (parity, crcs)
-
-    return call
+    return run

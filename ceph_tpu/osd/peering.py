@@ -670,11 +670,31 @@ class Peering:
                 # cand"; a mid-backfill shard has holes below its head
                 continue
             lus[osd_id] = tuple(info.get("last_update", ZERO_EV))
+        # peers that did not answer this round (RPC timeout under a
+        # busy host, map lag): any of them may hold the newest head
+        unknown = sum(1 for i in infos.values() if i.get("unknown"))
         auth_ev = None
         for cand in sorted(set(lus.values()), reverse=True):
-            if sum(1 for lu in lus.values() if lu >= cand) >= k:
+            holders = sum(1 for lu in lus.values() if lu >= cand)
+            if holders >= k:
                 auth_ev = cand
                 break
+            if holders + unknown >= k:
+                # the unanswered peers could make `cand` decodable:
+                # choosing an OLDER head now would rewind — destroy —
+                # writes that every shard acked (seen on the chip: 7
+                # of 9 survivors answered in time, a catching-up
+                # shard's old head then won the vote and the pg's
+                # objects were rolled back to nothing).  Stay inactive
+                # and ask again; a peer that is really gone leaves the
+                # acting set with the next map.
+                self.log.warn(
+                    "pg incomplete: head %s held by %d known shards, "
+                    "%d peer(s) unanswered; re-peering before any "
+                    "rewind", cand, holders, unknown)
+                self.osd.clock.timer(
+                    0.5, lambda: self.osd.queue_peering(self.pgid))
+                return None
         if auth_ev is None:
             self.log.warn("pg incomplete: no head held by >=%d known "
                           "shards (last_updates %s)", k, lus)

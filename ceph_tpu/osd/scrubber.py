@@ -175,6 +175,7 @@ class ScrubService:
             except FuturesTimeout:
                 # wedged pipeline (hung device fetch): self-serve the
                 # fold on host — same bytes, same CRCs
+                pipe.note_result_timeout()
                 crcs = crc_mod.crc32c_batch(arr)
             for (name, _d, expected), got in zip(chunk, crcs):
                 out[name] = (size, bool(int(got) == expected))

@@ -11,6 +11,10 @@ fails when a refactor silently stops incrementing it.  This pass
     (``add_u64_counter("x")`` / ``add_time_avg("x")`` / ...) and
     increment sites (``.inc("x")`` / ``.tinc("x")`` / ``.dec("x")``,
     including ternaries like ``.inc("op_w" if w else "op_r")``);
+  * scans the EC device plane's plain-dict counters too
+    (``_c["x"] += n`` in ops/pipeline.py and ops/hbm_cache.py, the
+    warm-up registry's ``_warm["x"] += 1``) — they ride perf dump's
+    ``ec_pipeline`` block;
   * requires every discovered name to appear as a quoted string in
     tests/test_observability.py (the schema assertions).
 
@@ -36,6 +40,10 @@ _NAME = re.compile(r"[\"']([a-z][a-z0-9_]*)[\"']")
 _CALLS = re.compile(
     r"\.(?:inc|tinc|dec|add_u64_counter|add_u64|add_time_avg|"
     r"add_time|add_histogram)\(")
+
+# plain-dict counter increments of the EC device plane
+_DICT_INCS = re.compile(
+    r"\b_(?:c|warm)\[[\"']([a-z][a-z0-9_]*)[\"']\]\s*\+=")
 
 TEST_FILE = "tests/test_observability.py"
 
@@ -81,6 +89,8 @@ def scan_counters(src: str) -> dict[str, list[int]]:
     out: dict[str, list[int]] = {}
     lines = _blanked(src).splitlines()
     for lineno, line in enumerate(lines, start=1):
+        for name in _DICT_INCS.findall(line):
+            out.setdefault(name, []).append(lineno)
         for m in _CALLS.finditer(line):
             # names live in the call's argument text: the rest of
             # this line plus the next (continuation) line covers

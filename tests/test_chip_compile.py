@@ -1,0 +1,108 @@
+"""The served kernels compile for the real chip at real widths.
+
+The TPU compiler is installed in the sandbox and compiles for a chip
+that is DESCRIBED, not attached (a v5e 2x2 host).  Nothing runs, so
+this says nothing about results or speed — chip_smoke.py does that on
+the chip — but a kernel the chip's compiler would refuse (a slice off
+the tiling, too much VMEM, a program that does not fit HBM, a sharding
+that cannot be partitioned) fails here, at no chip time.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and every xdist
+worker imports every test file.  All of these tests live in this one
+file for the same reason.
+"""
+
+import jax
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from ceph_tpu.ops import ec_kernels, gf, pallas_ec
+
+K, M = 8, 3
+MATRIX = gf.reed_sol_van_matrix(K, M)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes, dtype=np.uint8):
+    args = [jax.ShapeDtypeStruct(s, dtype, sharding=sharding)
+            for s in shapes]
+    return jax.jit(fn).lower(*args).compile()
+
+
+# (B, k, L): one 4 MiB object's stripes, and two coalesced.  The
+# (8, k, 1 MiB) shape compiles too but takes ~27 s: run by hand
+# (CHANGES.md, PR 23), not kept here.
+@pytest.mark.parametrize("shape", [(128, K, 4096), (256, K, 4096)])
+def test_pallas_fused_encode_crc(one_chip, shape):
+    fn = pallas_ec.make_encode_crc_fn(MATRIX, shape[-1], interpret=False)
+    text = _compile(fn, one_chip, shape).as_text()
+    # encode + data CRC + parity CRC, each a real Mosaic kernel
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_pallas_crc(one_chip):
+    fn = pallas_ec.make_crc_fn(4096, interpret=False)
+    assert "tpu_custom_call" in _compile(
+        fn, one_chip, (1408, 4096)).as_text()
+
+
+# rows rebuilt by a degraded read: 1..m lost data shards
+@pytest.mark.parametrize("n_lost", [1, 2, 3])
+def test_xla_decode(one_chip, n_lost):
+    """What TpuBackend._fn("bytes") serves: the bit-matrix is an
+    operand, so ONE executable covers every decode pattern of a
+    shape."""
+    fn = ec_kernels._apply_fn(ec_kernels.DEFAULT_COMPUTE)
+    g = jax.ShapeDtypeStruct((8 * n_lost, 8 * K), np.uint8,
+                             sharding=one_chip)
+    data = jax.ShapeDtypeStruct((128, K, 4096), np.uint8,
+                                sharding=one_chip)
+    compiled = fn.lower(g, data).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+# (rows, bytes): stripe-chunk rows, and whole shard files as deep
+# scrub folds them.  The 512 KiB shard file of a 4 MiB object compiles
+# too but takes ~36 s: run by hand (CHANGES.md, PR 23), not kept here.
+@pytest.mark.parametrize("shape", [(1408, 4096), (64, 64 << 10)])
+def test_xla_scrub_crc(one_chip, shape):
+    fn = ec_kernels.make_crc_fn(shape[-1])
+    compiled = _compile(fn, one_chip, shape)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+def test_mesh_encode_crc_on_four_chips(topo):
+    """The pod-scale dispatch: chunk-length axis sharded over a 1x4
+    mesh, CRC partials combined on device."""
+    devices = list(topo.devices)[:4]
+    run = ec_kernels.make_mesh_encode_crc_fn(MATRIX, 4096, devices,
+                                             n_dp=1, n_ls=4, donate=True)
+    mesh = Mesh(np.array(devices).reshape(1, 4), ("dp", "ls"))
+    assert run.data_sharding == NamedSharding(mesh, P("dp", None, "ls"))
+    arg = jax.ShapeDtypeStruct((2048, K, 4096), np.uint8,
+                               sharding=run.data_sharding)
+    compiled = run.jitted.lower(arg).compile()
+    text = compiled.as_text()
+    # the XOR psum of the CRC partials is the only collective
+    assert "all-reduce" in text
+    # per-device bytes: a quarter of the batch plus its bit expansion
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30

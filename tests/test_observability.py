@@ -219,6 +219,21 @@ class TestPerfCounters:
                     "device_mesh", "qos_cost_unit",
                     "qos_cost_picks"):
             assert key in stats, key
+        # transfer-plane bytes, measured placement, and the counters
+        # that show when the device path was bypassed: a device set
+        # that found no device, a route callback that raised, a
+        # producer that self-served after RESULT_TIMEOUT, warm-ups
+        # that failed or are still compiling
+        for key in ("bytes_h2d", "bytes_d2h", "cost_placements",
+                    "cost_diverged", "devset_errors", "route_errors",
+                    "result_timeouts", "warm_failures",
+                    "last_warm_error", "warmups_inflight"):
+            assert key in stats, key
+        # the HBM stripe cache's counters ride the same block
+        for name in ("hit", "miss", "insert", "evict", "invalidate",
+                     "lane_drops", "append_throughs",
+                     "read_bytes_served", "bytes_d2h"):
+            assert f"cache_{name}" in stats, name
         # the mesh table is None until a mesh plane is built, else a
         # per-axis device map
         if stats["mesh"] is not None:

@@ -136,6 +136,34 @@ class TestDivergentRewind:
         cluster.wait_for_osd_down(primary)
         assert _wait_read(io, "obj2") == v2
 
+    def test_unanswered_peer_blocks_the_rewind(self, cluster):
+        """A peer that did not answer the info round may hold the
+        newest head: the vote must NOT settle on an older head that a
+        catching-up shard happens to share — that rewound (destroyed)
+        fully acked writes on a busy host.  It stays inactive, and
+        picks the real head once the peer answers."""
+        rados, io = _ec_setup(cluster)
+        io.write_full("obj", b"v1-acked-by-all" * 300)
+        v2 = b"v2-acked-by-all" * 300
+        io.write_full("obj", v2)
+        m = cluster.leader().osdmon.osdmap
+        pgid = m.object_to_pg(io.pool_id, "obj")
+        _up, acting = m.pg_to_up_acting_osds(pgid)
+        primary = next(o for o in acting if o >= 0)
+        ppg = cluster.osds[primary].get_pg(pgid)
+        lagging, silent = [o for o in acting if o != primary]
+        head = ppg.pglog.head
+        older = (head[0], head[1] - 1)
+        infos = {lagging: {"last_update": older, "log_tail": ZERO_EV},
+                 silent: {"unknown": True, "unreachable": True}}
+        with ppg.lock:
+            assert ppg._ec_choose_and_rewind(infos) is None
+        assert ppg.pglog.head == head, "the primary was rewound"
+        infos[silent] = {"last_update": head, "log_tail": ZERO_EV}
+        with ppg.lock:
+            assert ppg._ec_choose_and_rewind(infos) == head
+        assert _wait_read(io, "obj") == v2
+
     def test_rewind_restores_stash_content(self, cluster):
         """Unit-ish: rewind_to restores the pre-write shard bytes and
         version index from the stash."""

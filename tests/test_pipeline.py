@@ -382,7 +382,7 @@ def test_crc_channel_latches_host_after_device_error():
     chan = ec_pipeline.crc_channel(64)
     try:
         assert chan.route(64) is True
-        ec_pipeline._crc_on_error(RuntimeError("tunnel died"))
+        ec_pipeline._crc_on_error(RuntimeError("device died"))
         assert ec_pipeline._crc_device_dead
         assert chan.route(64) is False
         # host path still produces correct CRCs through the pipeline
@@ -437,7 +437,7 @@ def test_real_device_failure_degrades_and_drains():
     # sabotage the backend: fused fn "ready" but explodes on use
     def bad_fused(matrix, shape, device=None):
         def fn(batch):
-            raise RuntimeError("tunnel collapsed")
+            raise RuntimeError("device collapsed")
         return fn
 
     codec.backend.fused_fn_if_ready = bad_fused
