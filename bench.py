@@ -489,73 +489,6 @@ def bench_e2e_pipelined(rows: list, chunk: int = 1 << 20,
             "crossover": codec.backend.crossover_estimate()}
 
 
-def bench_transfer_breakdown(rows: list, chunk: int = 1 << 20,
-                             reps: int = 3) -> dict:
-    """Per-phase split of the transfer-inclusive path — H2D upload,
-    on-device fused compute, parity+CRC readback — each timed alone,
-    so the remaining e2e time is attributable to a specific phase
-    instead of one opaque number.  Distinct buffers per dispatch."""
-    import jax
-
-    from ceph_tpu.ops import ec_kernels, gf
-
-    k, m = 8, 3
-    batch = 1
-    matrix = gf.reed_sol_van_matrix(k, m)
-    fused = ec_kernels.make_encode_crc_fn(matrix, chunk)
-    rng = np.random.default_rng(17)
-    bufs = [rng.integers(0, 256, size=(batch, k, chunk),
-                         dtype=np.uint8) for _ in range(reps + 1)]
-    useful = batch * k * chunk
-    # warm/compile
-    warm = jax.device_put(bufs[0])
-    p, c = fused(warm)
-    np.asarray(p), np.asarray(c)
-    # h2d: upload alone
-    t0 = time.perf_counter()
-    devs = []
-    for b in bufs[1:]:
-        d = jax.device_put(b)
-        d.block_until_ready()
-        devs.append(d)
-    t_h2d = (time.perf_counter() - t0) / reps
-    # compute: device-resident inputs, outputs blocked on device
-    outs = []
-    t0 = time.perf_counter()
-    for d in devs:
-        p, c = fused(d)
-        c.block_until_ready()
-        p.block_until_ready()
-        outs.append((p, c))
-    t_comp = (time.perf_counter() - t0) / reps
-    # d2h: fetch the already-computed parity + CRCs
-    d2h_bytes = 0
-    t0 = time.perf_counter()
-    for p, c in outs:
-        pn, cn = np.asarray(p), np.asarray(c)
-        d2h_bytes = pn.nbytes + cn.nbytes
-    t_d2h = (time.perf_counter() - t0) / reps
-    out = {
-        "h2d_gbs": round(useful / max(t_h2d, 1e-9) / 1e9, 4),
-        "compute_gbs": round(useful / max(t_comp, 1e-9) / 1e9, 4),
-        "d2h_gbs": round(useful / max(t_d2h, 1e-9) / 1e9, 4),
-        "d2h_bytes_per_dispatch": int(d2h_bytes),
-        "d2h_parity_only": bool(
-            d2h_bytes == ec_kernels.encode_readback_bytes(
-                batch, k, m, chunk)),
-    }
-    for phase, gbs in (("h2d", out["h2d_gbs"]),
-                       ("compute", out["compute_gbs"]),
-                       ("d2h", out["d2h_gbs"])):
-        rows.append((f"phase-{phase}", "tpu", k, m, chunk, gbs))
-    log(f"transfer breakdown (payload {useful >> 20} MiB): "
-        f"h2d {out['h2d_gbs']:.3f} GB/s | compute "
-        f"{out['compute_gbs']:.3f} GB/s | d2h {out['d2h_gbs']:.3f} "
-        f"GB/s ({d2h_bytes} B/dispatch, parity-only="
-        f"{out['d2h_parity_only']})")
-    return out
-
-
 def _warm_mesh_codec(codec, k: int, chunk: int, shapes,
                      plane_key: tuple, window: float,
                      donate: bool = False) -> bool:
@@ -2239,8 +2172,6 @@ def main() -> None:
         pipelined_dev = _section(
             "e2e_pipelined_dev", lambda: bench_e2e_pipelined(
                 rows, nops=16, warm_window=120.0, routing="device"))
-    breakdown = _section("transfer_breakdown",
-                         lambda: bench_transfer_breakdown(rows))
     # serving plane: open-loop multi-tenant load + cache-served reads
     # (fast mode trims duration/object counts, never the row set —
     # the BENCH trajectory tracks these keys from r06 on)
@@ -2325,7 +2256,6 @@ def main() -> None:
         if pipelined else None,
         "pipelined_bytes_d2h": pipelined["bytes_d2h"]
         if pipelined else None,
-        "transfer_breakdown": breakdown,
         "host_path_breakdown": host_path,
         "host_copies_per_write": (
             round(sum(h.get("copies", 0) for name, h in

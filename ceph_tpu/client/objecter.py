@@ -224,8 +224,11 @@ class Objecter(Dispatcher):
             return None
         op.attempts += 1
         self.msgr.send_message(
+            # `attempt`: which send of this op this is; the OSD puts
+            # it on the op's doc, so resends can be counted from dumps
             MOSDOp(tid=op.tid, pgid=str(pgid), oid=op.oid, ops=op.ops,
-                   epoch=m.epoch, snapc=op.snapc, snapid=op.snapid),
+                   epoch=m.epoch, snapc=op.snapc, snapid=op.snapid,
+                   attempt=op.attempts),
             f"osd.{primary}", tuple(addr))
         return primary
 

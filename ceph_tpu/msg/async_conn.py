@@ -40,7 +40,8 @@ from ..auth import cephx
 from ..utils import faults
 from .message import Message
 from .messenger import (AuthError, BANNER_MAGIC, Policy, _BANNER,
-                        _BANNER_REPLY, _pack_addr, _unpack_addr)
+                        _BANNER_REPLY, _pack_addr, _unpack_addr,
+                        stamp_received)
 
 _READ = 1       # selectors.EVENT_READ
 _WRITE = 2      # selectors.EVENT_WRITE
@@ -494,6 +495,7 @@ def _frames_gen(msgr, conn, sock: _Sock, skey, accepted: bool):
     hdr_size = Message.header_size()
     while not conn._closed:
         hdr = yield ("read", hdr_size)
+        recv_stamp, recv_cpu = time.monotonic(), time.thread_time()
         type_id, plen, seq, has_segs = Message.parse_header_any(hdr)
         body = yield ("read", plen)
         segments: list[bytes] = []
@@ -518,6 +520,8 @@ def _frames_gen(msgr, conn, sock: _Sock, skey, accepted: bool):
         if type_id == msgr.ACK_TYPE:
             conn._handle_ack(seq)
             continue
+        stamps = (recv_stamp, recv_cpu, time.monotonic(),
+                  time.thread_time(), nbytes)
         ack = msgr._ack_frame(seq)
         if skey is not None:
             ack = ack + cephx.sign(skey, send_label + ack)
@@ -531,6 +535,7 @@ def _frames_gen(msgr, conn, sock: _Sock, skey, accepted: bool):
             msgr.log.error("undecodable frame type=%d seq=%d from %s",
                            type_id, seq, conn.peer_name)
             continue
+        stamp_received(msg, stamps)
         d = fs.recv_delay(
             conn.peer_name, msgr.name,
             float(msgr.conf.ms_inject_delay_probability),

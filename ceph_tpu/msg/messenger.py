@@ -90,6 +90,22 @@ class Policy:
         return Policy(lossy=False)
 
 
+def stamp_received(msg: Message, stamps: tuple) -> None:
+    """Leave on a received message when its frame arrived, as
+    Message::recv_stamp / recv_complete_stamp do (src/msg/Message.h):
+    `_recv_stamp` when the header was read, `_recv_complete_stamp`
+    when the last segment was read and the signature checked, both on
+    time.monotonic(), with the reading thread's CPU clock at each and
+    the frame's bytes.  Whoever makes a tracked op of the message
+    closes decode and dispatch (osd/daemon.py: `msgr.recv`,
+    `msgr.dispatch`).  Underscore attrs never ride the wire, so a
+    forwarded message does not carry them on.  The loop thread serves
+    every connection of its messenger, so the CPU between the two
+    stamps includes other frames it read meanwhile."""
+    (msg._recv_stamp, msg._recv_cpu, msg._recv_complete_stamp,
+     msg._recv_complete_cpu, msg._recv_bytes) = stamps
+
+
 class Dispatcher:
     """Interface daemons implement to receive messages."""
 
@@ -759,6 +775,7 @@ class Messenger:
         try:
             while not conn._closed:
                 hdr = await reader.readexactly(hdr_size)
+                recv_stamp, recv_cpu = time.monotonic(), time.thread_time()
                 type_id, plen, seq, has_segs = \
                     Message.parse_header_any(hdr)
                 body = await reader.readexactly(plen)
@@ -794,6 +811,8 @@ class Messenger:
                 if type_id == self.ACK_TYPE:
                     conn._handle_ack(seq)
                     continue
+                stamps = (recv_stamp, recv_cpu, time.monotonic(),
+                          time.thread_time(), nbytes)
                 if writer is not None:
                     try:
                         ack = self._ack_frame(seq)
@@ -816,6 +835,7 @@ class Messenger:
                         "undecodable frame type=%d seq=%d from %s",
                         type_id, seq, conn.peer_name)
                     continue
+                stamp_received(msg, stamps)
                 d = fs.recv_delay(
                     conn.peer_name, self.name,
                     float(self.conf.ms_inject_delay_probability),

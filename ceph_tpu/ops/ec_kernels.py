@@ -152,10 +152,10 @@ def _apply_fn(compute: str):
     first batches were host-served while it warmed."""
 
     @jax.jit
-    def run(g_bits, data):
+    def run_decode(g_bits, data):
         return gf2_matmul_bytes_packed(g_bits, data, compute)
 
-    return run
+    return run_decode
 
 
 def make_codec_fn(matrix: np.ndarray, w: int = 8,
@@ -232,7 +232,7 @@ def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int,
         np.frombuffer(bits_key, dtype=np.uint8).reshape(rows, cols))
 
     @jax.jit
-    def run(data):
+    def run_packet_codec(data):
         # data: (B, n, L) uint8, n*w == cols, L % (w*packetsize) == 0
         B, n, L = data.shape
         nblk = L // (w * packetsize)
@@ -244,7 +244,7 @@ def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int,
         out = out.reshape(B, nblk, r, w, packetsize).transpose(0, 2, 1, 3, 4)
         return out.reshape(B, r, nblk * w * packetsize)
 
-    return run
+    return run_packet_codec
 
 
 def make_packet_codec_fn(matrix: np.ndarray, w: int, packetsize: int,
@@ -305,7 +305,7 @@ def _crc_fn(nbytes: int, block: int, compute: str):
     weights32 = jnp.asarray([1 << i for i in range(32)], dtype=jnp.uint32)
 
     @jax.jit
-    def run(chunks):
+    def run_scrub_crc(chunks):
         # chunks: (..., L) uint8; bits byte-major LSB-first to match
         # crc32c.message_matrix's column convention.
         lead = chunks.shape[:-1]
@@ -334,7 +334,7 @@ def _crc_fn(nbytes: int, block: int, compute: str):
         bits_out = _mod2(acc).astype(jnp.uint32)
         return jnp.sum(bits_out * weights32, axis=-1, dtype=jnp.uint32)
 
-    return run
+    return run_scrub_crc
 
 
 def make_crc_fn(nbytes: int, block: int = DEFAULT_CRC_BLOCK,
@@ -370,12 +370,12 @@ def _encode_crc_fn(g_bits_key: bytes, shape_key: tuple, nbytes: int,
     crc = _crc_fn(nbytes, block, compute)
 
     @jax.jit
-    def run(data):
+    def run_xla_encode_crc(data):
         parity = gf2_matmul_bytes_packed(g_const, data, compute)
         chunks = jnp.concatenate([data, parity], axis=-2)
         return crc(chunks) if witness_only else (parity, crc(chunks))
 
-    return run
+    return run_xla_encode_crc
 
 
 def encode_readback_bytes(B: int, k: int, m: int, L: int) -> int:

@@ -87,10 +87,11 @@ def _encode_call(g_key: bytes, mk: tuple[int, int], L: int, tile: int,
     mask_const = jnp.asarray(mask_np)
 
     @jax.jit
-    def run(data):                                  # (B, k, L) uint8
+    def run_encode(data):                           # (B, k, L) uint8
         B = data.shape[0]
         return pl.pallas_call(
             kernel,
+            name="ec_encode",
             grid=(B, ntiles),
             in_specs=[
                 pl.BlockSpec((8 * m, 8 * k), lambda b, j: (0, 0),
@@ -106,7 +107,7 @@ def _encode_call(g_key: bytes, mk: tuple[int, int], L: int, tile: int,
             interpret=interpret,
         )(g_const, mask_const, data)
 
-    return run
+    return run_encode
 
 
 def _pick_tile(L: int, tile: int = DEFAULT_TILE) -> int | None:
@@ -221,7 +222,7 @@ def _crc_call(L: int, tile: int, rows_block: int, interpret: bool):
     weights32 = jnp.asarray([1 << i for i in range(32)], dtype=jnp.uint32)
 
     @jax.jit
-    def run(rows):                                  # (N, L) uint8
+    def run_crc(rows):                              # (N, L) uint8
         N = rows.shape[0]
         pad = (-N) % rows_block
         if pad:
@@ -230,6 +231,7 @@ def _crc_call(L: int, tile: int, rows_block: int, interpret: bool):
         NP = N + pad
         bits_out = pl.pallas_call(
             kernel,
+            name="crc_fold",
             grid=(NP // rows_block, ntiles),
             in_specs=[
                 pl.BlockSpec((8 * tile, 32), lambda n, j: (0, 0),
@@ -253,7 +255,7 @@ def _crc_call(L: int, tile: int, rows_block: int, interpret: bool):
                        axis=-1, dtype=jnp.uint32)
         return crcs[:N]
 
-    return run
+    return run_crc
 
 
 def make_crc_fn(L: int, tile: int = CRC_TILE,
@@ -304,7 +306,7 @@ def _encode_crc_call(m_key: bytes, mk: tuple[int, int], L: int,
     crc = make_crc_fn(L, interpret=interpret)
 
     @jax.jit
-    def run(data):
+    def run_encode_crc(data):
         B = data.shape[0]
         parity = enc(data)
         # CRC data and parity slabs separately: a concatenate would
@@ -314,4 +316,4 @@ def _encode_crc_call(m_key: bytes, mk: tuple[int, int], L: int,
         crcs = jnp.concatenate([dcrc, pcrc], axis=1)
         return parity, crcs
 
-    return run
+    return run_encode_crc

@@ -16,7 +16,7 @@ from ..crush.map import ITEM_NONE
 from ..ops import crc32c as crc_mod
 from ..ops import hbm_cache
 from ..store.objectstore import ENOENT, StoreError, Transaction
-from ..utils import denc
+from ..utils import denc, optracker
 from ..utils.bufferlist import BufferList
 from . import ecutil
 from .messages import (MOSDECSubOpReadReply, MOSDECSubOpWrite,
@@ -214,13 +214,33 @@ class ECBackend:
                  "born": self.osd.clock.now(),
                  "applied": {self.role_of(self.osd.whoami)}}
         self._inflight[reqid] = state
-        for osd_id, sub in sub_msgs.values():
-            self.osd.send_osd(osd_id, sub)
+        self._send_sub_writes(sub_msgs)
         if trk is not None and state["waiting"]:
             # closes at reply time (trk.finish auto-close): the span
             # IS the shard sub-op round trip
             trk.span_begin("replica_wait", shards=len(waiting))
         self._maybe_commit(reqid)
+
+    def _send_sub_writes(self, sub_msgs: dict) -> None:
+        """Hand the shard sub-ops to the messenger, BEFORE
+        `replica_wait` opens: `msgr.send` is what this thread pays for
+        the hand-off (the loop thread encodes, signs and writes; that
+        shows on the receiver as the lag before its `msgr.recv`)."""
+        with optracker.span(
+                "msgr.send", frames=len(sub_msgs),
+                bytes=sum(self.osd._qos_payload_bytes(sub)
+                          for _o, sub in sub_msgs.values())):
+            for osd_id, sub in sub_msgs.values():
+                self.osd.send_osd(osd_id, sub)
+
+    def _send_sub_write_reply(self, conn, msg, result: int) -> None:
+        """A shard's answer to the primary, after the store's
+        `store_apply` / `wal` block has closed (the stores apply
+        synchronously and return), under `msgr.send` on the sub-op."""
+        with optracker.span("msgr.send", frames=1):
+            self.osd.send_osd_reply(conn, MOSDECSubOpWriteReply(
+                reqid=msg.reqid, pgid=str(self.pgid), shard=msg.shard,
+                result=result))
 
     # ---- EC partial-stripe append (ECTransaction.h:201 model) -----------
     #
@@ -402,8 +422,7 @@ class ECBackend:
                  "born": self.osd.clock.now(),
                  "applied": {my_shard}}
         self._inflight[reqid] = state
-        for osd_id, sub in sub_msgs.values():
-            self.osd.send_osd(osd_id, sub)
+        self._send_sub_writes(sub_msgs)
         trk = getattr(msg, "_trk", None)
         if trk is not None and waiting:
             trk.span_begin("replica_wait", shards=len(waiting))
@@ -483,18 +502,14 @@ class ECBackend:
     def handle_ec_sub_write(self, conn, msg, _parked: bool = False) -> None:
         with self.lock:
             if self._already_applied(tuple(msg.log["ev"])):
-                self.osd.send_osd_reply(conn, MOSDECSubOpWriteReply(
-                    reqid=msg.reqid, pgid=str(self.pgid),
-                    shard=msg.shard, result=0))
+                self._send_sub_write_reply(conn, msg, 0)
                 return
             if self._superseded(msg.log):
                 # this shard skipped op N but applied newer N+1 (park
                 # expired or cap hit).  A meta-only N+1 over a missed
                 # data write leaves STALE shard bytes — rebuild us.
                 self._request_ec_heal(msg.log["oid"], msg.shard, msg)
-                self.osd.send_osd_reply(conn, MOSDECSubOpWriteReply(
-                    reqid=msg.reqid, pgid=str(self.pgid),
-                    shard=msg.shard, result=0))
+                self._send_sub_write_reply(conn, msg, 0)
                 return
             if not _parked and self._park_if_gap(conn, msg, "ec"):
                 return            # replied when the gap fills/expires
@@ -510,9 +525,7 @@ class ECBackend:
             rf = getattr(msg, "roll_forward_to", None)
             if rf is not None:
                 self._trim_rollback(tuple(rf))
-            self.osd.send_osd_reply(conn, MOSDECSubOpWriteReply(
-                reqid=msg.reqid, pgid=str(self.pgid), shard=msg.shard,
-                result=result))
+            self._send_sub_write_reply(conn, msg, result)
             if result == 0:
                 self._flush_parked(msg.log["oid"])
 

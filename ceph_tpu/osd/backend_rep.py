@@ -8,6 +8,7 @@ Mixed into PG (pg.py).
 from __future__ import annotations
 
 from ..store.objectstore import StoreError, Transaction
+from ..utils import optracker
 from .messages import MOSDRepOp, MOSDRepOpReply, sender_id
 
 
@@ -60,8 +61,13 @@ class ReplicatedBackend:
                  "kind": "rep", "peers": sub_msgs,
                  "born": self.osd.clock.now()}
         self._inflight[reqid] = state
-        for peer, sub in sub_msgs.items():
-            self.osd.send_osd(peer, sub)
+        # the hand-off to the messenger, before `replica_wait` opens
+        with optracker.span(
+                "msgr.send", frames=len(sub_msgs),
+                bytes=sum(self.osd._qos_payload_bytes(sub)
+                          for sub in sub_msgs.values())):
+            for peer, sub in sub_msgs.items():
+                self.osd.send_osd(peer, sub)
         if trk is not None and state["waiting"]:
             # open until the gather completes — trk.finish() at reply
             # time closes it, so the span IS the replica round trip
@@ -105,8 +111,11 @@ class ReplicatedBackend:
                 result = 0
             except StoreError as e:
                 result = -e.errno
-            self.osd.send_osd_reply(conn, MOSDRepOpReply(
-                reqid=msg.reqid, pgid=str(self.pgid), result=result))
+            # after the store's journal / store_apply block closed
+            with optracker.span("msgr.send", frames=1):
+                self.osd.send_osd_reply(conn, MOSDRepOpReply(
+                    reqid=msg.reqid, pgid=str(self.pgid),
+                    result=result))
             if result == 0:
                 self._flush_parked(msg.log["oid"])
 

@@ -807,10 +807,14 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                 # the trace id is minted from the client reqid (stable
                 # across resends); sub-ops and recovery pushes carry
                 # it over the wire so per-daemon dumps correlate
+                # `attempt` is the client's send count for this op
+                # (objecter resends): a top-level field of the doc,
+                # never part of the description
                 msg._trk = self.op_tracker.create(
                     f"osd_op({msg.src}:{msg.tid} {msg.oid} "
                     f"{[op[0] for op in msg.ops]})",
-                    trace_id=f"{msg.src}:{msg.tid}")
+                    trace_id=f"{msg.src}:{msg.tid}",
+                    attempt=getattr(msg, "attempt", None))
                 self.perf.inc("op")
                 from ..utils.bufferlist import BufferList
                 self.perf.inc("op_in_bytes", sum(
@@ -826,12 +830,26 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                     f"ev={msg.log.get('ev')})",
                     trace_id=str(getattr(msg, "trace", "") or ""),
                     kind="subop")
+            elif isinstance(msg, MOSDECSubOpRead):
+                msg._trk = self.op_tracker.create(
+                    f"sub_read({msg.src} {msg.pgid} {msg.oid} "
+                    f"s{msg.shard})",
+                    trace_id=str(getattr(msg, "trace", "") or ""),
+                    kind="subop")
             elif isinstance(msg, MPGPush):
                 msg._trk = self.op_tracker.create(
                     f"push({msg.src} {msg.pgid} {msg.oid} "
                     f"v={getattr(msg, 'version', None)})",
                     trace_id=str(getattr(msg, "trace", "") or ""),
                     kind="recovery")
+            elif isinstance(msg, MPGInfo) and msg.op == "scan":
+                # a peer's half of a PG scrub, under the primary's
+                # scrub trace id (pg.scrub)
+                msg._trk = self.op_tracker.create(
+                    f"pg_scan({msg.src} {msg.pgid} "
+                    f"deep={int(bool(msg.deep))})",
+                    trace_id=str(getattr(msg, "trace", "") or ""),
+                    kind="scrub_scan")
             pgid = PgId.parse(msg.pgid)
             # tenant traffic (client ops + the replica halves of its
             # writes) is scheduled under the pool's service class;
@@ -868,6 +886,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                     cost = 1.0 + len(data) / unit
             trk = getattr(msg, "_trk", None)
             if trk is not None:
+                self._note_recv(trk, msg)
                 # queue wait is anchored to the op's INITIATION (the
                 # dispatch bookkeeping above is queue time too): the
                 # span covers the op-shard deque AND any dmClock
@@ -879,6 +898,27 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                              qos=qos, qos_cost=cost)
             return True
         return False
+
+    @staticmethod
+    def _note_recv(trk, msg) -> None:
+        """The messenger's part of a tracked op, from the stamps it
+        left on the message (msg/messenger.py `stamp_received`):
+        `msgr.recv` from header read to the last segment read and the
+        signature checked, `msgr.dispatch` from there to the op's
+        creation (decode, dispatcher walk).  Both end at or before
+        `mstart`, so they lie inside no other span.  A loopback
+        message was never on a wire and has no stamps."""
+        r0 = getattr(msg, "_recv_stamp", None)
+        mstart = getattr(trk, "mstart", None)
+        if r0 is None or mstart is None:
+            return
+        r1 = msg._recv_complete_stamp
+        trk.add_span("msgr.recv", r0, r1,
+                     _cpu=max(0.0, msg._recv_complete_cpu - msg._recv_cpu),
+                     bytes=msg._recv_bytes)
+        trk.add_span("msgr.dispatch", r1, max(r1, mstart),
+                     _cpu=max(0.0, time.thread_time()
+                              - msg._recv_complete_cpu))
 
     @staticmethod
     def _qos_payload_bytes(msg) -> int:
@@ -1303,6 +1343,8 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
             reply.rpc_tid = getattr(msg, "rpc_tid", None)
             self.send_osd_reply(conn, reply)
         elif msg.op == "scan":
+            # under this message's `scrub_scan` op (ms_dispatch): the
+            # scan's spans land on it through optracker.current()
             reply = MPGInfo(op="scanned", pgid=msg.pgid,
                             epoch=self.osdmap.epoch,
                             info=self._scan_pg(pg, msg.deep))

@@ -82,6 +82,7 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
         self.last_scrub_stamp = now
         self.last_deep_scrub_stamp = now
         self.last_scrub_result: dict | None = None
+        self._scrub_seq = itertools.count(1)   # scrub trace ids
         self.active = False
         # last_backfill watermark (the reference's info_t.last_backfill,
         # a real high-water mark now, not just a flag): None = this
@@ -922,7 +923,24 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
         selection + repair pushes for replicated pools,
         PGBackend.cc:501 be_select_auth_object; shard rebuild for EC,
         test/osd/osd-scrub-repair.sh:201-243 scenarios) and re-scrubs
-        to report `clean_after_repair`."""
+        to report `clean_after_repair`.
+
+        The scrub is a tracked op of its own (kind `scrub`): the scan,
+        the waits for the peers' scans (their `scrub_scan` ops carry
+        this op's trace id) and the repair stamp their spans on it."""
+        from ..utils import optracker
+        trk = self.osd.op_tracker.create(
+            f"pg_scrub({self.pgid} deep={int(bool(deep or self.is_ec))})",
+            trace_id=f"scrub:{self.osd.whoami}:{self.pgid}:"
+                     f"{next(self._scrub_seq)}",
+            kind="scrub")
+        try:
+            with optracker.op_context(trk):
+                return self._scrub(deep, repair)
+        finally:
+            trk.finish()
+
+    def _scrub(self, deep: bool, repair: bool) -> dict:
         with self.lock:
             result = (self.osd.scrub_ec_pg(self) if self.is_ec
                       else self.osd.scrub_replicated_pg(self, deep))

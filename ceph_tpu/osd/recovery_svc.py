@@ -1029,10 +1029,16 @@ class RecoveryService:
                         done_ev.set()
             return cb
 
+        # sub-reads carry the trace id of the op this thread serves
+        # (a client read, a recovery rebuild), as sub-op writes do:
+        # the shard OSD's sub_read op correlates under it
+        from ..utils import optracker
+        trace = getattr(optracker.current(), "trace_id", "") or ""
         for shard, osd_id in targets:
             self._call_async(osd_id, MOSDECSubOpRead(
                 reqid=None, pgid=str(pgid), shard=shard, oid=oid,
-                off=off, length=length, need_ver=need_ver),
+                off=off, length=length, need_ver=need_ver,
+                trace=trace),
                 make_cb(shard, osd_id), timeout=timeout)
         # bound by REAL time too: _call_async timeouts ride the
         # cluster clock, which only advances when a test ticks it

@@ -18,7 +18,7 @@ import numpy as np
 from ..crush.map import ITEM_NONE
 from ..ops import crc32c as crc_mod
 from ..store.objectstore import StoreError, Transaction
-from ..utils import denc
+from ..utils import denc, optracker
 from .messages import MPGInfo
 from .pg import HINFO_KEY, PG, VER_KEY, shard_oid
 
@@ -81,10 +81,12 @@ class ScrubService:
     def _scan_pg(self, pg: PG, deep: bool) -> dict:
         """Local scrub scan: {oid_or_shard: (size, crc|None)}."""
         out = {}
-        try:
-            names = self.store.collection_list(pg.cid)
-        except StoreError:
-            return out
+        with optracker.span("scrub.list") as note:
+            try:
+                names = self.store.collection_list(pg.cid)
+            except StoreError:
+                return out
+            note["names"] = len(names)
         if pg.is_ec and deep:
             return self._scan_ec_deep(pg, names)
         for name in names:
@@ -138,45 +140,65 @@ class ScrubService:
             cached_folds[base] = folds
             return folds
 
-        for name in names:
-            if name.startswith("_pgmeta") or "@" in name:
-                continue          # pg meta + EC rollback stashes
-            base, _, sfx = name.rpartition(".s")
-            if sfx.isdigit():
-                folds = cache_folds(base)
-                shard = int(sfx)
-                if folds is not None and shard < len(folds):
-                    try:
-                        size = self.store.stat(pg.cid, name)["size"]
-                        hinfo = denc.loads(self.store.getattr(
-                            pg.cid, name, HINFO_KEY))
-                    except StoreError:
-                        continue
-                    out[name] = (size, bool(folds[shard]
-                                            == hinfo["crc"]))
+        # two passes so that each is one span: first what the HBM
+        # cache can answer (lookup, fold, stat, getattr), then the
+        # store reads of everything else
+        to_read: list[str] = []
+        with optracker.span("scrub.cache_fold") as note:
+            hits = 0
+            for name in names:
+                if name.startswith("_pgmeta") or "@" in name:
+                    continue          # pg meta + EC rollback stashes
+                base, _, sfx = name.rpartition(".s")
+                folds = cache_folds(base) if sfx.isdigit() else None
+                if folds is None or int(sfx) >= len(folds):
+                    to_read.append(name)
                     continue
-            try:
-                data = self.store.read(pg.cid, name)
-                hinfo = denc.loads(self.store.getattr(pg.cid, name,
-                                                      HINFO_KEY))
-            except StoreError:
-                continue
-            by_size.setdefault(len(data), []).append(
-                (name, data, hinfo["crc"]))
+                try:
+                    size = self.store.stat(pg.cid, name)["size"]
+                    hinfo = denc.loads(self.store.getattr(
+                        pg.cid, name, HINFO_KEY))
+                except StoreError:
+                    continue
+                out[name] = (size, bool(folds[int(sfx)]
+                                        == hinfo["crc"]))
+                hits += 1
+            note.update(shards=hits, objects=sum(
+                1 for f in cached_folds.values() if f is not None))
+        with optracker.span("scrub.read") as note:
+            nbytes = 0
+            for name in to_read:
+                try:
+                    data = self.store.read(pg.cid, name)
+                    hinfo = denc.loads(self.store.getattr(pg.cid, name,
+                                                          HINFO_KEY))
+                except StoreError:
+                    continue
+                nbytes += len(data)
+                by_size.setdefault(len(data), []).append(
+                    (name, data, hinfo["crc"]))
+            note.update(shards=sum(len(g) for g in by_size.values()),
+                        bytes=nbytes)
         batch_max = int(self.conf.osd_deep_scrub_stripe_batch)
         pipe = ec_pipeline.get()
         pending: list = []
 
         def collect_one() -> None:
             size, chunk, arr, fut = pending.pop(0)
-            try:
-                _path, (crcs,) = fut.result(
-                    ec_pipeline.RESULT_TIMEOUT)
-            except FuturesTimeout:
-                # wedged pipeline (hung device fetch): self-serve the
-                # fold on host — same bytes, same CRCs
-                pipe.note_result_timeout()
-                crcs = crc_mod.crc32c_batch(arr)
+            with optracker.span("scrub.collect") as note:
+                try:
+                    _path, (crcs,) = fut.result(
+                        ec_pipeline.RESULT_TIMEOUT)
+                except FuturesTimeout:
+                    # wedged pipeline (hung device fetch): self-serve
+                    # the fold on host — same bytes, same CRCs
+                    pipe.note_result_timeout()
+                    crcs = crc_mod.crc32c_batch(arr)
+                    note["timeouts"] = 1
+            # the dispatch's own phases (coalesce, H2D, compute, D2H),
+            # as ecutil notes them for writes
+            optracker.note_pipeline_phases(
+                getattr(fut, "trace_phases", None))
             for (name, _d, expected), got in zip(chunk, crcs):
                 out[name] = (size, bool(int(got) == expected))
 
@@ -189,10 +211,12 @@ class ScrubService:
                                            max_coalesce=batch_max)
             for i in range(0, len(group), batch_max):
                 chunk = group[i:i + batch_max]
-                arr = np.stack([np.frombuffer(d, dtype=np.uint8)
-                                for _n, d, _c in chunk])
-                pending.append((size, chunk, arr,
-                                pipe.submit(chan, arr)))
+                with optracker.span("scrub.stack", batches=1,
+                                    bytes=size * len(chunk)):
+                    arr = np.stack([np.frombuffer(d, dtype=np.uint8)
+                                    for _n, d, _c in chunk])
+                    pending.append((size, chunk, arr,
+                                    pipe.submit(chan, arr)))
                 # sliding window: keep a handful of batches in flight
                 # for dispatch overlap without queueing a second copy
                 # of the whole PG's shard bytes at once
@@ -207,21 +231,37 @@ class ScrubService:
         peers = [o for o in pg.acting_live() if o != self.whoami]
         scans = {self.whoami: my_scan}
         for osd_id in peers:
-            reply = self._call(osd_id, MPGInfo(
-                op="scan", pgid=str(pg.pgid), deep=deep,
-                epoch=self.osdmap.epoch), timeout=20.0)
+            reply = self._scan_peer(pg, osd_id, deep)
             if reply is not None:
                 scans[osd_id] = reply.info
         inconsistent = []
         all_names = set()
-        for scan in scans.values():
-            all_names.update(scan)
-        for name in sorted(all_names):
-            variants = {osd: scan.get(name) for osd, scan in scans.items()}
-            vals = set(variants.values())
-            if len(vals) > 1:
-                inconsistent.append({"object": name, "copies": variants})
+        with optracker.span("scrub.compare") as note:
+            for scan in scans.values():
+                all_names.update(scan)
+            for name in sorted(all_names):
+                variants = {osd: scan.get(name)
+                            for osd, scan in scans.items()}
+                vals = set(variants.values())
+                if len(vals) > 1:
+                    inconsistent.append({"object": name,
+                                         "copies": variants})
+            note.update(checked=len(all_names),
+                        inconsistent=len(inconsistent))
         return {"checked": len(all_names), "inconsistent": inconsistent}
+
+    def _scan_peer(self, pg: PG, osd_id: int, deep: bool):
+        """Ask one peer for its scan and wait for it: one
+        `scrub.peer_wait` span a peer on the scrub's op, whose trace id
+        rides the request so the peer's `scrub_scan` op carries it."""
+        trk = optracker.current()
+        with optracker.span("scrub.peer_wait", osd=osd_id) as note:
+            reply = self._call(osd_id, MPGInfo(
+                op="scan", pgid=str(pg.pgid), deep=deep,
+                trace=getattr(trk, "trace_id", "") or "",
+                epoch=self.osdmap.epoch), timeout=20.0)
+            note["ok"] = int(reply is not None)
+        return reply
 
     def scrub_ec_pg(self, pg: PG) -> dict:
         """Each shard OSD verifies its shards against hinfo (deep);
@@ -231,35 +271,38 @@ class ScrubService:
         for osd_id in pg.acting_live():
             if osd_id == self.whoami:
                 continue
-            reply = self._call(osd_id, MPGInfo(
-                op="scan", pgid=str(pg.pgid), deep=True,
-                epoch=self.osdmap.epoch), timeout=20.0)
+            reply = self._scan_peer(pg, osd_id, True)
             if reply is not None:
                 scans[osd_id] = reply.info
         inconsistent = []
         checked = 0
         bases = set()
-        for osd_id, scan in scans.items():
-            for name, (size, ok) in scan.items():
-                checked += 1
-                base, _, sfx = name.rpartition(".s")
-                if sfx.isdigit():
-                    bases.add(base)
-                if ok is False:
-                    inconsistent.append({"object": name, "osd": osd_id})
-        # a shard FILE a live holder lacks entirely never shows up in
-        # its scan: cross-check expected placement (only for holders
-        # whose scan we actually have — a scan timeout is not absence)
-        for base in bases:
-            if base not in pg.pglog.objects:
-                continue
-            for shard, holder in enumerate(pg.acting):
-                if holder == ITEM_NONE or holder not in scans:
+        with optracker.span("scrub.compare") as note:
+            for osd_id, scan in scans.items():
+                for name, (size, ok) in scan.items():
+                    checked += 1
+                    base, _, sfx = name.rpartition(".s")
+                    if sfx.isdigit():
+                        bases.add(base)
+                    if ok is False:
+                        inconsistent.append({"object": name,
+                                             "osd": osd_id})
+            # a shard FILE a live holder lacks entirely never shows up
+            # in its scan: cross-check expected placement (only for
+            # holders whose scan we actually have — a scan timeout is
+            # not absence)
+            for base in bases:
+                if base not in pg.pglog.objects:
                     continue
-                name = shard_oid(base, shard)
-                if name not in scans[holder]:
-                    inconsistent.append({"object": name, "osd": holder,
-                                         "missing": True})
+                for shard, holder in enumerate(pg.acting):
+                    if holder == ITEM_NONE or holder not in scans:
+                        continue
+                    name = shard_oid(base, shard)
+                    if name not in scans[holder]:
+                        inconsistent.append({"object": name,
+                                             "osd": holder,
+                                             "missing": True})
+            note.update(checked=checked, inconsistent=len(inconsistent))
         return {"checked": checked, "inconsistent": inconsistent}
 
     def repair_replicated_pg(self, pg: PG, inconsistent: list) -> int:

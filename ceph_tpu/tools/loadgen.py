@@ -315,6 +315,10 @@ class LoadGen:
         # object's recovery pull (the blocked-op span)
         "recovery_wait": "recovery",
         "execute": "execute",
+        # frame receive + dispatch before the op exists, and the
+        # hand-off of sub-op / reply frames to the messenger
+        "msgr.recv": "messenger", "msgr.dispatch": "messenger",
+        "msgr.send": "messenger",
     }
 
     def run(self, ioctxs: dict[str, object],

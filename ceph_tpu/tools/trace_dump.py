@@ -9,11 +9,19 @@ p999 outlier or a lost-ack incident reads as a timeline: each daemon
 is a process row, each trace id a thread row, each span a complete
 ("ph": "X") slice, each op event an instant marker.
 
-Span endpoints ride the process-wide ``time.monotonic()`` clock (all
-daemons in one test process share it), so cross-daemon rows line up
-without offset fixups: a client op's `queue`/`execute` on the primary
-nests visually over the correlated `sub_op` rows on its replicas —
-the same trace id groups them.
+Span endpoints ride ``time.monotonic()``, which one process shares:
+docs of one process line up as they are.  Docs of DIFFERENT processes
+do not share it, so where every doc carries ``mstart_ns`` (its
+``mstart`` on the wall clock) each doc is placed by that, and its
+spans by their distance from its ``mstart``: a client op's
+`queue`/`execute` on the primary nests visually over the correlated
+`sub_op` rows on its replicas, whichever process served them.  Dumps
+from before ``mstart_ns`` fall back to the shared monotonic clock.
+
+PG scrubs (kinds ``scrub`` and ``scrub_scan``) get a process row of
+their own per daemon (``<daemon> scrub``), apart from its client ops.
+A span's ``cpu`` (thread CPU seconds) and an op's ``attempt`` (the
+client's send count) ride as event args.
 
     python -m ceph_tpu.tools.trace_dump --dump-dir <incident-dir> \
         [--out trace.json]
@@ -29,6 +37,10 @@ import argparse
 import json
 import os
 import sys
+
+
+# PG scrubs are shown apart from a daemon's client ops
+SCRUB_KINDS = ("scrub", "scrub_scan")
 
 
 def _iter_ops(doc) -> list[dict]:
@@ -64,8 +76,8 @@ def chrome_trace(daemon_docs: dict[str, object]) -> dict:
     pids are daemons, tids are trace ids (falling back to the op
     description for untraced internals); numeric ids carry
     process_name / thread_name metadata events so the UI shows the
-    real names.  Timestamps are microseconds on the shared monotonic
-    timebase, rebased to the earliest op so traces start near 0."""
+    real names.  Timestamps are microseconds, rebased to the earliest
+    op so traces start near 0."""
     events: list[dict] = []
     pids: dict[str, int] = {}
     tids: dict[tuple, int] = {}
@@ -80,12 +92,30 @@ def chrome_trace(daemon_docs: dict[str, object]) -> dict:
             ops.append((op.get("daemon") or daemon, op))
     if not ops:
         return {"traceEvents": [], "displayTimeUnit": "ms"}
-    base = min(op.get("mstart", 0.0) for _d, op in ops)
+    # one timebase for all docs: the wall clock where every doc has
+    # it (docs of several processes), else the monotonic clock
+    walled = all("mstart_ns" in op for _d, op in ops)
+
+    def shift(op: dict) -> float:
+        """What to add to one doc's monotonic stamps."""
+        if not walled:
+            return 0.0
+        return op["mstart_ns"] / 1e9 - op.get("mstart", 0.0)
+
+    def first_stamp(op: dict) -> float:
+        """An op's earliest stamp: its msgr.recv / msgr.dispatch spans
+        lie before its mstart."""
+        return min([op.get("mstart", 0.0)] + [
+            float(sp["t0"]) for sp in op.get("spans", []) if "t0" in sp])
+
+    base = min(first_stamp(op) + shift(op) for _d, op in ops)
 
     def us(t: float) -> float:
         return round((t - base) * 1e6, 1)
 
     for daemon, op in ops:
+        if op.get("kind") in SCRUB_KINDS:
+            daemon = f"{daemon} scrub"
         if daemon not in pids:
             pids[daemon] = len(pids) + 1
             events.append({"ph": "M", "name": "process_name",
@@ -100,22 +130,29 @@ def chrome_trace(daemon_docs: dict[str, object]) -> dict:
                            "pid": pid, "tid": tids[tkey],
                            "args": {"name": lane}})
         tid = tids[tkey]
-        mstart = op.get("mstart", base)
+        off = shift(op)
+        mstart = op.get("mstart", base - off)
         dur = max(float(op.get("duration", 0.0)), 0.0)
+        args = {"trace_id": op.get("trace_id", ""), "age": op.get("age")}
+        if "attempt" in op:
+            args["attempt"] = op["attempt"]
         events.append({
             "ph": "X", "name": op.get("description", "op"),
             "cat": op.get("kind", "op"), "pid": pid, "tid": tid,
-            "ts": us(mstart), "dur": round(dur * 1e6, 1),
-            "args": {"trace_id": op.get("trace_id", ""),
-                     "age": op.get("age")}})
+            "ts": us(mstart + off), "dur": round(dur * 1e6, 1),
+            "args": args})
         for sp in op.get("spans", []):
             t0, t1 = float(sp.get("t0", mstart)), float(
                 sp.get("t1", mstart))
+            args = dict(sp.get("args") or {})
+            if "cpu" in sp:
+                args["cpu"] = sp["cpu"]
             events.append({
                 "ph": "X", "name": sp.get("name", "span"),
                 "cat": "span", "pid": pid, "tid": tid,
-                "ts": us(t0), "dur": round(max(t1 - t0, 0.0) * 1e6, 1),
-                "args": dict(sp.get("args") or {})})
+                "ts": us(t0 + off),
+                "dur": round(max(t1 - t0, 0.0) * 1e6, 1),
+                "args": args})
         for ev in op.get("events", []):
             mt = ev.get("mtime")
             if mt is None:
@@ -123,7 +160,7 @@ def chrome_trace(daemon_docs: dict[str, object]) -> dict:
             events.append({
                 "ph": "i", "s": "t", "name": ev.get("event", "?"),
                 "cat": "event", "pid": pid, "tid": tid,
-                "ts": us(float(mt))})
+                "ts": us(float(mt) + off)})
     events.sort(key=lambda e: (e["ph"] != "M", e.get("ts", 0.0)))
     return {"traceEvents": events, "displayTimeUnit": "ms"}
 

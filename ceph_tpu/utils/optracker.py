@@ -5,21 +5,61 @@ The TrackedOp/OpTracker analog (common/TrackedOp.{h,cc},
 osd/OpRequest.cc) grown from an event timeline into a span tracer:
 
   * every client op carries a **trace id** (``"<client>:<tid>"``) and a
-    list of named **spans** — [t0, t1) intervals on the process-wide
-    monotonic clock — stamped by every layer the op crosses: messenger
-    receive -> op-shard queue wait (dmClock stalls included, tagged
-    with the pool service class), execution, EC pipeline phases
-    (coalesce wait, H2D staging, device compute, D2H fetch — or the
-    host drain), journal/WAL append+fsync, and the replica sub-op
-    round trip.  Sub-ops and recovery pushes carry the SAME trace id
-    over the wire (a plain CTM2 frame field), so per-daemon dumps
-    correlate into one cross-daemon timeline
-    (tools/trace_dump.py -> chrome://tracing / Perfetto).
+    list of named **spans**: [t0, t1) intervals on the process-wide
+    monotonic clock.  What is stamped, and where:
+
+      msgr.recv, msgr.dispatch   osd/daemon.py, from the stamps the
+                                 messenger leaves on a received message
+                                 (header read, last segment read and
+                                 signature checked); both end at or
+                                 before the op's ``mstart``
+      queue, execute             osd/daemon.py (op-shard deque wait,
+                                 dmClock stalls included; the handler)
+      msgr.send                  osd/backend_ec.py, osd/backend_rep.py:
+                                 the calling thread's hand-off of sub-op
+                                 and reply frames to the messenger
+      ec.coalesce, ec.stage_h2d, ec.device_compute, ec.d2h,
+      ec.host_encode             :func:`note_pipeline_phases`, from the
+                                 stamps a pipeline future carries
+                                 (osd/ecutil.py, osd/scrubber.py)
+      journal, wal, store_apply  store/filestore.py, store/blockstore.py,
+                                 store/objectstore.py
+      replica_wait               osd/backend_ec.py, osd/backend_rep.py
+                                 (sub-op round trip; closes at finish)
+      recovery_wait, push_rpc, rebuild
+                                 osd/pg.py, osd/recovery_svc.py
+      scrub.list, scrub.cache_fold, scrub.read, scrub.stack,
+      scrub.collect, scrub.peer_wait, scrub.compare
+                                 osd/scrubber.py, on the primary's
+                                 ``scrub`` op and each peer's
+                                 ``scrub_scan`` op
+      paxos.propose, paxos.commit, execute (mon commands)
+                                 mon/monitor.py
+
+    Sub-op writes and reads, scrub scans and recovery pushes carry the
+    SAME trace id over the wire (a plain frame field, ``trace``), so
+    per-daemon dumps correlate into one cross-daemon timeline
+    (tools/trace_dump.py -> chrome://tracing / Perfetto).  A resent
+    client op leaves one doc per send under one trace id; the doc's
+    ``attempt`` field (the objecter's send count) tells them apart.
+  * the spans of :data:`CPU_SPANS` (``execute`` and the ``scrub.*``
+    family), when closed by the thread that opened them, also carry
+    ``cpu``: the seconds of ``time.thread_time()`` that thread spent
+    inside.  Wall time many times ``cpu`` means the thread waited (for
+    the interpreter, a lock, an fsync), not computed.  ``msgr.recv`` and
+    ``msgr.dispatch`` carry the messenger loop thread's.  No other span
+    does: the clock is a real system call where the daemons run (5.9 us
+    a call on the gVisor chip host against 0.09 us for
+    ``time.monotonic()``), so only the spans whose CPU time something
+    reads pay for it.
   * two clocks on purpose: ``start``/``age`` ride the daemon's
     injectable Clock (slow-op complaint math stays deterministic under
     the test ManualClock), while span endpoints ride
     ``time.monotonic()`` (real latency attribution; one process-wide
     timebase means per-daemon dumps merge without offset fixups).
+    Every doc also carries ``mstart_ns``, its ``mstart`` on the wall
+    clock, so dumps of different processes (and tools with a wall
+    clock) can place it.
   * each tracker keeps a bounded in-flight table, a historic ring
     (``osd_op_history_size`` / ``osd_op_history_duration``) and a
     separate slow-op ring (ops that crossed ``osd_op_complaint_time``),
@@ -55,6 +95,13 @@ from contextlib import contextmanager
 
 _tls = threading.local()
 
+# the spans that read the thread's CPU clock at both ends (see the
+# module docstring for why not all): an op's handler on its op-shard
+# thread, and the phases of a PG scrub
+CPU_SPANS = frozenset((
+    "execute", "scrub.list", "scrub.cache_fold", "scrub.read",
+    "scrub.stack", "scrub.collect", "scrub.peer_wait", "scrub.compare"))
+
 
 def set_current(op: "TrackedOp | None") -> None:
     _tls.op = op
@@ -79,16 +126,19 @@ def op_context(op: "TrackedOp | None"):
 @contextmanager
 def span(name: str, **args):
     """Stamp a span onto the thread's current op around the block; a
-    plain passthrough when nothing is being traced."""
+    plain passthrough when nothing is being traced.  Yields a dict:
+    what the block puts there (counts known only at its end) joins
+    the span's args when it closes."""
     op = current()
+    late: dict = {}
     if op is None:
-        yield None
+        yield late
         return
     op.span_begin(name, **args)
     try:
-        yield op
+        yield late
     finally:
-        op.span_end(name)
+        op.span_end(name, **late)
 
 
 def add_span(name: str, t0: float, t1: float, **args) -> None:
@@ -133,12 +183,13 @@ def note_pipeline_phases(ph: dict | None) -> None:
 
 
 class TrackedOp:
-    __slots__ = ("desc", "trace_id", "kind", "start", "mstart", "mend",
-                 "events", "spans", "_open", "_tracker", "_id", "_done",
-                 "_slock")
+    __slots__ = ("desc", "trace_id", "kind", "attempt", "start", "mstart",
+                 "mstart_ns", "mend", "events", "spans", "_open",
+                 "_tracker", "_id", "_done", "_slock")
 
     def __init__(self, tracker: "OpTracker", desc: str, now: float,
-                 trace_id: str = "", kind: str = "client"):
+                 trace_id: str = "", kind: str = "client",
+                 attempt: int | None = None):
         self._tracker = tracker
         # span/event state is touched from more than one thread (the
         # op shard's execute spans vs a timer/messenger continuation
@@ -147,16 +198,20 @@ class TrackedOp:
         self.desc = desc
         self.trace_id = trace_id
         self.kind = kind
+        self.attempt = attempt           # the client's send count
         self.start = now                 # tracker clock (age math)
         self.mstart = time.monotonic()   # span timebase
+        self.mstart_ns = time.time_ns()  # the same instant, wall clock
         self.mend: float | None = None
         self._id = 0
         self._done = False
         self.events: list[tuple[float, float, str]] = [
             (now, self.mstart, "initiated")]
-        # closed spans: [name, t0, t1, args-or-None] (monotonic)
+        # closed spans: [name, t0, t1, args-or-None, cpu-or-None]
+        # (monotonic; cpu in seconds of the opening thread)
         self.spans: list[list] = []
-        self._open: list[list] = []      # LIFO of open [name, t0, args]
+        # LIFO of open [name, t0, args, opener thread id, its cpu clock]
+        self._open: list[list] = []
 
     # -- events ------------------------------------------------------------
 
@@ -173,18 +228,31 @@ class TrackedOp:
                    **args) -> None:
         """Open a span; `_t0` backdates its start (the queue span is
         anchored to the op's initiation instant so span coverage has
-        no pre-queue bookkeeping hole on sub-millisecond ops)."""
+        no pre-queue bookkeeping hole on sub-millisecond ops).  A span
+        of CPU_SPANS notes the opening thread and its CPU clock, so
+        that the same thread's close can tell the CPU spent inside."""
+        if name in CPU_SPANS:
+            tid, cpu0 = threading.get_ident(), time.thread_time()
+        else:
+            tid = cpu0 = None
         with self._slock:
             if self._done:
                 return
             self._open.append([name, time.monotonic() if _t0 is None
-                               else _t0, args or None])
+                               else _t0, args or None, tid, cpu0])
 
-    def span_end(self, name: str | None = None) -> float | None:
+    @staticmethod
+    def _cpu_since(tid, cpu0) -> float | None:
+        if tid is None or tid != threading.get_ident():
+            return None
+        return max(0.0, time.thread_time() - cpu0)
+
+    def span_end(self, name: str | None = None, **args) -> float | None:
         """Close the most recent open span (matching `name` when
         given); a no-op when nothing matches — layers may race the
-        op's finish and must never raise.  Returns the close stamp so
-        an adjacent span can begin at exactly the same instant."""
+        op's finish and must never raise.  `args` join the ones given
+        at its start.  Returns the close stamp so an adjacent span can
+        begin at exactly the same instant."""
         with self._slock:
             if not self._open:
                 return None
@@ -194,17 +262,23 @@ class TrackedOp:
                     idx -= 1
                 if idx < 0:
                     return None
-            nm, t0, args = self._open.pop(idx)
+            nm, t0, a0, tid, cpu0 = self._open.pop(idx)
+            cpu = self._cpu_since(tid, cpu0)
             t1 = time.monotonic()
-            self.spans.append([nm, t0, t1, args])
+            if args:
+                a0 = dict(a0 or {}, **args)
+            self.spans.append([nm, t0, t1, a0, cpu])
             return t1
 
-    def add_span(self, name: str, t0: float, t1: float, **args) -> None:
+    def add_span(self, name: str, t0: float, t1: float,
+                 _cpu: float | None = None, **args) -> None:
+        """A closed span from stamps taken elsewhere; `_cpu` where the
+        thread that took them read its CPU clock too."""
         with self._slock:
             if self._done:
                 return
             self.spans.append([name, float(t0), float(t1),
-                               args or None])
+                               args or None, _cpu])
 
     # -- lifecycle ---------------------------------------------------------
 
@@ -215,8 +289,9 @@ class TrackedOp:
             if self._done:
                 return
             while self._open:                # auto-close (replica_wait
-                nm, t0, args = self._open.pop()   # ends at reply)
-                self.spans.append([nm, t0, now_m, args])
+                nm, t0, args, tid, cpu0 = self._open.pop()  # ends at reply)
+                self.spans.append([nm, t0, now_m, args,
+                                   self._cpu_since(tid, cpu0)])
             self.mend = now_m
             self.events.append((now_c, now_m, "done"))
             self._done = True
@@ -235,19 +310,24 @@ class TrackedOp:
         with self._slock:
             events = list(self.events)
             spans = list(self.spans)
-        return {"description": self.desc,
-                "trace_id": self.trace_id,
-                "kind": self.kind,
-                "daemon": self._tracker.daemon,
-                "initiated_at": self.start,
-                "age": self._tracker.clock.now() - self.start,
-                "mstart": self.mstart,
-                "duration": round(self.duration, 6),
-                "events": [{"time": t, "mtime": mt, "event": e}
-                           for t, mt, e in events],
-                "spans": [{"name": nm, "t0": t0, "t1": t1,
-                           **({"args": args} if args else {})}
-                          for nm, t0, t1, args in spans]}
+        doc = {"description": self.desc,
+               "trace_id": self.trace_id,
+               "kind": self.kind,
+               "daemon": self._tracker.daemon,
+               "initiated_at": self.start,
+               "age": self._tracker.clock.now() - self.start,
+               "mstart": self.mstart,
+               "mstart_ns": self.mstart_ns,
+               "duration": round(self.duration, 6),
+               "events": [{"time": t, "mtime": mt, "event": e}
+                          for t, mt, e in events],
+               "spans": [{"name": nm, "t0": t0, "t1": t1,
+                          **({"cpu": cpu} if cpu is not None else {}),
+                          **({"args": args} if args else {})}
+                         for nm, t0, t1, args, cpu in spans]}
+        if self.attempt is not None:
+            doc["attempt"] = self.attempt
+        return doc
 
 
 class _NullOp:
@@ -270,10 +350,11 @@ class _NullOp:
                    **args) -> None:
         pass
 
-    def span_end(self, name: str | None = None) -> float | None:
+    def span_end(self, name: str | None = None, **args) -> float | None:
         return None
 
-    def add_span(self, name: str, t0: float, t1: float, **args) -> None:
+    def add_span(self, name: str, t0: float, t1: float,
+                 _cpu: float | None = None, **args) -> None:
         pass
 
     def finish(self) -> None:
@@ -312,11 +393,11 @@ class OpTracker:
         self._complained: set[int] = set()
 
     def create(self, desc: str, trace_id: str = "",
-               kind: str = "client"):
+               kind: str = "client", attempt: int | None = None):
         if not self.enabled:
             return _NullOp(self.clock.now(), trace_id)
         op = TrackedOp(self, desc, self.clock.now(), trace_id=trace_id,
-                       kind=kind)
+                       kind=kind, attempt=attempt)
         with self._lock:
             self._seq += 1
             op._id = self._seq
