@@ -207,6 +207,12 @@ class Rados:
         self.msgr.shutdown()
         self._connected = False
 
+    def perf_dump(self) -> dict:
+        """The client's `perf dump`: the objecter's block (sends and
+        resends by cause, kicked connections, each target's resend
+        timeout) and the messenger's counters."""
+        return {**self.objecter.perf_dump(), "msgr": self.msgr.perf.dump()}
+
     # -- cluster admin -----------------------------------------------------
 
     def mon_command(self, cmd: dict, timeout: float = 30.0):

@@ -40,7 +40,7 @@ from ..auth import cephx
 from ..utils import faults
 from .message import Message
 from .messenger import (AuthError, BANNER_MAGIC, Policy, _BANNER,
-                        _BANNER_REPLY, _pack_addr, _unpack_addr,
+                        _BANNER_REPLY, _forget, _pack_addr, _unpack_addr,
                         stamp_received)
 
 _READ = 1       # selectors.EVENT_READ
@@ -517,6 +517,7 @@ def _frames_gen(msgr, conn, sock: _Sock, skey, accepted: bool):
         fs = faults.get()
         if fs.partitioned(conn.peer_name, msgr.name):
             raise ConnectionResetError("partitioned")
+        conn.last_recv = time.monotonic()
         if type_id == msgr.ACK_TYPE:
             conn._handle_ack(seq)
             continue
@@ -565,7 +566,7 @@ class AsyncConnection:
         self._sent: list[tuple[int, list]] = []     # sent, not yet acked
         self._writer = None      # the OPEN out-_Sock (None while down;
         self._closed = False     # MonClient probes this for liveness)
-        self.last_active = time.time()
+        self.last_recv = 0.0     # see Connection.last_recv
         self._socks: set[_Sock] = set()
         self._out_running = False
         self._backoff = float(msgr.conf.ms_initial_backoff)
@@ -609,6 +610,7 @@ class AsyncConnection:
                            if s > peer_in_seq]
 
     def mark_down(self) -> None:
+        _forget(self)
         self.worker.call(self._close)
 
     def _close(self) -> None:
@@ -828,7 +830,6 @@ class AsyncConnection:
                     self._queue.pop(0)
                     if not self.policy.lossy:
                         self._sent.append((s, f))
-                self.last_active = time.time()
                 self._pump()
             sock.send_iov(iov, on_done=_done)
             return

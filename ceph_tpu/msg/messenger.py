@@ -106,6 +106,16 @@ def stamp_received(msg: Message, stamps: tuple) -> None:
      msg._recv_complete_cpu, msg._recv_bytes) = stamps
 
 
+def _forget(conn) -> None:
+    """mark_down, both stacks: the connection leaves its messenger's
+    table in the caller's thread, so the caller's next send dials a
+    fresh session; queued behind the close on the loop it would be
+    dropped with the old one."""
+    conns = conn.msgr.conns
+    if conns.get(conn.peer_name) is conn:
+        conns.pop(conn.peer_name, None)
+
+
 class Dispatcher:
     """Interface daemons implement to receive messages."""
 
@@ -145,7 +155,9 @@ class Connection:
         self._closed = False
         self._send_event = asyncio.Event()
         self._task: asyncio.Task | None = None
-        self.last_active = time.time()
+        # time.monotonic() of the last frame read from the peer on any
+        # socket of this link, acks included; 0 until there is one
+        self.last_recv = 0.0
         msgr.perf.inc("open_connections")
         self._counted = True
 
@@ -182,6 +194,7 @@ class Connection:
                            if s > peer_in_seq]
 
     def mark_down(self) -> None:
+        _forget(self)
         self.msgr._loop_call(self._close)
 
     def _close(self) -> None:
@@ -655,6 +668,12 @@ class Messenger:
                 # The frame is an iovec — header, seg table, payload,
                 # data segments — gather-written as-is; the signature
                 # folds the buffers without joining them.
+                if writer.is_closing():
+                    # marked down, or reset by the peer, while this
+                    # task slept: asyncio (3.12) clears a lost
+                    # transport's write hook and calls it all the same
+                    # (TypeError: 'NoneType' object is not callable)
+                    raise ConnectionResetError("closed")
                 if skey is None:
                     writer.writelines(frame)
                 else:
@@ -665,7 +684,6 @@ class Messenger:
                 if not conn.policy.lossy:
                     # lossless: keep until the peer acks the seq
                     conn._sent.append((seq, frame))
-                conn.last_active = time.time()
             conn._send_event.clear()
             await conn._send_event.wait()
 
@@ -808,6 +826,7 @@ class Messenger:
                     # delivered and a lossless peer resends it after
                     # the heal
                     raise ConnectionResetError("partitioned")
+                conn.last_recv = time.monotonic()
                 if type_id == self.ACK_TYPE:
                     conn._handle_ack(seq)
                     continue

@@ -241,13 +241,22 @@ _opt("objecter_op_timeout", float, 30.0,
      "per-op deadline: an op not acked within this window fails with "
      "ETIMEDOUT (110) instead of hanging on a dead primary")
 _opt("objecter_backoff_base", float, 0.5,
-     "first resend interval for a silent op; doubles per silent try")
+     "floor of the resend timer: a silent op is first resent after the "
+     "reply latency seen from its target (smoothed latency + 4 "
+     "deviations of ops answered on their first send), never sooner "
+     "than this; also the timer of a target nothing is known of and of "
+     "an idle cluster, whose replies take milliseconds.  Doubles per "
+     "silent try")
 _opt("objecter_backoff_max", float, 5.0,
-     "resend interval cap for the exponential backoff")
+     "cap on the doubling of the resend timer; not a ceiling under the "
+     "target's observed timeout: where replies take longer than this, "
+     "the observed timeout is the cap")
 _opt("objecter_silent_kick", float, 6.0,
-     "seconds of continuous silence on one primary's link before the "
-     "connection is marked down and redialed; must exceed a slow-but-"
-     "alive op's service time or the kick drops its in-flight reply")
+     "seconds of silence of the LINK to a primary (no reply, no "
+     "messenger ack, no frame of any op, the waiting op's last send "
+     "unanswered too) before the connection is marked down and "
+     "redialed; a slow op on a link that carries acks and other ops' "
+     "replies is never a reason")
 
 # -- rgw -------------------------------------------------------------------
 _opt("rgw_sync_retries", int, 3,

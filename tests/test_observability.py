@@ -76,6 +76,12 @@ class TestCounterSchema:
             # accepted-socket handshakes
             "event_workers", "open_connections", "event_wakeups",
             "partial_write_resumes", "accepts"}
+    # the client's objecter: sends, resends by cause (the timer that
+    # follows the target's reply latency, a session reset, a map
+    # change, an EAGAIN) and connections marked down as silent
+    OBJECTER = {"op_send", "op_resend", "op_resend_timer",
+                "op_resend_reset", "op_resend_map", "op_resend_eagain",
+                "conn_kick"}
     MON = {"elections_won", "elections_lost", "commands"}
     PAXOS = {"collect", "begin", "commit", "lease"}
     # multisite replication agent: rounds attempted, per-bucket/round
@@ -90,6 +96,27 @@ class TestCounterSchema:
         osd = next(iter(cluster.osds.values()))
         assert set(osd.perf._schema) == self.OSD
         assert set(osd.msgr.perf._schema) == self.MSGR
+
+    def test_client_schema_complete(self, cluster, io):
+        """The client's `perf dump`: the objecter block's counters plus
+        the ops in flight and each target's resend timeout, and the
+        messenger's set; healthy I/O moves sends and nothing else."""
+        rados = io.rados
+        assert set(rados.objecter.perf._schema) == self.OBJECTER
+        io.read("warm")
+        dump = rados.perf_dump()
+        assert set(dump) == {"objecter", "msgr"}
+        assert set(dump["msgr"]) == self.MSGR
+        obj = dump["objecter"]
+        assert set(obj) == self.OBJECTER | {"ops_in_flight",
+                                            "resend_timeout"}
+        assert obj["op_send"] >= 2 and obj["conn_kick"] == 0
+        assert obj["ops_in_flight"] == 0
+        assert obj["resend_timeout"]
+        for target, secs in obj["resend_timeout"].items():
+            kind, _, rw = target.partition("/")
+            assert kind.startswith("osd.") and rw in ("read", "write")
+            assert secs >= float(cluster.conf.objecter_backoff_base)
 
     def test_mon_schema_complete(self, cluster):
         mon = cluster.leader()

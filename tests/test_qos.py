@@ -555,5 +555,9 @@ class TestRecoveryDecodeLane:
             assert "@recovery" in dec_classes, \
                 (dec_classes, picks[-20:])
         finally:
-            pipe.submit = orig
+            # not `pipe.submit = orig`: that leaves the bound method ON
+            # the process-wide instance, where it shadows the class's
+            # for every later test of this worker (test_copy_audit
+            # patches the class)
+            del pipe.submit
             cluster.stop()
