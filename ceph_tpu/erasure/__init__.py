@@ -11,7 +11,8 @@ Plugins (mirroring ErasureCodePluginRegistry's dlopen set):
   tpu       — the north-star device backend (all matrix techniques)
   jerasure  — numpy-exact port of jerasure techniques (correctness oracle)
   isa       — ISA-L matrix semantics (reed_sol_van / cauchy), table cache
-  shec      — shingled EC with exhaustive decoding-matrix search
+  shec      — shingled EC (not MDS: decode by plan); the tpu plugin's
+              shec_multiple / shec_single techniques under its own name
   lrc       — locally repairable codes by layered composition
 """
 

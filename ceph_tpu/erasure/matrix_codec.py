@@ -2,9 +2,11 @@
 
 The jerasure, isa and tpu plugins all reduce to: build an (m x k) coding
 matrix over GF(2^8) for a named technique, encode as matrix x data, decode
-by inverting the surviving generator rows.  This module holds the
-technique table, the decode-matrix planner + cache, and two compute
-backends over the same representation:
+by inverting the surviving generator rows.  The shec techniques are the
+same with a coding matrix that is not MDS: which chunks decode, and by
+which rows, comes from a plan (`MatrixErasureCode._plan`) and not from
+"any k".  This module holds the technique table, the decode-matrix
+planner + cache, and two compute backends over the same representation:
 
   * NumpyBackend — exact host reference (the correctness oracle, analog
     of the reference's gf-complete scalar path);
@@ -96,6 +98,18 @@ def _liber8tion(k, m, w, packetsize):
         raise ErasureCodeError(str(e))
 
 
+def _shec(single: bool):
+    def build(k, m, w, packetsize, c):
+        if not 0 < c <= m <= k:
+            raise ErasureCodeError(
+                f"require 0 < c <= m <= k, got k={k} m={m} c={c}")
+        try:
+            return gf.shec_matrix(k, m, c, single)
+        except ValueError as e:
+            raise ErasureCodeError(str(e))
+    return build
+
+
 TECHNIQUES: dict[str, tuple] = {
     "reed_sol_van": (_rs_van, REP_BYTES),
     "reed_sol_r6_op": (_rs_r6, REP_BYTES),
@@ -109,6 +123,12 @@ TECHNIQUES: dict[str, tuple] = {
     # ISA-L matrix semantics exposed as techniques of the tpu plugin
     "isa_reed_sol_van": (_isa_rs, REP_BYTES),
     "isa_cauchy": (_isa_cauchy, REP_BYTES),
+    # SHEC (ErasureCodeShec.cc) exposed the same way: reed_sol_van rows
+    # zeroed by shingle windows, durability `c`; a third entry marks a
+    # matrix that is not MDS, whose builder takes `c` and whose decode
+    # goes by plan
+    "shec_multiple": (_shec(False), REP_BYTES, "planned"),
+    "shec_single": (_shec(True), REP_BYTES, "planned"),
 }
 
 # techniques whose natural word size is not 8
@@ -226,6 +246,14 @@ class TpuBackend:
                 w, packetsize = extra
                 fn = self._ek.make_packet_codec_fn(matrix, w, packetsize,
                                                    self.compute)
+            if len(self._fns) > 256:
+                # decode patterns first: a "bytes" closure is cheap to
+                # build again and its readiness hangs on the matrix
+                # SHAPE, not on this entry, so dropping them strands
+                # nothing (a degraded pool sees hundreds of patterns)
+                for old in list(self._fns):
+                    if old[0] == "bytes":
+                        self._fns.pop(old, None)
             if len(self._fns) > 256:
                 # readiness is keyed on the fn cache: evicting one
                 # without the other would strand "ready" shapes whose
@@ -515,6 +543,7 @@ class MatrixErasureCode(ErasureCode):
     DEFAULT_W = 8
     DEFAULT_PACKETSIZE = 2048
     DEFAULT_TECHNIQUE = "reed_sol_van"
+    DEFAULT_C = 2               # planned (shec) techniques only
 
     def __init__(self, backend=None, techniques: Mapping[str, tuple] | None = None):
         self.backend = backend or NumpyBackend()
@@ -525,6 +554,9 @@ class MatrixErasureCode(ErasureCode):
         self.coding_matrix: np.ndarray | None = None
         self.generator: np.ndarray | None = None
         self._decode_cache: dict[tuple[int, ...], np.ndarray] = {}
+        # planned techniques: (want, available) -> plan, see _plan
+        self.planned = False
+        self._plan_cache: dict[tuple[frozenset, frozenset], tuple] = {}
         self._fast1 = None
 
     # -- init -------------------------------------------------------------
@@ -546,12 +578,16 @@ class MatrixErasureCode(ErasureCode):
             raise ErasureCodeError(
                 f"unknown technique {self.technique!r}; "
                 f"have {sorted(self.techniques)}")
-        builder, self.rep = self.techniques[self.technique]
+        builder, self.rep, *traits = self.techniques[self.technique]
+        self.planned = "planned" in traits
         if self.rep != REP_BITS and self.w != 8:
             raise ErasureCodeError(
                 f"technique {self.technique} supports w=8 only")
+        extra = ((self.profile_int(profile, "c", self.DEFAULT_C),)
+                 if self.planned else ())
         self.coding_matrix = np.asarray(
-            builder(self.k, self.m, self.w, self.packetsize), dtype=np.uint8)
+            builder(self.k, self.m, self.w, self.packetsize, *extra),
+            dtype=np.uint8)
         if self.rep == REP_BITS:
             # native GF(2): generator = [identity; coding bits]
             self.generator = None
@@ -562,6 +598,11 @@ class MatrixErasureCode(ErasureCode):
             self.generator = gf.systematic_generator(
                 self.coding_matrix, self.k)
         self._decode_cache.clear()
+        self._plan_cache.clear()
+        # the data chunks each parity covers (what a plan reads)
+        self._support = [frozenset(np.flatnonzero(row).tolist())
+                         for row in self.coding_matrix] \
+            if self.planned else []
         self._fast1 = self._build_fast1()
 
     def _build_fast1(self):
@@ -636,32 +677,144 @@ class MatrixErasureCode(ErasureCode):
 
     # -- decode -----------------------------------------------------------
 
+    def _note_plan_miss(self) -> None:
+        """`perf dump`: decode patterns computed (a plan search or a
+        decode matrix), beside `decode_plans`, the patterns cached."""
+        c = self.stat_counters()
+        c["decode_plan_misses"] = c.get("decode_plan_misses", 0) + 1
+        c["decode_plans"] = len(self._decode_cache) + len(self._plan_cache)
+
+    def _plan(self, want: frozenset, avail: frozenset) -> tuple:
+        """A planned (non-MDS) technique's way to `want` from `avail`:
+        (chunks to read, parities used, unknown data chunks, the
+        inverse of the parities' rows restricted to the unknowns).
+
+        Of the subsets of the available parities, the one that reads
+        the fewest chunks among those whose rows, restricted to the
+        data chunks they touch that are not available, are square and
+        invertible over GF(2^8) (the reference's search for a decoding
+        matrix, ErasureCodeShec.cc shec_make_decoding_matrix).  Raises
+        ErasureCodeError when no subset is: more than c chunks lost,
+        in a pattern the shingles do not cover."""
+        key = (want, avail)
+        plan = self._plan_cache.get(key)
+        if plan is not None:
+            return plan
+        k, cm, support = self.k, self.coding_matrix, self._support
+        # data chunks to produce: wanted ones, and what a wanted
+        # parity that is not available is computed from
+        need0 = {i for i in want if i < k}
+        for p in want:
+            if p >= k and p not in avail:
+                need0 |= support[p - k]
+        parities = sorted(i - k for i in avail if i >= k)
+        best, best_read = None, None
+        # one equation a parity: fewer parities than unknowns cannot
+        # decode, whatever they cover (the cheap refusal a gather asks
+        # for after every arrival)
+        if len(parities) >= len(need0 - avail):
+            from ..utils import optracker
+            with optracker.span("ec.plan", want=sorted(want),
+                                present=sorted(avail)):
+                self._note_plan_miss()
+                for mask in range(1 << len(parities)):
+                    ps = [p for i, p in enumerate(parities) if mask >> i & 1]
+                    need = need0.union(*(support[p] for p in ps))
+                    unknowns = sorted(need - avail)
+                    if len(unknowns) != len(ps):
+                        continue
+                    read = len(need & avail) + len(ps)
+                    if best is not None and read >= best_read:
+                        continue
+                    inv = np.zeros((0, 0), dtype=np.uint8)
+                    if ps:
+                        try:
+                            inv = gf.gf_mat_inv(cm[np.ix_(ps, unknowns)])
+                        except np.linalg.LinAlgError:
+                            continue
+                    fetch = (need & avail) | {p + k for p in ps} \
+                        | {p for p in want if p >= k and p in avail}
+                    best, best_read = (frozenset(fetch), tuple(ps),
+                                       tuple(unknowns), inv), read
+        if best is None:
+            raise ErasureCodeError(
+                f"cannot decode {sorted(want)} from {sorted(avail)}")
+        if len(self._plan_cache) > 1024:
+            self._plan_cache.clear()
+        # the plan's own chunks decode by the same plan
+        self._plan_cache[key] = self._plan_cache[(want, best[0])] = best
+        return best
+
+    def minimum_to_decode(self, want_to_read, available) -> list[int]:
+        if not self.planned:
+            return super().minimum_to_decode(want_to_read, available)
+        want = frozenset(int(i) for i in want_to_read)
+        avail = frozenset(int(i) for i in available)
+        if want <= avail:
+            return sorted(want)
+        return sorted(self._plan(want, avail)[0])
+
+    def _planned_rows(self, want: Sequence[int],
+                      present: Sequence[int]) -> np.ndarray:
+        """The plan's solved system as a matrix over `present`: every
+        data chunk the plan touches is a combination of the chunks
+        read (itself, if it was read; for an unknown, the inverse's
+        row applied to each parity minus its known terms), a wanted
+        parity the product of its coding row with those.  Chunks of
+        `present` the plan does not read get zero columns."""
+        k, cm = self.k, self.coding_matrix
+        _fetch, ps, unknowns, inv = self._plan(frozenset(want),
+                                               frozenset(present))
+        col = {c: i for i, c in enumerate(present)}
+        unit = np.eye(len(present), dtype=np.uint8)
+        # row d: data chunk d as a combination of `present` (zero
+        # while unknown, and for the chunks the plan does not touch)
+        data = np.zeros((k, len(present)), dtype=np.uint8)
+        for d in present:
+            if d < k:
+                data[d] = unit[col[d]]
+        if ps:
+            rhs = unit[[col[p + k] for p in ps]] \
+                ^ gf.gf_matmul(cm[list(ps)], data)
+            data[list(unknowns)] = gf.gf_matmul(inv, rhs)
+        return np.stack([
+            unit[col[c]] if c in col else data[c] if c < k
+            else gf.gf_matmul(cm[c - k][None, :], data)[0]
+            for c in want]).astype(np.uint8)
+
     def _decode_rows(self, want: Sequence[int],
                      present: Sequence[int]) -> np.ndarray:
-        """(len(want) x len(present)) matrix rebuilding `want` from `present`."""
+        """(len(want) x len(present)) matrix rebuilding `want` from
+        `present`, cached by pattern."""
         key = (tuple(want), tuple(present))
         cached = self._decode_cache.get(key)
         if cached is not None:
             return cached
-        if self.rep == REP_BITS:
-            out = gf.bitmatrix_decode_rows(
-                self.gen_bits, self.k, self.w, list(want), list(present))
+        from ..utils import optracker
+        with optracker.span("ec.plan", want=list(want),
+                            present=list(present)):
+            if self.rep == REP_BITS:
+                out = gf.bitmatrix_decode_rows(
+                    self.gen_bits, self.k, self.w, list(want),
+                    list(present))
+            elif self.planned:
+                out = self._planned_rows(want, present)
+            else:
+                inv = gf.decode_matrix(self.generator, self.k,
+                                       list(present))
+                rows = []
+                for c in want:
+                    if c < self.k:
+                        rows.append(inv[c])
+                    else:
+                        rows.append(gf.gf_matmul(
+                            self.coding_matrix[c - self.k][None, :],
+                            inv)[0])
+                out = np.stack(rows).astype(np.uint8)
             if len(self._decode_cache) > 512:
                 self._decode_cache.clear()
             self._decode_cache[key] = out
-            return out
-        inv = gf.decode_matrix(self.generator, self.k, list(present))
-        rows = []
-        for c in want:
-            if c < self.k:
-                rows.append(inv[c])
-            else:
-                rows.append(gf.gf_matmul(
-                    self.coding_matrix[c - self.k][None, :], inv)[0])
-        out = np.stack(rows).astype(np.uint8)
-        if len(self._decode_cache) > 512:
-            self._decode_cache.clear()
-        self._decode_cache[key] = out
+            self._note_plan_miss()
         return out
 
     def encode_stripes_with_crcs(self, stripes) -> tuple:

@@ -6,6 +6,9 @@ jerasure/CMakeLists.txt:94-97) with ONE backend: every matrix technique
 becomes a batched GF(2) matmul on the TPU MXU (ceph_tpu.ops.ec_kernels).
 
 Profile keys beyond the standard k/m/w/technique/packetsize:
+  c=N                   technique=shec_multiple|shec_single only: the
+                        lost chunks the shingled code survives
+                        (0 < c <= m <= k; ErasureCodeShec's `c`)
   compute=int8|bf16     MXU accumulation path (default int8)
   batch_stripes=N       coalesce-size hint for the shared device
                         pipeline: at most N stripes fuse into one
@@ -405,6 +408,16 @@ class ErasureCodeTpu(MatrixErasureCode):
         if self.rep != REP_BYTES or chunks.ndim != 3 or \
                 rows.shape[0] == 0:
             return _Done(self._apply(rows, chunks))
+        short = self.k - len(present)
+        if short > 0:
+            # a plan that reads fewer than k chunks (shec's local
+            # repair) rides the (r x k) operand and the (B, k, L)
+            # stack every other decode uses: zero columns, zero-filled
+            # chunks.  No executable of its own to compile, every
+            # decode of one row count shares a dispatch shape, and the
+            # kernel's work stays 2*8k*8r*L a stripe.
+            rows = np.pad(rows, ((0, 0), (0, short)))
+            chunks = np.pad(chunks, ((0, 0), (0, short), (0, 0)))
         chan = self._decode_channel(want, present, rows,
                                     chunks.shape[2])
         return _PipelinedDecode(

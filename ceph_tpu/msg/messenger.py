@@ -54,6 +54,13 @@ _BANNER = struct.Struct("<4sQII")    # magic, nonce, name len, addr-blob len
 _BANNER_REPLY = struct.Struct("<4sQ")  # magic, acceptor's in_seq
 _ADDR = struct.Struct("<HI")         # host length, port
 BANNER_MAGIC = b"CTB2"
+# An accepted stream buffers twice this before it stops reading its
+# socket.  asyncio's 64 KiB default stops and starts the socket (two
+# epoll calls and a task switch) every chunk of a large frame: a 4 MiB
+# reply in 1,024 rope segments kept the client's loop thread at 47% of
+# its time in pause_reading / resume_reading (chip run, PR 28).  Sized
+# to hold a rados-bench object whole.
+STREAM_LIMIT = 4 << 20
 
 
 def _pack_addr(addr: "EntityAddr") -> bytes:
@@ -414,7 +421,8 @@ class Messenger:
 
     async def _bind_server(self) -> None:
         host, port = self.addr
-        self._server = await asyncio.start_server(self._accept, host, port)
+        self._server = await asyncio.start_server(
+            self._accept, host, port, limit=STREAM_LIMIT)
         if port == 0:     # ephemeral: learn the real port
             sock = self._server.sockets[0]
             self.addr = (host, sock.getsockname()[1])

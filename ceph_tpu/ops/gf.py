@@ -274,6 +274,66 @@ def cauchy_good_matrix(k: int, m: int) -> np.ndarray:
     return mtx
 
 
+def _shec_windows(k: int, groups) -> list[tuple[int, int, int]]:
+    """(first covered column, one past the last, width) of every parity
+    row's shingle, group after group; columns wrap modulo k."""
+    return [((rr * k) // mg % k, ((rr + cg) * k) // mg % k,
+             ((rr + cg) * k) // mg - (rr * k) // mg)
+            for mg, cg in groups for rr in range(mg)]
+
+
+def _shec_recovery_efficiency(k: int, groups) -> float:
+    """The reference's r_e1 (ErasureCodeShec.cc
+    shec_calc_recovery_efficiency1): the chunks read to rebuild one
+    lost chunk, averaged over the k data chunks (each by its
+    narrowest covering shingle) and the parities (by their width)."""
+    narrowest = [10 ** 8] * k
+    total = 0
+    for start, end, width in _shec_windows(k, groups):
+        cc, first = start, True
+        while first or cc != end:
+            first = False
+            narrowest[cc] = min(narrowest[cc], width)
+            cc = (cc + 1) % k
+        total += width
+    return (total + sum(narrowest)) / (k + sum(mg for mg, _c in groups))
+
+
+def shec_matrix(k: int, m: int, c: int, single: bool = False) -> np.ndarray:
+    """(m x k) SHEC coding matrix (ErasureCodeShec.cc
+    shec_reedsolomon_coding_matrix): the `reed_sol_van` rows, each
+    zeroed outside its shingle.  The m parities form two groups (m1,
+    c1) and (m2, c2) = (m - m1, c - c1); in row rr of a group (mg, cg)
+    the columns from ((rr+cg)*k/mg) % k round to (rr*k/mg) % k are
+    zero.  Technique `single` is one group (m, c); `multiple` takes
+    the split with the least recovery efficiency r_e1, the first such
+    in the order c1 = 0..c/2, m1 = 0..m.  The code is not MDS: it
+    survives any c lost chunks, not any m."""
+    if single:
+        groups = [(0, 0), (m, c)]
+    else:
+        best = None
+        for c1 in range(c // 2 + 1):
+            for m1 in range(m + 1):
+                m2, c2 = m - m1, c - c1
+                if m1 < c1 or m2 < c2 or (m1 == 0) != (c1 == 0) \
+                        or (m2 == 0) != (c2 == 0):
+                    continue
+                r = _shec_recovery_efficiency(k, [(m1, c1), (m2, c2)])
+                if best is None or r < best[0] - 1e-12:
+                    best = (r, [(m1, c1), (m2, c2)])
+        if best is None:
+            raise ValueError(f"no valid shec split for k={k} m={m} c={c}")
+        groups = best[1]
+    mtx = reed_sol_van_matrix(k, m).copy()
+    for rr, (start, end, _w) in enumerate(_shec_windows(k, groups)):
+        cc = end
+        while cc != start:
+            mtx[rr, cc] = 0
+            cc = (cc + 1) % k
+    return mtx
+
+
 def systematic_generator(coding: np.ndarray, k: int) -> np.ndarray:
     """Stack identity over the coding rows: full (k+m) x k generator."""
     return np.concatenate([np.eye(k, dtype=np.uint8), coding], axis=0)

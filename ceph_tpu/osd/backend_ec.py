@@ -13,9 +13,10 @@ from __future__ import annotations
 import numpy as np
 
 from ..crush.map import ITEM_NONE
+from ..erasure.interface import ErasureCodeError
 from ..ops import crc32c as crc_mod
 from ..ops import hbm_cache
-from ..store.objectstore import ENOENT, StoreError, Transaction
+from ..store.objectstore import EIO, ENOENT, StoreError, Transaction
 from ..utils import denc, optracker
 from ..utils.bufferlist import BufferList
 from . import ecutil
@@ -23,6 +24,31 @@ from .messages import (MOSDECSubOpReadReply, MOSDECSubOpWrite,
                        MOSDECSubOpWriteReply, MPGInfo, sender_id)
 from .pglog import (HINFO_KEY, VER_KEY, ZERO_EV, _parse_ev, shard_oid,
                     stash_oid)
+
+
+_UNREAD = object()      # _ec_read: the object's bytes not gathered yet
+
+
+class _EcRead:
+    """One reconstructing read of an EC object, between its steps:
+    the shards in hand (`have`, their applied versions where the read
+    is version-gated, one `hinfo`), whom the next gather asks
+    (`targets`), and whether this is the last-resort sweep."""
+
+    __slots__ = ("oid", "exclude", "need_ver", "qos", "interval", "have",
+                 "vers", "hinfo", "targets", "sweep", "strict_have",
+                 "replans")
+
+    def __init__(self, oid, exclude, need_ver, qos, interval):
+        self.oid, self.exclude, self.need_ver = oid, exclude, need_ver
+        self.qos, self.interval = qos, interval
+        self.have: dict[int, bytes] = {}
+        self.vers: dict[int, tuple] = {}      # shard -> applied version
+        self.hinfo = None
+        self.targets: list[tuple[int, int]] = []
+        self.sweep = False
+        self.strict_have: set[int] = set()    # the acting pass's shards
+        self.replans = 0        # k or more in hand and no decode yet
 
 
 class ECBackend:
@@ -307,7 +333,7 @@ class ECBackend:
                     remote.append((i, holder))
             if remote:
                 fetched = self.osd.ec_fetch_shards(
-                    self.pgid, oid, remote, off=chunk_off, length=L)
+                    self.pgid, oid, remote, off=chunk_off, length=L).out
                 for i, _h in remote:
                     if i not in fetched:
                         return False
@@ -634,6 +660,18 @@ class ECBackend:
 
     # ---- EC read path ----------------------------------------------------
 
+    #
+    # A reconstructing read goes in steps: `_ec_read_begin` (HBM cache,
+    # the shards this OSD holds, whom to ask for the rest), one gather
+    # of sub-reads, `_ec_read_step` (decode — or, when the acting
+    # holders did not give a decodable set, the last-resort sweep,
+    # which is a second gather and a second step).  `_ec_read_local`
+    # walks them on the calling thread (rebuild, scrub repair, the
+    # append's read-modify-write); a client read PARKS between steps
+    # as a write parks in `replica_wait`: the op worker goes on to the
+    # next op, the gather's completion re-queues the read, and the
+    # `sub_read` ops it waits for are never queued behind it.
+
     def _ec_read_local(self, oid: str,
                        exclude: set | None = None,
                        need_ver: tuple | None = None,
@@ -646,7 +684,17 @@ class ECBackend:
         `qos` names the dmClock class any decode dispatch bills
         against (rebuild reads ride @recovery under the repair cap,
         like the rebuild's re-encode)."""
-        exclude = exclude or set()
+        rd = self._ec_read_begin(oid, exclude, need_ver, qos)
+        while isinstance(rd, _EcRead):
+            rd = self._ec_read_step(rd, self._ec_read_fetch(rd))
+        return rd
+
+    def _ec_read_begin(self, oid: str, exclude: set | None = None,
+                       need_ver: tuple | None = None,
+                       qos: str | None = None):
+        """The object's bytes if the HBM cache holds them, else the
+        read's state after the local shards: an `_EcRead` to gather
+        for."""
         # HBM stripe cache fast path: a committed entry at the
         # object's CURRENT version serves the whole payload straight
         # from the chip — no shard gather, no decode matmul, no H2D
@@ -663,54 +711,93 @@ class ECBackend:
                 data = ent.data_bytes()
                 if data is not None:
                     return data
-        codec = self._ec_codec()
-        k = codec.get_data_chunk_count()
+        rd = _EcRead(oid, exclude or set(), need_ver, qos,
+                     self.interval_epoch)
         store = self.osd.store
-        my_shard = self.role_of(self.osd.whoami)
-        have: dict[int, bytes] = {}
-        vers: dict[int, tuple] = {}      # shard -> applied version
-        hinfo = None
         for shard, osd_id in enumerate(self.acting):
-            if osd_id == ITEM_NONE or shard in exclude:
+            if osd_id != self.osd.whoami or shard in rd.exclude:
                 continue
             soid = shard_oid(oid, shard)
-            if osd_id == self.osd.whoami:
-                try:
-                    if need_ver is not None:
-                        mine = _parse_ev(store.getattr(self.cid, soid,
-                                                       VER_KEY))
-                        if mine is None or mine < tuple(need_ver):
-                            continue
-                        vers[shard] = mine
-                    have[shard] = store.read(self.cid, soid)
-                    hinfo = denc.loads(store.getattr(self.cid, soid,
-                                                     HINFO_KEY))
-                except StoreError:
-                    pass
-            if len(have) >= k:
-                break
-        # fetch the rest synchronously from peers.  DEGRADED READS:
-        # the gather early-completes once k shards exist — any k of
-        # the k+m live shards reconstruct the object (ECBackend
-        # get_min_avail_to_read_shards semantics), so a down holder
-        # costs nothing when the live ones reach k, and is still
-        # TRIED when they cannot (a wrongly-marked-down daemon may
-        # well answer)
-        if len(have) < k or hinfo is None:
-            fetched = self.osd.ec_fetch_shards(
-                self.pgid, oid,
-                [(s, o) for s, o in enumerate(self.acting)
-                 if o != ITEM_NONE and s not in have and s not in exclude
-                 and o != self.osd.whoami],
-                need_ver=need_ver,
-                need=max(1, k - len(have)))
-            for shard, (data, hi, ver) in fetched.items():
-                have[shard] = data
-                if ver is not None:
-                    vers[shard] = tuple(ver)
-                if hinfo is None and hi is not None:
-                    hinfo = hi
-        if hinfo is None or len(have) < k:
+            try:
+                if need_ver is not None:
+                    mine = _parse_ev(store.getattr(self.cid, soid,
+                                                   VER_KEY))
+                    if mine is None or mine < tuple(need_ver):
+                        continue
+                    rd.vers[shard] = mine
+                rd.have[shard] = store.read(self.cid, soid)
+                rd.hinfo = denc.loads(store.getattr(self.cid, soid,
+                                                    HINFO_KEY))
+            except StoreError:
+                pass
+        # every other live holder is asked, and the first set that
+        # DECODES serves the read (the reference's fast_read): for an
+        # MDS code any k of the k+m shards (ECBackend
+        # get_min_avail_to_read_shards), for shec what its plan
+        # accepts.  A down holder costs nothing when the live ones
+        # do, and is still TRIED when they cannot (a wrongly-marked-
+        # down daemon may well answer).
+        rd.targets = [(s, o) for s, o in enumerate(self.acting)
+                      if o != ITEM_NONE and s not in rd.have
+                      and s not in rd.exclude and o != self.osd.whoami]
+        return rd
+
+    def _ec_decodable(self, shards) -> bool:
+        """Do these shards give the object?  The codec's word: what
+        it cannot plan a decode of the data chunks from, it refuses."""
+        codec = self._ec_codec()
+        try:
+            codec.minimum_to_decode(
+                range(codec.get_data_chunk_count()), shards)
+        except ErasureCodeError:
+            return False
+        return True
+
+    def _ec_read_fetch(self, rd: "_EcRead", done=None):
+        """The gather of one step: complete when what is in hand
+        decodes, or nothing is outstanding."""
+        k = self._ec_codec().get_data_chunk_count()
+
+        def enough(fetched: set) -> bool:
+            shards = fetched | rd.have.keys()
+            if self._ec_decodable(shards):
+                return True
+            if len(shards) >= k:
+                rd.replans += 1     # as many as an MDS code asks: not
+            return False            # a set this code decodes, go on
+
+        if rd.hinfo is not None and self._ec_decodable(rd.have):
+            rd.targets = []         # what this OSD holds is enough
+        return self.osd.ec_fetch_shards(
+            self.pgid, rd.oid, rd.targets, need_ver=rd.need_ver,
+            enough=enough, done=done)
+
+    def _ec_read_step(self, rd: "_EcRead", gather):
+        """After a gather: the object's bytes, None (unreadable), or
+        the `_EcRead` of the sweep to gather for next."""
+        oid, have = rd.oid, rd.have
+        for shard, (data, hi, ver) in gather.out.items():
+            have[shard] = data
+            if ver is not None:
+                rd.vers[shard] = tuple(ver)
+            if rd.hinfo is None and hi is not None:
+                rd.hinfo = hi
+        codec = self._ec_codec()
+        k = codec.get_data_chunk_count()
+        decodable = rd.hinfo is not None and self._ec_decodable(have)
+        if decodable:
+            # the decode is handed the chunks it uses and no others:
+            # the data chunks in hand, and what the codec's plan reads
+            # to rebuild the rest
+            lost = [i for i in range(k) if i not in have]
+            used = {i for i in have if i < k}.union(
+                codec.minimum_to_decode(lost, have) if lost else ())
+            have = {i: have[i] for i in sorted(used)}
+        gather.stamp(optracker.current(), replans=rd.replans,
+                     chunks=sorted(have))
+        if not decodable:
+            if rd.sweep:
+                return None
             # LAST-RESORT DEGRADED SWEEP: mid-remap (pg_temp release,
             # backfill in flight) shard files can sit on members the
             # acting order no longer points at; ask every up osd for
@@ -719,125 +806,93 @@ class ECBackend:
             # callers too when the gate is at/under our recorded
             # version (the sweep serves exactly that version).
             cur = self.pglog.objects.get(oid)
-            if cur is not None and (need_ver is None
-                                    or tuple(need_ver) <= tuple(cur)):
-                return self._ec_read_sweep(oid, exclude,
-                                           strict_have=set(have),
-                                           qos=qos)
+            if cur is not None and (rd.need_ver is None or
+                                    tuple(rd.need_ver) <= tuple(cur)):
+                return self._ec_sweep_begin(rd, tuple(cur))
             return None
-        if need_ver is not None:
+        if rd.need_ver is not None:
             # the >= gate alone is one-sided: a concurrent NEWER write
             # landing on some sources mid-collection would mix shard
             # generations into one decode.  Require every contributor
             # to report the SAME applied version (mismatch -> the
             # caller's retry/backoff takes another pass).
-            got = {vers.get(s) for s in have}
+            got = {rd.vers.get(s) for s in have}
             if len(got) != 1 or None in got:
-                self.log.info("rebuild read of %s: mixed source "
-                              "versions %s; retrying", oid, vers)
+                self.log.info("%s of %s: mixed source versions %s; "
+                              "retrying", "degraded sweep" if rd.sweep
+                              else "rebuild read", oid, rd.vers)
                 return None
         # stripe-aware reassembly: intact data shards concatenate
         # directly; missing chunks rebuild in one batched pass
         sinfo = ecutil.StripeInfo(
-            k, hinfo.get("stripe_unit") or len(next(iter(have.values()))))
+            k, rd.hinfo.get("stripe_unit") or len(next(iter(have.values()))))
         try:
-            return ecutil.decode_object(codec, sinfo, have,
-                                        hinfo["size"], qos=qos)
+            data = ecutil.decode_object(codec, sinfo, have,
+                                        rd.hinfo["size"], qos=rd.qos)
         except Exception as e:
             self.log.warn("decode %s failed: %s (have %s, size %s)",
-                          oid, e, sorted(have), hinfo.get("size"))
+                          oid, e, sorted(have), rd.hinfo.get("size"))
             return None
+        if rd.sweep:
+            self._ec_sweep_served(rd)
+        return data
 
-    def _ec_read_sweep(self, oid: str, exclude: set | None = None,
-                       strict_have: set | None = None,
-                       qos: str | None = None) -> bytes | None:
+    def _ec_sweep_begin(self, rd: "_EcRead", cur: tuple) -> "_EcRead":
         """Broad degraded read: gather shards from ANY up osd, every
         source gated on the primary's recorded object version (the
-        same-version rule below rejects mixed generations).  This is
-        the fallback when the acting-indexed gather cannot reach k —
-        the shards exist somewhere (a remap in flight moved the roles
-        out from under the acting order) even though the acting set's
+        same-version rule rejects mixed generations).  This is the
+        fallback when the acting-indexed gather cannot decode — the
+        shards exist somewhere (a remap in flight moved the roles out
+        from under the acting order) even though the acting set's
         holders do not serve them."""
-        exclude = exclude or set()
-        cur = self.pglog.objects.get(oid)
-        if cur is None:
-            return None
-        need_ver = tuple(cur)
-        codec = self._ec_codec()
-        k = codec.get_data_chunk_count()
-        km = codec.get_chunk_count()
+        sw = _EcRead(rd.oid, rd.exclude, cur, rd.qos, rd.interval)
+        sw.sweep, sw.strict_have = True, set(rd.have)
+        km = self._ec_codec().get_chunk_count()
         store = self.osd.store
-        have: dict[int, bytes] = {}
-        vers: dict[int, tuple] = {}
-        hinfo = None
         for shard in range(km):        # any shard WE hold post-remap
-            if shard in exclude:
+            if shard in sw.exclude:
                 continue
-            soid = shard_oid(oid, shard)
+            soid = shard_oid(rd.oid, shard)
             try:
                 mine = _parse_ev(store.getattr(self.cid, soid, VER_KEY))
-                if mine is None or mine < need_ver:
+                if mine is None or mine < cur:
                     continue
-                have[shard] = store.read(self.cid, soid)
-                vers[shard] = mine
-                if hinfo is None:
-                    hinfo = denc.loads(store.getattr(self.cid, soid,
-                                                     HINFO_KEY))
+                sw.have[shard] = store.read(self.cid, soid)
+                sw.vers[shard] = mine
+                if sw.hinfo is None:
+                    sw.hinfo = denc.loads(store.getattr(self.cid, soid,
+                                                        HINFO_KEY))
             except StoreError:
                 continue
-        missing = [s for s in range(km)
-                   if s not in have and s not in exclude]
         # every addressable osd is a candidate source — a wrongly-
-        # marked-down daemon often still answers, and the `need`
-        # early-exit keeps live replies from waiting on dead ones
+        # marked-down daemon often still answers, and the gather's
+        # early completion keeps live replies from waiting on dead ones
         peers = [o for o in self.osd.osdmap.osds
                  if o != self.osd.whoami
                  and self.osd.osdmap.get_addr(o) is not None]
-        if missing and peers:
-            fetched = self.osd.ec_fetch_shards(
-                self.pgid, oid, [(s, o) for s in missing for o in peers],
-                need_ver=need_ver, need=max(1, k - len(have)))
-            for shard, (data, hi, ver) in fetched.items():
-                have[shard] = data
-                if ver is not None:
-                    vers[shard] = tuple(ver)
-                if hinfo is None and hi is not None:
-                    hinfo = hi
-        if hinfo is None or len(have) < k:
-            return None
-        got = {vers.get(s) for s in have}
-        if len(got) != 1 or None in got:
-            self.log.info("degraded sweep of %s: mixed source "
-                          "versions %s; retrying", oid, vers)
-            return None
-        sinfo = ecutil.StripeInfo(
-            k, hinfo.get("stripe_unit") or len(next(iter(have.values()))))
-        try:
-            data = ecutil.decode_object(codec, sinfo, have,
-                                        hinfo["size"], qos=qos)
-        except Exception as e:
-            self.log.warn("degraded sweep decode %s failed: %s "
-                          "(have %s)", oid, e, sorted(have))
-            return None
+        sw.targets = [(s, o) for s in range(km)
+                      if s not in sw.have and s not in sw.exclude
+                      for o in peers]
+        return sw
+
+    def _ec_sweep_served(self, sw: "_EcRead") -> None:
         self.log.info("degraded sweep read of %s served from shards "
-                      "%s", oid, sorted(have))
+                      "%s", sw.oid, sorted(sw.have))
         # read-triggered repair: the acting holders that failed the
         # strict pass are missing (or mis-rolled for) their shard —
         # queue a rebuild so placement converges instead of every
         # future read paying the sweep
-        if strict_have is not None and getattr(self, "is_primary",
-                                               False):
+        if getattr(self, "is_primary", False):
             misplaced = [(s, o) for s, o in enumerate(self.acting)
-                         if o != ITEM_NONE and s not in strict_have
-                         and s not in exclude]
+                         if o != ITEM_NONE and s not in sw.strict_have
+                         and s not in sw.exclude]
             # one rebuild per shard: a joint rebuild excludes ALL its
             # target shard ids as sources, which can leave fewer than
             # k — rebuilding singly lets the other misplaced shards
             # serve as (version-gated, swept) sources
             for s, o in misplaced:
-                self.osd.queue_ec_rebuild(self.pgid, oid, need_ver,
+                self.osd.queue_ec_rebuild(self.pgid, sw.oid, sw.need_ver,
                                           [(s, o)])
-        return data
 
     def handle_ec_sub_read(self, conn, msg) -> None:
         with self.lock:
@@ -894,16 +949,60 @@ class ECBackend:
             reply.rpc_tid = getattr(msg, "rpc_tid", None)
             self.osd.send_osd_reply(conn, reply)
 
-    def _ec_read(self, conn, msg) -> None:
+    def _ec_read_park(self, conn, msg, rd: "_EcRead") -> None:
+        """Send the step's sub-reads and give the worker back: the
+        gather's completion (on the messenger or a timer thread, which
+        must not take pg.lock) re-queues the op, whose `queue` span
+        opens again until a worker picks it up.  Caller holds
+        self.lock."""
+        trk = getattr(msg, "_trk", None)
+
+        def gathered(gather) -> None:
+            if trk is not None:
+                trk.span_begin("queue")
+            self.osd.op_wq.queue(
+                self.pgid, self.osd._handle_op, conn, msg,
+                lambda: self._ec_read_resume(conn, msg, rd, gather))
+
+        self._ec_read_fetch(rd, gathered)
+
+    def _ec_read_resume(self, conn, msg, rd: "_EcRead", gather) -> None:
+        with self.lock:
+            if not (self.is_primary and self.active
+                    and self.interval_epoch == rd.interval):
+                # gathered in an interval that is over: the client
+                # resends to whoever serves the pg now
+                self._reply(conn, msg, -11, [])
+                return
+            nxt = self._ec_read_step(rd, gather)
+            if isinstance(nxt, _EcRead):
+                self._ec_read_park(conn, msg, nxt)      # the sweep
+                return
+            self._ec_read(conn, msg, nxt)
+
+    def _ec_read(self, conn, msg, data=_UNREAD) -> None:
+        """Serve a read-class op vector.  What needs the object's
+        bytes gathers for them first and PARKS meanwhile (`data` is
+        what a parked gather brought back: the bytes, or None)."""
+        if data is _UNREAD and any(op[0] == "read" for op in msg.ops):
+            rd = self._ec_read_begin(msg.oid)
+            if isinstance(rd, _EcRead):
+                self._ec_read_park(conn, msg, rd)
+                return
+            data = rd
         out = []
         result = 0
         store = self.osd.store
         for op in msg.ops:
             try:
                 if op[0] == "read":
-                    data = self._ec_read_local(msg.oid)
                     if data is None:
-                        raise StoreError(ENOENT, "unreadable EC object")
+                        # an object the log holds and the live shards
+                        # do not give is an I/O error (ECBackend
+                        # answers EIO); ENOENT says it does not exist
+                        raise StoreError(
+                            EIO if msg.oid in self.pglog.objects
+                            else ENOENT, "unreadable EC object")
                     end = None if op[2] == 0 else op[1] + op[2]
                     out.append(data[op[1]: end])
                 elif op[0] == "stat":
@@ -921,10 +1020,11 @@ class ECBackend:
                             except StoreError:
                                 continue
                     if size is None:
-                        data = self._ec_read_local(msg.oid)
-                        if data is None:
+                        whole = self._ec_read_local(msg.oid) \
+                            if data is _UNREAD or data is None else data
+                        if whole is None:
                             raise StoreError(ENOENT, "no such object")
-                        size = len(data)
+                        size = len(whole)
                     out.append({"size": size,
                                 "version": self._obj_version(msg.oid)})
                 elif op[0] == "getxattr":

@@ -965,24 +965,28 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         else:
             pg.handle_ec_sub_write_reply(msg)
 
-    def _handle_op(self, conn, msg) -> None:
+    def _handle_op(self, conn, msg, resume: Callable | None = None) -> None:
         """Op-shard entry: close the queue-wait span, publish the op
         as the thread's current trace target (deep layers — journal,
         EC staging — attach their spans through it), and run it under
         an `execute` span.  Sub-op / recovery-push trackers finish
         here (their reply is sent inline); client-op trackers finish
-        at reply time in pg._reply, which may be a later gather."""
+        at reply time in pg._reply, which may be a later gather.
+        `resume` is the re-entry of an op that parked without a
+        worker (an EC read waiting for its sub-reads): it runs in
+        place of the dispatch, under an `execute` span of its own."""
         from ..utils import optracker
+        run = resume or (lambda: self._execute_op(conn, msg))
         trk = getattr(msg, "_trk", None)
         if trk is None:
-            self._execute_op(conn, msg)
+            run()
             return
         t_dq = trk.span_end("queue")
         trk.mark_event("dequeued")
         trk.span_begin("execute", _t0=t_dq)   # contiguous: no hole
         try:
             with optracker.op_context(trk):
-                self._execute_op(conn, msg)
+                run()
         finally:
             trk.span_end("execute")     # no-op if already finished
             if not isinstance(msg, MOSDOp):

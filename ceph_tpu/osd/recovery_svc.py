@@ -12,17 +12,97 @@ the async-RPC callbacks — never on the messenger loop.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable
 
 from ..msg import Message
 from ..store.objectstore import StoreError, Transaction
-from ..utils import denc
+from ..utils import denc, optracker
 from .messages import (MOSDECSubOpRead, MOSDECSubOpReadReply, MOSDOp,
                        MOSDOpReply, MPGInfo, MPGPush, MPGPushReply)
 from .osdmap import PgId
 from ..crush.map import ITEM_NONE
 from .pg import (HINFO_KEY, PG, SNAPSET_KEY, VER_KEY,
                  WHITEOUT_KEY, shard_oid)
+
+
+class ShardGather:
+    """One concurrent fetch of EC shards from peers
+    (`RecoveryService.ec_fetch_shards`).  Replies land on the
+    messenger thread; the gather completes ONCE — when the caller's
+    predicate holds on the shards fetched so far, when nothing is
+    outstanding, or when its window ends — and then either wakes the
+    thread that waits for it or runs the continuation it was given."""
+
+    def __init__(self, targets, enough, done, trk):
+        # keyed per (shard, holder): the degraded sweep may ask SEVERAL
+        # osds for the same shard id (mid-remap it could be anywhere),
+        # and one holder's failure must not end the shard's gather
+        self.remaining = {(shard, osd_id) for shard, osd_id in targets}
+        self.asked = len(self.remaining)
+        self.enough, self.done, self.trk = enough, done, trk
+        self.out: dict[int, tuple] = {}
+        self.late = 0               # good replies after completion
+        self.finished = False
+        self.t_sent = self.t_done = time.monotonic()
+        self._lock = threading.Lock()
+        self._ev = threading.Event()
+
+    def reply_cb(self, shard: int, osd_id: int) -> Callable:
+        def cb(reply) -> None:
+            good = reply is not None and reply.result == 0
+            with self._lock:
+                if self.finished:
+                    if good:
+                        self.late += 1
+                    return
+                if good and shard not in self.out:
+                    self.out[shard] = (reply.data, reply.hinfo,
+                                       getattr(reply, "ver", None))
+                self.remaining.discard((shard, osd_id))
+                if self.remaining and not (
+                        good and self.enough is not None
+                        and self._enough()):
+                    return
+            self.complete()
+        return cb
+
+    def _enough(self) -> bool:
+        # a plan the codec computes here is the op's own work
+        with optracker.op_context(self.trk):
+            return bool(self.enough(set(self.out)))
+
+    def complete(self) -> None:
+        with self._lock:
+            if self.finished:
+                return
+            self.finished = True
+            self.t_done = time.monotonic()
+        self._ev.set()
+        if self.done is not None:
+            self.done(self)
+
+    def sent(self) -> None:
+        """Every sub-read is out: the wait starts."""
+        with self._lock:
+            self.t_sent = self.t_done if self.finished \
+                else time.monotonic()
+
+    def wait(self, window: float) -> None:
+        """Blocking form: until complete, or `window` real seconds —
+        the sub-reads' own timeouts ride the cluster clock, which a
+        test may leave standing while this thread waits."""
+        self._ev.wait(window)
+        self.complete()
+
+    def stamp(self, trk, **args) -> None:
+        """The op's `gather_wait` span: from the last sub-read sent to
+        completion, with what was asked, what was in hand then and
+        what came too late to matter."""
+        if trk is not None and self.asked:
+            trk.add_span("gather_wait", self.t_sent, self.t_done,
+                         asked=self.asked, used=len(self.out),
+                         late=self.late, **args)
 
 
 class RecoveryService:
@@ -995,56 +1075,48 @@ class RecoveryService:
                         off: int = 0, length: int = 0,
                         timeout: float = 5.0,
                         need_ver: tuple | None = None,
-                        need: int | None = None) -> dict:
+                        enough: Callable | None = None,
+                        done: Callable | None = None):
         """Fetch shards from peers CONCURRENTLY (start_read_op model,
         osd/ECBackend.cc:321): one gather, one timeout window — a
         multi-shard outage costs one RPC window, not one per shard.
         off/length select a range (the partial-append tail read,
         O(chunk) not O(shard)); 0,0 fetches the whole shard.
-        `need` early-completes the gather once that many shards
-        answered OK — a degraded read returns as soon as k shards
-        exist instead of waiting out a dead peer's full RPC window.
-        Returns {shard: (data, hinfo, ver)} — ver is the shard's
-        applied version when the read was version-gated, else None."""
-        if not targets:
-            return {}
-        out: dict[int, tuple] = {}
-        # keyed per (shard, holder): the degraded sweep may ask SEVERAL
-        # osds for the same shard id (mid-remap it could be anywhere),
-        # and one holder's failure must not end the shard's gather
-        remaining = {(shard, osd_id) for shard, osd_id in targets}
-        lock = threading.Lock()
-        done_ev = threading.Event()
+        `enough(shards)` early-completes the gather: it is asked after
+        every good reply, with the shard ids fetched so far, whether
+        the caller can go on without the rest — for a read, whether
+        they (and what the caller holds itself) decode; a gather with
+        no predicate waits for every reply.  A dead peer's full RPC
+        window is then waited out only if the live ones do not do.
 
-        def make_cb(shard: int, osd_id: int) -> Callable:
-            def cb(reply) -> None:
-                with lock:
-                    if reply is not None and reply.result == 0 \
-                            and shard not in out:
-                        out[shard] = (reply.data, reply.hinfo,
-                                      getattr(reply, "ver", None))
-                    remaining.discard((shard, osd_id))
-                    if not remaining or (need is not None
-                                         and len(out) >= need):
-                        done_ev.set()
-            return cb
-
+        Returns the gather; the fetched shards are `gather.out`,
+        {shard: (data, hinfo, ver)} — ver is the shard's applied
+        version when the read was version-gated, else None.  Without
+        `done` the call blocks until the gather is complete.  With it
+        the call returns at once and `done(gather)` runs once, on the
+        messenger or a timer thread, when it is: the caller holds no
+        worker meanwhile, so the peers' `sub_read` ops never queue
+        behind a thread that is waiting for them.  Each sub-read has
+        its RPC timeout on the cluster clock, so the gather ends."""
+        gather = ShardGather(targets, enough, done, optracker.current())
         # sub-reads carry the trace id of the op this thread serves
         # (a client read, a recovery rebuild), as sub-op writes do:
         # the shard OSD's sub_read op correlates under it
-        from ..utils import optracker
-        trace = getattr(optracker.current(), "trace_id", "") or ""
+        trace = getattr(gather.trk, "trace_id", "") or ""
         for shard, osd_id in targets:
+            if gather.finished:
+                break                   # the first replies did
             self._call_async(osd_id, MOSDECSubOpRead(
                 reqid=None, pgid=str(pgid), shard=shard, oid=oid,
                 off=off, length=length, need_ver=need_ver,
                 trace=trace),
-                make_cb(shard, osd_id), timeout=timeout)
-        # bound by REAL time too: _call_async timeouts ride the
-        # cluster clock, which only advances when a test ticks it
-        done_ev.wait(timeout + 1.0)
-        with lock:
-            return dict(out)
+                gather.reply_cb(shard, osd_id), timeout=timeout)
+        gather.sent()
+        if not targets:
+            gather.complete()
+        elif done is None:
+            gather.wait(timeout + 1.0)
+        return gather
 
     def ec_get_omap(self, pgid: PgId, oid: str, acting: list[int]) -> dict:
         """omap lives on shard 0; fetch from its holder when that is

@@ -26,6 +26,16 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
                                  store/objectstore.py
       replica_wait               osd/backend_ec.py, osd/backend_rep.py
                                  (sub-op round trip; closes at finish)
+      gather_wait                osd/recovery_svc.py `ShardGather.stamp`,
+                                 from osd/backend_ec.py: an EC read's
+                                 sub-reads sent to the gather complete
+                                 (args asked, used, late, replans,
+                                 chunks); a client read holds no worker
+                                 meanwhile and has an `execute` (and a
+                                 `queue`) either side of it
+      ec.plan                    erasure/matrix_codec.py: a decode
+                                 pattern the codec had not cached (a
+                                 plan search, a decode matrix)
       recovery_wait, push_rpc, rebuild
                                  osd/pg.py, osd/recovery_svc.py
       scrub.list, scrub.cache_fold, scrub.read, scrub.stack,

@@ -315,14 +315,21 @@ class TestMessengerSpans:
                 assert names.count(want) == 1
             (disp,) = _spans(d, "msgr.dispatch")
             assert disp["t1"] <= d["mstart"]
-        # nothing new on the primary's read doc
+        # nothing on the primary's read doc but these (PR 28: the
+        # parked gather's `gather_wait`, a decode pattern's `ec.plan`)
         assert {s["name"] for s in client["spans"]} <= \
             {"msgr.recv", "msgr.dispatch", "queue", "execute",
              "ec.coalesce", "ec.stage_h2d", "ec.device_compute", "ec.d2h",
-             "ec.host_encode", "recovery_wait"}
-        (ex,) = _spans(client, "execute")
-        assert not [s for s in client["spans"]
-                    if s["name"].startswith("msgr.") and _inside(s, ex)]
+             "ec.host_encode", "recovery_wait", "gather_wait", "ec.plan"}
+        # the read parked for its gather: an `execute` either side
+        # of `gather_wait`, neither around a messenger span
+        executes = _spans(client, "execute")
+        (wait,) = _spans(client, "gather_wait")
+        assert len(executes) == 2
+        assert executes[0]["t1"] <= wait["t1"] <= executes[1]["t0"]
+        for ex in executes:
+            assert not [s for s in client["spans"]
+                        if s["name"].startswith("msgr.") and _inside(s, ex)]
 
 
 # ---------------------------------------------------------------------------
