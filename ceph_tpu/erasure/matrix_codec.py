@@ -220,9 +220,24 @@ class TpuBackend:
         self._warming: set = set()
         self._warm_failed: set = set()
         self._warm_lock = threading.Lock()
+        self._fn_lock = threading.Lock()
 
     def _fn(self, kind: str, matrix: np.ndarray, *extra):
         key = (kind, matrix.tobytes(), matrix.shape, *extra)
+        fn = self._fns.get(key)
+        if fn is not None:
+            return fn
+        # one fn object a key: the warm threads of two shapes start
+        # together, and were each to build its own, the last one kept
+        # would be compiled for its own shape alone while `_ready`
+        # vouches for both (the other then compiles in the thread that
+        # serves it: a coalesced batch's first dispatch, seconds into
+        # a window)
+        with self._fn_lock:
+            return self._fn_locked(key, kind, matrix, *extra)
+
+    def _fn_locked(self, key: tuple, kind: str, matrix: np.ndarray,
+                   *extra):
         fn = self._fns.get(key)
         if fn is None:
             if kind == "bytes":

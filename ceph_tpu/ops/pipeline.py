@@ -617,6 +617,7 @@ class EcDevicePipeline:
         self._qos_scrub = 0
         self._busy = 0                     # dispatches being processed
         self._stalled = False              # collectors wedged: host-only
+        self._slices_warm: set = set()     # see _warm_item_slices
         self._running = False
         self._threads: list = []
         self._c = {
@@ -1744,6 +1745,7 @@ class EcDevicePipeline:
         if disp.dev_in is None or len(disp.out) < 2 or \
                 not any(it.cache is not None for it in disp.items):
             return
+        self._warm_item_slices(disp)
         off = 0
         for it in disp.items:
             if it.cache is not None:
@@ -1756,6 +1758,48 @@ class EcDevicePipeline:
                 except Exception:
                     pass        # cache is an optimization, never a fault
             off += it.n
+
+    def _warm_item_slices(self, disp: _Dispatch) -> None:
+        """The slices above are device programs, one a (batch rows,
+        item rows) pair.  When an item of n rows is first staged,
+        compile, on a warm thread, the pairs that batches of two and
+        more such items will need: otherwise the first dispatch in
+        which two ops coalesce compiles them on the collector, seconds
+        or minutes into serving."""
+        lane = disp.lane
+        if lane.device is None:
+            return              # host arrays: numpy views, no program
+        like = tuple((tuple(a.shape[1:]), np.dtype(a.dtype))
+                     for a in (disp.dev_in, disp.out[0]))
+        keys = {(lane.index, it.n, like) for it in disp.items
+                if it.cache is not None}
+        with self._lock:
+            keys -= self._slices_warm
+            self._slices_warm |= keys
+        if not keys:
+            return
+        # batches one lane takes: up to the channel's cap in rows and
+        # the lane's staging budget in bytes (larger ones ride the mesh)
+        cap = disp.chan.max_coalesce or self.max_batch
+        budget = self.mesh_min_bytes if self.mesh_min_bytes > 0 \
+            else DEFAULT_MESH_MIN_BYTES
+        row_bytes = disp.dev_in.nbytes // disp.dev_in.shape[0]
+        todo = sorted({(next_bucket(j * n), n) for _idx, n, _like in keys
+                       for j in range(2, cap // n + 1)
+                       if next_bucket(j * n) * row_bytes <= budget})
+
+        def warm():
+            import jax.numpy as jnp
+            try:
+                for rows, n in todo:
+                    for tail, dtype in like:
+                        zeros = jnp.zeros((rows,) + tail, dtype=dtype,
+                                          device=lane.device)
+                        zeros[rows - n: rows].block_until_ready()
+            except Exception as e:
+                note_warm_failure(f"item slices {todo}", e)
+
+        start_warm_thread(warm, "ec-slice-warm")
 
     def _group_part_done(self, disp: _Dispatch, outs: tuple) -> None:
         g = disp.group

@@ -17,7 +17,11 @@ durability point, exactly like BlueStore's _kv_sync_thread:
     analog) and are applied to the block device after commit; mount
     replays any pending records (idempotent pwrites);
   * every min_alloc block carries a crc32c verified on read
-    (BlueStore's per-blob csum); mismatch surfaces StoreError(EIO).
+    (BlueStore's per-blob csum); mismatch surfaces StoreError(EIO);
+  * a write's run of whole blocks is one extent: one allocation, one
+    native checksum call, and at commit one device write for blocks
+    that lie one behind the other.  The onode still maps a block at a
+    time and the crash sites below still tear by block.
 
 Divergence from the reference: clone copies blocks instead of
 refcounting shared blobs (correctness-equivalent; COW sharing is a
@@ -78,6 +82,7 @@ MIN_ALLOC = 4096               # bluestore_min_alloc_size
 DEFERRED_MAX = 64 * 1024       # writes at or under this ride the KV WAL
 GROW = 256 * MIN_ALLOC         # device growth increment (1 MiB)
 WAL_FLUSH_EVERY = 16           # applied WAL records kept before trim
+IOV_MAX = os.sysconf("SC_IOV_MAX")      # buffers one gather write takes
 
 P_SUPER = "S"
 P_COLL = "C"
@@ -187,7 +192,9 @@ class _Device:
         if self.path:
             if self._f is not None:
                 self._f.close()    # mkfs-then-mount must not leak one
-            self._f = open(self.path, "r+b")
+            # unbuffered: pwrite goes to the descriptor, and a buffer
+            # would serve reads what it held from before
+            self._f = open(self.path, "r+b", buffering=0)
             self._f.seek(0, os.SEEK_END)
             self.size = self._f.tell()
         else:
@@ -207,22 +214,50 @@ class _Device:
             self._mem.extend(b"\x00" * (new_size - len(self._mem)))
         self.size = new_size
 
-    def pwrite(self, off: int, data: bytes) -> None:
+    def pwrite(self, off: int, data) -> None:
         if self._f is not None:
-            self._f.seek(off)
-            self._f.write(data)
+            fd = self._f.fileno()
+            done = os.pwrite(fd, data, off)
+            while done < len(data):         # a short write: the rest
+                done += os.pwrite(fd, memoryview(data)[done:], off + done)
         else:
             self._mem[off: off + len(data)] = data
+
+    def pwritev(self, off: int, pieces: list) -> None:
+        """Buffers that lie one behind the other on the device, in one
+        gather write (no copy to join them)."""
+        if self._f is None:
+            for piece in pieces:
+                self._mem[off: off + len(piece)] = piece
+                off += len(piece)
+            return
+        fd = self._f.fileno()
+        for i in range(0, len(pieces), IOV_MAX):
+            batch = pieces[i: i + IOV_MAX]
+            want = sum(len(piece) for piece in batch)
+            if os.pwritev(fd, batch, off) != want:
+                # a short gather write: the pieces again, one by one
+                # (pwrite finishes a short write or raises what stops it)
+                at = off
+                for piece in batch:
+                    self.pwrite(at, piece)
+                    at += len(piece)
+            off += want
 
     def pread(self, off: int, length: int) -> bytes:
         if self._f is not None:
             self._f.seek(off)
-            return self._f.read(length)
+            data = self._f.read(length)
+            while len(data) < length:      # short of the end of file
+                more = self._f.read(length - len(data))
+                if not more:
+                    break
+                data += more
+            return data
         return bytes(self._mem[off: off + length])
 
     def flush(self) -> None:
         if self._f is not None:
-            self._f.flush()
             os.fsync(self._f.fileno())
 
 
@@ -304,13 +339,36 @@ class BlockStore(ObjectStore):
         from ..utils import faults
         return faults.get().crash_tracking_armed(self.owner)
 
-    def _dev_write(self, poff: int, data: bytes) -> None:
-        """All device mutation funnels through here so the reordering
-        model can roll un-fsync'd writes back at crash time."""
-        if self._crash_tracking():
+    def _dev_write(self, poff: int, data, tracked: bool) -> None:
+        """One block to the device; with crash tracking armed its
+        pre-image is kept, so the reordering model can roll un-fsync'd
+        writes back, a block each, at crash time."""
+        if tracked:
             self._unflushed.append(
                 (poff, self.dev.pread(poff, len(data))))
         self.dev.pwrite(poff, data)
+
+    def _write_staged(self, staged: dict, tracked: bool) -> int:
+        """All device mutation of a commit funnels through here:
+        staged blocks (poff -> data, in the order they were staged)
+        that lie one behind the other go to the device in ONE call.
+        With crash tracking armed a run is written a block at a time,
+        each with its pre-image.  Returns the device calls made."""
+        if tracked:
+            for poff, data in staged.items():
+                self._dev_write(poff, data, True)
+            return len(staged)
+        runs: list[tuple[int, list]] = []
+        end = None
+        for poff, data in staged.items():
+            if poff == end:
+                runs[-1][1].append(data)
+            else:
+                runs.append((poff, [data]))
+            end = poff + len(data)
+        for start, pieces in runs:
+            self.dev.pwritev(start, pieces)
+        return len(runs)
 
     def _dev_flush(self) -> None:
         """fsync barrier: everything buffered is durable now."""
@@ -346,14 +404,15 @@ class BlockStore(ObjectStore):
         prefix of the block), the rest never reach the device."""
         from ..utils import faults
         fs = faults.get()
+        tracked = self._crash_tracking()
         items = list(writes.items())
         k = int(fs.torn_keep_fraction(self.owner) * len(items))
         for poff, data in items[:k]:
-            self._dev_write(poff, data)
+            self._dev_write(poff, data, tracked)
         if k < len(items):
             poff, data = items[k]
             keep = int(fs.torn_keep_fraction(self.owner) * len(data))
-            self._dev_write(poff, data[:keep])
+            self._dev_write(poff, data[:keep], tracked)
         self._panic(site)
 
     def _maybe_crash_torn_kv(self, site: str, kvt: KVTransaction) -> None:
@@ -472,11 +531,16 @@ class BlockStore(ObjectStore):
         # commit + deferred applies — the BlockStore durability cost
         # a write pays, the journal-span analog for this backend
         from ..utils import optracker
-        with optracker.span("wal"):
-            self._commit_traced(st)
+        with optracker.span("wal") as late:
+            # how often a run was one device call: blocks written by
+            # this commit (COW and deferred) over the calls made
+            late["blocks"] = len(st["direct"]) + len(st["wal"])
+            late["dev_writes"] = self._commit_traced(st)
 
-    def _commit_traced(self, st: dict) -> None:
+    def _commit_traced(self, st: dict) -> int:
         kvt: KVTransaction = st["kvt"]
+        tracked = self._crash_tracking()
+        dev_writes = 0
         # If a freed extent is still the target of an untrimmed WAL
         # record, trim the WAL first — otherwise a crash after the
         # extent is reused would replay stale bytes over live data
@@ -493,8 +557,7 @@ class BlockStore(ObjectStore):
             from ..utils import faults
             if faults.get().should_crash(self.owner, "alloc.mid_cow"):
                 self._torn_extent_crash("alloc.mid_cow", st["direct"])
-            for poff, data in st["direct"].items():
-                self._dev_write(poff, data)
+            dev_writes += self._write_staged(st["direct"], tracked)
             self._dev_flush()
         wal_key = None
         if st["wal"]:
@@ -530,25 +593,27 @@ class BlockStore(ObjectStore):
                 # crash site: power loss partway through the deferred
                 # applies, one extent torn mid-block; replay rewrites
                 self._torn_extent_crash("wal.mid_apply", st["wal"])
-            for poff, data in st["wal"].items():
-                self._dev_write(poff, data)
+            dev_writes += self._write_staged(st["wal"], tracked)
             self._wal_applied.append(wal_key)
             self._wal_poffs.update(st["wal"])
             if len(self._wal_applied) >= WAL_FLUSH_EVERY:
                 self._flush_deferred()
+        return dev_writes
 
     # -- allocation helpers ------------------------------------------------
 
-    def _allocate_block(self, st: dict) -> int:
-        try:
-            ext = self.alloc.allocate(MIN_ALLOC)
-        except MemoryError:
-            new_size = self.dev.size + GROW
-            self.alloc.release([(self.dev.size, GROW)])
-            self.dev.grow(new_size)
-            ext = self.alloc.allocate(MIN_ALLOC)
+    def _allocate(self, st: dict, nbytes: int) -> list[tuple[int, int]]:
+        """Space for a run of blocks in one request, the device grown
+        until it fits; first fit, so the extents may be split."""
+        while True:
+            try:
+                ext = self.alloc.allocate(nbytes)
+                break
+            except MemoryError:
+                self.alloc.release([(self.dev.size, GROW)])
+                self.dev.grow(self.dev.size + GROW)
         st["allocated"].extend(ext)
-        return ext[0][0]
+        return ext
 
     # -- onode helpers -----------------------------------------------------
 
@@ -590,20 +655,45 @@ class BlockStore(ObjectStore):
                                   f"at {poff:#x} for rmw")
         return data
 
+    def _put_run(self, st: dict, head: dict, blk: int, data,
+                 deferred: bool) -> None:
+        """COW a run of whole logical blocks from `blk` on as one
+        extent: free the old blocks, allocate once, checksum the run in
+        one native call, then point the onode at each block and stage
+        its device write (slices of `data`, no copy).  A call a block
+        gives the GIL up a block, and on a host whose OSDs share one
+        interpreter every such call hands it round."""
+        n, rest = divmod(len(data), MIN_ALLOC)
+        assert n and not rest
+        blocks = head["blocks"]
+        for b in range(blk, blk + n):
+            old = blocks.get(b)
+            if old is not None:
+                self._free_block(st, old[0])
+        extents = self._allocate(st, n * MIN_ALLOC)
+        sums = crc32c_batch(np.frombuffer(
+            data, dtype=np.uint8).reshape(n, MIN_ALLOC)).tolist()
+        view = memoryview(data)
+        pending = st["pending"]
+        staged = st["wal"] if deferred else st["direct"]
+        i = 0
+        for off, length in extents:
+            for poff in range(off, off + length, MIN_ALLOC):
+                blocks[blk + i] = [poff, sums[i]]
+                pending[poff] = staged[poff] = \
+                    view[i * MIN_ALLOC: (i + 1) * MIN_ALLOC]
+                i += 1
+
     def _put_block(self, st: dict, head: dict, blk: int,
-                   data: bytes, deferred: bool) -> None:
-        """COW one logical block: allocate, stage the device write,
-        point the onode at it, free the old block."""
+                   data, deferred: bool) -> None:
+        """COW one logical block, zero-padded to its size: a run of
+        one."""
         assert len(data) <= MIN_ALLOC
-        old = head["blocks"].get(blk)
-        if old is not None:
-            self._free_block(st, old[0])
         if len(data) < MIN_ALLOC:
-            data = data + b"\x00" * (MIN_ALLOC - len(data))
-        poff = self._allocate_block(st)
-        head["blocks"][blk] = [poff, crc32c(0, data)]
-        st["pending"][poff] = data
-        (st["wal"] if deferred else st["direct"])[poff] = data
+            block = bytearray(MIN_ALLOC)
+            block[: len(data)] = data
+            data = block
+        self._put_run(st, head, blk, data, deferred)
 
     def _free_block(self, st: dict, poff: int) -> None:
         st["freed"].append((poff, MIN_ALLOC))
@@ -618,28 +708,34 @@ class BlockStore(ObjectStore):
 
     def _write_span(self, st: dict, head: dict, offset: int,
                     data: bytes, zero: bool = False) -> None:
-        deferred = len(data) <= self.deferred_max
+        view = memoryview(data).cast("B")
+        size = len(view)
+        deferred = size <= self.deferred_max
         pos = 0
-        while pos < len(data):
-            blk = (offset + pos) // MIN_ALLOC
-            boff = (offset + pos) % MIN_ALLOC
-            take = min(len(data) - pos, MIN_ALLOC - boff)
-            chunk = data[pos: pos + take]
-            if zero and take == MIN_ALLOC:
-                self._drop_block(st, head, blk)     # punch a hole
-            else:
-                if take == MIN_ALLOC:
-                    merged = chunk
+        while pos < size:
+            blk, boff = divmod(offset + pos, MIN_ALLOC)
+            whole = 0 if boff else (size - pos) // MIN_ALLOC
+            if whole:
+                # the aligned middle of the write: whole blocks, taken
+                # together (a 512 KiB shard file is one run of 128)
+                take = whole * MIN_ALLOC
+                if zero:
+                    for b in range(blk, blk + whole):
+                        self._drop_block(st, head, b)   # punch holes
                 else:
-                    cur = bytearray(self._read_block_raw(st, head, blk))
-                    if len(cur) < boff + take:
-                        cur.extend(b"\x00" * (boff + take - len(cur)))
-                    cur[boff: boff + take] = chunk
-                    merged = bytes(cur)
-                if zero and not any(merged):
+                    self._put_run(st, head, blk, view[pos: pos + take],
+                                  deferred)
+            else:
+                # a head or tail fragment: read-modify-write
+                take = min(size - pos, MIN_ALLOC - boff)
+                cur = bytearray(self._read_block_raw(st, head, blk))
+                if len(cur) < boff + take:
+                    cur.extend(b"\x00" * (boff + take - len(cur)))
+                cur[boff: boff + take] = view[pos: pos + take]
+                if zero and not any(cur):
                     self._drop_block(st, head, blk)
                 else:
-                    self._put_block(st, head, blk, merged, deferred)
+                    self._put_block(st, head, blk, cur, deferred)
             pos += take
 
     def _purge(self, st: dict, cid: str, oid: str) -> None:

@@ -23,7 +23,10 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
                                  stamps a pipeline future carries
                                  (osd/ecutil.py, osd/scrubber.py)
       journal, wal, store_apply  store/filestore.py, store/blockstore.py,
-                                 store/objectstore.py
+                                 store/objectstore.py (BlockStore's wal
+                                 carries args blocks, dev_writes: the
+                                 4 KiB blocks the commit wrote and the
+                                 device write calls it made for them)
       replica_wait               osd/backend_ec.py, osd/backend_rep.py
                                  (sub-op round trip; closes at finish)
       gather_wait                osd/recovery_svc.py `ShardGather.stamp`,

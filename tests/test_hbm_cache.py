@@ -672,3 +672,102 @@ class TestCostAwarePlacement:
                     "cache_lane_drops", "cache_bytes",
                     "cache_capacity", "cache_entries"):
             assert key in st, key
+
+
+class TestCoalescedDispatchCompilesNothing:
+    """What the first dispatch of two coalesced ops needs (the fused
+    fn at the coalesced bucket, the programs that cut each item's rows
+    out of the batch for the HBM cache) is compiled when its parts are
+    first warmed, not on the threads that serve it."""
+
+    @pytest.fixture
+    def compiles(self):
+        """A call that starts counting: the programs JAX compiles (or
+        fetches from its cache) from then on."""
+        import jax
+        stamps, listeners = [], []
+
+        def start():
+            def on(event, _secs, **_kw):
+                if event == "/jax/core/compile/backend_compile_duration":
+                    stamps.append(event)
+            listeners.append(on)
+            jax.monitoring.register_event_duration_secs_listener(on)
+            return stamps
+        yield start
+        for on in listeners:
+            jax.monitoring.unregister_event_duration_listener(on)
+
+    def test_item_slices_are_warm_before_two_ops_coalesce(self, compiles):
+        import jax
+        S, Lx = 4, 384              # shapes no other test compiles
+        matrix = gf.reed_sol_van_matrix(K, M)
+        fn = ec_kernels.make_encode_crc_fn(matrix, Lx)
+        chan = ec_pipeline.PipelineChannel(
+            key=("hbm", "coalesce"), host_fn=None,
+            device_fn=lambda padded, device=None: fn(padded),
+            route=lambda nbytes: True, max_coalesce=2 * S)
+        for rows in (S, 2 * S):     # what a codec's warm-up compiles
+            jax.block_until_ready(fn(jax.device_put(
+                np.zeros((rows, K, Lx), np.uint8), jax.devices()[0])))
+        pipe = ec_pipeline.EcDevicePipeline(depth=1, device_shards=1,
+                                            coalesce_wait=0.001)
+        rng = np.random.default_rng(29)
+        cache = hbm_cache.get()
+
+        def submit(i):
+            data = rng.integers(0, 256, size=(S, K, Lx), dtype=np.uint8)
+            return data, pipe.submit(chan, data, cache=hbm_cache.CacheIntent(
+                "pg_w", f"o{i}", (1, i), S * K * Lx, Lx))
+        try:
+            _d, fut = submit(0)                     # one op alone
+            assert fut.result(timeout=60)[0] == "dev"
+            assert ec_pipeline.wait_warmups(60)
+            stamps = compiles()
+            st0 = pipe.stats()
+            # hold the lane's one slot so that two ops queue together
+            with pipe._lock:
+                lane = pipe._devset.lanes[0]
+                lane.staging += 1
+            subs = [submit(i) for i in (1, 2)]
+            with pipe._lock:
+                lane.staging -= 1
+                pipe._fetch_cv.notify_all()
+            for _d, fut in subs:
+                assert fut.result(timeout=60)[0] == "dev"
+            st1 = pipe.stats()
+            assert st1["dispatches"] - st0["dispatches"] == 1, \
+                "the two ops did not coalesce"
+            assert stamps == []
+            for i, (data, _f) in zip((1, 2), subs):
+                assert cache.commit("pg_w", f"o{i}", (1, i))
+                assert cache.lookup("pg_w", f"o{i}").data_bytes() == \
+                    data.tobytes()
+        finally:
+            pipe.stop()
+
+    def test_one_fn_object_a_key_under_concurrent_warm_ups(self, compiles):
+        """Two shapes of one fn warm on two threads at once: both must
+        be compiled on the fn the backend keeps, or the one left out
+        compiles on the thread that first serves it."""
+        import time
+        import jax
+        from ceph_tpu.erasure.matrix_codec import TpuBackend
+        matrix = gf.reed_sol_van_matrix(K, M)
+        stamps = compiles()
+        for trial in range(4):
+            backend = TpuBackend()
+            shapes = [(b, K, 640 + 128 * trial) for b in (2, 4, 8)]
+            for shape in shapes:
+                backend.fused_fn_if_ready(matrix, shape)
+            end = time.monotonic() + 120
+            while not all(backend.fused_fn_if_ready(matrix, s) is not None
+                          for s in shapes):
+                assert time.monotonic() < end
+                time.sleep(0.02)
+            assert ec_pipeline.wait_warmups(60)
+            before = len(stamps)
+            for shape in shapes:
+                jax.block_until_ready(backend.fused_fn_if_ready(
+                    matrix, shape)(np.zeros(shape, np.uint8)))
+            assert len(stamps) == before, trial

@@ -809,3 +809,428 @@ class TestBlockStoreCrashMatrix:
         assert s2.read("c", "r6") == b"last-one" * 100
         assert s2.counters["wal_torn_extent_repairs"] >= 1
         s2.umount()
+
+
+# ---------------------------------------------------------------------------
+# BlockStore extents (ISSUE 29): a run of whole blocks is one
+# allocation, one checksum call and one device write; the onode, the
+# WAL record and the crash plane keep block granularity.
+# ---------------------------------------------------------------------------
+
+
+def _seeded(n, seed):
+    import numpy as np
+    return np.random.default_rng(seed).integers(
+        0, 256, n, dtype=np.uint8).tobytes()
+
+
+def _count_pwrites(s):
+    """Record each write call the store makes on its device: (offset,
+    bytes), a gather write of a run being one call."""
+    from ceph_tpu.store.blockstore import _Device
+    calls = []
+
+    def pwrite(off, data):
+        calls.append((off, len(data)))
+        _Device.pwrite(s.dev, off, data)
+
+    def pwritev(off, pieces):
+        calls.append((off, sum(len(p) for p in pieces)))
+        _Device.pwritev(s.dev, off, pieces)
+    s.dev.pwrite, s.dev.pwritev = pwrite, pwritev
+    return calls
+
+
+def _extents(head):
+    """Runs of blocks that lie one behind the other on the device, in
+    logical order."""
+    from ceph_tpu.store.blockstore import MIN_ALLOC
+    runs, last = 0, None
+    for blk in sorted(head["blocks"]):
+        poff = head["blocks"][blk][0]
+        if last is None or poff != last + MIN_ALLOC:
+            runs += 1
+        last = poff
+    return runs
+
+
+def _block_at_a_time_store(path):
+    """A BlockStore with the parent's write path (ISSUE 29's "before"),
+    kept here to write stores the way they were written: one allocation,
+    one checksum call and one device write a 4 KiB block."""
+    from ceph_tpu.ops.crc32c import crc32c
+    from ceph_tpu.store.blockstore import MIN_ALLOC, BlockStore
+
+    class Old(BlockStore):
+        def _put_block(self, st, head, blk, data, deferred):
+            old = head["blocks"].get(blk)
+            if old is not None:
+                self._free_block(st, old[0])
+            data = bytes(data)
+            if len(data) < MIN_ALLOC:
+                data = data + b"\x00" * (MIN_ALLOC - len(data))
+            poff = self._allocate(st, MIN_ALLOC)[0][0]
+            head["blocks"][blk] = [poff, crc32c(0, data)]
+            st["pending"][poff] = data
+            (st["wal"] if deferred else st["direct"])[poff] = data
+
+        def _put_run(self, st, head, blk, data, deferred):
+            for i in range(len(data) // MIN_ALLOC):
+                self._put_block(
+                    st, head, blk + i,
+                    data[i * MIN_ALLOC: (i + 1) * MIN_ALLOC], deferred)
+
+        def _write_staged(self, staged, tracked):
+            for poff, data in staged.items():
+                self._dev_write(poff, data, tracked)
+            return len(staged)
+
+    return Old(path)
+
+
+class TestBlockStoreExtents:
+    OWNER = "osd.7"
+
+    @pytest.fixture(autouse=True)
+    def _clean_faults(self):
+        from ceph_tpu.utils import faults
+        faults.get().reset(seed=0)
+        yield
+        faults.get().reset(seed=0)
+
+    def _mk(self, tmp_path, disk=True, **kw):
+        from ceph_tpu.store.blockstore import BlockStore
+        s = BlockStore(str(tmp_path / "bs") if disk else "", **kw)
+        s.owner = self.OWNER
+        s.mkfs()
+        if disk:
+            s.mount()
+        s.apply_transaction(T().create_collection("c"))
+        return s
+
+    def _remount(self, tmp_path):
+        from ceph_tpu.store.blockstore import BlockStore
+        s = BlockStore(str(tmp_path / "bs"))
+        s.mount()
+        return s
+
+    # (a) ----------------------------------------------------------------
+
+    @pytest.mark.parametrize("disk", [False, True], ids=["mem", "disk"])
+    def test_shard_file_is_one_device_write(self, tmp_path, disk):
+        from ceph_tpu.ops.crc32c import crc32c
+        from ceph_tpu.store.blockstore import MIN_ALLOC
+        s = self._mk(tmp_path, disk=disk)
+        payload = _seeded(512 * 1024, 29)
+        calls = _count_pwrites(s)
+        s.apply_transaction(T().write("c", "shard", 0, memoryview(payload)))
+        assert len(calls) == 1 and calls[0][1] == len(payload)
+        head = s._committed_onode("c", "shard")
+        assert sorted(head["blocks"]) == list(range(128))
+        for blk, (poff, csum) in head["blocks"].items():
+            block = payload[blk * MIN_ALLOC: (blk + 1) * MIN_ALLOC]
+            assert csum == crc32c(0, block), blk
+            assert s.dev.pread(poff, MIN_ALLOC) == block, blk
+        assert s.read("c", "shard") == payload
+        assert s.read("c", "shard", 5000, 300000) == payload[5000:305000]
+        # one byte flipped under the store, in block 77
+        at = head["blocks"][77][0] + 1234
+        s.dev.pwrite(at, bytes([s.dev.pread(at, 1)[0] ^ 0x40]))
+        with pytest.raises(StoreError) as ei:
+            s.read("c", "shard")
+        assert ei.value.errno == 5 and "block 77" in str(ei.value)
+        assert s.read("c", "shard", 0, 77 * MIN_ALLOC) == \
+            payload[:77 * MIN_ALLOC]
+        s.umount()
+
+    @pytest.mark.parametrize("case", ["short", "over_iov_max"])
+    def test_gather_write_of_a_run_lands_whole(self, tmp_path, monkeypatch,
+                                               case):
+        """The one device write of a run is a gather write: a short one
+        is finished, and a run of more buffers than one call takes is
+        still one run."""
+        import os
+        from ceph_tpu.store import blockstore
+        s = self._mk(tmp_path)
+        real, seen = os.pwritev, []
+
+        def pwritev(fd, bufs, off):
+            seen.append(len(bufs))
+            if case == "short" and len(seen) == 1:
+                return real(fd, [bufs[0], bufs[1][:100]], off)
+            return real(fd, bufs, off)
+        monkeypatch.setattr(os, "pwritev", pwritev)
+        n = 20 if case == "short" else blockstore.IOV_MAX + 256
+        payload = _seeded(n * blockstore.MIN_ALLOC, 11)
+        calls = _count_pwrites(s)
+        s.apply_transaction(T().write("c", "o", 0, payload))
+        monkeypatch.undo()
+        if case == "short":
+            assert seen == [20] and len(calls) == 1 + 20
+        else:
+            assert seen == [blockstore.IOV_MAX, 256] and len(calls) == 1
+        s.umount()
+        s2 = self._remount(tmp_path)
+        assert s2.read("c", "o") == payload
+        s2.umount()
+
+    # (b) ----------------------------------------------------------------
+
+    @pytest.mark.parametrize("blocks", [40, 128])
+    def test_fragmented_free_list_one_write_an_extent(self, tmp_path,
+                                                      blocks):
+        from ceph_tpu.store.blockstore import MIN_ALLOC
+        s = self._mk(tmp_path)
+        t = T()
+        for i in range(64):
+            t.write("c", f"f{i:02d}", 0, bytes([i]) * MIN_ALLOC)
+        s.apply_transaction(t)
+        t = T()
+        for i in range(0, 64, 2):               # interleaved removes
+            t.remove("c", f"f{i:02d}")
+        s.apply_transaction(t)
+        holes = [e for e in s.alloc.dump() if e[1] == MIN_ALLOC]
+        assert len(holes) >= 31
+        payload = _seeded(blocks * MIN_ALLOC, blocks)
+        calls = _count_pwrites(s)
+        s.apply_transaction(T().write("c", "big", 0, payload))
+        head = s._committed_onode("c", "big")
+        assert len(head["blocks"]) == blocks
+        assert len(calls) == _extents(head) > 31
+        assert sum(n for _off, n in calls) == len(payload)
+        assert s.read("c", "big") == payload
+        for i in range(1, 64, 2):               # the neighbours stand
+            assert s.read("c", f"f{i:02d}") == bytes([i]) * MIN_ALLOC
+        s.umount()
+        s2 = self._remount(tmp_path)
+        assert s2.read("c", "big") == payload
+        s2.umount()
+
+    # (c) ----------------------------------------------------------------
+
+    @pytest.mark.parametrize("offset", [0, 1, 4095, 4096, 6000])
+    def test_head_and_tail_fragments_equal_a_model(self, tmp_path, offset):
+        s = self._mk(tmp_path)
+        model = bytearray(_seeded(90000, 1))
+        s.apply_transaction(T().write("c", "o", 0, bytes(model)))
+        for i, length in enumerate([1, 100, 4096, 4097, 8192, 12288 + 17,
+                                    65536 + 100, 3 * 4096 - offset % 4096]):
+            data = _seeded(length, 100 + i)
+            calls = _count_pwrites(s)
+            s.apply_transaction(T().write("c", "o", offset, data))
+            if len(model) < offset + length:
+                model.extend(b"\x00" * (offset + length - len(model)))
+            model[offset: offset + length] = data
+            assert s.read("c", "o") == bytes(model), (offset, length)
+            # every byte written reached the device in whole blocks
+            assert all(n % 4096 == 0 for _off, n in calls)
+            first, last = offset // 4096, (offset + length - 1) // 4096
+            assert sum(n for _off, n in calls) == (last - first + 1) * 4096
+        # a hole, then a write that ends inside a block beyond it
+        data = _seeded(5000, 7)
+        s.apply_transaction(T().write("c", "o", 200000 + offset, data))
+        model.extend(b"\x00" * (200000 + offset + 5000 - len(model)))
+        model[200000 + offset: 200000 + offset + 5000] = data
+        assert s.read("c", "o") == bytes(model)
+        s.umount()
+        s2 = self._remount(tmp_path)
+        assert s2.read("c", "o") == bytes(model)
+        s2.umount()
+
+    # (d) ----------------------------------------------------------------
+
+    @pytest.mark.parametrize("then", ["truncate0", "remove", "overwrite"])
+    def test_blocks_freed_in_their_own_txn_stay_off_the_device(
+            self, tmp_path, then):
+        s = self._mk(tmp_path)
+        free_before = s.alloc.total_free()
+        size_before = s.dev.size
+        a, b = _seeded(512 * 1024, 3), _seeded(512 * 1024, 4)
+        t = T().write("c", "o", 0, a)
+        if then == "truncate0":
+            t.truncate("c", "o", 0)
+        elif then == "remove":
+            t.remove("c", "o")
+        else:
+            t.write("c", "o", 0, b)
+        calls = _count_pwrites(s)
+        s.apply_transaction(t)
+        grown = s.dev.size - size_before
+        if then == "overwrite":
+            assert sum(n for _off, n in calls) == len(b)
+            assert len(calls) == _extents(s._committed_onode("c", "o"))
+            assert s.read("c", "o") == b
+            assert s.alloc.total_free() == free_before + grown - len(b)
+        else:
+            assert calls == []
+            assert s.alloc.total_free() == free_before + grown
+            if then == "remove":
+                assert not s.exists("c", "o")
+            else:
+                assert s.read("c", "o") == b""
+        s.umount()
+
+    # (e) ----------------------------------------------------------------
+
+    @pytest.mark.parametrize("case", ["replay", "middle_free"])
+    def test_deferred_run_is_one_wal_record(self, tmp_path, case):
+        from ceph_tpu.store import CrashPoint
+        from ceph_tpu.store.blockstore import MIN_ALLOC, P_WAL
+        from ceph_tpu.utils import denc, faults
+        s = self._mk(tmp_path)
+        payload = _seeded(32 * 1024, 5)
+        if case == "replay":
+            faults.get().reset(seed=1)
+            faults.get().crash("wal.post_kv_commit", 1.0, self.OWNER)
+            calls = _count_pwrites(s)
+            with pytest.raises(CrashPoint):
+                s.apply_transaction(T().write("c", "o", 0, payload))
+            assert calls == []                 # the applies never ran
+            records = list(s.db.iterate(P_WAL, ""))
+            assert len(records) == 1
+            writes = denc.loads(records[0][1])["writes"]
+            assert [len(d) for _o, d in writes] == [MIN_ALLOC] * 8
+            assert b"".join(d for _o, d in writes) == payload
+            s.umount()
+            s2 = self._remount(tmp_path)
+            assert s2.counters["wal_records_replayed"] == 1
+            assert s2.counters["wal_torn_extent_repairs"] == 8
+            assert s2.read("c", "o") == payload
+            s2.umount()
+            return
+        calls = _count_pwrites(s)
+        s.apply_transaction(T().write("c", "o", 0, payload))
+        assert len(calls) == 1 and calls[0][1] == len(payload)
+        head = s._committed_onode("c", "o")
+        poffs = {p for p, _c in head["blocks"].values()}
+        assert len(s._wal_applied) == 1 and poffs <= s._wal_poffs
+        record = s._wal_applied[0]
+        flushed = []
+        real = s._flush_deferred
+
+        def flush():
+            # the trim must come BEFORE the freed block can be reused
+            flushed.append(s.alloc.total_free())
+            real()
+        s._flush_deferred = flush
+        free_before = s.alloc.total_free()
+        patch = _seeded(MIN_ALLOC, 6)           # frees block 3 of 8
+        s.apply_transaction(T().write("c", "o", 3 * MIN_ALLOC, patch))
+        assert flushed and flushed[0] == free_before - MIN_ALLOC
+        assert record not in s._wal_applied
+        assert s.db.get(P_WAL, record) is None
+        assert s.read("c", "o") == \
+            payload[:3 * MIN_ALLOC] + patch + payload[4 * MIN_ALLOC:]
+        s.umount()
+
+    # (f) ----------------------------------------------------------------
+
+    @pytest.mark.parametrize("site", ["alloc.mid_cow", "wal.mid_apply"])
+    @pytest.mark.parametrize("seed", [0x5EED, 0xA11CE])
+    def test_crash_sites_tear_one_block_of_a_run(self, tmp_path, site,
+                                                 seed):
+        from ceph_tpu.store import CrashPoint
+        from ceph_tpu.store.blockstore import MIN_ALLOC
+        from ceph_tpu.utils import faults
+        deferred = site == "wal.mid_apply"
+        s = self._mk(tmp_path,
+                     deferred_max=(1 << 20) if deferred else 1024)
+        old, new = _seeded(512 * 1024, 8), _seeded(512 * 1024, 9)
+        s.apply_transaction(T().write("c", "victim", 0, old))
+        if deferred:
+            s._flush_deferred()         # old is durable, nothing buffered
+        faults.get().reset(seed=seed)
+        faults.get().fsync_reorder(1.0, self.OWNER)
+        faults.get().crash(site, 1.0, self.OWNER)
+        blocks = []
+        real = s._dev_write
+
+        def dev_write(poff, data, tracked):
+            assert tracked
+            blocks.append(len(data))
+            real(poff, data, tracked)
+        s._dev_write = dev_write
+        calls = _count_pwrites(s)
+        with pytest.raises(CrashPoint):
+            s.apply_transaction(T().write("c", "victim", 0, new))
+        assert s.frozen and not faults.get().rules()
+        # the run went to the device a block at a time: a seeded number
+        # whole, then exactly one torn, and the reordering model rolled
+        # blocks back, never a run
+        assert blocks[:-1] == [MIN_ALLOC] * (len(blocks) - 1)
+        assert blocks[-1] < MIN_ALLOC and len(blocks) <= 128
+        assert all(n <= MIN_ALLOC for _off, n in calls)
+        assert s.counters["fsync_reorder_windows"] == 1
+        s.umount()
+        s2 = self._remount(tmp_path)
+        got = s2.read("c", "victim")
+        assert got in (old, new), "a mix of generations"
+        assert got == (new if deferred else old)
+        if deferred:
+            assert s2.counters["wal_records_replayed"] == 1
+            assert s2.counters["wal_torn_extent_repairs"] >= 1
+        s2.apply_transaction(T().write("c", "fresh", 0, b"x" * 8192))
+        assert s2.read("c", "victim") == got
+        s2.umount()
+
+    # (g) ----------------------------------------------------------------
+
+    @staticmethod
+    def _history(s):
+        """A few transactions of every shape the write path has."""
+        s.apply_transaction(T().create_collection("c"))
+        s.apply_transaction(T().write("c", "shard", 0, _seeded(512 * 1024, 1))
+                            .setattr("c", "shard", "hinfo", b"h")
+                            .omap_setkeys("c", "shard", {"k": b"v"}))
+        s.apply_transaction(T().write("c", "small", 0, _seeded(32 * 1024, 2)))
+        s.apply_transaction(T().write("c", "shard", 6000, _seeded(70000, 3)))
+        s.apply_transaction(T().write("c", "small", 4095, _seeded(4098, 4)))
+        s.apply_transaction(T().remove("c", "small")
+                            .write("c", "again", 100, _seeded(300000, 5)))
+        s.apply_transaction(T().zero("c", "shard", 8192, 20000)
+                            .truncate("c", "again", 250001)
+                            .clone("c", "again", "copy"))
+
+    @pytest.mark.parametrize("case", ["mounts_and_reads", "identical"])
+    def test_store_written_a_block_at_a_time(self, tmp_path, case):
+        """A store directory the parent's write path made mounts and
+        reads under this one; and both paths leave the same onodes, the
+        same free list and the same block file."""
+        from ceph_tpu.store.blockstore import BlockStore
+        old = _block_at_a_time_store(str(tmp_path / "old"))
+        old.mkfs()
+        old.mount()
+        calls = _count_pwrites(old)
+        self._history(old)
+        assert max(n for _off, n in calls) == 4096
+        want = {o: old.read("c", o) for o in ("shard", "again", "copy")}
+        old.umount()
+        if case == "mounts_and_reads":
+            s = BlockStore(str(tmp_path / "old"))
+            s.mount()
+            for oid, data in want.items():
+                assert s.read("c", oid) == data
+            assert not s.exists("c", "small")
+            assert s.getattr("c", "shard", "hinfo") == b"h"
+            s.apply_transaction(
+                T().write("c", "shard", 4096, _seeded(8192, 6)))
+            data = bytearray(want["shard"])
+            data[4096: 4096 + 8192] = _seeded(8192, 6)
+            assert s.read("c", "shard") == bytes(data)
+            s.umount()
+            return
+        new = BlockStore(str(tmp_path / "new"))
+        new.mkfs()
+        new.mount()
+        self._history(new)
+        new.umount()
+        rows = []
+        for name in ("old", "new"):
+            s = BlockStore(str(tmp_path / name))
+            s.mount()
+            rows.append({p: list(s.db.iterate(p, "")) for p in "SCOMW"})
+            s.umount()
+        assert rows[0] == rows[1]
+        with open(tmp_path / "old" / "block", "rb") as f, \
+                open(tmp_path / "new" / "block", "rb") as g:
+            assert f.read() == g.read()
