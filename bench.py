@@ -489,42 +489,6 @@ def bench_e2e_pipelined(rows: list, chunk: int = 1 << 20,
             "crossover": codec.backend.crossover_estimate()}
 
 
-def _warm_mesh_codec(codec, k: int, chunk: int, shapes,
-                     plane_key: tuple, window: float,
-                     donate: bool = False) -> bool:
-    """Pre-compile the mesh-sharded fused fn for every batch shape the
-    timed run can coalesce into (the mesh executable is specialized
-    per shape AND per mesh plane)."""
-    matrix = codec.coding_matrix
-    want = {(S, k, chunk) for S in shapes}
-    ready: set = set()
-    end = time.time() + window
-    while time.time() < end and len(ready) < len(want):
-        for shape in want - ready:
-            if codec.backend.mesh_fn_if_ready(
-                    matrix, shape, plane_key, donate) is not None:
-                ready.add(shape)
-        time.sleep(0.25)
-    return len(ready) == len(want)
-
-
-def _host_oracle_encode_crc(codec, batch: np.ndarray):
-    """The independent host plane (native matmul + table CRC, no jax)
-    mesh results are checked bit-exactly against."""
-    from ceph_tpu.ops import crc32c as crc_mod
-
-    matrix = codec.coding_matrix
-    parity = np.asarray(codec._host_backend().apply_bytes(matrix, batch))
-    B, k, L = batch.shape
-    m = parity.shape[1]
-    crcs = np.empty((B, k + m), dtype=np.uint32)
-    crcs[:, :k] = crc_mod.crc32c_batch(
-        batch.reshape(B * k, L)).reshape(B, k)
-    crcs[:, k:] = crc_mod.crc32c_batch(
-        parity.reshape(B * m, L)).reshape(B, m)
-    return parity, crcs
-
-
 def bench_multichip(rows: list, chip_counts=(1, 2, 4, 8),
                     chunk: int = 1 << 20, nops: int = 32,
                     per_op: int = 2, depth: int = 2,
@@ -532,11 +496,8 @@ def bench_multichip(rows: list, chip_counts=(1, 2, 4, 8),
                     warm_window: float = 240.0) -> dict:
     """Multichip mode: the SAME pipelined op stream at 1/2/4/8 dispatch
     lanes, reporting aggregate GB/s, per-chip GB/s and scaling
-    efficiency (aggregate(n) / (n * aggregate(1))) for BOTH placement
-    modes — row-split (independent per-lane batches) and mesh dispatch
-    (one batch shard_mapped across the lanes) — plus the
-    object-larger-than-one-lane's-staging-budget case only the mesh
-    can dispatch at all.  Placement is the production pipeline's —
+    efficiency (aggregate(n) / (n * aggregate(1))).  Placement is the
+    production pipeline's (whole or row-split over idle lanes) —
     this measures the op path end to end (transfer-inclusive,
     distinct buffers), not an isolated kernel sweep."""
     import jax
@@ -563,8 +524,7 @@ def bench_multichip(rows: list, chip_counts=(1, 2, 4, 8),
     for n in counts:
         pipe.reset_devices(device_shards=n)
         ec_pipeline.configure(depth=depth, coalesce_wait=0.002,
-                              max_batch=max_batch, split_min=per_op,
-                              mesh_min_bytes=0)
+                              max_batch=max_batch, split_min=per_op)
         warmed = _warm_pipeline_codec(
             codec, k, chunk, max_batch, window=warm_window,
             devices=list(jax.devices())[:n])
@@ -594,113 +554,14 @@ def bench_multichip(rows: list, chip_counts=(1, 2, 4, 8),
             "scaling_efficiency": round(eff, 3),
             "dev_dispatches": dev, "split_dispatches": splits,
             "lanes_used": lanes_used,
-            # mesh row (filled below for n >= 2; a 1-chip "mesh" is
-            # not a mesh — the keys still always emit)
-            "mesh_aggregate_gbs": None,
-            "mesh_scaling_efficiency": None,
-            "mesh_dispatches": 0,
         }
         rows.append((f"encode-multichip-x{n}", "tpu", k, m, chunk,
                      gbs))
         log(f"multichip n={n}: {gbs:.3f} GB/s aggregate "
             f"({gbs / n:.3f}/chip, eff {eff:.2f}, {dev} dev "
             f"dispatches, {splits} splits, {lanes_used} lanes used)")
-        if n < 2:
-            continue
-        # mesh row: same op stream, every coalesced batch over the
-        # lane budget so placement picks mesh dispatch
-        ec_pipeline.configure(mesh_min_bytes=1)
-        plane_key = (tuple(jax.devices()[:n]), 1, n)
-        shapes = {min(s, max_batch) for s in
-                  range(per_op, max_batch + 1, per_op)} | {per_op}
-        mwarmed = _warm_mesh_codec(codec, k, chunk, shapes,
-                                   plane_key, warm_window)
-        if not mwarmed:
-            log(f"multichip n={n}: mesh fns not fully warm; mesh row "
-                "may include row-split dispatches")
-        mstats0 = ec_pipeline.stats()
-        t0 = time.perf_counter()
-        handles = [codec.encode_stripes_with_crcs_async(op)
-                   for op in ops]
-        for h in handles:
-            h.result()
-        t = time.perf_counter() - t0
-        mesh_gbs = useful / t / 1e9
-        mstats1 = ec_pipeline.stats()
-        mesh_disp = mstats1["mesh_dispatches"] - \
-            mstats0["mesh_dispatches"]
-        meff = mesh_gbs / (n * base_per_chip) if base_per_chip else 1.0
-        results[str(n)].update({
-            "mesh_aggregate_gbs": round(mesh_gbs, 3),
-            "mesh_scaling_efficiency": round(meff, 3),
-            "mesh_dispatches": mesh_disp,
-        })
-        ec_pipeline.configure(
-            mesh_min_bytes=ec_pipeline.DEFAULT_MESH_MIN_BYTES)
-        rows.append((f"encode-mesh-x{n}", "tpu", k, m, chunk,
-                     mesh_gbs))
-        log(f"multichip n={n} MESH: {mesh_gbs:.3f} GB/s aggregate "
-            f"(eff {meff:.2f} vs 1-chip row-split, {mesh_disp} mesh "
-            f"dispatches)")
-    results["mega_object"] = _bench_mesh_mega(
-        codec, k, chunk, counts[-1], warm_window, rows)
     pipe.reset_devices(device_shards=None)
-    ec_pipeline.configure(
-        mesh_min_bytes=ec_pipeline.DEFAULT_MESH_MIN_BYTES)
     return results
-
-
-def _bench_mesh_mega(codec, k: int, chunk: int, n: int,
-                     warm_window: float, rows: list) -> dict:
-    """The previously-undispatchable case: ONE batch whose staged
-    bytes exceed a single lane's budget.  Row-split placement cannot
-    serve it on a real HBM-bounded chip; the mesh shard_maps it and
-    the output is checked bit-exactly against the native host plane."""
-    import jax
-
-    from ceph_tpu.ops import pipeline as ec_pipeline
-
-    out = {"bytes": None, "gbs": None, "mesh_dispatches": 0,
-           "ok": False}
-    if n < 2:
-        return out
-    budget = max(4 * k * chunk, 1 << 20)        # the lane budget
-    S = max(2, (3 * budget) // (k * chunk))     # 3x over it
-    nbytes = S * k * chunk
-    out["bytes"] = nbytes
-    out["lane_budget_bytes"] = budget
-    ec_pipeline.get().reset_devices(device_shards=n)
-    ec_pipeline.configure(mesh_min_bytes=budget)
-    plane_key = (tuple(jax.devices()[:n]), 1, n)
-    if not _warm_mesh_codec(codec, k, chunk, {S}, plane_key,
-                            warm_window):
-        log(f"mesh mega-object: fn not warm in {warm_window:.0f}s, "
-            "skipping")
-        return out
-    rng = np.random.default_rng(31)
-    batch = rng.integers(0, 256, size=(S, k, chunk), dtype=np.uint8)
-    stats0 = ec_pipeline.stats()
-    t0 = time.perf_counter()
-    allc, crcs = codec.encode_stripes_with_crcs_async(batch).result(600)
-    t = time.perf_counter() - t0
-    stats1 = ec_pipeline.stats()
-    out["mesh_dispatches"] = stats1["mesh_dispatches"] - \
-        stats0["mesh_dispatches"]
-    out["gbs"] = round(nbytes / t / 1e9, 3)
-    parity_o, crcs_o = _host_oracle_encode_crc(codec, batch)
-    out["ok"] = bool(out["mesh_dispatches"] >= 1
-                     and np.array_equal(allc[:, k:], parity_o)
-                     and np.array_equal(allc[:, :k], batch)
-                     and np.array_equal(crcs, crcs_o))
-    ec_pipeline.configure(
-        mesh_min_bytes=ec_pipeline.DEFAULT_MESH_MIN_BYTES)
-    rows.append((f"encode-mesh-mega-x{n}", "tpu", k,
-                 codec.coding_matrix.shape[0], chunk,
-                 out["gbs"] or 0.0))
-    log(f"mesh mega-object: {nbytes >> 20} MiB batch (lane budget "
-        f"{budget >> 20} MiB) -> {out['gbs']} GB/s over {n} chips, "
-        f"{out['mesh_dispatches']} mesh dispatches, ok={out['ok']}")
-    return out
 
 
 def bench_crossover(rows: list) -> dict:
@@ -1535,60 +1396,6 @@ def bench_smoke() -> None:
     cache_hits = cstats1["cache_hit"] - cstats0["cache_hit"]
     cache_scrub_ok = bool(cache_scrub_ok and cache_h2d_bytes == 0
                           and cache_hits >= len(cached))
-    # mesh-dispatch gate: a payload whose staged bytes exceed a single
-    # lane's budget dispatches as ONE shard_mapped batch across the
-    # 8-device mesh, with the staging arena DONATED (the ec.stage copy
-    # becomes the H2D upload) — previously undispatchable on an
-    # HBM-bounded rig.  Gates: >= 1 mesh dispatch, bit-exact vs the
-    # host oracle codec, and host_copies_per_write <= 2 on the donated
-    # path (shard_layout only, plus slack for a cold-warm stage note).
-    from ceph_tpu.utils import copyaudit as _mca
-    MESH_COPY_BUDGET = 2.0
-    mesh_budget = 256 * 1024
-    ec_pipeline.configure(mesh_min_bytes=mesh_budget)
-    sinfo_m = ecutil.StripeInfo(k, chunk)     # stripe width 32 KiB
-    mesh_pay = rng.integers(
-        0, 256, size=12 * k * chunk - 1234,   # ~384 KiB, odd tail
-        dtype=np.uint8).tobytes()
-    mesh_ok = False
-    mesh_copies_per_write = None
-    mesh_disp = 0
-    mesh_donations = 0
-    mstats0 = ec_pipeline.stats()
-    mend = time.time() + 120
-    while time.time() < mend:               # mesh fn warms in background
-        shards_m, _mcrcs = ecutil.encode_object_ex(codec, sinfo_m,
-                                                   mesh_pay)
-        mst = ec_pipeline.stats()
-        if mst["mesh_dispatches"] - mstats0["mesh_dispatches"] >= 1:
-            break
-        time.sleep(0.25)
-    mst = ec_pipeline.stats()
-    mesh_disp = mst["mesh_dispatches"] - mstats0["mesh_dispatches"]
-    mesh_donations = mst["arena_donations"] - \
-        mstats0["arena_donations"]
-    if mesh_disp >= 1:
-        shards_o, _ocrcs = ecutil.encode_object_ex(oracle, sinfo_m,
-                                                   mesh_pay)
-        mesh_exact = all(bytes(a) == bytes(b)
-                         for a, b in zip(shards_m, shards_o))
-        # donated-path copy floor: warm mesh writes pay ONLY the
-        # shard-major layout (the staging copy rode the donation)
-        mc0 = _mca.snapshot()
-        for _ in range(4):
-            ecutil.encode_object_ex(codec, sinfo_m, mesh_pay)
-        mc1 = _mca.snapshot()
-        mesh_copies_per_write = (mc1["host_copies"]
-                                 - mc0["host_copies"]) / 4
-        mesh_ok = bool(mesh_exact and mesh_donations >= 1
-                       and mesh_copies_per_write <= MESH_COPY_BUDGET)
-    mst = ec_pipeline.stats()
-    log(f"smoke mesh: {mesh_disp} mesh dispatches, "
-        f"{mst['arena_donations'] - mstats0['arena_donations']} arena "
-        f"donations, copies/write="
-        f"{mesh_copies_per_write if mesh_copies_per_write is not None else 'n/a'}"
-        f" (budget {MESH_COPY_BUDGET}), mesh table={mst['mesh']}, "
-        f"ok={mesh_ok}")
     # quarantine drill: fault ONE chip of the mesh, keep encoding —
     # the lane quarantines, work redrains to survivors bit-exactly,
     # and the codec must NOT degrade
@@ -1974,7 +1781,7 @@ def bench_smoke() -> None:
         log(f"smoke conn gate FAILED: {type(e).__name__}: {e}")
     ok = (ok and sharded_ok and quarantine_ok and readback_ok
           and cache_scrub_ok and copy_ok and load_ok
-          and peering_flat_ok and mesh_ok and trace_overhead_ok
+          and peering_flat_ok and trace_overhead_ok
           and storm_ok and frontdoor_ok and conn_ok)
     log(f"smoke: host {host_gbs:.2f} GB/s, e2e serial "
         f"{serial_gbs:.3f} GB/s, pipelined {pipe_gbs:.3f} GB/s, "
@@ -2012,13 +1819,6 @@ def bench_smoke() -> None:
         "quarantines": qstats["quarantines"],
         "active_after_quarantine": qstats["active_devices"],
         "quarantine_ok": quarantine_ok,
-        "mesh_dispatches": mesh_disp,
-        "arena_donations": mesh_donations,
-        "mesh_copies_per_write": (
-            round(mesh_copies_per_write, 2)
-            if mesh_copies_per_write is not None else None),
-        "mesh_copy_budget": MESH_COPY_BUDGET,
-        "mesh_ok": mesh_ok,
         "load_p99_ms": load_p99,
         "load_errors": load_errors,
         "host_copies_per_read": (
@@ -2216,16 +2016,6 @@ def main() -> None:
         except Exception:
             return False
 
-    def _mesh_key(mc, key):
-        """`key` from the largest swept chip count that has it."""
-        if not mc:
-            return None
-        rows_by_n = sorted(((int(n), row) for n, row in mc.items()
-                            if n.isdigit()
-                            and row.get(key) is not None),
-                           reverse=True)
-        return rows_by_n[0][1][key] if rows_by_n else None
-
     print(json.dumps({
         "metric": "ec_fused_encode_crc_rs_k8m3_1MiB",
         "value": _r(primary["enc"]) if primary else None,
@@ -2288,19 +2078,6 @@ def main() -> None:
         "router_crossover_store_bytes": pipelined["crossover"]
         if pipelined else None,
         "multichip": multichip,
-        # pod-scale mesh headline keys (always emitted; null when the
-        # rig has one device or the sweep was skipped): the biggest
-        # swept mesh's aggregate GB/s + efficiency vs 1-chip
-        # row-split, and the object-larger-than-one-lane's-budget
-        # case that only mesh dispatch can serve
-        "mesh_aggregate_gbs": _mesh_key(multichip,
-                                        "mesh_aggregate_gbs"),
-        "mesh_scaling_efficiency": _mesh_key(
-            multichip, "mesh_scaling_efficiency"),
-        "mesh_mega_object_gbs": (multichip or {}).get(
-            "mega_object", {}).get("gbs"),
-        "mesh_mega_object_ok": (multichip or {}).get(
-            "mega_object", {}).get("ok"),
     }))
     sys.stdout.flush()
     sys.stderr.flush()

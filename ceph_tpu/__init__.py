@@ -9,7 +9,6 @@ idiomatic Python + native C++ where performance demands it.
 Layout (mirrors the reference layer map, SURVEY.md §1):
   ops/       device kernels: GF(2^8) math, bit-matrix matmuls, CRC32C
   erasure/   erasure-code plugin framework (tpu/jerasure/isa/shec/lrc)
-  parallel/  device-mesh sharding of EC/scrub pipelines, striping math
   crush/     CRUSH placement (rjenkins, straw2, do_rule)
   kv/        key/value store abstraction (mem, sqlite)
   store/     ObjectStore: transactional local object storage

@@ -199,11 +199,10 @@ class TpuBackend:
     MIN_DEVICE_BYTES = 1 << 16
     PROBE_EVERY = 64
 
-    def __init__(self, compute: str | None = None):
+    def __init__(self):
         import threading
         from ..ops import ec_kernels
         self._ek = ec_kernels
-        self.compute = compute or ec_kernels.DEFAULT_COMPUTE
         self._fns: dict[tuple, object] = {}
         self._host = NumpyBackend()
         # (path, bucket) -> {"spb": ema sec/byte, "n": samples}
@@ -241,26 +240,16 @@ class TpuBackend:
         fn = self._fns.get(key)
         if fn is None:
             if kind == "bytes":
-                fn = self._ek.make_codec_fn(matrix, 8, self.compute)
+                fn = self._ek.make_codec_fn(matrix, 8)
             elif kind == "fused":
                 (length,) = extra
                 fn = self._make_fused(matrix, length)
-            elif kind == "mesh":
-                # pod-scale fused encode+CRC shard_mapped over a
-                # device mesh; donate compiles the donated-input
-                # variant (the staging arena's upload is consumed)
-                length, devices, n_dp, n_ls, donate = extra
-                fn = self._ek.make_mesh_encode_crc_fn(
-                    matrix, length, devices, n_dp, n_ls,
-                    self.compute, donate)
             elif kind == "bits":
                 w, packetsize = extra
-                fn = self._ek.make_bits_codec_fn(matrix, w, packetsize,
-                                                 self.compute)
+                fn = self._ek.make_bits_codec_fn(matrix, w, packetsize)
             else:
                 w, packetsize = extra
-                fn = self._ek.make_packet_codec_fn(matrix, w, packetsize,
-                                                   self.compute)
+                fn = self._ek.make_packet_codec_fn(matrix, w, packetsize)
             if len(self._fns) > 256:
                 # decode patterns first: a "bytes" closure is cheap to
                 # build again and its readiness hangs on the matrix
@@ -294,8 +283,7 @@ class TpuBackend:
         if jax.devices()[0].platform == "tpu" and \
                 pallas_ec.supports(length):
             return pallas_ec.make_encode_crc_fn(matrix, length)
-        return self._ek.make_encode_crc_fn(matrix, length,
-                                           compute=self.compute)
+        return self._ek.make_encode_crc_fn(matrix, length)
 
     # -- measured routing --------------------------------------------------
 
@@ -531,18 +519,6 @@ class TpuBackend:
                           device=None):
         return self.device_fn_if_ready("fused", matrix, (shape[-1],),
                                        shape, device)
-
-    def mesh_fn_if_ready(self, matrix: np.ndarray, shape: tuple,
-                         plane_key: tuple, donate: bool):
-        """The mesh-sharded fused encode+CRC runner for (matrix, batch
-        shape, mesh plane) if compiled, else None after kicking off a
-        background warm-up — same contract as device_fn_if_ready, but
-        the executable spans every chip of the plane (`plane_key` =
-        (devices, n_dp, n_ls) from the pipeline's _MeshPlane)."""
-        devices, n_dp, n_ls = plane_key
-        return self.device_fn_if_ready(
-            "mesh", matrix, (shape[-1], devices, n_dp, n_ls,
-                             bool(donate)), shape)
 
 
 # ---------------------------------------------------------------------------

@@ -9,7 +9,6 @@ Profile keys beyond the standard k/m/w/technique/packetsize:
   c=N                   technique=shec_multiple|shec_single only: the
                         lost chunks the shingled code survives
                         (0 < c <= m <= k; ErasureCodeShec's `c`)
-  compute=int8|bf16     MXU accumulation path (default int8)
   batch_stripes=N       coalesce-size hint for the shared device
                         pipeline: at most N stripes fuse into one
                         dispatch for this codec's channels (validated
@@ -153,10 +152,7 @@ class ErasureCodeTpu(MatrixErasureCode):
         self._chan_lock = threading.Lock()
 
     def init(self, profile):
-        compute = profile.get("compute", ec_kernels.DEFAULT_COMPUTE)
-        if compute not in ec_kernels._COMPUTE_DTYPES:
-            raise ErasureCodeError(f"unknown compute={compute!r}")
-        self.backend = TpuBackend(compute)
+        self.backend = TpuBackend()
         if "host_cutover" in profile:
             self.backend.HOST_CUTOVER_BYTES = int(profile["host_cutover"])
         if "batch_stripes" in profile:
@@ -268,29 +264,11 @@ class ErasureCodeTpu(MatrixErasureCode):
                 return None     # background warm-up; host serves
             return fn(padded)
 
-        def mesh_fn(batch, plane, donate=False, keep_resident=False):
-            # pod-scale placement: the pipeline hands a whole
-            # mega-batch here when its staged bytes exceed one lane's
-            # budget; the backend's mesh runner shard_maps the chunk-
-            # length axis over the plane and returns host outputs
-            # bit-identical to host_fn (None while compiling — the
-            # batch then row-splits, same as a cold device_fn)
-            b = self.backend
-            if self.degraded or not isinstance(b, TpuBackend):
-                return None
-            run = b.mesh_fn_if_ready(matrix, tuple(batch.shape),
-                                     plane.key(), donate)
-            if run is None:
-                return None
-            parity, crcs, resident = run(batch,
-                                         keep_resident=keep_resident)
-            return (parity, crcs), resident
-
         chan = ec_pipeline.PipelineChannel(
             key=("enc", id(self), L),
             host_fn=host_fn, device_fn=device_fn, route=self._route,
             on_error=self._on_device_error, record=self._record,
-            max_coalesce=self.batch_stripes, mesh_fn=mesh_fn)
+            max_coalesce=self.batch_stripes)
         with self._chan_lock:
             return self._channels.setdefault(("enc", L), chan)
 
@@ -341,7 +319,7 @@ class ErasureCodeTpu(MatrixErasureCode):
     # -- batched stripe API (device-native entry points) -------------------
 
     def encode_stripes_with_crcs_async(self, stripes, cache=None,
-                                       qos=None, arena=None):
+                                       qos=None):
         """Submit an (S, k, L) stripe batch to the shared pipeline.
 
         Returns a handle whose .result() yields ((S, k+m, L) chunks,
@@ -357,28 +335,16 @@ class ErasureCodeTpu(MatrixErasureCode):
 
         `qos` names the service class (pool) the dispatch-lane picker
         schedules this batch under (ops.pipeline.configure_qos).
-
-        `arena` (an ops.pipeline.StagingArena the stripes were staged
-        into) marks the batch for donated mesh upload: on the mesh
-        path the arena's device buffer is donated to the computation
-        and the ``ec.stage`` copy retires; any other serve re-arms
-        the accounting.
         """
         stripes = np.ascontiguousarray(stripes, dtype=np.uint8)
         if stripes.ndim != 3 or stripes.shape[1] != self.k:
             raise ErasureCodeError(f"want (S, {self.k}, L), "
                                    f"got {stripes.shape}")
         if self.rep != REP_BYTES:
-            if arena is not None:
-                # bit-matrix techniques never enter the pipeline: the
-                # staging copy was a plain host materialization
-                from ..utils import copyaudit
-                arena.noted = True
-                copyaudit.note("ec.stage", arena.payload_bytes)
             return _Done(super().encode_stripes_with_crcs(stripes))
         chan = self._encode_channel(stripes.shape[2])
         fut = ec_pipeline.get().submit(chan, stripes, cache=cache,
-                                       qos=qos, arena=arena)
+                                       qos=qos)
         return _PipelinedEncode(self, stripes, fut)
 
     def encode_stripes_with_crcs(self, stripes) -> tuple:
@@ -439,8 +405,7 @@ class ErasureCodeTpu(MatrixErasureCode):
             self._degrade("injected device error")
         if not self.degraded:
             try:
-                fn = ec_kernels.make_encode_crc_fn(
-                    self.coding_matrix, L, compute=self.backend.compute)
+                fn = ec_kernels.make_encode_crc_fn(self.coding_matrix, L)
                 parity, crcs = fn(data)
                 return np.asarray(parity), np.asarray(crcs)
             except Exception as e:

@@ -35,25 +35,18 @@ from . import gf
 
 compile_cache.place()
 
-# Accumulation dtype pairs: int8 inputs with int32 accumulation hits the
-# MXU's integer path on TPU; bf16/f32 is a fallback knob for platforms
-# where the int8 path is slow.
-_COMPUTE_DTYPES = {
-    "int8": (jnp.int8, jnp.int32),
-    "bf16": (jnp.bfloat16, jnp.float32),
-}
-
-DEFAULT_COMPUTE = "int8"
+# int8 inputs with int32 accumulation: the MXU's integer path on TPU
+_IN_DTYPE, _ACC_DTYPE = jnp.int8, jnp.int32
 
 _BIT_SHIFTS = tuple(1 << b for b in range(8))
 
 
-def _unpack_bits(x: jnp.ndarray, in_dtype) -> jnp.ndarray:
+def _unpack_bits(x: jnp.ndarray) -> jnp.ndarray:
     """(..., n, L) uint8 -> (..., n*8, L) bits, row index = n*8 + bit."""
     shifts = jnp.arange(8, dtype=jnp.uint8).reshape((1,) * (x.ndim - 1) + (8, 1))
     bits = (x[..., :, None, :] >> shifts) & jnp.uint8(1)
     shape = x.shape[:-2] + (x.shape[-2] * 8, x.shape[-1])
-    return bits.reshape(shape).astype(in_dtype)
+    return bits.reshape(shape).astype(_IN_DTYPE)
 
 
 def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
@@ -65,26 +58,22 @@ def _pack_bits(bits: jnp.ndarray) -> jnp.ndarray:
 
 
 def _mod2(x: jnp.ndarray) -> jnp.ndarray:
-    if jnp.issubdtype(x.dtype, jnp.integer):
-        return (x & 1).astype(jnp.int32)
-    # float accumulation: values are exact small integers
-    return (x.astype(jnp.int32)) & 1
+    return (x & 1).astype(jnp.int32)
 
 
-def gf2_matmul_bytes(g_bits: jnp.ndarray, data: jnp.ndarray,
-                     compute: str = DEFAULT_COMPUTE) -> jnp.ndarray:
+def gf2_matmul_bytes(g_bits: jnp.ndarray,
+                     data: jnp.ndarray) -> jnp.ndarray:
     """Apply a GF(2) bit-matrix to byte chunks.
 
     g_bits: (R, C) 0/1 (R, C multiples of 8), data: (..., C/8, L) uint8
     -> (..., R/8, L) uint8.  The contraction runs on the MXU.
     """
-    in_dtype, acc_dtype = _COMPUTE_DTYPES[compute]
-    bits = _unpack_bits(data, in_dtype)
-    g = g_bits.astype(in_dtype)
+    bits = _unpack_bits(data)
+    g = g_bits.astype(_IN_DTYPE)
     acc = jax.lax.dot_general(
         g, bits,
         dimension_numbers=(((1,), (bits.ndim - 2,)), ((), ())),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=_ACC_DTYPE,
     )
     # dot_general output: (R, ..., L) — move R after batch dims
     if bits.ndim > 2:
@@ -107,33 +96,32 @@ def _k_packing(rows: int, cols: int, L: int) -> int:
     return d
 
 
-def gf2_matmul_bytes_packed(g_bits: jnp.ndarray, data: jnp.ndarray,
-                            compute: str = DEFAULT_COMPUTE) -> jnp.ndarray:
+def gf2_matmul_bytes_packed(g_bits: jnp.ndarray,
+                            data: jnp.ndarray) -> jnp.ndarray:
     """Like gf2_matmul_bytes but block-diagonally packed to fill the MXU.
 
     data: (B, k, L) uint8 -> (B, m, L) uint8.
     """
-    in_dtype, acc_dtype = _COMPUTE_DTYPES[compute]
     B, k, L = data.shape
     rows, cols = g_bits.shape
     m = rows // 8
     d = _k_packing(rows, cols, L)
     if d == 1:
-        return gf2_matmul_bytes(g_bits, data, compute)
+        return gf2_matmul_bytes(g_bits, data)
     Ld = L // d
     # block-diagonal packing = kron(I_d, g); jnp.kron keeps this
     # traceable (a sharded caller may feed a per-device generator
     # slice), and XLA constant-folds it for concrete matrices
     g = jnp.kron(jnp.eye(d, dtype=jnp.uint8),
-                 jnp.asarray(g_bits, dtype=jnp.uint8)).astype(in_dtype)
+                 jnp.asarray(g_bits, dtype=jnp.uint8)).astype(_IN_DTYPE)
     # segment b of the chunk axis -> block b of the packed contraction
     seg = data.reshape(B, k, d, Ld).transpose(0, 2, 1, 3)      # (B, d, k, Ld)
-    bits = _unpack_bits(seg, in_dtype)                          # (B, d, 8k, Ld)
+    bits = _unpack_bits(seg)                                    # (B, d, 8k, Ld)
     bits = bits.reshape(B, d * cols, Ld)
     acc = jax.lax.dot_general(
         g, bits,
         dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=_ACC_DTYPE,
     )                                                           # (dR, B, Ld)
     acc = jnp.transpose(acc, (1, 0, 2)).reshape(B, d, rows, Ld)
     packed = _pack_bits(_mod2(acc))                             # (B, d, m, Ld)
@@ -141,7 +129,7 @@ def gf2_matmul_bytes_packed(g_bits: jnp.ndarray, data: jnp.ndarray,
 
 
 @functools.lru_cache(maxsize=None)
-def _apply_fn(compute: str):
+def _apply_fn():
     """Jitted (g_bits (R, C), data (B, k, L) uint8) -> (B, R/8, L).
 
     The bit-matrix is an OPERAND, not a baked constant: every matrix
@@ -153,13 +141,12 @@ def _apply_fn(compute: str):
 
     @jax.jit
     def run_decode(g_bits, data):
-        return gf2_matmul_bytes_packed(g_bits, data, compute)
+        return gf2_matmul_bytes_packed(g_bits, data)
 
     return run_decode
 
 
-def make_codec_fn(matrix: np.ndarray, w: int = 8,
-                  compute: str = DEFAULT_COMPUTE):
+def make_codec_fn(matrix: np.ndarray, w: int = 8):
     """Build a jitted chunk transform from a GF(2^w) byte matrix.
 
     matrix: (m, k) uint8 over GF(2^8) (or an already-expanded GF(2)
@@ -173,7 +160,7 @@ def make_codec_fn(matrix: np.ndarray, w: int = 8,
         assert bits.shape[0] % 8 == 0 and bits.shape[1] % 8 == 0
     else:
         raise ValueError(f"unsupported w={w}")
-    fn = _apply_fn(compute)
+    fn = _apply_fn()
     bits = np.ascontiguousarray(bits)
 
     def call(data):
@@ -198,23 +185,22 @@ def make_codec_fn(matrix: np.ndarray, w: int = 8,
 # ---------------------------------------------------------------------------
 
 
-def gf2_packet_matmul(m_bits: jnp.ndarray, packets: jnp.ndarray,
-                      compute: str = DEFAULT_COMPUTE) -> jnp.ndarray:
+def gf2_packet_matmul(m_bits: jnp.ndarray,
+                      packets: jnp.ndarray) -> jnp.ndarray:
     """m_bits: (R, C) 0/1; packets: (..., C, P) uint8 -> (..., R, P) uint8.
 
     out[r] = XOR over c with m_bits[r, c] of packets[c]; bytes are 8
     independent GF(2) lanes, so unpack along the byte axis only.
     """
-    in_dtype, acc_dtype = _COMPUTE_DTYPES[compute]
     lead = packets.shape[:-2]
     C, P = packets.shape[-2:]
     shifts = jnp.arange(8, dtype=jnp.uint8)
     bits = ((packets[..., None] >> shifts) & jnp.uint8(1))
-    bits = bits.reshape(lead + (C, P * 8)).astype(in_dtype)
+    bits = bits.reshape(lead + (C, P * 8)).astype(_IN_DTYPE)
     acc = jax.lax.dot_general(
-        m_bits.astype(in_dtype), bits,
+        m_bits.astype(_IN_DTYPE), bits,
         dimension_numbers=(((1,), (bits.ndim - 2,)), ((), ())),
-        preferred_element_type=acc_dtype,
+        preferred_element_type=_ACC_DTYPE,
     )
     if bits.ndim > 2:
         perm = tuple(range(1, bits.ndim - 1)) + (0, bits.ndim - 1)
@@ -225,8 +211,7 @@ def gf2_packet_matmul(m_bits: jnp.ndarray, packets: jnp.ndarray,
 
 
 @functools.lru_cache(maxsize=256)
-def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int,
-               compute: str):
+def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int):
     rows, cols = shape_key
     m_bits = jnp.asarray(
         np.frombuffer(bits_key, dtype=np.uint8).reshape(rows, cols))
@@ -239,7 +224,7 @@ def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int,
         blocks = data.reshape(B, n, nblk, w, packetsize)
         packets = blocks.transpose(0, 2, 1, 3, 4).reshape(
             B, nblk, n * w, packetsize)
-        out = gf2_packet_matmul(m_bits, packets, compute)
+        out = gf2_packet_matmul(m_bits, packets)
         r = rows // w
         out = out.reshape(B, nblk, r, w, packetsize).transpose(0, 2, 1, 3, 4)
         return out.reshape(B, r, nblk * w * packetsize)
@@ -247,8 +232,7 @@ def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int,
     return run_packet_codec
 
 
-def make_packet_codec_fn(matrix: np.ndarray, w: int, packetsize: int,
-                         compute: str = DEFAULT_COMPUTE):
+def make_packet_codec_fn(matrix: np.ndarray, w: int, packetsize: int):
     """Jitted packetized transform from a GF(2^w) byte matrix.
 
     matrix: (r, c) uint8 -> fn(data (B, c, L) or (c, L)) -> (B, r, L)
@@ -256,16 +240,15 @@ def make_packet_codec_fn(matrix: np.ndarray, w: int, packetsize: int,
     reference's packetized encode).
     """
     bits = gf.expand_bitmatrix(np.asarray(matrix, dtype=np.uint8), w)
-    return make_bits_codec_fn(bits, w, packetsize, compute)
+    return make_bits_codec_fn(bits, w, packetsize)
 
 
-def make_bits_codec_fn(bits: np.ndarray, w: int, packetsize: int,
-                       compute: str = DEFAULT_COMPUTE):
+def make_bits_codec_fn(bits: np.ndarray, w: int, packetsize: int):
     """Jitted packetized transform from a raw GF(2) bit-matrix
     (liberation / blaum_roth minimal-density codes, which have no
     byte-matrix form)."""
     bits = np.asarray(bits, dtype=np.uint8)
-    fn = _packet_fn(bits.tobytes(), bits.shape, w, packetsize, compute)
+    fn = _packet_fn(bits.tobytes(), bits.shape, w, packetsize)
 
     def call(data):
         data = jnp.asarray(data, dtype=jnp.uint8)
@@ -289,8 +272,7 @@ CRC_GROUP = 64
 
 
 @functools.lru_cache(maxsize=64)
-def _crc_fn(nbytes: int, block: int, compute: str):
-    in_dtype, acc_dtype = _COMPUTE_DTYPES[compute]
+def _crc_fn(nbytes: int, block: int):
     nblk = nbytes // block
     hierarchical = nblk % CRC_GROUP == 0 and nblk >= CRC_GROUP
     if hierarchical:
@@ -312,33 +294,32 @@ def _crc_fn(nbytes: int, block: int, compute: str):
         blocks = chunks.reshape(lead + (nblk, block))
         shifts = jnp.arange(8, dtype=jnp.uint8)
         bits = (blocks[..., None] >> shifts) & jnp.uint8(1)   # (..., nblk, block, 8)
-        bits = bits.reshape(lead + (nblk, block * 8)).astype(in_dtype)
+        bits = bits.reshape(lead + (nblk, block * 8)).astype(_IN_DTYPE)
         # fold every block with the shared matrix: (..., nblk, 32)
         r = jax.lax.dot_general(
-            bits, fold.astype(in_dtype),
+            bits, fold.astype(_IN_DTYPE),
             dimension_numbers=(((bits.ndim - 1,), (1,)), ((), ())),
-            preferred_element_type=acc_dtype,
+            preferred_element_type=_ACC_DTYPE,
         )
-        r = _mod2(r).astype(in_dtype)
+        r = _mod2(r).astype(_IN_DTYPE)
         if hierarchical:
             ngroups = nblk // CRC_GROUP
             rg = r.reshape(lead + (ngroups, CRC_GROUP, 32))
-            s = jnp.einsum("tvu,...gtu->...gv", gcomb.astype(in_dtype), rg,
-                           preferred_element_type=acc_dtype)
-            s = _mod2(s).astype(in_dtype)
-            acc = jnp.einsum("gvu,...gu->...v", top.astype(in_dtype), s,
-                             preferred_element_type=acc_dtype)
+            s = jnp.einsum("tvu,...gtu->...gv", gcomb.astype(_IN_DTYPE), rg,
+                           preferred_element_type=_ACC_DTYPE)
+            s = _mod2(s).astype(_IN_DTYPE)
+            acc = jnp.einsum("gvu,...gu->...v", top.astype(_IN_DTYPE), s,
+                             preferred_element_type=_ACC_DTYPE)
         else:
-            acc = jnp.einsum("nvu,...nu->...v", comb.astype(in_dtype), r,
-                             preferred_element_type=acc_dtype)
+            acc = jnp.einsum("nvu,...nu->...v", comb.astype(_IN_DTYPE), r,
+                             preferred_element_type=_ACC_DTYPE)
         bits_out = _mod2(acc).astype(jnp.uint32)
         return jnp.sum(bits_out * weights32, axis=-1, dtype=jnp.uint32)
 
     return run_scrub_crc
 
 
-def make_crc_fn(nbytes: int, block: int = DEFAULT_CRC_BLOCK,
-                compute: str = DEFAULT_COMPUTE):
+def make_crc_fn(nbytes: int, block: int = DEFAULT_CRC_BLOCK):
     """Jitted CRC32C (seed 0) over the last axis: (..., L) uint8 -> (...) uint32.
 
     Seed chaining is applied on the host via crc32c.crc32c_combine (a 32x32
@@ -346,7 +327,7 @@ def make_crc_fn(nbytes: int, block: int = DEFAULT_CRC_BLOCK,
     """
     if nbytes % block:
         block = _pick_block(nbytes)
-    return _crc_fn(nbytes, block, compute)
+    return _crc_fn(nbytes, block)
 
 
 def _pick_block(nbytes: int) -> int:
@@ -363,15 +344,15 @@ def _pick_block(nbytes: int) -> int:
 
 @functools.lru_cache(maxsize=64)
 def _encode_crc_fn(g_bits_key: bytes, shape_key: tuple, nbytes: int,
-                   block: int, compute: str, witness_only: bool = False):
+                   block: int, witness_only: bool = False):
     rows, cols = shape_key
     g_bits = np.frombuffer(g_bits_key, dtype=np.uint8).reshape(rows, cols)
     g_const = jnp.asarray(g_bits)
-    crc = _crc_fn(nbytes, block, compute)
+    crc = _crc_fn(nbytes, block)
 
     @jax.jit
     def run_xla_encode_crc(data):
-        parity = gf2_matmul_bytes_packed(g_const, data, compute)
+        parity = gf2_matmul_bytes_packed(g_const, data)
         chunks = jnp.concatenate([data, parity], axis=-2)
         return crc(chunks) if witness_only else (parity, crc(chunks))
 
@@ -388,8 +369,7 @@ def encode_readback_bytes(B: int, k: int, m: int, L: int) -> int:
 
 
 def make_encode_crc_fn(matrix: np.ndarray, nbytes: int,
-                       block: int = DEFAULT_CRC_BLOCK,
-                       compute: str = DEFAULT_COMPUTE):
+                       block: int = DEFAULT_CRC_BLOCK):
     """fn(data (B, k, L)) -> (parity (B, m, L), crcs (B, k+m) uint32).
 
     One device dispatch per batch: chunks cross PCIe once (parity-only
@@ -400,12 +380,11 @@ def make_encode_crc_fn(matrix: np.ndarray, nbytes: int,
     bits = gf.expand_bitmatrix(np.asarray(matrix, dtype=np.uint8), 8)
     if nbytes % block:
         block = _pick_block(nbytes)
-    return _encode_crc_fn(bits.tobytes(), bits.shape, nbytes, block, compute)
+    return _encode_crc_fn(bits.tobytes(), bits.shape, nbytes, block)
 
 
 def make_encode_crc_witness_fn(matrix: np.ndarray, nbytes: int,
-                               block: int = DEFAULT_CRC_BLOCK,
-                               compute: str = DEFAULT_COMPUTE):
+                               block: int = DEFAULT_CRC_BLOCK):
     """Benchmark/scrub variant: fn(data (B, k, L)) -> crcs (B, k+m) uint32.
 
     Parity never leaves the device — only the 32-bit-per-chunk scrub
@@ -416,208 +395,5 @@ def make_encode_crc_witness_fn(matrix: np.ndarray, nbytes: int,
     bits = gf.expand_bitmatrix(np.asarray(matrix, dtype=np.uint8), 8)
     if nbytes % block:
         block = _pick_block(nbytes)
-    return _encode_crc_fn(bits.tobytes(), bits.shape, nbytes, block, compute,
+    return _encode_crc_fn(bits.tobytes(), bits.shape, nbytes, block,
                           witness_only=True)
-
-
-# ---------------------------------------------------------------------------
-# Mesh-sharded kernels (pod-scale: ONE batch across the device mesh)
-#
-# A single mega-batch larger than one chip's HBM cannot ride a dispatch
-# lane; it CAN ride the whole mesh.  The GF(2^8) encode matmul is
-# row-local in the chunk-length axis L (parity byte l depends only on
-# data bytes at position l), so shard_map-ing L across an "ls" mesh
-# axis needs NO communication for the parity — each device encodes its
-# L-slice against the full generator.  The per-chunk scrub CRC is
-# GF(2)-linear in the message under seed 0, so each device folds its
-# slice locally, advances the partial through the zero-advance matrix
-# for the bytes that FOLLOW its slice (crc32c.advance_matrix), and an
-# XOR psum over "ls" combines the partials ON DEVICE — the only CRC
-# bytes that cross D2H are the final 4 per chunk.
-#
-# L that does not divide by the mesh width is FRONT-padded with zeros:
-# with seed 0 the CRC register stays 0 through leading zero bytes, so
-# crc(0^pad || chunk) == crc(chunk), and the parity of the pad columns
-# is itself zero — both outputs slice back exactly.  An optional "dp"
-# axis additionally shards the stripe axis (conf osd_ec_device_mesh
-# "AxB"); S pads with zero stripes the caller slices off.
-# ---------------------------------------------------------------------------
-
-
-def _crc_bits_u32(c: jnp.ndarray) -> jnp.ndarray:
-    """(...,) uint32 -> (..., 32) 0/1 bits, bit i = (crc >> i) & 1
-    (the crc32c GF(2) state convention)."""
-    shifts = jnp.arange(32, dtype=jnp.uint32)
-    return ((c[..., None] >> shifts) & jnp.uint32(1))
-
-
-def mesh_geometry(nbytes: int, n_ls: int) -> tuple[int, int, int]:
-    """(L_pad, Lp, pad) for sharding an L=nbytes chunk axis over n_ls
-    devices: L front-pads to the next multiple of n_ls."""
-    L_pad = -(-nbytes // n_ls) * n_ls
-    return L_pad, L_pad // n_ls, L_pad - nbytes
-
-
-def _mesh_context(devices, n_dp: int, n_ls: int):
-    """Build the dp x ls jax Mesh plus the sharding/shard_map imports
-    shared by the mesh kernel builders."""
-    from jax import shard_map
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-    devs = np.array(list(devices)).reshape(n_dp, n_ls)
-    return jax, Mesh(devs, ("dp", "ls")), NamedSharding, P, shard_map
-
-
-def _slice_combine_matrices(n_ls: int, Lp: int) -> np.ndarray:
-    """(n_ls, 32, 32) GF(2): slice j's CRC partial advanced over the
-    (n_ls-1-j)*Lp bytes that follow it, so XOR over j yields the full
-    chunk CRC (linearity of seed-0 CRC32C in the message bits)."""
-    return np.stack([crc_mod.advance_matrix((n_ls - 1 - j) * Lp)
-                     for j in range(n_ls)]).astype(np.uint8)
-
-
-def _combine_local_crcs(jax, c, comb_c, in_dtype, acc_dtype):
-    """Advance this shard's (..., km) uint32 CRC partials by its slice
-    position and XOR-psum over the "ls" axis -> full (..., km) CRCs."""
-    idx = jax.lax.axis_index("ls")
-    M = comb_c[idx]                              # (32, 32), static per device
-    bits = _crc_bits_u32(c).astype(in_dtype)
-    adv = jnp.einsum("vu,...u->...v", M.astype(in_dtype), bits,
-                     preferred_element_type=acc_dtype)
-    tot = jax.lax.psum(_mod2(adv), "ls")         # GF(2) add == XOR
-    full = (tot & 1).astype(jnp.uint32)
-    weights32 = jnp.asarray([1 << i for i in range(32)], dtype=jnp.uint32)
-    return jnp.sum(full * weights32, axis=-1, dtype=jnp.uint32)
-
-
-def make_mesh_encode_crc_fn(matrix: np.ndarray, nbytes: int, devices,
-                            n_dp: int = 1, n_ls: int | None = None,
-                            compute: str = DEFAULT_COMPUTE,
-                            donate: bool = False):
-    """Mesh-sharded fused encode+CRC over len(devices) chips.
-
-    Returns run(batch (S, k, L=nbytes) uint8, keep_resident=False) ->
-    (parity (S, m, L) uint8, crcs (S, k+m) uint32, resident) with
-    outputs BIT-IDENTICAL to the single-device fused kernel / host
-    oracle.  resident is None, or (dev_data, dev_parity, chunk_pad) —
-    the mesh-sharded device arrays for the HBM stripe cache — when
-    keep_resident is asked and the input was not donated.
-
-    `donate` compiles with donate_argnums so the staged input buffer
-    is DONATED to the computation: its device allocation is consumed
-    (XLA may alias it for outputs) and the uploaded bytes are never
-    echoed — the staging arena copy becomes the H2D upload itself.
-    """
-    devices = tuple(devices)
-    if n_ls is None:
-        n_ls = len(devices) // max(1, n_dp)
-    if n_dp * n_ls != len(devices):
-        raise ValueError(f"mesh {n_dp}x{n_ls} != {len(devices)} devices")
-    jax_mod, mesh, NamedSharding, P, shard_map = _mesh_context(
-        devices, n_dp, n_ls)
-    in_dtype, acc_dtype = _COMPUTE_DTYPES[compute]
-    bits = gf.expand_bitmatrix(np.asarray(matrix, dtype=np.uint8), 8)
-    g_const = jnp.asarray(bits)
-    k = bits.shape[1] // 8
-    m = bits.shape[0] // 8
-    L = int(nbytes)
-    L_pad, Lp, pad = mesh_geometry(L, n_ls)
-    block = DEFAULT_CRC_BLOCK if Lp % DEFAULT_CRC_BLOCK == 0 \
-        else _pick_block(Lp)
-    crc_local = _crc_fn(Lp, block, compute)
-    comb_c = jnp.asarray(_slice_combine_matrices(n_ls, Lp))
-
-    def local_fn(local):
-        # local: (S/n_dp, k, Lp) — this device's chunk-length slice
-        parity = gf2_matmul_bytes_packed(g_const, local, compute)
-        chunks = jnp.concatenate([local, parity], axis=-2)
-        c = crc_local(chunks)                       # (s, k+m) partials
-        full = _combine_local_crcs(jax_mod, c, comb_c, in_dtype,
-                                   acc_dtype)
-        return parity, full
-
-    sharded = shard_map(local_fn, mesh=mesh,
-                        in_specs=(P("dp", None, "ls"),),
-                        out_specs=(P("dp", None, "ls"), P("dp", None)))
-    jitted = jax_mod.jit(sharded, donate_argnums=(0,) if donate else ())
-    data_sharding = NamedSharding(mesh, P("dp", None, "ls"))
-
-    def run(batch: np.ndarray, keep_resident: bool = False):
-        S = batch.shape[0]
-        S_pad = -(-S // n_dp) * n_dp
-        arr = batch
-        if pad or S_pad != S:
-            # uneven geometry: front-pad L (leading zeros are CRC- and
-            # parity-neutral) and tail-pad S with zero stripes — a
-            # real host copy of the whole batch, audited so the mesh
-            # path's copy story stays honest even when a degraded
-            # plane's width stops dividing L
-            arr = np.zeros((S_pad, k, L_pad), dtype=np.uint8)
-            arr[:S, :, pad:] = batch
-            from ..utils import copyaudit
-            copyaudit.note("ec.mesh_pad", batch.nbytes)
-        dev = jax_mod.device_put(arr, data_sharding)
-        parity_dev, crcs_dev = jitted(dev)
-        crcs = np.asarray(crcs_dev)[:S]
-        parity = np.asarray(parity_dev)
-        if pad or S_pad != S:
-            parity = parity[:S, :, pad:]
-        resident = None
-        if keep_resident and not donate:
-            resident = (dev, parity_dev, pad)
-        return parity, crcs, resident
-
-    run.chunk_pad = pad
-    run.mesh_devices = devices
-    # the jitted program and its input sharding, for ahead-of-time
-    # compiles against a described (not attached) topology
-    run.jitted = jitted
-    run.data_sharding = data_sharding
-    return run
-
-
-def make_mesh_crc_fn(nbytes: int, devices, n_dp: int = 1,
-                     n_ls: int | None = None,
-                     compute: str = DEFAULT_COMPUTE):
-    """Mesh-sharded CRC32C(seed 0) fold: run(batch (B, nbytes) uint8)
-    -> (B,) uint32, the deep-scrub channel's mega-batch form.  Each
-    device folds its slice of every row; partials combine on device
-    (advance + XOR psum) so D2H is 4 bytes per row."""
-    devices = tuple(devices)
-    if n_ls is None:
-        n_ls = len(devices) // max(1, n_dp)
-    if n_dp * n_ls != len(devices):
-        raise ValueError(f"mesh {n_dp}x{n_ls} != {len(devices)} devices")
-    jax_mod, mesh, NamedSharding, P, shard_map = _mesh_context(
-        devices, n_dp, n_ls)
-    in_dtype, acc_dtype = _COMPUTE_DTYPES[compute]
-    L = int(nbytes)
-    L_pad, Lp, pad = mesh_geometry(L, n_ls)
-    block = DEFAULT_CRC_BLOCK if Lp % DEFAULT_CRC_BLOCK == 0 \
-        else _pick_block(Lp)
-    crc_local = _crc_fn(Lp, block, compute)
-    comb_c = jnp.asarray(_slice_combine_matrices(n_ls, Lp))
-
-    def local_fn(local):
-        c = crc_local(local)                        # (b,) partials
-        return _combine_local_crcs(jax_mod, c, comb_c, in_dtype,
-                                   acc_dtype)
-
-    sharded = shard_map(local_fn, mesh=mesh,
-                        in_specs=(P("dp", "ls"),),
-                        out_specs=P("dp"))
-    jitted = jax_mod.jit(sharded)
-    data_sharding = NamedSharding(mesh, P("dp", "ls"))
-
-    def run(batch: np.ndarray):
-        B = batch.shape[0]
-        B_pad = -(-B // n_dp) * n_dp
-        arr = batch
-        if pad or B_pad != B:
-            arr = np.zeros((B_pad, L_pad), dtype=np.uint8)
-            arr[:B, pad:] = batch
-        dev = jax_mod.device_put(arr, data_sharding)
-        return np.asarray(jitted(dev))[:B]
-
-    run.chunk_pad = pad
-    run.mesh_devices = devices
-    return run

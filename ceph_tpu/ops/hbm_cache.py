@@ -91,33 +91,20 @@ class CacheIntent:
         self.chunk_size = int(chunk_size)
 
 
-def _on_lane(entry_lane, lane: int) -> bool:
-    """Whether an entry is resident on `lane`: single-lane entries pin
-    an int, MESH-resident entries (stripes sharded across the device
-    mesh) pin the tuple of every member lane — losing any one chip
-    loses a slice of the stripes, so membership means resident."""
-    if isinstance(entry_lane, tuple):
-        return lane in entry_lane
-    return entry_lane == lane
-
-
 class CacheEntry:
     """One object's encoded stripes, device-resident.
 
     dev_data (S, k, L) is the uploaded data batch, dev_parity
-    (S, m, L) the on-device encode output — both still on the lane's
-    chip (or sharded across a mesh's chips for a mesh dispatch, in
-    which case `lane` is the member-lane tuple and `pad` the leading
-    zero bytes each chunk was front-padded with for even sharding);
-    crcs (S, k+m) uint32 are the fused kernel's per-stripe chunk
-    CRCs (host-side, 4 bytes per chunk)."""
+    (S, m, L) the on-device encode output — both still on the chip
+    of pipeline lane `lane`; crcs (S, k+m) uint32 are the fused
+    kernel's per-stripe chunk CRCs (host-side, 4 bytes per chunk)."""
 
     __slots__ = ("cid", "oid", "version", "size", "chunk_size", "k",
                  "m", "dev_data", "dev_parity", "crcs", "lane",
-                 "pad", "nbytes", "committed")
+                 "nbytes", "committed")
 
-    def __init__(self, intent: CacheIntent, lane, dev_data,
-                 dev_parity, crcs: np.ndarray, pad: int = 0):
+    def __init__(self, intent: CacheIntent, lane: int, dev_data,
+                 dev_parity, crcs: np.ndarray):
         self.cid = intent.cid
         self.oid = intent.oid
         self.version = intent.version
@@ -129,7 +116,6 @@ class CacheEntry:
         self.dev_parity = dev_parity
         self.crcs = np.asarray(crcs, dtype=np.uint32)
         self.lane = lane
-        self.pad = int(pad)
         self.nbytes = (int(np.prod(dev_data.shape))
                        + int(np.prod(dev_parity.shape))
                        + self.crcs.nbytes)
@@ -146,23 +132,11 @@ class CacheEntry:
         """The logical object payload, fetched D2H from the cached
         data stripes (None if the device buffers are gone).  Returns a
         zero-copy BufferList VIEW over the fetched array — the D2H
-        fetch is the only materialization a cache-served read pays.
-        Mesh entries strip each chunk's leading pad after the fetch
-        (per-shard addressing keeps the padded on-device layout)."""
+        fetch is the only materialization a cache-served read pays."""
         try:
-            arr = np.asarray(self.dev_data, dtype=np.uint8)
+            arr = np.ascontiguousarray(
+                np.asarray(self.dev_data, dtype=np.uint8))
             get().count_d2h(arr.nbytes)
-            if self.pad:
-                # stripping each chunk's leading pad leaves a strided
-                # view; serving it as one rope needs a contiguous
-                # copy — a real read-path materialization, audited so
-                # host_copies_per_read stays honest for padded mesh
-                # entries
-                arr = np.ascontiguousarray(arr[:, :, self.pad:])
-                from ..utils import copyaudit
-                copyaudit.note("cache.mesh_unpad", arr.nbytes)
-            else:
-                arr = np.ascontiguousarray(arr)
         except Exception:
             return None
         from ..utils.bufferlist import BufferList
@@ -172,8 +146,7 @@ class CacheEntry:
 
     def shard_bytes(self, shard: int) -> bytes | None:
         """One shard file's bytes (chunk `shard` of every stripe),
-        fetched D2H — only this shard's rows cross the boundary (for
-        a mesh entry: that row's slice from each member chip)."""
+        fetched D2H — only this shard's rows cross the boundary."""
         try:
             if shard < self.k:
                 arr = np.asarray(self.dev_data[:, shard],
@@ -184,8 +157,6 @@ class CacheEntry:
         except Exception:
             return None
         get().count_d2h(arr.nbytes)
-        if self.pad:
-            arr = arr[:, self.pad:]
         return arr.tobytes()
 
 
@@ -216,18 +187,16 @@ class HbmStripeCache:
 
     # -- write path --------------------------------------------------------
 
-    def stage(self, intent: CacheIntent, lane, dev_data,
-              dev_parity, crcs: np.ndarray, pad: int = 0) -> None:
+    def stage(self, intent: CacheIntent, lane: int, dev_data,
+              dev_parity, crcs: np.ndarray) -> None:
         """Pipeline collect-time staging: the entry exists but is NOT
         servable until the producer commits it (shard bytes on disk).
-        `lane` is an int for a single-chip dispatch or the member-lane
-        tuple for a mesh dispatch (sharded residency); `pad` is the
-        mesh path's per-chunk leading zero pad."""
+        `lane` is the index of the pipeline lane whose chip holds the
+        arrays."""
         if self.capacity <= 0:
             return
         try:
-            ent = CacheEntry(intent, lane, dev_data, dev_parity, crcs,
-                             pad=pad)
+            ent = CacheEntry(intent, lane, dev_data, dev_parity, crcs)
         except Exception:
             return
         if ent.nbytes > self.capacity:
@@ -288,12 +257,7 @@ class HbmStripeCache:
             return False
         if ent is None or ent.version != tuple(old_version) or \
                 ent.chunk_size != chunk_size or \
-                ent.stripes < full_before or \
-                isinstance(ent.lane, tuple) or ent.pad:
-            # mesh-resident entries don't append-through: the tail
-            # concat would need resharding across the mesh — the
-            # conservative invalidate keeps coherence semantics
-            # identical and the next whole write restages
+                ent.stripes < full_before:
             self.invalidate(cid, oid)
             return False
         try:
@@ -443,14 +407,14 @@ class HbmStripeCache:
         with self._lock:
             dropped = 0
             for key in [k for k, e in self._entries.items()
-                        if _on_lane(e.lane, lane)]:
+                        if e.lane == lane]:
                 ent = self._entries.pop(key)
                 self._bytes -= ent.nbytes
                 dropped += 1
                 if key not in self._pending:
                     self._bases.discard(key)
             for key in [k for k, e in self._pending.items()
-                        if _on_lane(e.lane, lane)]:
+                        if e.lane == lane]:
                 pend = self._pending.pop(key)
                 self._pbytes -= pend.nbytes
                 dropped += 1

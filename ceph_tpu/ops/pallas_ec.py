@@ -130,7 +130,7 @@ def make_encode_fn(matrix: np.ndarray, L: int, tile: int = DEFAULT_TILE,
 
     L must be a multiple of 128 (use ec_kernels.make_codec_fn for odd
     sizes).  `interpret` defaults to True off-TPU so tests exercise the
-    same kernel on the CPU mesh.
+    same kernel on the CPU devices.
     """
     m, k = np.asarray(matrix).shape
     t = _pick_tile(L, tile)

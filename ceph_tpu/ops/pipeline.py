@@ -49,7 +49,7 @@ This module is the shared dispatcher all producers feed:
     contended dispatch slots (weight w -> one pick in round(1/w)).
 
   * **zero-copy transfer plane** — each lane owns a STAGER thread and
-    a double-buffered staging arena: the dispatcher hands a planned
+    a double-buffered staging queue: the dispatcher hands a planned
     part to the lane and moves on immediately; the stager performs the
     H2D upload and issues the async compute, so batch N+1 uploads
     while batch N computes and uploads to different chips run in
@@ -72,29 +72,6 @@ This module is the shared dispatcher all producers feed:
     measured slow chip would win the least-loaded tie, placement
     routes around it and ``cost_diverged`` counts how often the
     measured choice disagreed with least-loaded.
-  * **mesh dispatch** — the pod-scale placement mode: ONE coalesced
-    batch whose staged bytes exceed a single lane's budget
-    (``osd_ec_mesh_min_bytes``) shard_maps across a device mesh built
-    from the active lanes (``osd_ec_device_mesh`` picks the axis
-    layout: "auto" = every active chip on one chunk-length axis,
-    "AxB" = dp x ls) instead of splitting into independent per-lane
-    row batches.  Parity is row-local in the chunk-length axis so the
-    L-split needs no communication; scrub/chunk CRC partials combine
-    ON device (XOR psum) before one small D2H fetch — this is what
-    lets a batch bigger than one chip's HBM dispatch at all.  The
-    quarantine ladder extends downward: a device fault inside a mesh
-    dispatch degrades THAT batch to surviving-lane row splits (then
-    host), bit-identically (``mesh_dispatches`` / ``mesh_degrades``).
-  * **pinned staging arenas + donation** — mesh-sized encodes stage
-    their payload into a reusable arena buffer
-    (:meth:`EcDevicePipeline.checkout_arena`); on the mesh path the
-    arena's device allocation is DONATED to the computation
-    (``donate_argnums``), so the ``ec.stage`` staging copy *is* the
-    H2D upload — the copy-audit site retires there
-    (``arena_donations`` counts it) and re-arms automatically if the
-    batch degrades to a non-mesh path.  An arena is never recycled
-    while its dispatch (or the shard fan-out reading it) is in
-    flight; release() returns it to the pool for the next mega-write.
 
 Host batches run inline on the dispatcher thread — single-threaded
 host execution is itself the coalescing backpressure: while one host
@@ -119,14 +96,13 @@ from concurrent.futures import Future
 
 import numpy as np
 
-from ..utils import copyaudit, faults
+from ..utils import faults
 from . import hbm_cache
 
 # defaults; daemons override via configure() from their conf
 # (osd_ec_pipeline_depth / _coalesce_ms / _max_batch /
 #  osd_ec_device_shards / osd_ec_pipeline_scrub_weight /
 #  osd_ec_cost_aware_placement / osd_ec_hbm_cache_bytes /
-#  osd_ec_mesh_min_bytes / osd_ec_device_mesh /
 #  osd_qos_cost_bytes_unit)
 DEFAULT_DEPTH = 2
 DEFAULT_COALESCE_WAIT = 0.002
@@ -134,14 +110,12 @@ DEFAULT_MAX_BATCH = 256
 DEFAULT_SPLIT_MIN = 4       # min stripes per per-chip shard of a split
 DEFAULT_SCRUB_WEIGHT = 0.25
 DEFAULT_COST_AWARE = True
-# a single lane's staging budget: a coalesced batch larger than this
-# cannot ride one chip's HBM and dispatches via the device mesh
-DEFAULT_MESH_MIN_BYTES = 256 << 20
-DEFAULT_DEVICE_MESH = "auto"
+# the most bytes of one batch whose item slices _warm_item_slices
+# compiles ahead of serving
+LANE_STAGE_BYTES = 256 << 20
 # dmClock cost normalization for the dispatch-lane tenant picker
 # (mirrors the op queue's osd_qos_cost_bytes_unit; 0 = cost 1/pick)
 DEFAULT_QOS_COST_UNIT = 4096
-ARENA_POOL_MAX = 4          # free staging arenas kept for reuse
 # a measured-cost pick must beat the least-loaded pick by this factor
 # to override it: EMA noise alone must not starve a healthy lane of
 # the rotation (unprobed lanes have no EMA and always keep their turn)
@@ -289,21 +263,14 @@ class PipelineChannel:
     record(path, nbytes, secs, depth) feeds the owner's
     measured-routing EMA.  qos_class "scrub" marks channels that
     yield to "write" channels under contention.
-
-    mesh_fn(batch, plane, donate=False, keep_resident=False) is the
-    optional pod-scale entry: serve one whole batch sharded across
-    `plane`'s device mesh, returning (outputs, resident) — outputs
-    bit-identical to host_fn(batch), resident the device arrays for
-    the HBM cache or None — or None while the mesh kernel is still
-    compiling (the batch then row-splits or host-serves).
     """
 
     __slots__ = ("key", "host_fn", "device_fn", "route", "on_error",
-                 "record", "max_coalesce", "qos_class", "mesh_fn")
+                 "record", "max_coalesce", "qos_class")
 
     def __init__(self, key, host_fn, device_fn=None, route=None,
                  on_error=None, record=None, max_coalesce=None,
-                 qos_class="write", mesh_fn=None):
+                 qos_class="write"):
         self.key = key
         self.host_fn = host_fn
         self.device_fn = _wrap_device_fn(device_fn)
@@ -313,84 +280,18 @@ class PipelineChannel:
         self.record = _wrap_record(record)
         self.max_coalesce = max_coalesce
         self.qos_class = qos_class
-        self.mesh_fn = mesh_fn
-
-
-class StagingArena:
-    """One reusable (pinned, on a real rig) staging buffer: the
-    producer copies its payload rope straight into `buf`, the mesh
-    dispatch uploads FROM it with the device allocation donated to
-    the computation — so the staging copy and the H2D transfer are
-    one move, and the audited ``ec.stage`` site retires on that path.
-
-    Lifecycle: :meth:`EcDevicePipeline.checkout_arena` hands out a
-    zeroed buffer that is NOT in the free pool (a concurrent
-    submission always gets a fresh arena); the last reader — the
-    shard fan-out that lays shards out of the staged stripes — calls
-    :meth:`release` to return it.  ``consumed`` latches once a mesh
-    dispatch donated/uploaded it (the pipeline never reads it again);
-    a batch that degrades to a non-mesh path instead notes the
-    staging copy under ``ec.stage`` at resolve time, so the copy
-    audit stays honest on every rung of the ladder."""
-
-    __slots__ = ("buf", "payload_bytes", "consumed", "noted", "_pool")
-
-    def __init__(self, buf: np.ndarray, payload_bytes: int, pool):
-        self.buf = buf
-        self.payload_bytes = int(payload_bytes)
-        self.consumed = False
-        self.noted = False
-        self._pool = pool
-
-    def release(self) -> None:
-        pool, self._pool = self._pool, None
-        if pool is None:
-            return
-        if self.consumed or self.noted:
-            pool._return_arena(self)
-        else:
-            # neither flag set means the pipeline never RESOLVED this
-            # arena's item — the producer self-served around a wedged
-            # dispatch (RESULT_TIMEOUT) and the queued item still
-            # views buf.  Recycling it would let a new checkout zero
-            # the buffer under that live reader; drop it instead (the
-            # item's view keeps the memory alive, it just never
-            # re-enters the pool).
-            self.buf = None
-
-
-class _MeshPlane:
-    """The dp x ls device mesh a pod-scale dispatch shard_maps over:
-    a snapshot of the active lanes at build time.  Invalidated when
-    any member lane quarantines or the device set rebuilds."""
-
-    __slots__ = ("lanes", "lane_indices", "devices", "n_dp", "n_ls")
-
-    def __init__(self, lanes: list, n_dp: int, n_ls: int):
-        self.lanes = lanes
-        self.lane_indices = tuple(l.index for l in lanes)
-        self.devices = tuple(l.device for l in lanes)
-        self.n_dp = n_dp
-        self.n_ls = n_ls
-
-    def key(self) -> tuple:
-        return (self.devices, self.n_dp, self.n_ls)
 
 
 class _Item:
-    __slots__ = ("arr", "n", "fut", "t", "cache", "tag", "arena",
-                 "no_mesh", "ph")
+    __slots__ = ("arr", "n", "fut", "t", "cache", "tag", "ph")
 
-    def __init__(self, arr: np.ndarray, cache=None, tag=None,
-                 arena=None):
+    def __init__(self, arr: np.ndarray, cache=None, tag=None):
         self.arr = arr
         self.n = arr.shape[0]
         self.fut: Future = Future()
         self.t = time.monotonic()
         self.cache = cache          # hbm_cache.CacheIntent | None
         self.tag = tag              # QoS service class (pool name)
-        self.arena = arena          # StagingArena | None
-        self.no_mesh = False        # degrade latch: never re-mesh
         # op-tracing phase stamps (time.monotonic — the span
         # timebase): submit -> picked (coalesce wait) -> stage0/1
         # (H2D) -> issue -> collect0 (compute done) -> done (D2H), or
@@ -403,7 +304,7 @@ class _Item:
 class _Lane:
     """One device's dispatch lane: its own overlap window (a deque of
     in-flight dispatches bounded by the pipeline depth), a stager
-    thread + staging queue (the double-buffered H2D arena: upload of
+    thread + staging queue (double-buffered H2D: upload of
     batch N+1 proceeds while batch N computes, and uploads to
     different chips run in parallel), its own collector thread,
     transfer accounting, and per-shape-bucket marginal service-time
@@ -576,8 +477,6 @@ class EcDevicePipeline:
                  split_min: int = DEFAULT_SPLIT_MIN,
                  scrub_weight: float = DEFAULT_SCRUB_WEIGHT,
                  cost_aware: bool = DEFAULT_COST_AWARE,
-                 mesh_min_bytes: int = DEFAULT_MESH_MIN_BYTES,
-                 device_mesh: str = DEFAULT_DEVICE_MESH,
                  qos_cost_unit: int = DEFAULT_QOS_COST_UNIT):
         self.depth = max(1, int(depth))
         self.coalesce_wait = float(coalesce_wait)
@@ -586,12 +485,7 @@ class EcDevicePipeline:
         self.split_min = max(1, int(split_min))
         self.scrub_weight = float(scrub_weight)
         self.cost_aware = bool(cost_aware)
-        self.mesh_min_bytes = int(mesh_min_bytes)
-        self.device_mesh = str(device_mesh)
         self.qos_cost_unit = max(0, int(qos_cost_unit))
-        self._mesh: _MeshPlane | None = None
-        self._arena_lock = threading.Lock()
-        self._arena_free: list[np.ndarray] = []
         self._lock = threading.Lock()
         # three predicates, one lock: queued work (dispatcher waits),
         # in-flight dispatches (lane collectors wait), freed overlap
@@ -629,8 +523,9 @@ class EcDevicePipeline:
             "qos_scrub_yields": 0, "qos_cost_picks": 0,
             "bytes_h2d": 0, "bytes_d2h": 0,
             "cost_placements": 0, "cost_diverged": 0,
-            "mesh_dispatches": 0, "mesh_degrades": 0,
-            "arena_donations": 0,
+            # always 0: benchmark/cluster.py ZERO_COUNTERS reads this
+            # key from perf dump's ec_pipeline block
+            "mesh_degrades": 0,
             "devset_errors": 0, "route_errors": 0,
             "result_timeouts": 0,
         }
@@ -689,7 +584,6 @@ class EcDevicePipeline:
             if ds is not None:
                 for lane in ds.lanes:
                     lane.alive = False
-            self._mesh = None
             self._stalled = False
             self._inflight_cv.notify_all()
         # lane indices renumber with the topology: entries pinned to
@@ -707,7 +601,6 @@ class EcDevicePipeline:
             if ds is not None:
                 for lane in ds.lanes:
                     lane.alive = False
-            self._mesh = None
             self._work_cv.notify_all()
             self._inflight_cv.notify_all()
             self._fetch_cv.notify_all()
@@ -742,46 +635,8 @@ class EcDevicePipeline:
 
     # -- producer side -----------------------------------------------------
 
-    def checkout_arena(self, nbytes: int,
-                       payload_bytes: int | None = None):
-        """A staging arena for a mesh-sized encode, or None when the
-        batch is under the lane budget (the caller then stages into a
-        plain buffer and the classic ``ec.stage`` accounting applies).
-        Exclusively owned until release(); concurrent checkouts never
-        share a buffer.  The stripe-padding TAIL (everything past
-        `payload_bytes`) comes back zeroed; the first `payload_bytes`
-        are the caller's to overwrite entirely — a pooled reuse must
-        not pay a full multi-hundred-MiB memset on the hot staging
-        path when the payload copy-in immediately rewrites it."""
-        if self.mesh_min_bytes <= 0 or nbytes < self.mesh_min_bytes:
-            return None
-        zero_from = 0 if payload_bytes is None \
-            else min(int(payload_bytes), nbytes)
-        buf = None
-        with self._arena_lock:
-            for i, b in enumerate(self._arena_free):
-                if b.nbytes == nbytes:
-                    buf = self._arena_free.pop(i)
-                    break
-        if buf is None:
-            buf = np.zeros(nbytes, dtype=np.uint8)
-        elif zero_from < nbytes:
-            buf[zero_from:] = 0
-        return StagingArena(
-            buf, payload_bytes if payload_bytes is not None
-            else nbytes, self)
-
-    def _return_arena(self, arena: StagingArena) -> None:
-        buf, arena.buf = arena.buf, None
-        if buf is None:
-            return
-        with self._arena_lock:
-            if len(self._arena_free) < ARENA_POOL_MAX:
-                self._arena_free.append(buf)
-
     def submit(self, chan: PipelineChannel, arr: np.ndarray,
-               cache=None, qos: str | None = None,
-               arena=None) -> Future:
+               cache=None, qos: str | None = None) -> Future:
         """Queue a (B, ...) uint8 batch on `chan`.  The future resolves
         to (path, outputs) with path in {"dev", "host"} and outputs the
         channel fn's tuple, sliced to this submission's B rows.
@@ -795,15 +650,11 @@ class EcDevicePipeline:
         client-write encodes): work of one class coalesces together
         and the dispatcher's picks honor the class's dmClock tags
         (configure_qos) — dispatch-level reservation/weight/limit, so
-        a tenant saturating encodes cannot monopolize the lanes.
-
-        `arena` (a StagingArena the submission's stripes were staged
-        into) marks the batch for donated mesh upload; a non-mesh
-        serve re-arms the ``ec.stage`` copy accounting instead."""
+        a tenant saturating encodes cannot monopolize the lanes."""
         arr = np.ascontiguousarray(arr, dtype=np.uint8)
         if arr.ndim < 1 or arr.shape[0] == 0:
             raise ValueError(f"empty pipeline submission {arr.shape}")
-        item = _Item(arr, cache=cache, tag=qos, arena=arena)
+        item = _Item(arr, cache=cache, tag=qos)
         with self._lock:
             self._ensure_threads()
             self._chans[chan.key] = chan
@@ -831,19 +682,10 @@ class EcDevicePipeline:
             out["devices"] = {str(l.index): l.dump()
                               for l in ds.lanes} if ds else {}
             out["active_devices"] = len(ds.active()) if ds else 0
-            mp = self._mesh
-            # per-axis device table: which lanes the pod-scale plane
-            # spans and how the dp x ls axes map onto them
-            out["mesh"] = ({"dp": mp.n_dp, "ls": mp.n_ls,
-                            "lanes": list(mp.lane_indices),
-                            "devices": [str(d) for d in mp.devices]}
-                           if mp is not None else None)
         out["depth"] = self.depth
         out["device_shards"] = self.device_shards or "all"
         out["scrub_weight"] = self.scrub_weight
         out["cost_aware"] = self.cost_aware
-        out["mesh_min_bytes"] = self.mesh_min_bytes
-        out["device_mesh"] = self.device_mesh
         out["qos_cost_unit"] = self.qos_cost_unit
         d = out["dispatches"]
         out["mean_batch_size"] = (out["stripes"] / d) if d else 0.0
@@ -1056,11 +898,6 @@ class EcDevicePipeline:
         lane.quarantined = True
         lane.quarantine_reason = reason
         self._c["quarantines"] += 1
-        # a mesh plane spanning this chip is gone with it: later
-        # mega-batches rebuild from the survivors
-        if self._mesh is not None and \
-                lane.index in self._mesh.lane_indices:
-            self._mesh = None
         # the chip is in an unknown state: its HBM cache entries must
         # never serve again (redrain re-uploads from host)
         hbm_cache.get().drop_lane(lane.index)
@@ -1193,181 +1030,6 @@ class EcDevicePipeline:
             remaining -= 1
         return cuts
 
-    # -- mesh dispatch (pod scale: one batch across the device mesh) -------
-
-    def _mesh_eligible(self, chan: PipelineChannel, items: list,
-                       nbytes: int) -> bool:
-        """Mesh mode is chosen when the channel can shard_map, the
-        coalesced batch exceeds a single lane's staging budget, and no
-        item carries the degrade latch (a batch that already fell off
-        the mesh must finish on row splits, bit-identically)."""
-        return (chan.mesh_fn is not None and self.mesh_min_bytes > 0
-                and nbytes >= self.mesh_min_bytes
-                and not any(it.no_mesh for it in items))
-
-    @staticmethod
-    def _parse_mesh_spec(spec: str, avail: int) -> tuple | None:
-        """osd_ec_device_mesh -> (n_dp, n_ls): "auto" spans every
-        active lane on the chunk-length axis, an integer caps the
-        member count, "AxB" lays out dp x ls explicitly (None when
-        the layout cannot be satisfied by `avail` lanes)."""
-        s = str(spec or "auto").strip().lower()
-        if "x" in s:
-            try:
-                a, b = s.split("x", 1)
-                n_dp, n_ls = max(1, int(a)), max(1, int(b))
-            except ValueError:
-                return None
-            if n_dp * n_ls > avail:
-                return None
-            return n_dp, n_ls
-        if s.isdigit():
-            n = min(int(s), avail)
-            return (1, n) if n >= 2 else None
-        return 1, avail
-
-    def _mesh_plane(self) -> _MeshPlane | None:
-        """The current mesh plane, built lazily from the active lanes.
-        Injected per-device faults are rolled on every member here, at
-        mesh placement — a hit quarantines that lane, drops the plane
-        and degrades THIS dispatch to surviving-lane row splits (the
-        ladder's next rung)."""
-        now = time.monotonic()
-        fs = faults.get()
-        with self._lock:
-            plane = self._mesh
-            if plane is None:
-                ds = self._devset
-                if ds is None:
-                    return None
-                lanes = [l for l in ds.lanes
-                         if not l.quarantined and not l.stuck(now)
-                         and l.device is not None]
-                if len(lanes) < 2:
-                    return None
-                parsed = self._parse_mesh_spec(self.device_mesh,
-                                               len(lanes))
-                if parsed is None:
-                    return None
-                n_dp, n_ls = parsed
-                if n_dp * n_ls < 2:
-                    return None
-                plane = _MeshPlane(lanes[: n_dp * n_ls], n_dp, n_ls)
-                self._mesh = plane
-            for lane in plane.lanes:
-                if lane.quarantined or fs.tpu_error(device=lane.index):
-                    if not lane.quarantined:
-                        self._quarantine_locked(
-                            lane, "injected device error")
-                        self._c["device_errors"] += 1
-                        lane.errors += 1
-                    self._c["mesh_degrades"] += 1
-                    self._mesh = None
-                    return None
-        return plane
-
-    def _dispatch_mesh(self, chan: PipelineChannel, items: list,
-                       batch: np.ndarray) -> bool:
-        """Serve one coalesced mega-batch sharded across the mesh.
-        Returns True when the batch was handled (served, or requeued
-        by the degrade ladder); False to fall through to row-split
-        placement (no plane, mesh kernel still compiling, or a member
-        fault rolled at placement).
-
-        Runs inline on the dispatcher thread like the host path: a
-        pod-scale dispatch IS the backpressure that coalesces the
-        queue behind it."""
-        plane = self._mesh_plane()
-        if plane is None:
-            return False
-        donate = (len(items) == 1 and items[0].arena is not None
-                  and items[0].cache is None)
-        keep = hbm_cache.get().capacity > 0 and \
-            any(it.cache is not None for it in items)
-        t0 = time.perf_counter()
-        t_m0 = time.monotonic()
-        try:
-            res = chan.mesh_fn(batch, plane, donate=donate,
-                               keep_resident=keep)
-        except Exception as e:
-            self._mesh_failed(chan, items, e)
-            return True
-        if res is None:
-            return False
-        outs, resident = res
-        secs = max(time.perf_counter() - t0, 1e-9)
-        t_m1 = time.monotonic()
-        for it in items:
-            # the mesh serve stages+computes+fetches inline: one
-            # device window (H2D/compute/D2H not separable here)
-            it.ph["issue"] = t_m0
-            it.ph["collect0"] = t_m1
-            it.ph["done"] = t_m1
-        outs = tuple(np.asarray(o) for o in outs)
-        d2h = sum(int(o.nbytes) for o in outs)
-        with self._lock:
-            self._c["dispatches"] += 1
-            self._c["dev_dispatches"] += 1
-            self._c["mesh_dispatches"] += 1
-            self._c["bytes_h2d"] += batch.nbytes
-            self._c["bytes_d2h"] += d2h
-            if donate:
-                self._c["arena_donations"] += 1
-            if len(items) == 1 and items[0].arena is not None:
-                # the arena's upload WAS the staging copy — donated
-                # (device buffer consumed by the computation) or kept
-                # resident for the HBM cache, either way no further
-                # host materialization happened: ec.stage retires for
-                # this write (resolve skips the note)
-                items[0].arena.consumed = True
-        try:
-            chan.record("dev", batch.nbytes, secs, len(plane.lanes),
-                        device=None)
-        except Exception:
-            pass
-        if resident is not None:
-            self._stage_mesh_cache(items, plane, outs, resident)
-        self._resolve(items, "dev", outs)
-        return True
-
-    def _mesh_failed(self, chan: PipelineChannel, items: list,
-                     e: Exception) -> None:
-        """A mesh computation failed mid-flight.  The error is not
-        attributable to one chip, so no lane quarantines on this rung:
-        the plane drops and the batch requeues latched off the mesh —
-        surviving-lane row splits serve it bit-identically, and a
-        genuinely bad chip then fails its row-split part and
-        quarantines through the existing single-lane ladder."""
-        with self._lock:
-            self._c["device_errors"] += 1
-            self._c["mesh_degrades"] += 1
-            self._mesh = None
-            for it in items:
-                it.no_mesh = True
-            self._requeue_locked(chan, items)
-        _log().warn(
-            "EC mesh dispatch failed (%s: %s): degrading batch to "
-            "row-split placement", type(e).__name__, e)
-
-    def _stage_mesh_cache(self, items: list, plane: _MeshPlane,
-                          outs: tuple, resident: tuple) -> None:
-        """Mesh-resident HBM cache staging: entries address the WHOLE
-        mesh (their stripes are sharded device arrays), pinned to
-        every member lane — a quarantine of any one drops them."""
-        dev_data, dev_parity, pad = resident
-        off = 0
-        for it in items:
-            if it.cache is not None:
-                try:
-                    hbm_cache.get().stage(
-                        it.cache, plane.lane_indices,
-                        dev_data[off: off + it.n],
-                        dev_parity[off: off + it.n],
-                        outs[1][off: off + it.n], pad=pad)
-                except Exception:
-                    pass    # cache is an optimization, never a fault
-            off += it.n
-
     def _to_device(self, padded: np.ndarray, lane: _Lane):
         """Stage one part's H2D upload onto `lane`'s chip (runs on the
         lane's stager thread — uploads to different chips proceed in
@@ -1415,9 +1077,6 @@ class EcDevicePipeline:
                     type(e).__name__, e)
         if use_dev:
             self._ensure_devset()
-            if self._mesh_eligible(chan, items, nbytes) and \
-                    self._dispatch_mesh(chan, items, batch):
-                return
             bounds = None
             if hbm_cache.get().capacity > 0 and \
                     any(it.cache is not None for it in items):
@@ -1778,15 +1437,14 @@ class EcDevicePipeline:
             self._slices_warm |= keys
         if not keys:
             return
-        # batches one lane takes: up to the channel's cap in rows and
-        # the lane's staging budget in bytes (larger ones ride the mesh)
+        # batches worth warming: up to the channel's cap in rows and
+        # LANE_STAGE_BYTES in bytes
         cap = disp.chan.max_coalesce or self.max_batch
-        budget = self.mesh_min_bytes if self.mesh_min_bytes > 0 \
-            else DEFAULT_MESH_MIN_BYTES
         row_bytes = disp.dev_in.nbytes // disp.dev_in.shape[0]
         todo = sorted({(next_bucket(j * n), n) for _idx, n, _like in keys
                        for j in range(2, cap // n + 1)
-                       if next_bucket(j * n) * row_bytes <= budget})
+                       if next_bucket(j * n) * row_bytes
+                       <= LANE_STAGE_BYTES})
 
         def warm():
             import jax.numpy as jnp
@@ -1888,14 +1546,6 @@ class EcDevicePipeline:
     def _resolve(items: list, path: str, outs: tuple) -> None:
         off = 0
         for it in items:
-            ar = it.arena
-            if ar is not None and not ar.consumed and not ar.noted:
-                # the staged arena was NOT subsumed by a donated mesh
-                # upload (host or row-split serve): its staging copy
-                # is a real host materialization after all — account
-                # it exactly where the plain-buffer path would have
-                ar.noted = True
-                copyaudit.note("ec.stage", ar.payload_bytes)
             sl = tuple(o[off: off + it.n] for o in outs)
             off += it.n
             if not it.fut.done():
@@ -1932,8 +1582,6 @@ def configure(depth: int | None = None,
               split_min: int | None = None,
               cost_aware: bool | None = None,
               hbm_cache_bytes: int | None = None,
-              mesh_min_bytes: int | None = None,
-              device_mesh: str | None = None,
               qos_cost_unit: int | None = None) -> EcDevicePipeline:
     """Tune the shared pipeline (daemon startup applies its conf)."""
     p = get()
@@ -1951,12 +1599,6 @@ def configure(depth: int | None = None,
         p.cost_aware = bool(cost_aware)
     if hbm_cache_bytes is not None:
         hbm_cache.configure(hbm_cache_bytes)
-    if mesh_min_bytes is not None:
-        p.mesh_min_bytes = int(mesh_min_bytes)
-    if device_mesh is not None and device_mesh != p.device_mesh:
-        p.device_mesh = str(device_mesh)
-        with p._lock:
-            p._mesh = None      # axis layout change rebuilds the plane
     if qos_cost_unit is not None:
         p.qos_cost_unit = max(0, int(qos_cost_unit))
     if device_shards is not _UNSET and \
@@ -2059,57 +1701,6 @@ def _crc_device_fn(size: int):
     return device_fn
 
 
-# mesh-sharded scrub folds: one mega CRC batch shard_maps its chunk
-# axis across the mesh plane, per-shard partials combine on device
-# (ec_kernels.make_mesh_crc_fn).  Warm registry mirrors _crc_fns:
-# compiles happen off the dispatcher, a cold key row-splits instead.
-_crc_mesh_fns: dict = {}
-_crc_mesh_warming: set = set()
-_crc_mesh_failed: set = set()
-
-
-def _crc_mesh_fn(size: int):
-    def mesh_fn(batch, plane, donate=False, keep_resident=False):
-        if _crc_device_dead:
-            return None
-        key = (size, batch.shape[0], plane.key())
-        with _crc_lock:
-            fn = _crc_mesh_fns.get(key)
-            if fn is None:
-                if key not in _crc_mesh_warming and \
-                        key not in _crc_mesh_failed:
-                    _crc_mesh_warming.add(key)
-                    start_warm_thread(
-                        lambda: _warm_crc_mesh(*key),
-                        "ec-crc-mesh-warm")
-                return None
-        return (fn(batch),), None
-
-    return mesh_fn
-
-
-def _warm_crc_mesh(size: int, B: int, plane_key: tuple) -> None:
-    from . import ec_kernels
-    key = (size, B, plane_key)
-    fn = None
-    try:
-        devices, n_dp, n_ls = plane_key
-        fn = ec_kernels.make_mesh_crc_fn(size, devices, n_dp, n_ls)
-        fn(np.zeros((B, size), dtype=np.uint8))
-    except Exception as e:
-        fn = None       # negative-cached below; row-split/host serves
-        note_warm_failure(f"crc-mesh ({B}, {size})", e)
-    finally:
-        with _crc_lock:
-            _crc_mesh_warming.discard(key)
-            if fn is not None:
-                if len(_crc_mesh_fns) > 64:
-                    _crc_mesh_fns.clear()
-                _crc_mesh_fns[key] = fn
-            else:
-                _crc_mesh_failed.add(key)
-
-
 def _warm_crc(size: int, shape: tuple, device=None) -> None:
     from . import ec_kernels
     key = (size, shape, _device_warm_key(device))
@@ -2163,7 +1754,7 @@ def crc_channel(size: int,
                 key=("crc", size), host_fn=host_fn,
                 device_fn=_crc_device_fn(size), route=route,
                 on_error=_crc_on_error, max_coalesce=max_coalesce,
-                qos_class="scrub", mesh_fn=_crc_mesh_fn(size))
+                qos_class="scrub")
             _crc_channels[size] = chan
         elif max_coalesce is not None:
             # several daemons share this in-process registry: honor

@@ -141,8 +141,6 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                 self.conf.osd_ec_pipeline_scrub_weight),
             cost_aware=bool(self.conf.osd_ec_cost_aware_placement),
             hbm_cache_bytes=int(self.conf.osd_ec_hbm_cache_bytes),
-            mesh_min_bytes=int(self.conf.osd_ec_mesh_min_bytes),
-            device_mesh=str(self.conf.osd_ec_device_mesh),
             qos_cost_unit=int(self.conf.osd_qos_cost_bytes_unit))
         self._rpc_tid = itertools.count(1)
         self._rpc: dict = {}

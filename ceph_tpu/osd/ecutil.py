@@ -101,12 +101,11 @@ class EncodeHandle:
     sub-op messages (out-of-band CTM2 segments) and store applies
     without ever becoming per-shard bytes objects."""
 
-    __slots__ = ("_get", "_get_parts", "_arena", "_src")
+    __slots__ = ("_get", "_get_parts", "_src")
 
-    def __init__(self, get, get_parts=None, arena=None, src=None):
+    def __init__(self, get, get_parts=None, src=None):
         self._get = get
         self._get_parts = get_parts
-        self._arena = arena
         self._src = src             # codec handle: phase stamps source
 
     def result(self, timeout=None) -> tuple[list[memoryview], np.ndarray]:
@@ -123,12 +122,6 @@ class EncodeHandle:
             allc, stripe_crcs = self._get(timeout)
             S, km, L = allc.shape
             shards = np.ascontiguousarray(allc.transpose(1, 0, 2))
-        # the shard fan-out above was the LAST reader of the staging
-        # arena: return it to the pool for the next mega-write (its
-        # device buffer, if donated, is already consumed)
-        arena, self._arena = self._arena, None
-        if arena is not None:
-            arena.release()
         # op tracing: turn the pipeline's phase stamps (coalesce wait,
         # H2D staging, device compute, D2H — or the host drain) into
         # spans on whatever op this thread is executing; free when
@@ -162,43 +155,27 @@ def encode_object_async(codec, sinfo: StripeInfo, payload: bytes,
     `payload` may be bytes, a memoryview, or a BufferList rope — rope
     segments stage straight into the (S, k, L) batch buffer, so the
     whole client->encode journey costs exactly this ONE copy (the
-    audited `ec.stage` site).  A MESH-sized payload (staged bytes over
-    a single dispatch lane's budget, conf osd_ec_mesh_min_bytes)
-    stages into a pinned arena from the pipeline's pool instead: the
-    mesh dispatch donates the arena's device buffer to the
-    computation, so the staging copy IS the H2D upload and the
-    `ec.stage` site retires on that path (a degrade to row-split or
-    host re-arms it)."""
+    audited `ec.stage` site)."""
     plen = len(payload)
     S = sinfo.stripe_count(plen)
     L = sinfo.chunk_size
-    nbytes = S * sinfo.stripe_width
-    arena = None
-    if hasattr(codec, "encode_stripes_with_crcs_async"):
-        from ..ops import pipeline as ec_pipeline
-        arena = ec_pipeline.get().checkout_arena(nbytes, plen)
-    buf = arena.buf if arena is not None \
-        else np.zeros(nbytes, dtype=np.uint8)
+    buf = np.zeros(S * sinfo.stripe_width, dtype=np.uint8)
     off = 0
     for seg in iov_of(payload):
         n = len(seg)
         buf[off: off + n] = np.frombuffer(seg, dtype=np.uint8)
         off += n
-    if arena is None:
-        copyaudit.note("ec.stage", plen)
+    copyaudit.note("ec.stage", plen)
     stripes = buf.reshape(S, sinfo.k, L)
     if hasattr(codec, "encode_stripes_with_crcs_async"):
         try:
             handle = codec.encode_stripes_with_crcs_async(
-                stripes, cache=cache, qos=qos, arena=arena)
+                stripes, cache=cache, qos=qos)
         except TypeError:   # non-pipeline codec: no cache/qos support
-            if arena is not None:
-                arena.noted = True
-                copyaudit.note("ec.stage", plen)
             handle = codec.encode_stripes_with_crcs_async(stripes)
         parts = getattr(handle, "result_parts", None)
         return EncodeHandle(lambda t: handle.result(t),
-                            get_parts=parts, arena=arena, src=handle)
+                            get_parts=parts, src=handle)
     out = codec.encode_stripes_with_crcs(stripes)
     return EncodeHandle(lambda t: out)
 

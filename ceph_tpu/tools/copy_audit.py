@@ -50,15 +50,13 @@ ALLOWLIST: dict[str, dict[str, int]] = {
     "ceph_tpu/client/objecter.py": {},
     "ceph_tpu/osd/backend_ec.py": {"b''.join()": 1},
     "ceph_tpu/osd/ecutil.py": {},
-    # mesh-path files (PR 11): the retired ec.stage pattern must not
-    # silently reappear as a flatten/materialization here — the mesh
-    # dispatch's staging copy IS the donated H2D upload.  hbm_cache's
-    # one .tobytes() is the shard_bytes D2H fetch (a read serve, not
-    # a staging copy); ec_kernels' are the jit-cache matrix keys
-    # (metadata-sized generator bits, never payload).
+    # device-path files: hbm_cache's one .tobytes() is the
+    # shard_bytes D2H fetch (a read serve, not a staging copy);
+    # ec_kernels' are the jit-cache matrix keys (metadata-sized
+    # generator bits, never payload).
     "ceph_tpu/ops/pipeline.py": {},
     "ceph_tpu/ops/hbm_cache.py": {".tobytes()": 1},
-    "ceph_tpu/ops/ec_kernels.py": {".tobytes()": 4},
+    "ceph_tpu/ops/ec_kernels.py": {".tobytes()": 3},
     # decode_concat / decode_object return chunk-view ropes; the only
     # read-side materialization left is the audited rebuilt-chunk copy
     # (ec.decode_rebuild) on degraded reads

@@ -139,16 +139,6 @@ _opt("osd_ec_hbm_cache_bytes", int, 64 << 20,
      "HBM budget for the device-resident EC stripe cache (encoded "
      "stripes stay on-chip so deep scrub / recovery of a cached "
      "object pay zero re-upload); 0 disables the cache")
-_opt("osd_ec_mesh_min_bytes", int, 256 << 20,
-     "a single dispatch lane's staging budget: a coalesced EC batch "
-     "larger than this shard_maps its chunk-length axis across the "
-     "device mesh (one pod-scale dispatch, donated staging arena) "
-     "instead of riding one chip's HBM; 0 disables mesh dispatch")
-_opt("osd_ec_device_mesh", str, "auto",
-     "axis layout for EC mesh dispatch: 'auto' spans every active "
-     "lane on the chunk-length axis, an integer caps the member "
-     "count, 'AxB' lays out dp x ls (stripes x chunk-length) "
-     "explicitly")
 # -- per-pool QoS (dmClock-style service classes) ---------------------------
 # Options named `osd_pool_qos_<pool>` are DYNAMIC (auto-registered on
 # first set): the value is a `res:weight:lim` triple (utils/dmclock.

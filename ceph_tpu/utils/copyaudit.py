@@ -7,14 +7,7 @@ still materialized on the host:
 
   * ``ec.stage``        — padding/reshaping a payload into the (S, k, L)
                           stripe batch the encode kernel consumes (the
-                          H2D staging buffer; one copy per encode).
-                          RETIRED on the mesh-dispatch path: a
-                          mesh-sized payload stages into a pinned
-                          arena whose upload is donated to the device
-                          computation (ops/pipeline.py StagingArena),
-                          so the staging copy IS the H2D transfer —
-                          the site re-arms automatically when such a
-                          batch degrades to a non-mesh serve;
+                          H2D staging buffer; one copy per encode);
   * ``journal.append``  — the WAL flatten: journaled stores serialize
                           the transaction batch once, by design the only
                           place the write path flattens shard bytes;
@@ -54,8 +47,6 @@ _sites: dict[str, list[int]] = {}      # site -> [copies, bytes]
 READ_SITES = frozenset({
     "ec.decode_rebuild",       # degraded read: rebuilt chunks only
     "read.flatten",            # a read consumer flattening its rope
-    "cache.mesh_unpad",        # cache-served read of a PADDED mesh
-                               # entry: the pad-strip contiguous copy
 })
 
 

@@ -3,7 +3,7 @@
 on one TPU chip, in one process.
 
     python chip_smoke.py [--seed N]          # one chip (what CI runs)
-    python chip_smoke.py --chips 4           # builder-run: lanes + mesh
+    python chip_smoke.py --chips 4           # builder-run: the lanes
 
 Every step prints ONE JSON line ``{"step": ..., "ok": ..., "seconds":
 ..., evidence}``; the first failing step prints its evidence and the
@@ -54,7 +54,7 @@ PROFILE = {"k": str(K), "m": str(M), "technique": "reed_sol_van"}
 ORACLE = ("jerasure", dict(PROFILE, backend="host"))
 # what must stay 0 from the first device touch to the last line
 ZERO_COUNTERS = ("device_errors", "quarantines", "drained_to_host",
-                 "mesh_degrades", "warm_failures", "result_timeouts",
+                 "warm_failures", "result_timeouts",
                  "devset_errors", "route_errors")
 # generous for a cold compile cache: tens of seconds per shape
 WARM_BOUND = 600.0
@@ -690,7 +690,7 @@ def _drive_cluster(cluster, platform, seed, n_objects, object_bytes,
     out["perf_dump"] = {k: dump["ec_pipeline"][k] for k in (
         "dispatches", "dev_dispatches", "host_dispatches", "bytes_h2d",
         "bytes_d2h", "mean_batch_size", "warm_failures", "result_timeouts",
-        "warmups_inflight") + ZERO_COUNTERS[:4]}
+        "warmups_inflight") + ZERO_COUNTERS[:3]}
 
     # ---- production routing: reported, not asserted ----
     rados.create_ec_pool("smoke-prod", "k8m3prod",
@@ -727,7 +727,7 @@ def _codec_counters(cluster) -> dict:
     return tot
 
 
-# -- four chips: lanes + mesh (builder-run, --chips 4) ------------------------
+# -- four chips: the lanes (builder-run, --chips 4) --------------------------
 
 
 def step_lanes(platform: str = "tpu", seed: int = 0, n_lanes: int = 4,
@@ -832,80 +832,6 @@ def step_lanes(platform: str = "tpu", seed: int = 0, n_lanes: int = 4,
             "window": d, "lanes": lanes}
 
 
-def step_mesh(platform: str = "tpu", seed: int = 0, n_lanes: int = 4,
-              payload_bytes: int | None = None,
-              bound: float = WARM_BOUND) -> dict:
-    """One batch over the lane budget rides ONE mesh dispatch with a
-    donated arena; bit-exact vs one lane and vs the host oracle."""
-    import jax
-
-    from ceph_tpu.erasure.registry import registry
-    from ceph_tpu.ops import gf
-    from ceph_tpu.ops import pipeline as ec_pipeline
-    from ceph_tpu.osd import ecutil
-
-    pipe = ec_pipeline.get()
-    if payload_bytes is None:
-        payload_bytes = pipe.mesh_min_bytes
-    check(payload_bytes >= pipe.mesh_min_bytes, "payload under the budget")
-    tpu = registry.factory("tpu", dict(PROFILE, host_cutover="1"))
-    host = registry.factory(*ORACLE)
-    sinfo = ecutil.StripeInfo(K, STRIPE_UNIT)
-    payload = _rng(seed, 40).integers(0, 256, payload_bytes,
-                                      dtype=np.uint8).tobytes()
-    check(payload_bytes % sinfo.stripe_width == 0,
-          "payload must be whole stripes")
-    S = payload_bytes // sinfo.stripe_width
-    stripes = np.frombuffer(payload, dtype=np.uint8).reshape(
-        S, K, STRIPE_UNIT)
-
-    last: dict = {}
-
-    def encode():
-        def op():
-            last["shards"], last["crcs"] = ecutil.encode_object_async(
-                tpu, sinfo, payload).result()
-        last["delta"] = windowed(op, (
-            "mesh_dispatches", "arena_donations", "mesh_degrades",
-            "host_dispatches", "dev_dispatches", "split_dispatches"))
-
-    waited = drive_until(
-        encode, lambda: last["delta"]["mesh_dispatches"] >= 1, bound,
-        "mesh dispatch")
-    encode()
-    d = last["delta"]
-    check(d["mesh_dispatches"] >= 1 and d["arena_donations"] >= 1
-          and d["mesh_degrades"] == 0 and d["host_dispatches"] == 0,
-          "the window did not ride one donated mesh dispatch", delta=d)
-    s1 = _stats()
-    check(s1["mesh"] is not None and len(s1["mesh"]["devices"]) == n_lanes,
-          "mesh plane does not span every lane", mesh=s1["mesh"])
-    # vs the host oracle (jerasure plugin, native GF + CRC), a sample of
-    # stripes through gf.encode_np too
-    want_chunks, want_crcs = host.encode_stripes_with_crcs(stripes)
-    got = np.stack([np.frombuffer(s, dtype=np.uint8).reshape(S, STRIPE_UNIT)
-                    for s in last["shards"]], axis=1)
-    check(np.array_equal(got, want_chunks),
-          "mesh shards differ from the host plugin")
-    check(np.array_equal(last["crcs"], want_crcs),
-          "mesh CRCs differ from the host plugin")
-    matrix = gf.reed_sol_van_matrix(K, M)
-    for s in (0, S // 2, S - 1):
-        check(np.array_equal(got[s, K:], gf.encode_np(matrix, stripes[s])),
-              f"stripe {s} differs from gf.encode_np")
-    # vs the same batch on ONE lane (the fused kernel, device 0)
-    d0 = jax.devices()[0]
-    fn = tpu.backend._fn("fused", tpu.coding_matrix, STRIPE_UNIT)
-    parity, crcs = fn(jax.device_put(stripes, d0))
-    check(np.array_equal(np.asarray(parity), got[:, K:])
-          and np.array_equal(np.asarray(crcs), last["crcs"]),
-          "mesh result differs from the same batch on one lane")
-    _check_clean(s1, platform)
-    return {"routing": "pinned", "payload_bytes": payload_bytes,
-            "stripes": S, "waited_s": waited, "window": d,
-            "mesh": s1["mesh"]}
-
-
 # -- entry --------------------------------------------------------------------
 
 
@@ -931,8 +857,8 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=20260927)
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                    help="4: run ONLY the lane + mesh phases on four "
-                         "chips (builder-run)")
+                    help="4: run ONLY the lanes phase on four chips "
+                         "(builder-run)")
     args = ap.parse_args(argv)
     t0 = time.perf_counter()
     dev: dict = {}
@@ -943,8 +869,7 @@ def main(argv=None) -> int:
 
     if args.chips == 4:
         steps = [("device", device),
-                 ("lanes", lambda: step_lanes("tpu", args.seed, 4)),
-                 ("mesh", lambda: step_mesh("tpu", args.seed, 4))]
+                 ("lanes", lambda: step_lanes("tpu", args.seed, 4))]
     else:
         steps = [("device", device),
                  ("kernels", lambda: step_kernels("tpu", args.seed)),

@@ -80,14 +80,6 @@ def test_bench_smoke_runs_and_validates():
     assert out["peering_flat_ok"] is True
     assert out["peering_ms_at_1x"] is not None
     assert out["peering_ms_at_10x"] is not None
-    # pod-scale mesh dispatch: a payload over a single lane's staging
-    # budget rode ONE shard_mapped dispatch across the 8-device mesh,
-    # bit-exact vs the oracle, with the staging arena donated — and
-    # the donated path's per-write copy floor held
-    assert out["mesh_ok"] is True
-    assert out["mesh_dispatches"] >= 1
-    assert out["arena_donations"] >= 1
-    assert out["mesh_copies_per_write"] <= out["mesh_copy_budget"]
     # front doors under fire: the mini mixed-door round (rados + S3 +
     # CephFS + RBD) rode one seeded schedule through a zone
     # partition, a secondary-gateway crash and an OSD kill — zero

@@ -16,8 +16,7 @@ file for the same reason.
 import jax
 import numpy as np
 import pytest
-from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
-                          SingleDeviceSharding)
+from jax.sharding import SingleDeviceSharding
 
 from ceph_tpu.ops import ec_kernels, gf, pallas_ec
 
@@ -71,7 +70,7 @@ def test_xla_decode(one_chip, n_lost):
     """What TpuBackend._fn("bytes") serves: the bit-matrix is an
     operand, so ONE executable covers every decode pattern of a
     shape."""
-    fn = ec_kernels._apply_fn(ec_kernels.DEFAULT_COMPUTE)
+    fn = ec_kernels._apply_fn()
     g = jax.ShapeDtypeStruct((8 * n_lost, 8 * K), np.uint8,
                              sharding=one_chip)
     data = jax.ShapeDtypeStruct((128, K, 4096), np.uint8,
@@ -88,21 +87,3 @@ def test_xla_scrub_crc(one_chip, shape):
     fn = ec_kernels.make_crc_fn(shape[-1])
     compiled = _compile(fn, one_chip, shape)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
-
-
-def test_mesh_encode_crc_on_four_chips(topo):
-    """The pod-scale dispatch: chunk-length axis sharded over a 1x4
-    mesh, CRC partials combined on device."""
-    devices = list(topo.devices)[:4]
-    run = ec_kernels.make_mesh_encode_crc_fn(MATRIX, 4096, devices,
-                                             n_dp=1, n_ls=4, donate=True)
-    mesh = Mesh(np.array(devices).reshape(1, 4), ("dp", "ls"))
-    assert run.data_sharding == NamedSharding(mesh, P("dp", None, "ls"))
-    arg = jax.ShapeDtypeStruct((2048, K, 4096), np.uint8,
-                               sharding=run.data_sharding)
-    compiled = run.jitted.lower(arg).compile()
-    text = compiled.as_text()
-    # the XOR psum of the CRC partials is the only collective
-    assert "all-reduce" in text
-    # per-device bytes: a quarter of the batch plus its bit expansion
-    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30

@@ -99,7 +99,7 @@ class TestMain:
         monkeypatch.setattr(chip_smoke, "step_device",
                             lambda platform, count: dict(dev))
         for name in ("step_kernels", "step_plugin", "step_cluster",
-                     "step_lanes", "step_mesh"):
+                     "step_lanes"):
             monkeypatch.setattr(chip_smoke, name,
                                 lambda *a, **k: {"stub": True})
         monkeypatch.setattr(chip_smoke, "shutdown", lambda: {})
@@ -108,13 +108,13 @@ class TestMain:
         out = capsys.readouterr().out.strip().splitlines()
         assert json.loads(out[-1]) == {"ok": True, "device": dev}
         steps = [json.loads(l)["step"] for l in out[:-1]]
-        assert steps == (["device", "lanes", "mesh", "shutdown", "total"]
+        assert steps == (["device", "lanes", "shutdown", "total"]
                          if chips == 4 else
                          ["device", "kernels", "plugin", "cluster",
                           "shutdown", "total"])
         # a failing step: non-zero, no final line, nothing after it
         monkeypatch.setattr(
-            chip_smoke, "step_mesh" if chips == 4 else "step_plugin",
+            chip_smoke, "step_lanes" if chips == 4 else "step_plugin",
             lambda *a, **k: chip_smoke.check(False, "host served"))
         assert chip_smoke.main(argv) != 0
         out = capsys.readouterr().out.strip().splitlines()
@@ -170,18 +170,13 @@ class TestSteps:
         assert cluster["production_routing"]["asserted"] is False
 
     def test_four_lane_phase(self, fresh_plane):
-        """--chips 4's lanes + mesh steps on four virtual devices."""
-        fresh_plane(device_shards=4, mesh_min_bytes=256 << 10)
+        """--chips 4's lanes step on four virtual devices."""
+        fresh_plane(device_shards=4)
         lines = []
         ok = chip_smoke.run_steps([
             ("lanes", lambda: chip_smoke.step_lanes(
                 "cpu", seed=1, n_lanes=4, stripes=16, L=512, batches=8,
                 threads=4, bound=120.0)),
-            ("mesh", lambda: chip_smoke.step_mesh(
-                "cpu", seed=1, n_lanes=4, payload_bytes=256 << 10,
-                bound=120.0)),
         ], out=lines.append)
         assert ok, lines[-1]
         assert len(lines[0]["lanes"]) == 4
-        assert lines[1]["window"]["mesh_dispatches"] >= 1
-        assert lines[1]["window"]["arena_donations"] >= 1

@@ -2,7 +2,10 @@
 itself catches regressions, and the runtime counters flow into perf
 dump semantics."""
 
+import time
+
 import numpy as np
+import pytest
 
 from ceph_tpu.tools import copy_audit
 from ceph_tpu.utils import copyaudit
@@ -62,36 +65,75 @@ class TestRuntimeCounters:
             before["sites"].get("bufferlist.flatten",
                                 {"bytes": 0})["bytes"] + 128
 
-    def test_encode_staging_is_the_only_write_copy(self):
+    # what serves the encode -> (plugin, extra profile keys, lanes,
+    # the ec_pipeline counters that all rise when that rung served it)
+    RUNGS = {
+        "host-codec": ("jerasure", {}, 1, ()),
+        "lane": ("tpu", {"host_cutover": "1"}, 1, ("dev_dispatches",)),
+        "row-splits": ("tpu", {"host_cutover": "1"}, 2,
+                       ("split_dispatches", "dev_dispatches")),
+        "host-drain": ("tpu", {"host_cutover": str(1 << 40)}, 2,
+                       ("host_dispatches",)),
+    }
+
+    @pytest.mark.parametrize("rung", list(RUNGS))
+    def test_encode_staging_is_the_only_write_copy(self, rung,
+                                                   monkeypatch):
         """A whole-object EC encode through ecutil costs exactly one
         payload staging copy + one shard-major relayout — shard files
-        come back as views, never per-shard bytes."""
+        come back as views, never per-shard bytes — whatever serves
+        it: a codec without the pipeline, one lane, row splits over
+        two lanes, or the pipeline's host drain."""
         from ceph_tpu.erasure.registry import registry
+        from ceph_tpu.ops import pipeline as ec_pipeline
         from ceph_tpu.osd import ecutil
         from ceph_tpu.utils.bufferlist import BufferList
-        codec = registry.factory("jerasure", {"k": "2", "m": "1",
-                                              "technique":
-                                              "reed_sol_van"})
+        plugin, extra, lanes, counters = self.RUNGS[rung]
+        pipe = ec_pipeline.EcDevicePipeline(device_shards=lanes,
+                                            split_min=1)
+        monkeypatch.setattr(ec_pipeline, "_global", pipe)
+        profile = {"k": "2", "m": "1", "technique": "reed_sol_van"}
+        codec = registry.factory(plugin, dict(profile, **extra))
+        oracle = registry.factory("jerasure", profile)
         sinfo = ecutil.StripeInfo(2, 256)
         payload = BufferList(b"x" * 1000)
         payload.append(b"y" * 500)
-        before = copyaudit.snapshot()["sites"]
-        shards, crcs = ecutil.encode_object_ex(codec, sinfo, payload)
-        after = copyaudit.snapshot()["sites"]
+        want, _ = ecutil.encode_object_ex(oracle, sinfo,
+                                          payload.to_bytes())
 
-        def delta(site):
-            b = before.get(site, {"copies": 0})["copies"]
-            return after.get(site, {"copies": 0})["copies"] - b
+        def site(name):
+            return dict(copyaudit.snapshot()["sites"].get(
+                name, {"copies": 0, "bytes": 0}))
 
-        assert delta("ec.stage") == 1
-        assert delta("ec.shard_layout") == 1
-        assert delta("bufferlist.flatten") == 0
-        assert all(isinstance(s, memoryview) for s in shards)
-        # the views are correct shard bytes (vs the bytes-payload run)
-        shards2, _ = ecutil.encode_object_ex(codec, sinfo,
-                                             payload.to_bytes())
-        for a, b in zip(shards, shards2):
-            assert bytes(a) == bytes(b)
+        try:
+            # the device fns warm on background threads and the host
+            # serves meanwhile: the copy account holds on every call,
+            # and the rung asked for has to serve one within the bound
+            end = time.monotonic() + 120
+            while True:
+                st0, stage0 = pipe.stats(), site("ec.stage")
+                layout0 = site("ec.shard_layout")["copies"]
+                flat0 = site("bufferlist.flatten")["copies"]
+                shards, crcs = ecutil.encode_object_ex(codec, sinfo,
+                                                       payload)
+                stage1 = site("ec.stage")
+                assert stage1["copies"] == stage0["copies"] + 1
+                assert stage1["bytes"] == stage0["bytes"] + 1500
+                assert site("ec.shard_layout")["copies"] == layout0 + 1
+                assert site("bufferlist.flatten")["copies"] == flat0
+                assert all(isinstance(s, memoryview) for s in shards)
+                for a, b in zip(shards, want):
+                    assert bytes(a) == bytes(b)
+                st1 = pipe.stats()
+                if all(st1[c] > st0[c] for c in counters):
+                    break
+                assert time.monotonic() < end, (rung, st1)
+                time.sleep(0.05)
+            if rung == "lane":
+                assert st1["split_dispatches"] == 0
+            assert st1["device_errors"] == 0
+        finally:
+            pipe.stop()
 
 
 class TestDecodeNoCopy:

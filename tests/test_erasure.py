@@ -173,6 +173,19 @@ class TestTpu:
             assert np.array_equal(host.encode_chunks(chunks),
                                   dev.encode_chunks(chunks)), technique
 
+    def test_stored_profile_with_retired_compute_key_still_loads(self):
+        """A pool created when the profile still took compute= must
+        load: the key is ignored like any unknown key, whatever it
+        says, and the codec encodes as every other."""
+        profile = {"k": "4", "m": "2", "technique": "reed_sol_van",
+                   "host_cutover": "0"}
+        host = registry.factory("jerasure", profile)
+        chunks = RNG.integers(0, 256, size=(4, 4096), dtype=np.uint8)
+        for compute in ("int8", "no-such-path"):
+            dev = registry.factory("tpu", dict(profile, compute=compute))
+            assert np.array_equal(host.encode_chunks(chunks),
+                                  dev.encode_chunks(chunks)), compute
+
     def test_bit_identical_to_isa(self):
         host = registry.factory("isa", {"k": "8", "m": "3"})
         dev = registry.factory("tpu", {"k": "8", "m": "3",

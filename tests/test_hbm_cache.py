@@ -176,64 +176,6 @@ class TestAccounting:
         ent = cache.lookup("pg_a", "obj", version=(1, 3))
         assert ent is not None and ent.data_bytes() == d2.tobytes()
 
-    def test_mesh_resident_entry_roundtrip_and_lane_membership(self):
-        """A mesh dispatch stages entries addressed to EVERY member
-        lane (tuple lane), with each chunk front-padded for even
-        sharding: data_bytes/shard_bytes strip the pad, and losing ANY
-        member chip drops the entry (a slice of the stripes lived
-        there)."""
-        rng = np.random.default_rng(21)
-        cache = hbm_cache.HbmStripeCache()
-        data, parity, crcs = _entry_arrays(rng)
-        pad = 6
-        pdata = np.zeros((2, K, L + pad), dtype=np.uint8)
-        pdata[:, :, pad:] = data
-        pparity = np.zeros((2, M, L + pad), dtype=np.uint8)
-        pparity[:, :, pad:] = parity
-        intent = hbm_cache.CacheIntent("pg_a", "obj", (1, 1),
-                                       2 * K * L, L)
-        cache.stage(intent, (0, 1, 2), pdata, pparity, crcs, pad=pad)
-        assert cache.commit("pg_a", "obj", (1, 1))
-        ent = cache.lookup("pg_a", "obj", version=(1, 1))
-        assert ent is not None and ent.lane == (0, 1, 2)
-        from ceph_tpu.utils import copyaudit
-        c0 = copyaudit.snapshot()["sites"].get(
-            "cache.mesh_unpad", {"copies": 0})["copies"]
-        assert ent.data_bytes() == data.tobytes()
-        # the pad-strip contiguous copy is a read-path
-        # materialization and must be audited
-        c1 = copyaudit.snapshot()["sites"].get(
-            "cache.mesh_unpad", {"copies": 0})["copies"]
-        assert c1 == c0 + 1
-        for j in range(K):
-            assert ent.shard_bytes(j) == data[:, j].tobytes()
-        for j in range(M):
-            assert ent.shard_bytes(K + j) == parity[:, j].tobytes()
-        # a non-member lane's quarantine spares it...
-        cache.drop_lane(5)
-        assert cache.lookup("pg_a", "obj", version=(1, 1)) is not None
-        # ...any member lane's quarantine drops it
-        cache.drop_lane(1)
-        assert cache.lookup("pg_a", "obj", version=(1, 1)) is None
-        assert cache.stats()["lane_drops"] >= 1
-
-    def test_mesh_entry_append_through_invalidates_conservatively(self):
-        """append_through of a mesh-resident entry would need a
-        cross-mesh reshard: it must invalidate (never serve a stale
-        whole-object entry) and report False."""
-        rng = np.random.default_rng(22)
-        cache = hbm_cache.HbmStripeCache()
-        data, parity, crcs = _entry_arrays(rng)
-        intent = hbm_cache.CacheIntent("pg_a", "obj", (1, 1),
-                                       2 * K * L, L)
-        cache.stage(intent, (0, 1), data, parity, crcs)
-        assert cache.commit("pg_a", "obj", (1, 1))
-        tail_d, tail_p, tail_c = _entry_arrays(rng, S=1)
-        assert not cache.append_through(
-            "pg_a", "obj", (1, 1), (1, 2), 3 * K * L, L, 2,
-            tail_d, tail_p, tail_c)
-        assert cache.lookup("pg_a", "obj") is None
-
     def test_commit_wrong_version_rejected(self):
         rng = np.random.default_rng(4)
         cache = hbm_cache.HbmStripeCache()
@@ -541,6 +483,53 @@ class TestPipelineIntegration:
                 e = cache.lookup("pg_s", f"o{i}")
                 assert e is not None and \
                     e.data_bytes() == d2[i].tobytes()
+        finally:
+            pipe.stop()
+
+    def test_split_part_entry_drops_with_its_lane_alone(self):
+        """Two tagged ops that coalesce are cut at the item boundary:
+        each part stages its item on its own lane, so losing one of
+        the two lanes drops that object's entry and spares the
+        other's."""
+        chan = _fused_channel(key=("hbm", "parts"))
+        pipe = ec_pipeline.EcDevicePipeline(depth=1, split_min=1,
+                                            device_shards=2,
+                                            coalesce_wait=0.001)
+        cache = hbm_cache.get()
+        rng = np.random.default_rng(24)
+        try:
+            pipe.submit(chan, np.zeros((1, K, L), np.uint8)).result(
+                timeout=60)                 # builds the device set
+            st0 = pipe.stats()
+            # hold both lanes' one slot so that the two ops queue
+            # together and ride one placed batch
+            with pipe._lock:
+                lanes = pipe._devset.lanes
+                for lane in lanes:
+                    lane.staging += 1
+            datas = [rng.integers(0, 256, size=(4, K, L), dtype=np.uint8)
+                     for _ in range(2)]
+            futs = [pipe.submit(chan, d, cache=hbm_cache.CacheIntent(
+                "pg_s", f"p{i}", (6, i), 4 * K * L, L))
+                for i, d in enumerate(datas)]
+            with pipe._lock:
+                for lane in lanes:
+                    lane.staging -= 1
+                pipe._fetch_cv.notify_all()
+            for f in futs:
+                assert f.result(timeout=60)[0] == "dev"
+            st1 = pipe.stats()
+            assert st1["split_dispatches"] == st0["split_dispatches"] + 1
+            ents = []
+            for i in range(2):
+                assert cache.commit("pg_s", f"p{i}", (6, i))
+                ents.append(cache.lookup("pg_s", f"p{i}"))
+            assert {e.lane for e in ents} == {0, 1}
+            cache.drop_lane(ents[0].lane)
+            assert cache.lookup("pg_s", "p0") is None
+            kept = cache.lookup("pg_s", "p1")
+            assert kept is not None and \
+                kept.data_bytes() == datas[1].tobytes()
         finally:
             pipe.stop()
 

@@ -15,7 +15,10 @@ pod runs — the tier-1 contracts pinned here:
     same through the plugin path (untargeted injection still degrades
     the whole codec, as PR 1/2 pinned);
   * host fallback (and the owner's on_error degrade) happens only
-    once EVERY chip is quarantined.
+    once EVERY chip is quarantined;
+  * the plugin's fused encode+CRC and the scrub CRC channel, row-split
+    over the lanes, equal the host oracles for odd S and L, whatever
+    the batch's size against LANE_STAGE_BYTES.
 """
 
 import threading
@@ -25,7 +28,8 @@ import numpy as np
 import pytest
 
 from ceph_tpu.erasure.registry import registry
-from ceph_tpu.ops import ec_kernels, gf
+from ceph_tpu.ops import crc32c as crc_mod
+from ceph_tpu.ops import ec_kernels, gf, hbm_cache
 from ceph_tpu.ops import pipeline as ec_pipeline
 from ceph_tpu.utils import faults
 
@@ -141,10 +145,126 @@ def test_large_batch_splits_across_idle_lanes():
         pipe.stop()
 
 
-def test_one_bad_chip_quarantines_lane_and_redrains():
+WARM = 120.0        # device fns compile on background threads
+
+
+def _plugin_encode_on_lanes(monkeypatch, lanes, batch, cache=None):
+    """`batch` through a fresh plugin=tpu codec on a pipeline of
+    `lanes` lanes, again and again until the device served it (and
+    cut it across lanes, where it has the rows for that)."""
+    pipe = ec_pipeline.EcDevicePipeline(depth=2, split_min=1,
+                                        coalesce_wait=0.001,
+                                        device_shards=lanes)
+    monkeypatch.setattr(ec_pipeline, "_global", pipe)
+    codec = registry.factory("tpu", {"k": str(K), "m": str(M),
+                                     "technique": "reed_sol_van",
+                                     "host_cutover": "1"})
+    split = lanes > 1 and batch.shape[0] > 1 and cache is None
+    try:
+        end = time.monotonic() + WARM
+        while True:
+            st0 = pipe.stats()
+            dev0 = codec.stat_counters()["device_stripe_passes"]
+            out = codec.encode_stripes_with_crcs_async(
+                batch.copy(), cache=cache).result(60)
+            st1 = pipe.stats()
+            if codec.stat_counters()["device_stripe_passes"] > dev0 \
+                    and (not split or st1["split_dispatches"]
+                         > st0["split_dispatches"]):
+                break
+            assert time.monotonic() < end, st1
+            time.sleep(0.05)
+        assert not codec.degraded and st1["device_errors"] == 0
+        if cache is not None:
+            entries = hbm_cache.get()
+            assert entries.commit(cache.cid, cache.oid, cache.version)
+            assert entries.lookup(cache.cid, cache.oid).data_bytes() \
+                == batch.tobytes()
+        if split:
+            used = [d for d in st1["devices"].values()
+                    if d["dispatches"] > 0]
+            assert len(used) >= 2, st1["devices"]
+        return out
+    finally:
+        pipe.stop()
+
+
+@pytest.mark.parametrize("S,length,lanes,stage_bytes", [
+    (1, 192, 8, None),      # minimal batch: nothing to cut
+    (5, 250, 8, None),      # odd S, odd L, one row a lane
+    (3, 100, 4, None),      # fewer rows than lanes
+    (5, 250, 8, 1024),      # a batch of LANE_STAGE_BYTES and more
+])
+def test_plugin_split_bitexact_vs_one_lane_and_oracle(
+        monkeypatch, S, length, lanes, stage_bytes):
+    """Fused encode + CRC through the plugin, row-split over the
+    lanes == the same batch on one lane == the jerasure oracle.
+    LANE_STAGE_BYTES bounds what is compiled ahead for the HBM
+    cache's item slices and nothing else: a batch over it is placed
+    and cached like any other."""
+    if stage_bytes is not None:
+        monkeypatch.setattr(ec_pipeline, "LANE_STAGE_BYTES", stage_bytes)
+        assert S * K * length >= stage_bytes
+    rng = np.random.default_rng(S * 1000 + length)
+    batch = rng.integers(0, 256, size=(S, K, length), dtype=np.uint8)
+    oracle = registry.factory("jerasure", {"k": str(K), "m": str(M),
+                                           "technique": "reed_sol_van"})
+    allc_o, crcs_o = oracle.encode_stripes_with_crcs(batch)
+    for n in (lanes, 1):
+        allc, crcs = _plugin_encode_on_lanes(monkeypatch, n, batch)
+        np.testing.assert_array_equal(allc, allc_o)
+        np.testing.assert_array_equal(crcs, crcs_o)
+    if stage_bytes is not None:
+        hbm_cache.configure(64 << 20)
+        intent = hbm_cache.CacheIntent("pg_mc", "big", (1, 1),
+                                       batch.nbytes, length)
+        allc, crcs = _plugin_encode_on_lanes(monkeypatch, lanes, batch,
+                                             cache=intent)
+        np.testing.assert_array_equal(allc, allc_o)
+        np.testing.assert_array_equal(crcs, crcs_o)
+
+
+@pytest.mark.parametrize("size", [2048, 1000])
+def test_scrub_crc_channel_splits_bitexact_vs_host_crc32c(
+        monkeypatch, size):
+    """A deep-scrub CRC batch cut over four lanes: every row's CRC
+    equals crc32c on the host, for a size the two-level fold takes
+    and for one it does not."""
+    monkeypatch.setattr(ec_pipeline, "_crc_device_dead", False)
+    pipe = ec_pipeline.EcDevicePipeline(depth=2, split_min=1,
+                                        coalesce_wait=0.001,
+                                        device_shards=4)
+    chan = ec_pipeline.crc_channel(size)
+    rng = np.random.default_rng(size)
+    batch = rng.integers(0, 256, size=(7, size), dtype=np.uint8)
+    want = np.array([crc_mod.crc32c_sw(0, row.tobytes())
+                     for row in batch], dtype=np.uint32)
+    try:
+        end = time.monotonic() + WARM
+        while True:
+            st0 = pipe.stats()
+            path, (out,) = pipe.submit(chan, batch.copy()).result(60)
+            np.testing.assert_array_equal(np.asarray(out), want)
+            st1 = pipe.stats()
+            if path == "dev" and st1["split_dispatches"] \
+                    > st0["split_dispatches"]:
+                break
+            assert time.monotonic() < end, st1
+            time.sleep(0.05)
+        used = [d for d in st1["devices"].values() if d["dispatches"] > 0]
+        assert len(used) >= 2, st1["devices"]
+        assert st1["device_errors"] == 0
+    finally:
+        pipe.stop()
+
+
+# whole batches on one lane each, and batches cut across the lanes
+@pytest.mark.parametrize("split_min", [64, 1])
+def test_one_bad_chip_quarantines_lane_and_redrains(split_min):
     """A real device failure on one chip of eight: that lane
-    quarantines, its batch redrains to surviving chips bit-exactly,
-    and the channel owner's on_error (codec degrade) does NOT fire."""
+    quarantines, its batch (or the whole split batch its part belonged
+    to) redrains to surviving chips bit-exactly, and the channel
+    owner's on_error (codec degrade) does NOT fire."""
     degraded = []
     errors: list = []
     chan = ec_pipeline.PipelineChannel(
@@ -152,7 +272,7 @@ def test_one_bad_chip_quarantines_lane_and_redrains():
         device_fn=_ready_device_fn(bad_indices=(0,), errors=errors),
         route=lambda n: True,
         on_error=lambda e: degraded.append(e))
-    pipe = ec_pipeline.EcDevicePipeline(depth=2, split_min=64,
+    pipe = ec_pipeline.EcDevicePipeline(depth=2, split_min=split_min,
                                         coalesce_wait=0.001)
     try:
         batches, results = _submit_odd_batches(pipe, chan)
@@ -162,6 +282,7 @@ def test_one_bad_chip_quarantines_lane_and_redrains():
         assert st["devices"]["0"]["quarantined"]
         assert st["active_devices"] == 7
         assert st["redrained"] >= 1
+        assert (st["split_dispatches"] >= 1) == (split_min == 1)
         assert errors, "bad chip never probed"
         assert not degraded, "codec degraded despite 7 live chips"
         # the quarantined lane takes no further dispatches
@@ -200,19 +321,22 @@ def test_all_chips_quarantined_falls_back_to_host_and_degrades():
         pipe.stop()
 
 
-def test_targeted_tpu_error_quarantines_without_codec_degrade():
+# small batches, and batches with rows enough to cut across the lanes
+@pytest.mark.parametrize("sizes", [(1, 3, 2, 5), (32, 40)])
+def test_targeted_tpu_error_quarantines_without_codec_degrade(sizes):
     """Injected `tpu_error 1.0 <device>` through the PLUGIN path: the
     pipeline quarantines that chip's lane at placement time, results
     stay bit-exact, and the codec does NOT degrade."""
     pipe = ec_pipeline.get()
     pipe.reset_devices()
+    splits0 = pipe.stats()["split_dispatches"]
     codec = registry.factory("tpu", {"k": "2", "m": "1",
                                      "host_cutover": "1"})
     oracle = registry.factory("jerasure", {"k": "2", "m": "1"})
     faults.get().tpu_device_error(1.0, device="0")
     rng = np.random.default_rng(11)
     batches = [rng.integers(0, 256, size=(B, 2, 128), dtype=np.uint8)
-               for B in (1, 3, 2, 5)]
+               for B in sizes]
     handles = [codec.encode_stripes_with_crcs_async(b)
                for b in batches]
     for arr, h in zip(batches, handles):
@@ -225,6 +349,8 @@ def test_targeted_tpu_error_quarantines_without_codec_degrade():
     assert st["quarantines"] >= 1
     assert st["devices"]["0"]["quarantined"]
     assert st["active_devices"] == 7
+    if max(sizes) >= 2 * pipe.split_min:
+        assert st["split_dispatches"] > splits0
 
 
 def test_untargeted_tpu_error_still_degrades_codec():

@@ -513,9 +513,9 @@ class TestRecoveryDecodeLane:
         picks: list[tuple] = []
         orig = pipe.submit
 
-        def spy(chan, arr, cache=None, qos=None, arena=None):
+        def spy(chan, arr, cache=None, qos=None):
             picks.append((chan.key[0], qos))
-            return orig(chan, arr, cache=cache, qos=qos, arena=arena)
+            return orig(chan, arr, cache=cache, qos=qos)
 
         pipe.submit = spy
         try:

@@ -487,15 +487,14 @@ def test_jitted_programs_have_distinct_names():
         "crc": pallas_ec._crc_call(4096, 512, 8, True),
         "encode_crc": pallas_ec._encode_crc_call(
             np.ascontiguousarray(matrix).tobytes(), (1, 2), 4096, True),
-        "decode": ec_kernels._apply_fn(ec_kernels.DEFAULT_COMPUTE),
+        "decode": ec_kernels._apply_fn(),
         "scrub_crc": ec_kernels._crc_fn(
-            4096, ec_kernels._pick_block(4096), ec_kernels.DEFAULT_COMPUTE),
+            4096, ec_kernels._pick_block(4096)),
         "xla_encode_crc": ec_kernels._encode_crc_fn(
             gf.expand_bitmatrix(matrix, 8).tobytes(), (8, 16), 4096,
-            ec_kernels._pick_block(4096), ec_kernels.DEFAULT_COMPUTE),
+            ec_kernels._pick_block(4096)),
         "packet_codec": ec_kernels._packet_fn(
-            np.eye(8, dtype=np.uint8).tobytes(), (8, 8), 8, 8,
-            ec_kernels.DEFAULT_COMPUTE),
+            np.eye(8, dtype=np.uint8).tobytes(), (8, 8), 8, 8),
     }
     names = {key: fn.__name__ for key, fn in fns.items()}
     assert names == {key: f"run_{key}" for key in fns}
