@@ -33,6 +33,10 @@ class KVTransaction:
 
 
 class KeyValueDB(abc.ABC):
+    # statements a backend handed its engine (a `get`, an `iterate`, a
+    # transaction's runs of like ops); a store reads deltas of it
+    calls = 0
+
     @abc.abstractmethod
     def open(self) -> None: ...
 

@@ -12,6 +12,7 @@ class MemDB(KeyValueDB):
     def __init__(self):
         self._data: dict[str, dict[str, bytes]] = {}
         self._lock = threading.Lock()
+        self.calls = 0      # calls a SqliteDB would make statements of
 
     def open(self) -> None:
         pass
@@ -22,6 +23,7 @@ class MemDB(KeyValueDB):
     def submit_transaction(self, txn: KVTransaction,
                            sync: bool = False) -> None:
         with self._lock:
+            self.calls += 1
             for op, prefix, key, value in txn.ops:
                 space = self._data.setdefault(prefix, {})
                 if op == "set":
@@ -33,6 +35,7 @@ class MemDB(KeyValueDB):
 
     def get(self, prefix: str, key: str) -> bytes | None:
         with self._lock:
+            self.calls += 1
             return self._data.get(prefix, {}).get(key)
 
     def prefixes(self) -> list[str]:
@@ -42,6 +45,7 @@ class MemDB(KeyValueDB):
     def iterate(self, prefix: str, start: str = "",
                 end: str | None = None) -> Iterator[tuple[str, bytes]]:
         with self._lock:
+            self.calls += 1
             items = sorted(self._data.get(prefix, {}).items())
         for k, v in items:
             if k < start:

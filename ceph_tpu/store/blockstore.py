@@ -21,7 +21,11 @@ durability point, exactly like BlueStore's _kv_sync_thread:
   * a write's run of whole blocks is one extent: one allocation, one
     native checksum call, and at commit one device write for blocks
     that lie one behind the other.  The onode still maps a block at a
-    time and the crash sites below still tear by block.
+    time and the crash sites below still tear by block;
+  * decoded onodes stay in the store beside the KV, as BlueStore keeps
+    its onode cache: a bounded LRU written through at the commit point
+    and nowhere else, so a look-up of an object this store committed
+    or read is no KV call and no decode.
 
 Divergence from the reference: clone copies blocks instead of
 refcounting shared blobs (correctness-equivalent; COW sharing is a
@@ -66,6 +70,7 @@ from __future__ import annotations
 
 import os
 import threading
+from collections import OrderedDict
 from typing import Iterable
 
 import numpy as np
@@ -83,6 +88,9 @@ DEFERRED_MAX = 64 * 1024       # writes at or under this ride the KV WAL
 GROW = 256 * MIN_ALLOC         # device growth increment (1 MiB)
 WAL_FLUSH_EVERY = 16           # applied WAL records kept before trim
 IOV_MAX = os.sysconf("SC_IOV_MAX")      # buffers one gather write takes
+# decoded onodes a store keeps, counted in block-map entries (and one an
+# onode): 256 shard files of 4 MiB objects
+ONODE_CACHE_BLOCKS = 256 * 129
 
 P_SUPER = "S"
 P_COLL = "C"
@@ -261,6 +269,39 @@ class _Device:
             os.fsync(self._f.fileno())
 
 
+class _OnodeCache:
+    """Decoded committed onodes by okey, least recently used first out,
+    bounded by the block-map entries they hold.  A head in here is
+    shared: whoever gets one does not edit it."""
+
+    def __init__(self, limit: int):
+        self.limit = limit
+        self._heads: OrderedDict[str, dict] = OrderedDict()
+        self._weight = 0
+
+    def __len__(self) -> int:
+        return len(self._heads)
+
+    def get(self, okey: str) -> dict | None:
+        head = self._heads.get(okey)
+        if head is not None:
+            self._heads.move_to_end(okey)
+        return head
+
+    def put(self, okey: str, head: dict) -> None:
+        self.drop(okey)
+        self._heads[okey] = head
+        self._weight += 1 + len(head["blocks"])
+        while self._weight > self.limit:
+            _okey, old = self._heads.popitem(last=False)
+            self._weight -= 1 + len(old["blocks"])
+
+    def drop(self, okey: str) -> None:
+        old = self._heads.pop(okey, None)
+        if old is not None:
+            self._weight -= 1 + len(old["blocks"])
+
+
 class BlockStore(ObjectStore):
     """Onode format (P_ONODE, denc): {"size", "xattrs",
     "blocks": {block#: [poff, crc32c]}} — absent block# = hole."""
@@ -281,15 +322,23 @@ class BlockStore(ObjectStore):
         # fsync-reordering model rolls a seeded subset of them back at
         # crash time (durable B, lost earlier A)
         self._unflushed: list[tuple[int, bytes]] = []
+        # what the KV holds, decoded: onodes this store committed or
+        # read, and the collections (loaded at first use).  Both are
+        # written at the commit point only and read under _lock.
+        self._onodes = _OnodeCache(ONODE_CACHE_BLOCKS)
+        self._colls: set[str] | None = None
         self.counters = {
             "wal_records_replayed": 0,
             "wal_torn_extent_repairs": 0,
             "freelist_repairs": 0,
             "fsync_reorder_windows": 0,
+            "commits": 0,
+            "onode_lookups": 0,     # of committed onodes, reads included
+            "onode_hits": 0,
         }
 
     def journal_stats(self) -> dict:
-        return dict(self.counters)
+        return dict(self.counters, kv_calls=self.db.calls)
 
     def crash_sites(self) -> list[str]:
         return ["wal.pre_kv_commit", "wal.post_kv_commit",
@@ -314,6 +363,10 @@ class BlockStore(ObjectStore):
         if self.path and not os.path.exists(f"{self.path}/db"):
             raise FileNotFoundError(f"{self.path}/db")
         self.db.open()
+        # nothing decoded outlives a mount: a crashed store comes back
+        # with whatever part of its last commit landed
+        self._onodes = _OnodeCache(ONODE_CACHE_BLOCKS)
+        self._colls = None
         blob = self.db.get(P_SUPER, "super")
         if blob is None:
             raise StoreError(EIO, "no blockstore superblock")
@@ -334,6 +387,17 @@ class BlockStore(ObjectStore):
         self.db.close()
 
     # -- crash plane -------------------------------------------------------
+
+    def _forget(self) -> None:
+        """Drop everything decoded, and keep no onode from here on: a
+        store that crashed or was frozen answers from the KV (which no
+        longer changes) until it is mounted again."""
+        self._onodes = _OnodeCache(0)
+        self._colls = None
+
+    def freeze(self) -> None:
+        super().freeze()
+        self._forget()
 
     def _crash_tracking(self) -> bool:
         from ..utils import faults
@@ -381,6 +445,7 @@ class BlockStore(ObjectStore):
         armed, a seeded SUBSET survives (out-of-order durability) —
         the rest are rolled back to their pre-images."""
         self._apply_crash_reorder()
+        self._forget()
         super()._panic(site)
 
     def _apply_crash_reorder(self) -> None:
@@ -510,20 +575,33 @@ class BlockStore(ObjectStore):
                 "onodes": {},       # okey -> head dict | None
                 "omaps": {},        # "cid/oid/k" -> bytes | None
                 "new_colls": set(),
+                "rm_colls": set(),
                 "kvt": self.db.transaction(),
                 "pending": {},      # poff -> block bytes (this txn)
                 "direct": {},       # poff -> data, write-before-commit
                 "wal": {},          # poff -> data, rides the KV commit
                 "allocated": [],    # rollback on failure
                 "freed": [],        # released only at commit
+                # where the counts stood: the wal span reports what
+                # this txn added (no other thread moves them under _lock)
+                "before": (self.db.calls, self.counters["onode_lookups"],
+                           self.counters["onode_hits"]),
             }
             try:
-                for op in txn.ops:
-                    self._apply_op(op, st)
+                try:
+                    for op in txn.ops:
+                        self._apply_op(op, st)
+                except BaseException:
+                    self.alloc.release(st["allocated"])
+                    raise
+                self._commit(st)
             except BaseException:
-                self.alloc.release(st["allocated"])
+                # any way out but a whole commit: whatever the KV now
+                # holds of this txn's keys, it is the truth
+                for okey in st["onodes"]:
+                    self._onodes.drop(okey)
+                self._colls = None
                 raise
-            self._commit(st)
 
     def _commit(self, st: dict) -> None:
         self._check_frozen()     # crashed: no device or KV write lands
@@ -536,6 +614,14 @@ class BlockStore(ObjectStore):
             # this commit (COW and deferred) over the calls made
             late["blocks"] = len(st["direct"]) + len(st["wal"])
             late["dev_writes"] = self._commit_traced(st)
+            # how often the txn crossed into the KV tier (its look-ups
+            # before this span opened included), and how many of its
+            # onode look-ups the store answered from what it keeps
+            calls, lookups, hits = st["before"]
+            late["kv_calls"] = self.db.calls - calls
+            late["commits"] = 1
+            late["onode_lookups"] = self.counters["onode_lookups"] - lookups
+            late["onode_hits"] = self.counters["onode_hits"] - hits
 
     def _commit_traced(self, st: dict) -> int:
         kvt: KVTransaction = st["kvt"]
@@ -584,6 +670,18 @@ class BlockStore(ObjectStore):
         self._maybe_crash_torn_kv("wal.pre_kv_commit", kvt)
         self.db.submit_transaction(kvt, sync=True)
         # ---- commit point ----
+        # the one place what the store keeps decoded is written: the
+        # KV holds exactly these heads now (a txn edits copies, so a
+        # head becomes visible to readers here and not before)
+        self.counters["commits"] += 1
+        for okey, head in st["onodes"].items():
+            if head is None:
+                self._onodes.drop(okey)
+            else:
+                self._onodes.put(okey, head)
+        if self._colls is not None:
+            self._colls -= st["rm_colls"]
+            self._colls |= st["new_colls"]
         if st["wal"]:
             # crash site: KV durable (the txn is committed), deferred
             # device applies never run — mount replays the WAL record
@@ -617,12 +715,38 @@ class BlockStore(ObjectStore):
 
     # -- onode helpers -----------------------------------------------------
 
+    def _collections(self) -> set[str]:
+        if self._colls is None:
+            self._colls = {k for k, _v in self.db.iterate(P_COLL, "")}
+        return self._colls
+
+    def _committed(self, okey: str) -> dict | None:
+        """The onode as the KV holds it, from what the store keeps
+        decoded or, on a miss, from the KV (one SELECT, one decode; an
+        absent object is a SELECT every time: names are unbounded).
+        Shared with every other reader: not to be edited."""
+        self.counters["onode_lookups"] += 1
+        head = self._onodes.get(okey)
+        if head is not None:
+            self.counters["onode_hits"] += 1
+            return head
+        blob = self.db.get(P_ONODE, okey)
+        if blob is None:
+            return None
+        head = denc.loads(blob)
+        self._onodes.put(okey, head)
+        return head
+
     def _load_onode(self, st: dict, cid: str, oid: str):
         okey = _okey(cid, oid)
         if okey in st["onodes"]:
             return st["onodes"][okey]
-        blob = self.db.get(P_ONODE, okey)
-        head = denc.loads(blob) if blob is not None else None
+        head = self._committed(okey)
+        if head is not None:
+            # the txn edits a copy; block entries are replaced, never
+            # edited, so the map's own copy is enough
+            head = {"size": head["size"], "xattrs": dict(head["xattrs"]),
+                    "blocks": dict(head["blocks"])}
         st["onodes"][okey] = head
         return head
 
@@ -632,7 +756,7 @@ class BlockStore(ObjectStore):
             if not create:
                 raise StoreError(ENOENT, f"no object {cid}/{oid}")
             if cid not in st["new_colls"] and \
-                    self.db.get(P_COLL, cid) is None:
+                    cid not in self._collections():
                 raise StoreError(ENOENT, f"no collection {cid}")
             head = {"size": 0, "xattrs": {}, "blocks": {}}
             st["onodes"][_okey(cid, oid)] = head
@@ -784,8 +908,7 @@ class BlockStore(ObjectStore):
         kind = op[0]
         if kind == "mkcoll":
             _, cid = op
-            if self.db.get(P_COLL, cid) is not None or \
-                    cid in st["new_colls"]:
+            if cid in self._collections() or cid in st["new_colls"]:
                 raise StoreError(EEXIST, f"collection {cid} exists")
             st["new_colls"].add(cid)
             st["kvt"].set(P_COLL, cid, b"1")
@@ -793,6 +916,7 @@ class BlockStore(ObjectStore):
             _, cid = op
             st["kvt"].rmkey(P_COLL, cid)
             st["new_colls"].discard(cid)
+            st["rm_colls"].add(cid)
             # committed objects
             for key, _v in list(self.db.iterate(P_ONODE, f"{cid}/")):
                 if not key.startswith(f"{cid}/"):
@@ -856,7 +980,7 @@ class BlockStore(ObjectStore):
             if src_head is None:
                 raise StoreError(ENOENT, f"move src {scid}/{soid}")
             if dcid not in st["new_colls"] and \
-                    self.db.get(P_COLL, dcid) is None:
+                    dcid not in self._collections():
                 raise StoreError(ENOENT, f"no collection {dcid}")
             omap = self._omap_items(st, scid, soid)
             self._copy_object(st, src_head, dcid, doid, omap)
@@ -888,10 +1012,10 @@ class BlockStore(ObjectStore):
     # -- reads -------------------------------------------------------------
 
     def _committed_onode(self, cid: str, oid: str) -> dict:
-        blob = self.db.get(P_ONODE, _okey(cid, oid))
-        if blob is None:
+        head = self._committed(_okey(cid, oid))
+        if head is None:
             raise StoreError(ENOENT, f"no object {cid}/{oid}")
-        return denc.loads(blob)
+        return head
 
     def read(self, cid: str, oid: str, offset: int = 0,
              length: int = 0) -> bytes:
@@ -955,7 +1079,7 @@ class BlockStore(ObjectStore):
 
     def exists(self, cid: str, oid: str) -> bool:
         with self._lock:
-            return self.db.get(P_ONODE, _okey(cid, oid)) is not None
+            return self._committed(_okey(cid, oid)) is not None
 
     def getattr(self, cid: str, oid: str, name: str) -> bytes:
         with self._lock:
@@ -986,16 +1110,16 @@ class BlockStore(ObjectStore):
 
     def list_collections(self) -> list[str]:
         with self._lock:
-            return sorted(k for k, _ in self.db.iterate(P_COLL, ""))
+            return sorted(self._collections())
 
     def collection_exists(self, cid: str) -> bool:
         with self._lock:
-            return self.db.get(P_COLL, cid) is not None
+            return cid in self._collections()
 
     def collection_list(self, cid: str, start: str = "",
                         max_count: int = 0) -> list[str]:
         with self._lock:
-            if self.db.get(P_COLL, cid) is None:
+            if cid not in self._collections():
                 raise StoreError(ENOENT, f"no collection {cid}")
             prefix = f"{cid}/"
             names = []

@@ -1234,3 +1234,487 @@ class TestBlockStoreExtents:
         with open(tmp_path / "old" / "block", "rb") as f, \
                 open(tmp_path / "new" / "block", "rb") as g:
             assert f.read() == g.read()
+
+
+# ---------------------------------------------------------------------------
+# BlockStore's decoded onodes (ISSUE 31): what the store keeps beside
+# the KV is written at the commit point only, a txn edits copies, and
+# nothing decoded outlives a crash, a freeze or a mount.
+# ---------------------------------------------------------------------------
+
+
+_CACHE_CASES = (
+    ["raises_halfway", "installed_at_commit_only", "absent_is_a_select",
+     "remove", "rmcoll", "move", "lru_bound", "mount_and_freeze",
+     "torn_kv_prefix", "torn_kv_subset", "readers_see_commits_only",
+     "wal_span_counts"] +
+    ["panic:" + site for site in
+     ("wal.pre_kv_commit", "wal.post_kv_commit", "wal.mid_apply",
+      "wal.pre_trim", "alloc.mid_cow", "store.pre_apply",
+      "store.post_apply", "pglog.append")])
+
+
+class TestBlockStoreOnodeCache:
+    OWNER = "osd.7"
+
+    @pytest.fixture(autouse=True)
+    def _clean_faults(self):
+        from ceph_tpu.utils import faults
+        faults.get().reset(seed=0)
+        yield
+        faults.get().reset(seed=0)
+
+    def _mk(self, tmp_path, kv, **kw):
+        from ceph_tpu.store.blockstore import BlockStore
+        s = BlockStore(str(tmp_path / "bs") if kv == "sqlite" else "", **kw)
+        s.owner = self.OWNER
+        s.mkfs()
+        s.mount()
+        s.apply_transaction(T().create_collection("c"))
+        return s
+
+    def _remount(self, tmp_path, kv, dead):
+        """A new store over what the dead one left: its directory, or
+        (no path) its MemDB and its in-memory device."""
+        from ceph_tpu.store.blockstore import BlockStore
+        if kv == "sqlite":
+            s = BlockStore(str(tmp_path / "bs"))
+        else:
+            s = BlockStore()
+            s.db, s.dev = dead.db, dead.dev
+        s.mount()
+        return s
+
+    @staticmethod
+    def _kv_head(s, oid, cid="c"):
+        """The onode as the KV holds it, round the store."""
+        from ceph_tpu.store.blockstore import P_ONODE
+        from ceph_tpu.utils import denc
+        blob = s.db.get(P_ONODE, f"{cid}/{oid}")
+        return None if blob is None else denc.loads(blob)
+
+    def _agrees_with_kv(self, s, oids, data=True):
+        """`data=False` for a dead store: the blocks of a commit whose
+        deferred writes wait for the replay do not read yet."""
+        for oid in oids:
+            want = self._kv_head(s, oid)
+            assert s.exists("c", oid) == (want is not None), oid
+            if want is not None:
+                assert s._committed_onode("c", oid) == want, oid
+                assert s.stat("c", oid) == {"size": want["size"]}
+                assert s.getattrs("c", oid) == want["xattrs"]
+                if data:
+                    assert len(s.read("c", oid)) == want["size"]
+
+    def test_crash_sites_are_all_cases(self):
+        from ceph_tpu.store.blockstore import BlockStore
+        assert {"panic:" + site for site in BlockStore().crash_sites()} == \
+            {c for c in _CACHE_CASES if c.startswith("panic:")}
+
+    @pytest.mark.parametrize("case", _CACHE_CASES)
+    @pytest.mark.parametrize("kv", ["sqlite", "memdb"])
+    def test_cache_rule(self, tmp_path, kv, case):
+        if case.startswith("panic:"):
+            return self._panic_then_remount(tmp_path, kv, case[6:])
+        getattr(self, "_case_" + case)(tmp_path, kv)
+
+    # -- a txn edits copies, and installs at the commit point ----------------
+
+    def _case_raises_halfway(self, tmp_path, kv):
+        import copy
+        s = self._mk(tmp_path, kv)
+        old = _seeded(20000, 1)
+        s.apply_transaction(T().write("c", "o", 0, old)
+                            .setattr("c", "o", "gen", b"1"))
+        head = s._committed_onode("c", "o")
+        was = copy.deepcopy(head)
+        free = s.alloc.total_free()
+        with pytest.raises(StoreError) as ei:
+            s.apply_transaction(T().write("c", "o", 100, _seeded(9000, 2))
+                                .truncate("c", "o", 5)
+                                .setattr("c", "o", "gen", b"2")
+                                .write("c", "fresh", 0, b"x" * 5000)
+                                .remove("c", "never-was"))
+        assert ei.value.errno == ENOENT
+        # the head a reader held was not edited, and reads are at the
+        # old generation, from the cache or from the KV
+        assert head == was
+        assert s.read("c", "o") == old
+        assert s.getattrs("c", "o") == {"gen": b"1"}
+        assert not s.exists("c", "fresh")
+        assert s.alloc.total_free() == free
+        self._agrees_with_kv(s, ["o", "fresh"])
+        s.umount()
+
+    def _case_installed_at_commit_only(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        s.apply_transaction(T().write("c", "o", 0, b"a" * 6000))
+        old = s._committed_onode("c", "o")
+        at_commit = []
+        real = s.db.submit_transaction
+
+        def submit(kvt, sync=False):
+            # nothing of the txn is visible before the KV has it
+            at_commit.append((s._onodes.get("c/o"), s._onodes.get("c/new"),
+                              "d" in s._collections(), sync))
+            real(kvt, sync=sync)
+        s.db.submit_transaction = submit
+        s.apply_transaction(T().create_collection("d")
+                            .write("c", "o", 0, b"b" * 9000)
+                            .write("c", "new", 0, b"n" * 100))
+        # (the trim of the WAL record the old blocks rode is a KV
+        # commit of its own, before the txn's)
+        assert at_commit and \
+            all(seen == (old, None, False, True) for seen in at_commit)
+        assert at_commit[-1][0] is old and old["size"] == 6000
+        now = s._committed_onode("c", "o")
+        assert now is not old and now["size"] == 9000
+        assert now is s._onodes.get("c/o")        # written through
+        assert s._onodes.get("c/new")["size"] == 100
+        assert s.collection_exists("d")
+        assert s.list_collections() == ["c", "d"]
+        calls = s.db.calls
+        assert s.read("c", "o") == b"b" * 9000
+        assert s.stat("c", "new") == {"size": 100}
+        assert s.db.calls == calls                # no KV call for either
+        self._agrees_with_kv(s, ["o", "new"])
+        s.umount()
+
+    def _case_absent_is_a_select(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        before = len(s._onodes)
+        for _ in (1, 2):                # no negative entries
+            calls = s.db.calls
+            assert not s.exists("c", "nobody")
+            with pytest.raises(StoreError):
+                s.stat("c", "nobody")
+            assert s.db.calls == calls + 2
+        assert len(s._onodes) == before
+        stats = s.journal_stats()
+        assert stats["onode_lookups"] >= 4 and stats["onode_hits"] == 0
+        s.umount()
+
+    # -- evictions -------------------------------------------------------------
+
+    def _case_remove(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        s.apply_transaction(T().write("c", "o", 0, b"1" * 5000))
+        assert s._onodes.get("c/o") is not None
+        s.apply_transaction(T().remove("c", "o"))
+        assert s._onodes.get("c/o") is None and not s.exists("c", "o")
+        with pytest.raises(StoreError):
+            s.read("c", "o")
+        s.apply_transaction(T().write("c", "o", 0, b"2" * 10))
+        assert s.read("c", "o") == b"2" * 10
+        self._agrees_with_kv(s, ["o"])
+        s.umount()
+
+    def _case_rmcoll(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        s.apply_transaction(T().create_collection("d")
+                            .write("d", "a", 0, b"a" * 5000)
+                            .write("d", "b", 0, b"b" * 5000)
+                            .write("c", "keep", 0, b"k"))
+        assert s.read("d", "a") == b"a" * 5000
+        s.apply_transaction(T().remove_collection("d"))
+        assert s._onodes.get("d/a") is None and s._onodes.get("d/b") is None
+        assert not s.collection_exists("d")
+        assert s.list_collections() == ["c"]
+        with pytest.raises(StoreError):
+            s.collection_list("d")
+        with pytest.raises(StoreError):
+            s.apply_transaction(T().write("d", "a", 0, b"late"))
+        s.apply_transaction(T().create_collection("d")
+                            .write("d", "a", 0, b"again"))
+        assert s.read("d", "a") == b"again" and not s.exists("d", "b")
+        assert s.read("c", "keep") == b"k"
+        s.umount()
+
+    def _case_move(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        data = _seeded(70000, 3)
+        s.apply_transaction(T().create_collection("d")
+                            .write("c", "src", 0, data)
+                            .setattr("c", "src", "x", b"y"))
+        assert s.read("c", "src") == data
+        s.apply_transaction(
+            T().collection_move_rename("c", "src", "d", "dst"))
+        assert s._onodes.get("c/src") is None and not s.exists("c", "src")
+        calls = s.db.calls
+        assert s.read("d", "dst") == data
+        assert s.getattr("d", "dst", "x") == b"y"
+        assert s.db.calls == calls
+        with pytest.raises(StoreError):
+            s.apply_transaction(
+                T().collection_move_rename("d", "dst", "nowhere", "z"))
+        assert s.read("d", "dst") == data
+        s.umount()
+
+    def _case_lru_bound(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        s._onodes.limit = 40            # ten onodes of three blocks
+        want = {}
+        for i in range(30):
+            want[f"o{i:02d}"] = _seeded(3 * 4096, i)
+            s.apply_transaction(T().write("c", f"o{i:02d}", 0,
+                                          want[f"o{i:02d}"]))
+            assert s._onodes._weight <= 40 and len(s._onodes) <= 10
+        assert s._onodes._weight == sum(
+            1 + len(h["blocks"]) for h in s._onodes._heads.values())
+        # the newest is resident, the oldest went and reads back
+        calls = s.db.calls
+        assert s.read("c", "o29") == want["o29"]
+        assert s.db.calls == calls
+        assert s._onodes.get("c/o00") is None
+        assert s.read("c", "o00") == want["o00"]
+        assert s.db.calls == calls + 1
+        assert s._onodes.get("c/o00") is not None
+        # a look-up keeps an onode from going first
+        s.stat("c", "o21")
+        for i in range(30, 38):
+            s.apply_transaction(T().write("c", f"n{i}", 0, b"z" * 12288))
+        assert s._onodes.get("c/o21") is not None
+        assert s._onodes.get("c/o22") is None
+        # an onode larger than the bound is not kept, and still reads
+        big = _seeded(64 * 4096, 99)
+        s.apply_transaction(T().write("c", "big", 0, big))
+        assert len(s._onodes) == 0 and s._onodes._weight == 0
+        assert s.read("c", "big") == big
+        for oid, data in want.items():
+            assert s.read("c", oid) == data
+        s.umount()
+
+    def _case_mount_and_freeze(self, tmp_path, kv):
+        s = self._mk(tmp_path, kv)
+        data = _seeded(30000, 5)
+        s.apply_transaction(T().write("c", "o", 0, data))
+        assert len(s._onodes) >= 1
+        s.umount()
+        s2 = self._remount(tmp_path, kv, s)
+        assert len(s2._onodes) == 0 and s2._colls is None
+        assert s2.read("c", "o") == data            # a miss fills it
+        assert s2.journal_stats()["onode_hits"] == 0
+        assert s2.read("c", "o") == data
+        assert s2.journal_stats()["onode_hits"] == 1
+        s2.freeze()
+        assert len(s2._onodes) == 0
+        calls = s2.db.calls
+        assert s2.read("c", "o") == data            # from the KV
+        assert s2.exists("c", "o")
+        assert s2.db.calls == calls + 2 and len(s2._onodes) == 0
+        from ceph_tpu.store import CrashPoint
+        with pytest.raises(CrashPoint):
+            s2.apply_transaction(T().write("c", "o", 0, b"late"))
+        assert len(s2._onodes) == 0 and s2.read("c", "o") == data
+        s2.umount()
+
+    # -- crashes ---------------------------------------------------------------
+
+    def _poison(self, s):
+        """A head the KV does not hold, put where a surviving cache
+        would serve it from."""
+        s._onodes.put("c/bystander", {"size": 3, "blocks": {},
+                                      "xattrs": {"poison": b"1"}})
+        assert s.getattrs("c", "bystander") == {"poison": b"1"}
+
+    def _panic_then_remount(self, tmp_path, kv, site):
+        from ceph_tpu.store import CrashPoint
+        from ceph_tpu.utils import faults
+        direct = site == "alloc.mid_cow"
+        s = self._mk(tmp_path, kv, deferred_max=1024 if direct else 65536)
+        old, new = _seeded(16384, 1), _seeded(16384, 2)
+        s.apply_transaction(T().write("c", "victim", 0, old)
+                            .write("c", "bystander", 0, b"by")
+                            .setattr("c", "bystander", "gen", b"1"))
+        assert s.read("c", "victim") == old         # resident
+        self._poison(s)
+        faults.get().reset(seed=0x5EED)
+        faults.get().crash(site, 1.0, self.OWNER)
+        acked = []
+        t = T().write("c", "victim", 0, new).setattr("c", "victim", "g", b"2")
+        t.register_on_commit(lambda: acked.append(1))
+        try:
+            s.queue_transactions([t])
+            # the one site outside a store transaction: the PG's log
+            # append, after its transaction was acknowledged
+            assert site == "pglog.append" and acked
+            s._maybe_crash(site)
+            raise AssertionError("the crash rule did not fire")
+        except CrashPoint:
+            pass
+        assert s.frozen and s.crash_site == site
+        assert not faults.get().rules()
+        assert not acked or site == "pglog.append"
+        # the dead store answers from the KV: the poisoned head is gone
+        assert len(s._onodes) == 0
+        assert s.getattrs("c", "bystander") == {"gen": b"1"}
+        self._agrees_with_kv(s, ["victim", "bystander"], data=False)
+        assert len(s._onodes) == 0
+        s.umount()
+        s2 = self._remount(tmp_path, kv, s)
+        assert len(s2._onodes) == 0
+        got = s2.read("c", "victim")
+        if site in ("store.pre_apply", "alloc.mid_cow", "wal.pre_trim"):
+            # (the overwrite frees blocks an applied WAL record names,
+            # so the trim, and its crash site, come before the commit)
+            assert got == old
+        elif site == "wal.pre_kv_commit":
+            assert got in (old, new)
+        else:
+            assert got == new and s2.getattr("c", "victim", "g") == b"2"
+        assert s2.getattrs("c", "bystander") == {"gen": b"1"}
+        self._agrees_with_kv(s2, ["victim", "bystander"])
+        s2.apply_transaction(T().write("c", "victim", 0, b"after"))
+        assert s2.read("c", "victim")[:5] == b"after"
+        s2.umount()
+
+    def _torn_kv(self, tmp_path, kv, reorder):
+        from ceph_tpu.store import CrashPoint
+        from ceph_tpu.utils import faults
+        s = self._mk(tmp_path, kv)
+        oids = [f"t{i}" for i in range(6)]
+        t = T()
+        for oid in oids:
+            t.write("c", oid, 0, oid.encode() * 1000).setattr(
+                "c", oid, "gen", b"1")
+        s.apply_transaction(t)
+        self._poison(s)
+        outcomes = set()
+        faults.get().reset(seed=0xA11CE)
+        faults.get().crash("wal.pre_kv_commit", 1.0, self.OWNER)
+        if reorder:
+            faults.get().fsync_reorder(1.0, self.OWNER)
+        t = T()
+        for oid in oids:
+            t.write("c", oid, 0, oid.upper().encode() * 1000).setattr(
+                "c", oid, "gen", b"2")
+        t.remove("c", "bystander")
+        with pytest.raises(CrashPoint):
+            s.apply_transaction(t)
+        # some of the commit's rows landed and some did not: whichever,
+        # the dead store and the remounted one say what the KV says
+        for oid in oids:
+            outcomes.add(self._kv_head(s, oid)["xattrs"]["gen"])
+        assert outcomes == {b"1", b"2"}, "the commit did not tear"
+        assert len(s._onodes) == 0
+        self._agrees_with_kv(s, oids, data=False)
+        s.umount()
+        s2 = self._remount(tmp_path, kv, s)
+        # (of a subset the WAL record may be the row that was lost:
+        # then the new onodes' blocks do not read, as at the parent)
+        self._agrees_with_kv(s2, oids, data=not reorder)
+        for oid in oids:
+            gen = s2.getattr("c", oid, "gen")
+            if gen == b"1" or not reorder:
+                assert s2.read("c", oid) == (
+                    oid if gen == b"1" else oid.upper()).encode() * 1000
+        s2.umount()
+
+    def _case_torn_kv_prefix(self, tmp_path, kv):
+        self._torn_kv(tmp_path, kv, reorder=False)
+
+    def _case_torn_kv_subset(self, tmp_path, kv):
+        self._torn_kv(tmp_path, kv, reorder=True)
+
+    # -- readers beside a committer ---------------------------------------------
+
+    def _case_readers_see_commits_only(self, tmp_path, kv):
+        import sys
+        import time
+        s = self._mk(tmp_path, kv)
+        size = 3 * 4096 + 100
+
+        def commit(gen):
+            s.apply_transaction(
+                T().write("c", "o", 0, bytes([gen % 251]) * size)
+                .setattr("c", "o", "a", str(gen).encode())
+                .setattr("c", "o", "b", str(gen).encode()))
+        commit(0)
+        started, done = [0], [0]
+        stop = threading.Event()
+        faults_seen = []
+
+        def reader(kind):
+            try:
+                while not stop.is_set():
+                    lo = done[0]
+                    if kind == "data":
+                        got = s.read("c", "o")
+                        assert len(got) == size and got == got[:1] * size, \
+                            "a mix of generations"
+                        gens = [g for g in range(lo, started[0] + 1)
+                                if g % 251 == got[0]]
+                        assert gens, (lo, got[0], started[0])
+                    else:
+                        attrs = s.getattrs("c", "o")
+                        assert attrs["a"] == attrs["b"], attrs
+                        assert lo <= int(attrs["a"]) <= started[0]
+            except BaseException as e:      # noqa: BLE001 - reported below
+                faults_seen.append(e)
+        threads = [threading.Thread(target=reader, args=(k,), daemon=True)
+                   for k in ("data", "attrs")]
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            for t in threads:
+                t.start()
+            end = time.monotonic() + 1.5
+            gen = 0
+            while time.monotonic() < end and not faults_seen:
+                gen += 1
+                started[0] = gen
+                commit(gen)
+                done[0] = gen
+        finally:
+            stop.set()
+            for t in threads:
+                t.join(timeout=20)
+            sys.setswitchinterval(interval)
+        assert not any(t.is_alive() for t in threads)
+        assert not faults_seen, faults_seen[0]
+        assert gen >= 5
+        stats = s.journal_stats()
+        assert stats["onode_hits"] > gen        # readers and txns both hit
+        s.umount()
+
+    # -- the counts on the wal span ----------------------------------------------
+
+    def _case_wal_span_counts(self, tmp_path, kv):
+        from ceph_tpu.utils import optracker
+        from ceph_tpu.utils.clock import ManualClock
+        s = self._mk(tmp_path, kv)
+        s.apply_transaction(T().touch("c", "_pgmeta"))
+        trk = optracker.OpTracker(ManualClock(), history_size=8)
+
+        def traced(txn):
+            op = trk.create("w")
+            before = s.journal_stats()
+            with optracker.op_context(op):
+                s.apply_transaction(txn)
+            op.finish()
+            doc = trk.dump_historic_ops()["ops"][-1]
+            (wal,) = [sp for sp in doc["spans"] if sp["name"] == "wal"]
+            after = s.journal_stats()
+            moved = {k: after[k] - before[k] for k in
+                     ("kv_calls", "commits", "onode_lookups", "onode_hits")}
+            assert {k: wal["args"][k] for k in moved} == moved
+            return wal["args"]
+
+        def shard_txn(oid):
+            # the shape of an EC shard's sub-op
+            return (T().truncate("c", oid, 0)
+                    .write("c", oid, 0, _seeded(512 * 1024, 7))
+                    .setattr("c", oid, "hinfo", b"h" * 40)
+                    .setattr("c", oid, "ver", b"v" * 8)
+                    .setattr("c", "_pgmeta", "log", b"L" * 20000))
+        # a new object: its one SELECT finds nothing, the PG's meta
+        # object is resident, and the commit is one statement
+        args = traced(shard_txn("new.s3"))
+        assert args["blocks"] == 128 and args["dev_writes"] == 1
+        assert args["kv_calls"] == 2 and args["commits"] == 1
+        assert args["onode_lookups"] == 2 and args["onode_hits"] == 1
+        # an overwrite: both resident, the commit alone crosses
+        args = traced(shard_txn("new.s3"))
+        assert args["kv_calls"] == 1
+        assert args["onode_lookups"] == 2 and args["onode_hits"] == 2
+        s.umount()

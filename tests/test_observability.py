@@ -405,7 +405,8 @@ class TestPerfCounters:
         bs.mkfs()
         bstats = bs.journal_stats()
         for key in ("wal_records_replayed", "wal_torn_extent_repairs",
-                    "freelist_repairs", "fsync_reorder_windows"):
+                    "freelist_repairs", "fsync_reorder_windows",
+                    "kv_calls", "commits", "onode_lookups", "onode_hits"):
             assert key in bstats, key
         assert set(bs.crash_sites()) >= {
             "wal.pre_kv_commit", "wal.post_kv_commit",
