@@ -135,6 +135,12 @@ TECHNIQUES: dict[str, tuple] = {
 TECH_DEFAULT_W = {"liberation": 7, "blaum_roth": 6, "liber8tion": 8}
 
 
+def raw_bitmatrix(rep: str, matrix: np.ndarray, w: int) -> np.ndarray:
+    """The GF(2) matrix a packet-layout code XORs packets by: a
+    liberation-family matrix as it is, a cauchy matrix expanded."""
+    return matrix if rep == REP_BITS else gf.expand_bitmatrix(matrix, w)
+
+
 # ---------------------------------------------------------------------------
 # Backends
 # ---------------------------------------------------------------------------
@@ -182,6 +188,12 @@ class TpuBackend:
     The callable cache avoids re-expanding the GF(2^8) matrix to bits on
     every call — that host-side work would dominate small-chunk ops.
 
+    A backend serves ONE codec, whose chunk representation (`rep`,
+    with `w` and `packetsize` for the packet layouts) it is told at the
+    codec's init (:meth:`serve`): the readiness predicates answer for
+    that representation, so a caller names a matrix, a shape and a
+    device and gets the program the pool's technique needs.
+
     Host/device routing is MEASURED, not hardcoded: per size bucket
     (power of two of payload bytes) the backend keeps an EMA of observed
     seconds-per-byte for each path, routes to the faster one, and
@@ -203,6 +215,7 @@ class TpuBackend:
         import threading
         from ..ops import ec_kernels
         self._ek = ec_kernels
+        self.rep, self.w, self.packetsize = REP_BYTES, 8, 0
         self._fns: dict[tuple, object] = {}
         self._host = NumpyBackend()
         # (path, bucket) -> {"spb": ema sec/byte, "n": samples}
@@ -220,6 +233,10 @@ class TpuBackend:
         self._warm_failed: set = set()
         self._warm_lock = threading.Lock()
         self._fn_lock = threading.Lock()
+
+    def serve(self, rep: str, w: int, packetsize: int) -> None:
+        """The chunk representation of the codec this backend serves."""
+        self.rep, self.w, self.packetsize = rep, w, packetsize
 
     def _fn(self, kind: str, matrix: np.ndarray, *extra):
         key = (kind, matrix.tobytes(), matrix.shape, *extra)
@@ -242,8 +259,7 @@ class TpuBackend:
             if kind == "bytes":
                 fn = self._ek.make_codec_fn(matrix, 8)
             elif kind == "fused":
-                (length,) = extra
-                fn = self._make_fused(matrix, length)
+                fn = self._make_fused(matrix, *extra)
             elif kind == "bits":
                 w, packetsize = extra
                 fn = self._ek.make_bits_codec_fn(matrix, w, packetsize)
@@ -251,12 +267,12 @@ class TpuBackend:
                 w, packetsize = extra
                 fn = self._ek.make_packet_codec_fn(matrix, w, packetsize)
             if len(self._fns) > 256:
-                # decode patterns first: a "bytes" closure is cheap to
+                # decode patterns first: an apply closure is cheap to
                 # build again and its readiness hangs on the matrix
                 # SHAPE, not on this entry, so dropping them strands
                 # nothing (a degraded pool sees hundreds of patterns)
                 for old in list(self._fns):
-                    if old[0] == "bytes":
+                    if old[0] != "fused":
                         self._fns.pop(old, None)
             if len(self._fns) > 256:
                 # readiness is keyed on the fn cache: evicting one
@@ -270,18 +286,26 @@ class TpuBackend:
             self._fns[key] = fn
         return fn
 
-    def _make_fused(self, matrix: np.ndarray, length: int):
+    def _make_fused(self, matrix: np.ndarray, length: int,
+                    rep: str = REP_BYTES, w: int = 8, packetsize: int = 0):
         """Fused encode+CRC kernel.  On a TPU the hand-tiled pallas
         kernel IS the kernel: a failure to build or compile it reaches
         the warm thread, which logs and counts it (warm_failures) and
         leaves the shape on the host path.  Pallas TPU kernels don't
         run on the CPU backend, so the tests' platform serves the XLA
-        formulation."""
+        formulation.  A packet-layout code (cauchy's expanded matrix, a
+        liberation-family bit-matrix as it is) has one program for
+        both: XORs of whole packets, and the byte program's CRC fold."""
         import jax
         from ..ops import pallas_ec
 
-        if jax.devices()[0].platform == "tpu" and \
-                pallas_ec.supports(length):
+        pallas = jax.devices()[0].platform == "tpu" and \
+            pallas_ec.supports(length)
+        if rep != REP_BYTES:
+            return self._ek.make_packet_encode_crc_fn(
+                raw_bitmatrix(rep, matrix, w), w, packetsize, length,
+                crc=pallas_ec.make_crc_fn(length) if pallas else None)
+        if pallas:
             return pallas_ec.make_encode_crc_fn(matrix, length)
         return self._ek.make_encode_crc_fn(matrix, length)
 
@@ -395,21 +419,28 @@ class TpuBackend:
         the multichip pipeline probes each lane's readiness and the
         warm probe runs pinned to that device.
 
-        For the "bytes" kind the matrix is an operand of the
-        executable (ec_kernels._apply_fn), so readiness is keyed on
-        the matrix SHAPE: a decode pattern never seen before is
-        served by the device at once when another pattern of its
+        For every kind but "fused" the matrix is an operand of the
+        executable (ec_kernels._apply_fn, _packet_fn), so readiness is
+        keyed on the matrix SHAPE: a decode pattern never seen before
+        is served by the device at once when another pattern of its
         shape already warmed — only the cheap per-matrix closure is
         built here.
+
+        `kind` "bytes" asks for the apply of the codec's OWN
+        representation: on a backend that serves a packet-layout codec
+        it is that codec's packet program (a caller that holds decode
+        rows need not know which family made them).
         """
         from ..ops import pipeline as ec_pipeline
+        if kind == REP_BYTES and self.rep != REP_BYTES:
+            kind, extra = self.rep, (self.w, self.packetsize)
         fkey = (kind, matrix.tobytes(), matrix.shape, *extra)
-        rkey = ((kind, matrix.shape) if kind == "bytes" else fkey,
+        rkey = (fkey if kind == "fused" else (kind, matrix.shape, *extra),
                 shape, ec_pipeline._device_warm_key(device))
         if rkey in self._ready:
             fn = self._fns.get(fkey)
-            if fn is None and kind == "bytes":
-                fn = self._fn(kind, matrix)
+            if fn is None and kind != "fused":
+                fn = self._fn(kind, matrix, *extra)
             return fn
         with self._warm_lock:
             if rkey in self._warming or rkey in self._warm_failed:
@@ -517,8 +548,10 @@ class TpuBackend:
 
     def fused_fn_if_ready(self, matrix: np.ndarray, shape: tuple,
                           device=None):
-        return self.device_fn_if_ready("fused", matrix, (shape[-1],),
-                                       shape, device)
+        """The fused encode+CRC fn of the served representation."""
+        return self.device_fn_if_ready(
+            "fused", matrix,
+            (shape[-1], self.rep, self.w, self.packetsize), shape, device)
 
 
 # ---------------------------------------------------------------------------
@@ -595,6 +628,8 @@ class MatrixErasureCode(ErasureCode):
                          for row in self.coding_matrix] \
             if self.planned else []
         self._fast1 = self._build_fast1()
+        if isinstance(self.backend, TpuBackend):
+            self.backend.serve(self.rep, self.w, self.packetsize)
 
     def _build_fast1(self):
         """Pre-bound single-stripe encoder for the vstart-default
@@ -643,16 +678,20 @@ class MatrixErasureCode(ErasureCode):
 
     # -- encode -----------------------------------------------------------
 
-    def _apply(self, matrix: np.ndarray, chunks: np.ndarray) -> np.ndarray:
+    def _apply(self, matrix: np.ndarray, chunks: np.ndarray,
+               backend=None) -> np.ndarray:
+        """`matrix` applied to `chunks` in the codec's representation,
+        by `backend` (the codec's own unless given)."""
+        backend = backend or self.backend
         if matrix.shape[0] == 0:
             return np.zeros((0, chunks.shape[-1]), dtype=np.uint8)
         if self.rep == REP_PACKETS:
-            return self.backend.apply_packets(
+            return backend.apply_packets(
                 matrix, chunks, self.w, self.packetsize)
         if self.rep == REP_BITS:
-            return self.backend.apply_bits(
+            return backend.apply_bits(
                 matrix, chunks, self.w, self.packetsize)
-        return self.backend.apply_bytes(matrix, chunks)
+        return backend.apply_bytes(matrix, chunks)
 
     def encode_chunks(self, data_chunks: np.ndarray) -> np.ndarray:
         f = self._fast1
@@ -819,7 +858,7 @@ class MatrixErasureCode(ErasureCode):
         if stripes.ndim != 3 or stripes.shape[1] != self.k:
             raise ErasureCodeError(f"want (S, {self.k}, L), "
                                    f"got {stripes.shape}")
-        if self.rep == REP_BYTES and isinstance(self.backend, TpuBackend):
+        if isinstance(self.backend, TpuBackend):
             fn = None
             if self.backend.use_device(stripes.nbytes):
                 dev_in = self.backend.pad_batch(stripes)
@@ -842,8 +881,8 @@ class MatrixErasureCode(ErasureCode):
             # WITHOUT the fused CRC, muddying both metrics and semantics
             parity = self.backend._timed(
                 "host", stripes.nbytes,
-                lambda: np.asarray(self.backend._host.apply_bytes(
-                    self.coding_matrix, stripes)))
+                lambda: np.asarray(self._apply(
+                    self.coding_matrix, stripes, self.backend._host)))
         else:
             parity = np.asarray(self._apply(self.coding_matrix, stripes))
         allc = np.concatenate([stripes, parity], axis=1)

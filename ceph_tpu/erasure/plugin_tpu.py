@@ -40,7 +40,7 @@ from ..utils import faults
 from ..utils.dout import DoutLogger
 from .interface import ErasureCodeError
 from .matrix_codec import (REP_BYTES, TECHNIQUES, MatrixErasureCode,
-                           NumpyBackend, TpuBackend)
+                           NumpyBackend, TpuBackend, raw_bitmatrix)
 from .registry import ErasureCodePlugin
 
 
@@ -54,6 +54,11 @@ class _Done:
 
     def result(self, timeout=None):
         return self._v
+
+
+def _with_rep(fut, rep: str) -> dict | None:
+    ph = getattr(fut, "trace_phases", None)
+    return None if ph is None else dict(ph, rep=rep)
 
 
 class _PipelinedEncode:
@@ -78,8 +83,9 @@ class _PipelinedEncode:
     def trace_phases(self) -> dict | None:
         """Pipeline phase stamps for the op tracer (attached to the
         raw future at resolve; None while unresolved / on the
-        self-serve host fallback)."""
-        return getattr(self._fut, "trace_phases", None)
+        self-serve host fallback), with the chunk representation the
+        dispatch computed in."""
+        return _with_rep(self._fut, self._codec.rep)
 
     def result_parts(self, timeout=None):
         """(stripes, parity, crcs) WITHOUT materializing the joined
@@ -108,17 +114,18 @@ class _PipelinedEncode:
 
 
 class _PipelinedDecode:
-    __slots__ = ("_fut", "_host")
+    __slots__ = ("_fut", "_host", "_rep")
 
-    def __init__(self, fut, host):
+    def __init__(self, fut, host, rep):
         self._fut = fut
         self._host = host
+        self._rep = rep
 
     @property
     def trace_phases(self) -> dict | None:
         """The pipeline's per-item phase stamps (set at resolve) —
         decode-path op spans (recovery rebuild device time)."""
-        return getattr(self._fut, "trace_phases", None)
+        return _with_rep(self._fut, self._rep)
 
     def result(self, timeout=None):
         if timeout is None:
@@ -185,7 +192,10 @@ class ErasureCodeTpu(MatrixErasureCode):
         from .registry import registry as _registry
         _registry.note_degraded("tpu", reason)
 
-    def _apply(self, matrix: np.ndarray, chunks: np.ndarray) -> np.ndarray:
+    def _apply(self, matrix: np.ndarray, chunks: np.ndarray,
+               backend=None) -> np.ndarray:
+        if backend is not None:
+            return super()._apply(matrix, chunks, backend)
         if not self.degraded:
             if faults.get().tpu_error():
                 self._degrade("injected device error")
@@ -200,7 +210,9 @@ class ErasureCodeTpu(MatrixErasureCode):
 
     # -- shared-pipeline channels ------------------------------------------
     #
-    # One channel per (kind, chunk length): items from every producer
+    # One channel per (kind, chunk length), whatever the technique's
+    # chunk representation (byte symbols, cauchy's packets, a
+    # liberation-family bit-matrix): items from every producer
     # concatenate into mega-batches; the channel's callbacks carry the
     # degrade guard (route), the warm-gated per-device jitted fn
     # (device_fn — the pipeline passes the lane's device and readiness
@@ -244,7 +256,7 @@ class ErasureCodeTpu(MatrixErasureCode):
             # hand crc32c_batch one contiguous array, which on a slow-
             # memory rig cost more than the encode itself
             parity = np.asarray(
-                self._host_backend().apply_bytes(matrix, batch))
+                self._apply(matrix, batch, self._host_backend()))
             B, k, CL = batch.shape
             pm = parity.shape[1]
             crcs = np.empty((B, k + pm), dtype=np.uint32)
@@ -291,13 +303,14 @@ class ErasureCodeTpu(MatrixErasureCode):
 
         def host_fn(batch):
             return (np.asarray(
-                self._host_backend().apply_bytes(rows, batch)),)
+                self._apply(rows, batch, self._host_backend())),)
 
         def device_fn(padded, device=None):
             b = self.backend
             if self.degraded or not isinstance(b, TpuBackend):
                 return None
-            fn = b.device_fn_if_ready("bytes", rows, (),
+            # "bytes": the apply of the representation `b` serves
+            fn = b.device_fn_if_ready(REP_BYTES, rows, (),
                                       tuple(padded.shape), device)
             if fn is None:
                 return None
@@ -340,8 +353,6 @@ class ErasureCodeTpu(MatrixErasureCode):
         if stripes.ndim != 3 or stripes.shape[1] != self.k:
             raise ErasureCodeError(f"want (S, {self.k}, L), "
                                    f"got {stripes.shape}")
-        if self.rep != REP_BYTES:
-            return _Done(super().encode_stripes_with_crcs(stripes))
         chan = self._encode_channel(stripes.shape[2])
         fut = ec_pipeline.get().submit(chan, stripes, cache=cache,
                                        qos=qos)
@@ -371,24 +382,24 @@ class ErasureCodeTpu(MatrixErasureCode):
         want, present = list(want), list(present)
         rows = self._decode_rows(want, present)
         chunks = np.ascontiguousarray(chunks, dtype=np.uint8)
-        if self.rep != REP_BYTES or chunks.ndim != 3 or \
-                rows.shape[0] == 0:
+        if chunks.ndim != 3 or rows.shape[0] == 0:
             return _Done(self._apply(rows, chunks))
         short = self.k - len(present)
-        if short > 0:
+        if short > 0 and self.rep == REP_BYTES:
             # a plan that reads fewer than k chunks (shec's local
             # repair) rides the (r x k) operand and the (B, k, L)
             # stack every other decode uses: zero columns, zero-filled
             # chunks.  No executable of its own to compile, every
             # decode of one row count shares a dispatch shape, and the
-            # kernel's work stays 2*8k*8r*L a stripe.
+            # kernel's work stays 2*8k*8r*L a stripe.  (Planned
+            # techniques are byte codes; a packet code's plan is any k.)
             rows = np.pad(rows, ((0, 0), (0, short)))
             chunks = np.pad(chunks, ((0, 0), (0, short), (0, 0)))
         chan = self._decode_channel(want, present, rows,
                                     chunks.shape[2])
         return _PipelinedDecode(
             ec_pipeline.get().submit(chan, chunks, qos=qos),
-            lambda: chan.host_fn(chunks)[0])
+            lambda: chan.host_fn(chunks)[0], self.rep)
 
     def encode_with_crcs(self, data: np.ndarray):
         """(B, k, L) -> (parity (B, m, L), crcs (B, k+m) uint32), fused.
@@ -396,16 +407,20 @@ class ErasureCodeTpu(MatrixErasureCode):
         CRCs are CRC32C(seed 0) of each chunk; combine with a running
         object CRC via ceph_tpu.ops.crc32c.crc32c_combine on the host.
         """
-        if self.rep != REP_BYTES:
-            raise ErasureCodeError(
-                "fused encode+crc supports byte-matrix techniques only")
         data = np.asarray(data, dtype=np.uint8)
         B, k, L = data.shape
         if not self.degraded and faults.get().tpu_error():
             self._degrade("injected device error")
         if not self.degraded:
             try:
-                fn = ec_kernels.make_encode_crc_fn(self.coding_matrix, L)
+                if self.rep == REP_BYTES:
+                    fn = ec_kernels.make_encode_crc_fn(
+                        self.coding_matrix, L)
+                else:
+                    fn = ec_kernels.make_packet_encode_crc_fn(
+                        raw_bitmatrix(self.rep, self.coding_matrix,
+                                      self.w),
+                        self.w, self.packetsize, L)
                 parity, crcs = fn(data)
                 return np.asarray(parity), np.asarray(crcs)
             except Exception as e:

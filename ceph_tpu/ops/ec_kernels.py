@@ -210,15 +210,18 @@ def gf2_packet_matmul(m_bits: jnp.ndarray,
     return jnp.sum(out_bits * weights, axis=-1).astype(jnp.uint8)
 
 
-@functools.lru_cache(maxsize=256)
-def _packet_fn(bits_key: bytes, shape_key: tuple, w: int, packetsize: int):
-    rows, cols = shape_key
-    m_bits = jnp.asarray(
-        np.frombuffer(bits_key, dtype=np.uint8).reshape(rows, cols))
+@functools.lru_cache(maxsize=None)
+def _packet_fn(w: int, packetsize: int):
+    """Jitted (m_bits (R, C), data (B, n, L) uint8) -> (B, R/w, L).
+
+    The bit-matrix is an OPERAND, as in :func:`_apply_fn`: the decode
+    rows of every (want, present) pattern of one shape share one
+    executable per data shape."""
 
     @jax.jit
-    def run_packet_codec(data):
+    def run_packet_codec(m_bits, data):
         # data: (B, n, L) uint8, n*w == cols, L % (w*packetsize) == 0
+        rows, _cols = m_bits.shape
         B, n, L = data.shape
         nblk = L // (w * packetsize)
         blocks = data.reshape(B, n, nblk, w, packetsize)
@@ -247,18 +250,46 @@ def make_bits_codec_fn(bits: np.ndarray, w: int, packetsize: int):
     """Jitted packetized transform from a raw GF(2) bit-matrix
     (liberation / blaum_roth minimal-density codes, which have no
     byte-matrix form)."""
-    bits = np.asarray(bits, dtype=np.uint8)
-    fn = _packet_fn(bits.tobytes(), bits.shape, w, packetsize)
+    bits = np.ascontiguousarray(bits, dtype=np.uint8)
+    fn = _packet_fn(w, packetsize)
 
     def call(data):
         data = jnp.asarray(data, dtype=jnp.uint8)
         squeeze = data.ndim == 2
         if squeeze:
             data = data[None]
-        out = fn(data)
+        out = fn(bits, data)
         return out[0] if squeeze else out
 
     return call
+
+
+def gf2_packet_xor(m_bits: np.ndarray, data: jnp.ndarray, w: int,
+                   packetsize: int) -> jnp.ndarray:
+    """A CONSTANT (R, C) 0/1 matrix applied to packet chunks as XORs.
+
+    data: (B, C/w, L) uint8 -> (B, R/w, L) uint8, bit-identical to
+    :func:`gf2_packet_matmul` on the same layout.  An encode's matrix
+    never changes, so the schedule is unrolled at trace time: coding
+    packet r is the XOR of the data packets whose bit is set in row r,
+    whole bytes at a time, with no 8x bit expansion and no MXU pass.
+    The packets first move to the LEADING axis, (C, B, nblk * P), so
+    that a packet is a dense (B, nblk * P) slab and not a 32-byte sliver
+    of a 128-lane row; the two relayouts are the cost."""
+    rows, cols = m_bits.shape
+    B, n, L = data.shape
+    nblk = L // (w * packetsize)
+    packets = data.reshape(B, n, nblk, w, packetsize).transpose(
+        1, 3, 0, 2, 4).reshape(cols, B, nblk * packetsize)
+    zero = jnp.zeros_like(packets[0])
+    out = jnp.stack([
+        functools.reduce(jnp.bitwise_xor,
+                         [packets[c] for c in np.flatnonzero(m_bits[r])],
+                         zero)
+        for r in range(rows)])
+    out = out.reshape(rows // w, w, B, nblk, packetsize).transpose(
+        2, 0, 3, 1, 4)
+    return out.reshape(B, rows // w, L)
 
 
 # ---------------------------------------------------------------------------
@@ -397,3 +428,41 @@ def make_encode_crc_witness_fn(matrix: np.ndarray, nbytes: int,
         block = _pick_block(nbytes)
     return _encode_crc_fn(bits.tobytes(), bits.shape, nbytes, block,
                           witness_only=True)
+
+
+@functools.lru_cache(maxsize=64)
+def _packet_encode_crc_fn(bits_key: bytes, shape_key: tuple, w: int,
+                          packetsize: int, nbytes: int, crc):
+    rows, cols = shape_key
+    m_bits = np.frombuffer(bits_key, dtype=np.uint8).reshape(rows, cols)
+    k, m = cols // w, rows // w
+
+    @jax.jit
+    def run_packet_encode_crc(data):
+        B = data.shape[0]
+        parity = gf2_packet_xor(m_bits, data, w, packetsize)
+        # the CRC of a chunk is over its bytes as stored: the fold the
+        # byte program has, data and parity slabs apart (no concatenate)
+        dcrc = crc(data.reshape(B * k, nbytes)).reshape(B, k)
+        pcrc = crc(parity.reshape(B * m, nbytes)).reshape(B, m)
+        return parity, jnp.concatenate([dcrc, pcrc], axis=1)
+
+    return run_packet_encode_crc
+
+
+def make_packet_encode_crc_fn(bits: np.ndarray, w: int, packetsize: int,
+                              nbytes: int, crc=None):
+    """fn(data (B, k, L)) -> (parity (B, m, L), crcs (B, k+m) uint32)
+    for a packet-layout code, from its raw (w*m x w*k) GF(2) bit-matrix
+    (the expansion of a cauchy matrix, or a liberation-family matrix
+    as it is): parity bit-identical to jerasure's packet-wise
+    bit-matrix encode, CRC32C (seed 0) of every chunk as stored, one
+    dispatch.  `crc` is the rows fold, (N, L) uint8 -> (N,) uint32:
+    the XLA one unless the caller brings another (on a TPU the Pallas
+    kernel the byte program uses, `pallas_ec.make_crc_fn`)."""
+    bits = np.ascontiguousarray(bits, dtype=np.uint8)
+    if nbytes % (w * packetsize):
+        raise ValueError(f"chunk of {nbytes} bytes is not whole "
+                         f"super-blocks of {w} x {packetsize}")
+    return _packet_encode_crc_fn(bits.tobytes(), bits.shape, w, packetsize,
+                                 nbytes, crc or make_crc_fn(nbytes))

@@ -296,9 +296,12 @@ class _Item:
         # timebase): submit -> picked (coalesce wait) -> stage0/1
         # (H2D) -> issue -> collect0 (compute done) -> done (D2H), or
         # host0/host1 for the host drain; requeues counts degrades.
+        # `stripes` is this submission's own rows, `padded` its
+        # pro-rata share of the bucket its dispatch ran at (the
+        # submissions that shared a dispatch add up to the bucket).
         # Attached to the future as `trace_phases` at resolve so the
         # producer's op thread can span its TrackedOp.
-        self.ph: dict = {"submit": self.t}
+        self.ph: dict = {"submit": self.t, "stripes": self.n}
 
 
 class _Lane:
@@ -1055,6 +1058,7 @@ class EcDevicePipeline:
         for it in items:
             # quarantine/failure degrade marker for the op trace
             it.ph["requeues"] = it.ph.get("requeues", 0) + 1
+            it.ph.pop("padded", None)   # the next dispatch pads anew
         q.extendleft(reversed(items))
         self._c["redrained"] += len(items)
         self._work_cv.notify()
@@ -1236,9 +1240,12 @@ class EcDevicePipeline:
                                          staged, e)
             return
         t_s1 = time.monotonic()
+        share = padded.shape[0] / sum(it.n for it in its)
         for it in its:
             # split-group parts stage concurrently; the per-item
-            # stamps keep the widest window (min start, max end)
+            # stamps keep the widest window (min start, max end) and
+            # add up the parts' buckets
+            it.ph["padded"] = it.ph.get("padded", 0.0) + share * it.n
             it.ph["stage0"] = min(it.ph.get("stage0", t_s0), t_s0)
             it.ph["stage1"] = max(it.ph.get("stage1", t_s1), t_s1)
             it.ph["issue"] = it.ph["stage1"]

@@ -21,7 +21,11 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
       ec.coalesce, ec.stage_h2d, ec.device_compute, ec.d2h,
       ec.host_encode             :func:`note_pipeline_phases`, from the
                                  stamps a pipeline future carries
-                                 (osd/ecutil.py, osd/scrubber.py)
+                                 (osd/ecutil.py, osd/scrubber.py);
+                                 ec.device_compute carries args
+                                 stripes, padded (the submission's
+                                 rows and its share of the padded
+                                 batch) and, from a codec, rep
       journal, wal, store_apply  store/filestore.py, store/blockstore.py,
                                  store/objectstore.py (BlockStore's wal
                                  carries args blocks, dev_writes: the
@@ -180,7 +184,11 @@ def note_pipeline_phases(ph: dict | None) -> None:
     c0, c1 = ph.get("collect0"), ph.get("done")
     issue = ph.get("issue")
     if issue is not None and c0 is not None and c0 > issue:
-        op.add_span("ec.device_compute", issue, c0)
+        # what the dispatch computed: this submission's stripes, its
+        # share of the padded batch, the chunk representation
+        op.add_span("ec.device_compute", issue, c0,
+                    **{a: ph[a] for a in ("stripes", "padded", "rep")
+                       if a in ph})
     if c0 is not None and c1 is not None and c1 > c0:
         op.add_span("ec.d2h", c0, c1)
     h0, h1 = ph.get("host0"), ph.get("host1")

@@ -58,6 +58,21 @@ def test_pallas_fused_encode_crc(one_chip, shape):
     assert text.count("tpu_custom_call") >= 3
 
 
+def test_packet_encode_crc(one_chip):
+    """What TpuBackend._make_fused serves a packet-layout codec on a
+    TPU: jerasure cauchy_good k=6 m=3 packetsize=32 at one 4 MiB
+    object's 171 stripes in their 256-bucket; XORs of whole packets in
+    XLA, the data and parity CRC folds the byte program's Mosaic
+    kernel."""
+    bits = gf.expand_bitmatrix(gf.cauchy_good_matrix(6, 3), 8)
+    fn = ec_kernels.make_packet_encode_crc_fn(
+        bits, 8, 32, 4096, crc=pallas_ec.make_crc_fn(4096,
+                                                     interpret=False))
+    compiled = _compile(fn, one_chip, (256, 6, 4096))
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28
+
+
 def test_pallas_crc(one_chip):
     fn = pallas_ec.make_crc_fn(4096, interpret=False)
     assert "tpu_custom_call" in _compile(

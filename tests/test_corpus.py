@@ -38,6 +38,8 @@ CONFIGS = [
     ("isa", {"technique": "cauchy", "k": "4", "m": "3"}),
     ("tpu", {"technique": "reed_sol_van", "k": "8", "m": "3"}),
     ("tpu", {"technique": "isa_reed_sol_van", "k": "6", "m": "2"}),
+    ("tpu", {"technique": "cauchy_good", "k": "6", "m": "3",
+             "packetsize": "32"}),
     ("shec", {"k": "5", "m": "3", "c": "2"}),
     ("lrc", {"k": "4", "m": "2", "l": "3"}),
 ]

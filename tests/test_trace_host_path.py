@@ -493,8 +493,9 @@ def test_jitted_programs_have_distinct_names():
         "xla_encode_crc": ec_kernels._encode_crc_fn(
             gf.expand_bitmatrix(matrix, 8).tobytes(), (8, 16), 4096,
             ec_kernels._pick_block(4096)),
-        "packet_codec": ec_kernels._packet_fn(
-            np.eye(8, dtype=np.uint8).tobytes(), (8, 8), 8, 8),
+        "packet_codec": ec_kernels._packet_fn(8, 8),
+        "packet_encode_crc": ec_kernels.make_packet_encode_crc_fn(
+            np.eye(8, dtype=np.uint8), 8, 8, 64),
     }
     names = {key: fn.__name__ for key, fn in fns.items()}
     assert names == {key: f"run_{key}" for key in fns}
