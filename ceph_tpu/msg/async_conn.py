@@ -33,15 +33,13 @@ import errno
 import random
 import socket
 import threading
-import time
 from typing import Callable
 
 from ..auth import cephx
 from ..utils import faults
 from .message import Message
 from .messenger import (AuthError, BANNER_MAGIC, Policy, _BANNER,
-                        _BANNER_REPLY, _forget, _pack_addr, _unpack_addr,
-                        stamp_received)
+                        _BANNER_REPLY, _forget, _pack_addr, _unpack_addr)
 
 _READ = 1       # selectors.EVENT_READ
 _WRITE = 2      # selectors.EVENT_WRITE
@@ -71,6 +69,7 @@ class _Sock:
         self._connecting = connecting
         self._rbuf = bytearray()
         self._rpos = 0
+        self.recvs = 0                          # sock.recv calls so far
         self._plans: list[tuple[int, Callable]] = []
         self._draining = False
         # write queue entries are [list-of-memoryviews, on_done]; the
@@ -125,6 +124,7 @@ class _Sock:
         try:
             while True:
                 chunk = self.sock.recv(_RECV_CHUNK)
+                self.recvs += 1
                 if not chunk:
                     self._fail(ConnectionResetError("peer closed"))
                     return
@@ -486,64 +486,14 @@ def _accept_hs_gen(msgr, sock: _Sock):
 
 
 def _frames_gen(msgr, conn, sock: _Sock, skey, accepted: bool):
-    """The frame read loop — field-for-field the blocking stack's
-    _read_frames: header, body, scatter-read segments, signature
-    check, partition gate, ack handling, dup suppression, decode,
-    injected delay, deliver."""
-    recv_label = b"C" if accepted else b"S"
-    send_label = b"S" if accepted else b"C"
-    hdr_size = Message.header_size()
-    while not conn._closed:
-        hdr = yield ("read", hdr_size)
-        recv_stamp, recv_cpu = time.monotonic(), time.thread_time()
-        type_id, plen, seq, has_segs = Message.parse_header_any(hdr)
-        body = yield ("read", plen)
-        segments: list[bytes] = []
-        if has_segs:
-            seg_lens, payload = Message.parse_seg_table(body)
-            for n in seg_lens:
-                segments.append((yield ("read", n)))
-        else:
-            payload = body
-        nbytes = hdr_size + plen + sum(len(s) for s in segments)
-        msgr.perf.inc("bytes_recv", nbytes)
-        if skey is not None:
-            sig = yield ("read", cephx.SIG_LEN)
-            if not cephx.check_iov(
-                    skey, [recv_label, hdr, body, *segments], sig):
-                msgr.log.warn("bad frame signature from %s, dropping "
-                              "connection", conn.peer_name)
-                raise ConnectionResetError("bad signature")
-        fs = faults.get()
-        if fs.partitioned(conn.peer_name, msgr.name):
-            raise ConnectionResetError("partitioned")
-        conn.last_recv = time.monotonic()
-        if type_id == msgr.ACK_TYPE:
-            conn._handle_ack(seq)
-            continue
-        stamps = (recv_stamp, recv_cpu, time.monotonic(),
-                  time.thread_time(), nbytes)
-        ack = msgr._ack_frame(seq)
-        if skey is not None:
-            ack = ack + cephx.sign(skey, send_label + ack)
-        sock.send_iov([ack])          # fire and forget, like writer.write
-        if seq <= conn.in_seq:
-            continue                  # dup after reconnect
-        conn.in_seq = seq
-        try:
-            msg = Message.decode(type_id, seq, payload, segments)
-        except ValueError:
-            msgr.log.error("undecodable frame type=%d seq=%d from %s",
-                           type_id, seq, conn.peer_name)
-            continue
-        stamp_received(msg, stamps)
-        d = fs.recv_delay(
-            conn.peer_name, msgr.name,
-            float(msgr.conf.ms_inject_delay_probability),
-            float(msgr.conf.ms_inject_delay_max))
-        if d > 0:
-            yield ("sleep", d)
-        msgr._deliver(conn, msg)
+    """The frame read loop: the blocking stack's own generator
+    (`Messenger._frames`: header, body, scatter-read segments,
+    signature check, partition gate, ack handling, dup suppression,
+    decode, injected delay, deliver), fed by this stack's socket.  The
+    ack goes out fire and forget, like writer.write; a frame's reads
+    are this socket's `recv` calls."""
+    return msgr._frames(conn, lambda ack: sock.send_iov([ack]),
+                        lambda: sock.recvs, skey, accepted)
 
 
 class AsyncConnection:

@@ -54,13 +54,17 @@ _BANNER = struct.Struct("<4sQII")    # magic, nonce, name len, addr-blob len
 _BANNER_REPLY = struct.Struct("<4sQ")  # magic, acceptor's in_seq
 _ADDR = struct.Struct("<HI")         # host length, port
 BANNER_MAGIC = b"CTB2"
-# An accepted stream buffers twice this before it stops reading its
-# socket.  asyncio's 64 KiB default stops and starts the socket (two
-# epoll calls and a task switch) every chunk of a large frame: a 4 MiB
-# reply in 1,024 rope segments kept the client's loop thread at 47% of
-# its time in pause_reading / resume_reading (chip run, PR 28).  Sized
-# to hold a rados-bench object whole.
-STREAM_LIMIT = 4 << 20
+# An accepted connection's receive buffer (`_FrameReader`).  Headers,
+# bodies, signatures and data segments shorter than it are parsed out
+# of it, many frames a socket read where many arrived together; a field
+# at least this long gets a buffer of its own and the socket is read
+# into that.  A frame's first read lands here whatever follows, so a
+# frame that fits arrives in ONE read and is cut out with one copy: a
+# shard of a rados-bench object (4 MiB over k >= 4) fits, the object
+# does not.  At 64 KiB a 512 KiB sub-op frame took two reads and W
+# read 1.4% lower (chip runs, PR 33).  Pages no read has filled are
+# never touched, so an idle connection holds a few KiB of it.
+RECV_BUF = 1 << 20
 
 
 def _pack_addr(addr: "EntityAddr") -> bytes:
@@ -102,15 +106,18 @@ def stamp_received(msg: Message, stamps: tuple) -> None:
     Message::recv_stamp / recv_complete_stamp do (src/msg/Message.h):
     `_recv_stamp` when the header was read, `_recv_complete_stamp`
     when the last segment was read and the signature checked, both on
-    time.monotonic(), with the reading thread's CPU clock at each and
-    the frame's bytes.  Whoever makes a tracked op of the message
-    closes decode and dispatch (osd/daemon.py: `msgr.recv`,
-    `msgr.dispatch`).  Underscore attrs never ride the wire, so a
-    forwarded message does not carry them on.  The loop thread serves
-    every connection of its messenger, so the CPU between the two
-    stamps includes other frames it read meanwhile."""
+    time.monotonic(), with the reading thread's CPU clock at each, the
+    frame's bytes and the socket reads that fed it between the two (a
+    read that fed three small frames counts for each; a frame that
+    arrived whole inside an earlier frame's read counts 1).  Whoever
+    makes a tracked op of the message closes decode and dispatch
+    (osd/daemon.py: `msgr.recv`, `msgr.dispatch`).  Underscore attrs
+    never ride the wire, so a forwarded message does not carry them
+    on.  The loop thread serves every connection of its messenger, so
+    the CPU between the two stamps includes other frames it read
+    meanwhile."""
     (msg._recv_stamp, msg._recv_cpu, msg._recv_complete_stamp,
-     msg._recv_complete_cpu, msg._recv_bytes) = stamps
+     msg._recv_complete_cpu, msg._recv_bytes, msg._recv_reads) = stamps
 
 
 def _forget(conn) -> None:
@@ -422,7 +429,7 @@ class Messenger:
     async def _bind_server(self) -> None:
         host, port = self.addr
         self._server = await asyncio.start_server(
-            self._accept, host, port, limit=STREAM_LIMIT)
+            self._accept, host, port)
         if port == 0:     # ephemeral: learn the real port
             sock = self._server.sockets[0]
             self.addr = (host, sock.getsockname()[1])
@@ -600,7 +607,7 @@ class Messenger:
             # either side failing tears the socket down and, for
             # lossless links, triggers reconnect + resend of unacked
             reader_t = self._loop.create_task(
-                self._read_frames(conn, reader, writer, skey))
+                self._read_acks(conn, reader, writer, skey))
             drain_t = self._loop.create_task(
                 self._drain_queue(conn, writer, skey))
             done, pending = await asyncio.wait(
@@ -765,8 +772,8 @@ class Messenger:
             return
         self.perf.inc("accepts")
         try:
-            await self._read_frames(conn, reader, writer, skey,
-                                    accepted=True)
+            await _FrameReader(self, conn, writer.transport,
+                               skey).serve(reader)
         except Exception as e:
             # an unexpected error must not ABANDON the socket: leaving
             # it open-but-unread lets the peer write into a black hole
@@ -786,11 +793,17 @@ class Messenger:
         from .message import _HDR, MAGIC
         return _HDR.pack(MAGIC, self.ACK_TYPE, 0, seq)
 
-    async def _read_frames(self, conn: Connection,
-                           reader: asyncio.StreamReader,
-                           writer: asyncio.StreamWriter | None,
-                           skey: bytes | None = None,
-                           accepted: bool = False) -> None:
+    def _frames(self, conn: Connection, write: Callable,
+                reads: Callable[[], int], skey: bytes | None,
+                accepted: bool):
+        """The frame loop of one socket, as a generator over what it
+        waits for: it yields ("read", n) and is sent a field of n
+        bytes, yields ("sleep", d) where a delivery is to be delayed.
+        Whoever drives it owns the bytes' way from the socket
+        (`_FrameReader` on an accepted socket, `_read_acks` on a
+        dialed one, `async_conn._frames_gen` on the event-loop
+        stack's); `write` takes an ack, `reads()` is the driver's
+        count of socket reads so far."""
         # Signatures are DIRECTION-BOUND: the connector signs under
         # "C", the acceptor under "S" — without the label a MITM could
         # reflect a side's own signed frame back at it and it would
@@ -798,80 +811,99 @@ class Messenger:
         recv_label = b"C" if accepted else b"S"
         send_label = b"S" if accepted else b"C"
         hdr_size = Message.header_size()
-        try:
-            while not conn._closed:
-                hdr = await reader.readexactly(hdr_size)
-                recv_stamp, recv_cpu = time.monotonic(), time.thread_time()
-                type_id, plen, seq, has_segs = \
-                    Message.parse_header_any(hdr)
-                body = await reader.readexactly(plen)
-                segments: list[bytes] = []
-                if has_segs:
-                    # CTM2: the body is <seg table><denc payload>; the
-                    # data segments follow and scatter-read one by one
-                    # (never joined with the payload)
-                    seg_lens, payload = Message.parse_seg_table(body)
-                    for n in seg_lens:
-                        segments.append(await reader.readexactly(n))
-                else:
-                    payload = body
-                nbytes = hdr_size + plen + sum(len(s) for s in segments)
-                self.perf.inc("bytes_recv", nbytes)
+        while not conn._closed:
+            hdr = yield ("read", hdr_size)
+            recv_stamp, recv_cpu = time.monotonic(), time.thread_time()
+            # the read that brought the header is the frame's first
+            reads0 = reads() - 1
+            type_id, plen, seq, has_segs = Message.parse_header_any(hdr)
+            body = yield ("read", plen)
+            segments: list = []
+            if has_segs:
+                # CTM2: the body is <seg table><denc payload>; the
+                # data segments follow and scatter-read one by one
+                # (never joined with the payload)
+                seg_lens, payload = Message.parse_seg_table(body)
+                for n in seg_lens:
+                    segments.append((yield ("read", n)))
+            else:
+                payload = body
+            nbytes = hdr_size + plen + sum(len(s) for s in segments)
+            self.perf.inc("bytes_recv", nbytes)
+            if skey is not None:
+                sig = yield ("read", cephx.SIG_LEN)
+                if not cephx.check_iov(
+                        skey, [recv_label, hdr, body, *segments], sig):
+                    self.log.warn("bad frame signature from %s, "
+                                  "dropping connection", conn.peer_name)
+                    raise ConnectionResetError("bad signature")
+            fs = faults.get()
+            if fs.partitioned(conn.peer_name, self.name):
+                # a partition installed mid-connection must stop
+                # delivery too — and BEFORE the ack/in_seq
+                # bookkeeping, so the frame is not acknowledged as
+                # delivered and a lossless peer resends it after
+                # the heal
+                raise ConnectionResetError("partitioned")
+            conn.last_recv = time.monotonic()
+            if type_id == self.ACK_TYPE:
+                conn._handle_ack(seq)
+                continue
+            stamps = (recv_stamp, recv_cpu, time.monotonic(),
+                      time.thread_time(), nbytes, reads() - reads0)
+            try:
+                ack = self._ack_frame(seq)
                 if skey is not None:
-                    sig = await reader.readexactly(cephx.SIG_LEN)
-                    if not cephx.check_iov(
-                            skey, [recv_label, hdr, body, *segments],
-                            sig):
-                        self.log.warn("bad frame signature from %s, "
-                                      "dropping connection",
-                                      conn.peer_name)
-                        raise ConnectionResetError("bad signature")
-                fs = faults.get()
-                if fs.partitioned(conn.peer_name, self.name):
-                    # a partition installed mid-connection must stop
-                    # delivery too — and BEFORE the ack/in_seq
-                    # bookkeeping, so the frame is not acknowledged as
-                    # delivered and a lossless peer resends it after
-                    # the heal
-                    raise ConnectionResetError("partitioned")
-                conn.last_recv = time.monotonic()
-                if type_id == self.ACK_TYPE:
-                    conn._handle_ack(seq)
-                    continue
-                stamps = (recv_stamp, recv_cpu, time.monotonic(),
-                          time.thread_time(), nbytes)
-                if writer is not None:
-                    try:
-                        ack = self._ack_frame(seq)
-                        if skey is not None:
-                            ack = ack + cephx.sign(skey,
-                                                   send_label + ack)
-                        writer.write(ack)
-                    except (ConnectionError, OSError):
-                        pass
-                if seq <= conn.in_seq:
-                    continue            # dup after reconnect
-                conn.in_seq = seq
-                try:
-                    msg = Message.decode(type_id, seq, payload, segments)
-                except ValueError:
-                    # corrupt/hostile frame: data-only decode failed;
-                    # skip it (resend would fail identically) but keep
-                    # the link and subsequent frames alive
-                    self.log.error(
-                        "undecodable frame type=%d seq=%d from %s",
-                        type_id, seq, conn.peer_name)
-                    continue
-                stamp_received(msg, stamps)
-                d = fs.recv_delay(
-                    conn.peer_name, self.name,
-                    float(self.conf.ms_inject_delay_probability),
-                    float(self.conf.ms_inject_delay_max))
-                if d > 0:
-                    await asyncio.sleep(d)
-                self._deliver(conn, msg)
-        except (asyncio.IncompleteReadError, ConnectionError, OSError):
+                    ack = ack + cephx.sign(skey, send_label + ack)
+                write(ack)
+            except (ConnectionError, OSError):
+                pass
+            if seq <= conn.in_seq:
+                continue            # dup after reconnect
+            conn.in_seq = seq
+            try:
+                msg = Message.decode(type_id, seq, payload, segments)
+            except ValueError:
+                # corrupt/hostile frame: data-only decode failed;
+                # skip it (resend would fail identically) but keep
+                # the link and subsequent frames alive
+                self.log.error(
+                    "undecodable frame type=%d seq=%d from %s",
+                    type_id, seq, conn.peer_name)
+                continue
+            stamp_received(msg, stamps)
+            d = fs.recv_delay(
+                conn.peer_name, self.name,
+                float(self.conf.ms_inject_delay_probability),
+                float(self.conf.ms_inject_delay_max))
+            if d > 0:
+                yield ("sleep", d)
+            self._deliver(conn, msg)
+
+    async def _read_acks(self, conn: Connection,
+                         reader: asyncio.StreamReader,
+                         writer: asyncio.StreamWriter,
+                         skey: bytes | None = None) -> None:
+        """The frame loop of a socket this side dialed.  The peer
+        answers there with acks alone (its own messages come over a
+        socket IT dials), so the handshake's StreamReader goes on
+        serving it, which also keeps `writer.drain()` working for the
+        frames this side sends."""
+        frames = self._frames(conn, writer.write, lambda: 1, skey,
+                              accepted=False)
+        try:
+            want, arg = frames.send(None)
+            while True:
+                if want == "read":
+                    got = await reader.readexactly(arg)
+                else:
+                    got = await asyncio.sleep(arg)
+                want, arg = frames.send(got)
+        except (StopIteration, asyncio.IncompleteReadError,
+                ConnectionError, OSError):
             pass
+        finally:
+            frames.close()
 
     def _deliver(self, conn: Connection, msg: Message) -> None:
         self.perf.inc("msg_recv")
@@ -891,6 +923,174 @@ class Messenger:
                 self.log.error("dispatch of %r failed", msg)
                 return
         self.log.warn("unhandled message %r from %s", msg, conn.peer_name)
+
+
+class _FrameReader(asyncio.BufferedProtocol):
+    """An accepted socket's bytes on their way to `Messenger._frames`,
+    from the banner reply on.  The loop asks `get_buffer` where to
+    `recv_into`: the unfilled rest of the field under way where that
+    field has a buffer of its own (one at least RECV_BUF long: asked
+    for ALL that is missing, so one read takes whatever the kernel has
+    queued and no byte is copied again), else the free end of the
+    connection's receive buffer, out of which `_run` cuts the fields
+    the frame loop wants until a field is short.  A frame is complete,
+    acknowledged and delivered inside the `buffer_updated` that
+    brought its last byte: no coroutine is woken between two pieces.
+    A field cut out of the receive buffer is copied once (`bytes`); a
+    field with its own buffer is handed on as that `bytearray`, never
+    written again."""
+
+    def __init__(self, msgr: Messenger, conn: Connection,
+                 transport: asyncio.Transport, skey: bytes | None):
+        self.transport = transport
+        self.reads = 0                  # socket reads so far
+        self.done = msgr._loop.create_future()   # the connection's end
+        self._loop = msgr._loop
+        self._buf = memoryview(bytearray(RECV_BUF))
+        self._r = self._w = 0           # parsed up to, filled up to
+        self._own: bytearray | None = None   # the long field under way
+        self._got = 0                   # ... and how much of it is in
+        self._early = b""               # fed, not yet taken in (held)
+        self._timer = None              # a delayed delivery holds all
+        self._want = 0
+        self._frames = msgr._frames(conn, transport.write,
+                                    lambda: self.reads, skey,
+                                    accepted=True)
+        self._step(None)
+
+    def serve(self, reader: asyncio.StreamReader) -> asyncio.Future:
+        """Take the socket over from the stream the handshake was read
+        from: what that had read past the handshake goes to the frame
+        loop first.  The future ends with the connection."""
+        early, reader._buffer = reader._buffer, bytearray()
+        self.transport.set_protocol(self)
+        self._guarded(self.feed, early)
+        if reader.at_eof():
+            self._finish(None)          # the peer is gone already
+        elif self._timer is None:
+            # the stream pauses its transport at twice its limit
+            self.transport.resume_reading()
+        return self.done
+
+    # -- asyncio's side ------------------------------------------------
+
+    def get_buffer(self, sizehint: int) -> memoryview:
+        if self._own is not None:
+            return memoryview(self._own)[self._got:]
+        return self._buf[self._w:]
+
+    def buffer_updated(self, nbytes: int) -> None:
+        self.reads += 1
+        self._guarded(self._filled, nbytes)
+
+    def connection_lost(self, exc) -> None:
+        self._finish(None)
+
+    # -- bytes in ------------------------------------------------------
+
+    def feed(self, data) -> None:
+        """Bytes no read of this protocol brought, through the same
+        two buffers."""
+        view = memoryview(data)
+        while len(view):
+            if self._timer is not None or self._frames is None:
+                self._early = view
+                return
+            buf = self.get_buffer(-1)
+            n = min(len(buf), len(view))
+            buf[:n] = view[:n]
+            view = view[n:]
+            self._filled(n)
+
+    def _filled(self, n: int) -> None:
+        if self._own is None:
+            self._w += n
+        else:
+            self._got += n
+            if self._got < len(self._own):
+                return
+            field, self._own = self._own, None
+            self._step(field)
+        self._run()
+
+    def _run(self) -> None:
+        """Hand the frame loop the fields it wants for as long as the
+        receive buffer holds them."""
+        buf = self._buf
+        while self._frames is not None and self._timer is None:
+            n, have = self._want, self._w - self._r
+            if n < len(buf):
+                if have < n:
+                    break
+                field = bytes(buf[self._r:self._r + n])
+                self._r += n
+            else:
+                # its own buffer: the head from what is here already,
+                # the rest straight off the socket
+                field = bytearray(n)
+                take = min(have, n)
+                field[:take] = buf[self._r:self._r + take]
+                self._r += take
+                if take < n:
+                    self._own, self._got = field, take
+                    break
+            self._step(field)
+        # what is left is the head of a field shorter than the buffer
+        # (or whole frames behind a held delivery): to the front, so
+        # the next read has the rest of the buffer to fill
+        if self._r:
+            left = self._w - self._r
+            buf[:left] = buf[self._r:self._w]
+            self._r, self._w = 0, left
+
+    # -- the frame loop ------------------------------------------------
+
+    def _step(self, value) -> None:
+        try:
+            want, arg = self._frames.send(value)
+        except StopIteration:
+            self._finish(None)          # the connection was marked down
+            return
+        if want == "read":
+            self._want = arg
+        else:
+            # fault injection: nothing behind this frame is read,
+            # acknowledged or delivered before it is
+            self.transport.pause_reading()
+            self._timer = self._loop.call_later(
+                arg, self._guarded, self._wake)
+
+    def _wake(self) -> None:
+        self._timer = None
+        self._step(None)
+        self._run()
+        early, self._early = self._early, b""
+        self.feed(early)
+        if self._timer is None and self._frames is not None:
+            self.transport.resume_reading()
+
+    def _guarded(self, fn, *args) -> None:
+        try:
+            fn(*args)
+        except (ConnectionError, OSError):
+            self._finish(None)          # bad signature, partition
+        except Exception as e:
+            self._finish(e)
+
+    def _finish(self, exc) -> None:
+        if self._frames is None:
+            return
+        self._frames.close()
+        self._frames = self._own = None
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+        self.transport.close()
+        if not self.done.done():
+            if exc is None:
+                self.done.set_result(None)
+            else:
+                self.done.set_exception(exc)
 
 
 def create_messenger(name: str, conf=None) -> Messenger:

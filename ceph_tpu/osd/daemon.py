@@ -902,7 +902,8 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         """The messenger's part of a tracked op, from the stamps it
         left on the message (msg/messenger.py `stamp_received`):
         `msgr.recv` from header read to the last segment read and the
-        signature checked, `msgr.dispatch` from there to the op's
+        signature checked (args: the frame's bytes and the socket
+        reads that fed it), `msgr.dispatch` from there to the op's
         creation (decode, dispatcher walk).  Both end at or before
         `mstart`, so they lie inside no other span.  A loopback
         message was never on a wire and has no stamps."""
@@ -913,7 +914,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         r1 = msg._recv_complete_stamp
         trk.add_span("msgr.recv", r0, r1,
                      _cpu=max(0.0, msg._recv_complete_cpu - msg._recv_cpu),
-                     bytes=msg._recv_bytes)
+                     bytes=msg._recv_bytes, reads=msg._recv_reads)
         trk.add_span("msgr.dispatch", r1, max(r1, mstart),
                      _cpu=max(0.0, time.thread_time()
                               - msg._recv_complete_cpu))

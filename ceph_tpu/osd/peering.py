@@ -670,6 +670,24 @@ class Peering:
                 # cand"; a mid-backfill shard has holes below its head
                 continue
             lus[osd_id] = tuple(info.get("last_update", ZERO_EV))
+        # A write whose gather is still open is PENDING, not
+        # divergent: its entry is on the shards its sub-op has reached
+        # so far.  A round that runs meanwhile (the re-peer an
+        # unanswered peer schedules on an active pg, a nudge) found
+        # fewer than k holders and rewound it under the gather: the
+        # write was then acked without the primary's shard, and the
+        # next one minted under the SAME version, which the peers that
+        # had applied the first dropped as a duplicate (an acked write
+        # read back EIO; ROADMAP's "rewind family").  So every head
+        # votes with what it holds below the oldest open write.
+        pending = [tuple(s["version"]) for s in self._inflight.values()
+                   if s["waiting"]]
+        if pending:
+            floor = min(pending)
+            settled = max((e["ev"] for e in self.pglog.entries
+                           if e["ev"] < floor), default=self.pglog.tail)
+            lus = {o: lu if lu < floor else settled
+                   for o, lu in lus.items()}
         # peers that did not answer this round (RPC timeout under a
         # busy host, map lag): any of them may hold the newest head
         unknown = sum(1 for i in infos.values() if i.get("unknown"))

@@ -20,8 +20,9 @@ from before ``mstart_ns`` fall back to the shared monotonic clock.
 
 PG scrubs (kinds ``scrub`` and ``scrub_scan``) get a process row of
 their own per daemon (``<daemon> scrub``), apart from its client ops.
-A span's ``cpu`` (thread CPU seconds) and an op's ``attempt`` (the
-client's send count) ride as event args.
+A span's own args (``msgr.recv``'s ``bytes`` and ``reads``, ``wal``'s
+``blocks`` ...), its ``cpu`` (thread CPU seconds) and an op's
+``attempt`` (the client's send count) ride as event args.
 
     python -m ceph_tpu.tools.trace_dump --dump-dir <incident-dir> \
         [--out trace.json]

@@ -164,6 +164,45 @@ class TestDivergentRewind:
             assert ppg._ec_choose_and_rewind(infos) == head
         assert _wait_read(io, "obj") == v2
 
+    def test_open_gather_is_pending_not_divergent(self, cluster):
+        """A round that runs while a write's gather is open (the
+        re-peer of an active pg) finds its entry on the shards the
+        sub-op has reached so far, here the primary's alone: that is
+        a PENDING write, and rewinding it under the gather lost the
+        acked write and minted the next one under the same version.
+        With the gather gone the same heads are divergent."""
+        rados, io = _ec_setup(cluster)
+        v1 = b"v1-acked-by-all" * 300
+        io.write_full("obj", v1)
+        m = cluster.leader().osdmon.osdmap
+        pgid = m.object_to_pg(io.pool_id, "obj")
+        _up, acting = m.pg_to_up_acting_osds(pgid)
+        primary = next(o for o in acting if o >= 0)
+        ppg = cluster.osds[primary].get_pg(pgid)
+        settled = ppg.pglog.head
+        _partial_ec_write(cluster, io, "obj", b"v2-in-flight!!!" * 300,
+                          to_shards=[acting.index(primary)])
+        pending = ppg.pglog.head
+        assert pending > settled
+        infos = {o: {"last_update": settled, "log_tail": ZERO_EV}
+                 for o in acting if o != primary}
+        reqid = ("client.test", 1)
+        with ppg.lock:
+            ppg._inflight[reqid] = {"waiting": {1, 2}, "version": pending}
+            try:
+                assert ppg._ec_choose_and_rewind(infos) == settled
+                assert ppg.pglog.head == pending, "rewound under its gather"
+                # a peer the sub-op has reached votes the same way
+                ahead = dict(infos)
+                ahead[next(iter(ahead))] = {"last_update": pending,
+                                            "log_tail": ZERO_EV}
+                assert ppg._ec_choose_and_rewind(ahead) == settled
+            finally:
+                del ppg._inflight[reqid]
+            assert ppg._ec_choose_and_rewind(infos) == settled
+            assert ppg.pglog.head == settled
+        assert _wait_read(io, "obj") == v1
+
     def test_rewind_restores_stash_content(self, cluster):
         """Unit-ish: rewind_to restores the pre-write shard bytes and
         version index from the stash."""

@@ -293,6 +293,32 @@ class TestMessengerSpans:
                 s["t1"] for s in d["spans"]
                 if s["name"] in ("journal", "wal", "store_apply"))
 
+    def test_recv_carries_the_socket_reads_that_fed_the_frame(
+            self, cluster, written, tmp_path):
+        """ISSUE 33: `reads` beside `bytes`, on the client doc and every
+        sub-op doc; a 1 MiB frame takes fewer reads than the five that a
+        256 KiB piece a read made of it; trace_dump shows both."""
+        import json
+
+        from ceph_tpu.tools import trace_dump
+        client, subs = _write_docs(cluster, written, "m-reads",
+                                   b"r" * (1 << 20))
+        for d in [client] + subs:
+            args = _spans(d, "msgr.recv")[0]["args"]
+            assert args["reads"] >= 1 and args["bytes"] > 0
+        big = _spans(client, "msgr.recv")[0]["args"]
+        assert big["bytes"] > 1 << 20 and big["reads"] <= 4
+        small, _ = _write_docs(cluster, written, "m-reads-small",
+                               b"r" * 100)
+        assert _spans(small, "msgr.recv")[0]["args"]["reads"] == 1
+        daemon = client["daemon"]
+        (tmp_path / f"{daemon}.json").write_text(json.dumps([client]))
+        events = trace_dump.chrome_trace(
+            trace_dump.load_dump_dir(str(tmp_path)))["traceEvents"]
+        (recv,) = [e for e in events if e.get("name") == "msgr.recv"]
+        assert recv["args"]["reads"] == big["reads"]
+        assert recv["args"]["bytes"] == big["bytes"]
+
     def test_ec_read_leaves_sub_read_ops(self, cluster, written):
         from ceph_tpu.ops import hbm_cache
         # a read the HBM cache serves asks no shard: drop the entries
