@@ -190,3 +190,30 @@ class CrushMap:
             Step(STEP_CHOOSE_INDEP, 0, 0),
             Step(STEP_EMIT),
         ], type="erasure", min_size=k, max_size=k + m_))
+
+    def make_locality_rule(self, name: str, k: int, m_: int, group: int,
+                           locality: str, failure_domain: str = "host",
+                           root_name: str = "default") -> int:
+        """indep rule for an EC pool whose chunks come in local groups
+        of `group` positions (ErasureCodeLrc::create_ruleset with
+        ruleset-locality set): as many buckets of type `locality` as
+        there are groups, then `group` leaves in each, one a
+        `failure_domain`, so that positions 0..group-1 share one
+        locality bucket, the next `group` the next."""
+        root = self.bucket_by_name(root_name)
+        if root is None:
+            raise ValueError(f"no bucket named {root_name}")
+        ids = {n: t for t, n in self.types.items()}
+        for type_name in (locality, failure_domain):
+            if type_name not in ids:
+                raise ValueError(f"no bucket type named {type_name}")
+        if group < 1 or (k + m_) % group:
+            raise ValueError(f"{k + m_} chunks are no whole number of "
+                             f"groups of {group}")
+        return self.add_rule(Rule(name, [
+            Step(STEP_SET_CHOOSELEAF_TRIES, 5),
+            Step(STEP_TAKE, root.id),
+            Step(STEP_CHOOSE_INDEP, (k + m_) // group, ids[locality]),
+            Step(STEP_CHOOSELEAF_INDEP, group, ids[failure_domain]),
+            Step(STEP_EMIT),
+        ], type="erasure", min_size=k, max_size=k + m_))

@@ -5,8 +5,11 @@ matrix over GF(2^8) for a named technique, encode as matrix x data, decode
 by inverting the surviving generator rows.  The shec techniques are the
 same with a coding matrix that is not MDS: which chunks decode, and by
 which rows, comes from a plan (`MatrixErasureCode._plan`) and not from
-"any k".  This module holds the technique table, the decode-matrix
-planner + cache, and two compute backends over the same representation:
+"any k".  The lrc technique is a layered code composed to one such
+matrix, planned layer by layer, whose chunks lie at the shard positions
+its mapping string gives.  This module holds the technique table, the
+decode-matrix planner + cache, and two compute backends over the same
+representation:
 
   * NumpyBackend — exact host reference (the correctness oracle, analog
     of the reference's gf-complete scalar path);
@@ -26,8 +29,10 @@ Two chunk representations, matching the reference's two code families
 
 from __future__ import annotations
 
+import itertools
+import json
 import math
-from typing import Mapping, Sequence
+from typing import Mapping, NamedTuple, Sequence
 
 import numpy as np
 
@@ -110,6 +115,131 @@ def _shec(single: bool):
     return build
 
 
+class Layered(NamedTuple):
+    """A layered code as one generator: the (n-k x k) coding matrix the
+    layers compose to, the shard position of every chunk id, and each
+    layer in chunk ids (its chunks, inputs first; how many are inputs;
+    its own systematic generator)."""
+    matrix: np.ndarray
+    mapping: list[int]
+    layers: list[tuple[tuple[int, ...], int, np.ndarray]]
+
+
+def _layer_profile(text: str) -> dict[str, str]:
+    out: dict[str, str] = {}
+    for tok in text.split():
+        if "=" not in tok:
+            raise ErasureCodeError(f"bad layer profile token {tok!r}")
+        key, val = tok.split("=", 1)
+        out[key] = val
+    return out
+
+
+def lrc_layout(profile: Mapping[str, str]) -> tuple[str, list]:
+    """(mapping string, [(layer mapping, layer profile)]) of an lrc
+    profile: its own `mapping` + `layers`, or the k/m/l form, one
+    global layer and (k+m)/l local ones, as the reference generates
+    them (ErasureCodeLrc::parse_kml)."""
+    kml = [ErasureCode.profile_int(profile, x, -1) for x in "kml"]
+    if any(v != -1 for v in kml):
+        if "layers" in profile or "mapping" in profile:
+            raise ErasureCodeError(
+                "layers/mapping cannot be combined with k/m/l")
+        k, m, l = kml
+        if -1 in kml:
+            raise ErasureCodeError("all of k, m, l must be set")
+        if l < 1 or (k + m) % l:
+            raise ErasureCodeError("k + m must be a multiple of l")
+        groups = (k + m) // l
+        if k % groups or m % groups:
+            raise ErasureCodeError("k and m must be multiples of (k+m)/l")
+        kg, mg = k // groups, m // groups
+        mapping = ("D" * kg + "_" * mg + "_") * groups
+        desc = [[("D" * kg + "c" * mg + "_") * groups, ""]]
+        desc += [["_" * (l + 1) * i + "D" * l + "c"
+                  + "_" * (l + 1) * (groups - 1 - i), ""]
+                 for i in range(groups)]
+    else:
+        if "mapping" not in profile or "layers" not in profile:
+            raise ErasureCodeError(
+                "lrc requires mapping + layers (or k/m/l)")
+        mapping = profile["mapping"]
+        try:
+            desc = json.loads(profile["layers"])
+        except json.JSONDecodeError as e:
+            raise ErasureCodeError(f"layers is not valid JSON: {e}") from e
+        if not isinstance(desc, list) or not desc:
+            raise ErasureCodeError("layers must be a non-empty JSON list")
+    layers = []
+    for entry in desc:
+        if not isinstance(entry, list) or len(entry) < 1:
+            raise ErasureCodeError(f"bad layer entry {entry!r}")
+        if len(entry[0]) != len(mapping):
+            raise ErasureCodeError(
+                f"layer mapping {entry[0]!r} length != {len(mapping)}")
+        layers.append((entry[0], _layer_profile(
+            entry[1] if len(entry) > 1 else "")))
+    produced = {i for lmap, _p in layers
+                for i, ch in enumerate(lmap) if ch == "c"}
+    missing = [i for i, ch in enumerate(mapping)
+               if ch != "D" and i not in produced]
+    if missing:
+        raise ErasureCodeError(
+            f"mapping positions {missing} produced by no layer")
+    return mapping, layers
+
+
+def chunk_mapping(mapping: str) -> list[int]:
+    """Shard position of every chunk id (ErasureCode::to_mapping): data
+    chunk i lies at the i-th 'D', the coding chunks at the other
+    positions in order."""
+    return ([i for i, ch in enumerate(mapping) if ch == "D"]
+            + [i for i, ch in enumerate(mapping) if ch != "D"])
+
+
+def _lrc(profile: Mapping[str, str]) -> Layered:
+    """The layers flattened into ONE (n-k x k) coding matrix over
+    GF(2^8): the layered code is linear, so every coding position is a
+    fixed combination of the k data chunks.  Walking the layers in
+    order, each position has its row over the data chunks (a 'D' of
+    the mapping a unit vector); a layer's coding rows are its own
+    matrix times the rows of its inputs, so a local layer over a
+    global parity composes too.  Only byte-matrix layers compose: a
+    packet technique's matrix means an XOR schedule, and a plugin
+    with a decoder of its own keeps the layered host path
+    (erasure/plugin_lrc.py)."""
+    mapping, desc = lrc_layout(profile)
+    pos_of = chunk_mapping(mapping)
+    chunk_at = {p: c for c, p in enumerate(pos_of)}
+    k = mapping.count("D")
+    if not 0 < k < len(mapping):
+        raise ErasureCodeError(
+            f"mapping {mapping!r} needs data and coding positions")
+    rows = {p: np.eye(k, dtype=np.uint8)[c]
+            for c, p in enumerate(pos_of[:k])}
+    layers = []
+    for lmap, lprofile in desc:
+        ins = [i for i, ch in enumerate(lmap) if ch == "D"]
+        outs = [i for i, ch in enumerate(lmap) if ch == "c"]
+        tech = TECHNIQUES.get(lprofile.get("technique", "reed_sol_van"))
+        if lprofile.get("plugin", "jerasure") not in ("jerasure", "tpu") \
+                or tech is None or tech[1:] != (REP_BYTES,) \
+                or set(lprofile) - {"plugin", "technique", "backend"}:
+            raise ErasureCodeError(
+                f"layer {lmap!r} {lprofile} is not a byte matrix")
+        if not outs:
+            continue
+        if any(p not in rows for p in ins):
+            raise ErasureCodeError(
+                f"layer {lmap!r} reads a position no earlier layer wrote")
+        cm = np.asarray(tech[0](len(ins), len(outs), 8, 0), dtype=np.uint8)
+        made = gf.gf_matmul(cm, np.stack([rows[p] for p in ins]))
+        rows.update(zip(outs, made))
+        layers.append((tuple(chunk_at[p] for p in ins + outs), len(ins),
+                       gf.systematic_generator(cm, len(ins))))
+    return Layered(np.stack([rows[p] for p in pos_of[k:]]), pos_of, layers)
+
+
 TECHNIQUES: dict[str, tuple] = {
     "reed_sol_van": (_rs_van, REP_BYTES),
     "reed_sol_r6_op": (_rs_r6, REP_BYTES),
@@ -129,6 +259,10 @@ TECHNIQUES: dict[str, tuple] = {
     # goes by plan
     "shec_multiple": (_shec(False), REP_BYTES, "planned"),
     "shec_single": (_shec(True), REP_BYTES, "planned"),
+    # LRC (ErasureCodeLrc.cc): layers composed to one matrix; the
+    # builder takes the profile (k/m/l, or mapping + layers), the plan
+    # goes layer by layer, and the chunks lie where the mapping says
+    "lrc": (_lrc, REP_BYTES, "planned", "layered"),
 }
 
 # techniques whose natural word size is not 8
@@ -581,14 +715,32 @@ class MatrixErasureCode(ErasureCode):
         # planned techniques: (want, available) -> plan, see _plan
         self.planned = False
         self._plan_cache: dict[tuple[frozenset, frozenset], tuple] = {}
+        # layered techniques: chunk id -> shard position, and the
+        # layers in chunk ids (see Layered)
+        self._mapping: list[int] = []
+        self._layers: list[tuple] = []
         self._fast1 = None
 
     # -- init -------------------------------------------------------------
 
     def init(self, profile: Mapping[str, str]) -> None:
-        self.k = self.profile_int(profile, "k", self.DEFAULT_K)
-        self.m = self.profile_int(profile, "m", self.DEFAULT_M)
         self.technique = profile.get("technique", self.DEFAULT_TECHNIQUE)
+        if self.technique not in self.techniques:
+            raise ErasureCodeError(
+                f"unknown technique {self.technique!r}; "
+                f"have {sorted(self.techniques)}")
+        builder, self.rep, *traits = self.techniques[self.technique]
+        self.planned = "planned" in traits
+        # a layered technique's builder takes the profile, and its
+        # composed generator says what k and m are
+        layered = builder(profile) if "layered" in traits else None
+        self._mapping, self._layers = [], []
+        if layered is not None:
+            self._mapping, self._layers = layered.mapping, layered.layers
+            self.m, self.k = layered.matrix.shape
+        else:
+            self.k = self.profile_int(profile, "k", self.DEFAULT_K)
+            self.m = self.profile_int(profile, "m", self.DEFAULT_M)
         self.w = self.profile_int(
             profile, "w", TECH_DEFAULT_W.get(self.technique,
                                              self.DEFAULT_W))
@@ -598,20 +750,17 @@ class MatrixErasureCode(ErasureCode):
             raise ErasureCodeError(f"invalid k={self.k} m={self.m}")
         if self.k + self.m > 256:
             raise ErasureCodeError("k+m must be <= 256 for w=8")
-        if self.technique not in self.techniques:
-            raise ErasureCodeError(
-                f"unknown technique {self.technique!r}; "
-                f"have {sorted(self.techniques)}")
-        builder, self.rep, *traits = self.techniques[self.technique]
-        self.planned = "planned" in traits
         if self.rep != REP_BITS and self.w != 8:
             raise ErasureCodeError(
                 f"technique {self.technique} supports w=8 only")
-        extra = ((self.profile_int(profile, "c", self.DEFAULT_C),)
-                 if self.planned else ())
-        self.coding_matrix = np.asarray(
-            builder(self.k, self.m, self.w, self.packetsize, *extra),
-            dtype=np.uint8)
+        if layered is not None:
+            self.coding_matrix = layered.matrix
+        else:
+            extra = ((self.profile_int(profile, "c", self.DEFAULT_C),)
+                     if self.planned else ())
+            self.coding_matrix = np.asarray(
+                builder(self.k, self.m, self.w, self.packetsize, *extra),
+                dtype=np.uint8)
         if self.rep == REP_BITS:
             # native GF(2): generator = [identity; coding bits]
             self.generator = None
@@ -623,10 +772,10 @@ class MatrixErasureCode(ErasureCode):
                 self.coding_matrix, self.k)
         self._decode_cache.clear()
         self._plan_cache.clear()
-        # the data chunks each parity covers (what a plan reads)
+        # the data chunks each parity covers (what a shingled plan reads)
         self._support = [frozenset(np.flatnonzero(row).tolist())
                          for row in self.coding_matrix] \
-            if self.planned else []
+            if self.planned and layered is None else []
         self._fast1 = self._build_fast1()
         if isinstance(self.backend, TpuBackend):
             self.backend.serve(self.rep, self.w, self.packetsize)
@@ -668,6 +817,9 @@ class MatrixErasureCode(ErasureCode):
 
     # -- geometry ---------------------------------------------------------
 
+    def get_chunk_mapping(self) -> list[int]:
+        return list(self._mapping)
+
     def get_alignment(self) -> int:
         if self.rep in (REP_PACKETS, REP_BITS):
             # a chunk must hold whole super-blocks of w packets AND be
@@ -707,29 +859,61 @@ class MatrixErasureCode(ErasureCode):
 
     # -- decode -----------------------------------------------------------
 
-    def _note_plan_miss(self) -> None:
+    def _note_plan_miss(self, local: bool = False) -> None:
         """`perf dump`: decode patterns computed (a plan search or a
-        decode matrix), beside `decode_plans`, the patterns cached."""
+        decode matrix), beside `decode_plans`, the patterns cached, and
+        `decode_plans_local`, the plans computed that read fewer than k
+        chunks (a local group, a shingle)."""
         c = self.stat_counters()
         c["decode_plan_misses"] = c.get("decode_plan_misses", 0) + 1
+        c["decode_plans_local"] = c.get("decode_plans_local", 0) \
+            + int(local)
         c["decode_plans"] = len(self._decode_cache) + len(self._plan_cache)
 
     def _plan(self, want: frozenset, avail: frozenset) -> tuple:
-        """A planned (non-MDS) technique's way to `want` from `avail`:
-        (chunks to read, parities used, unknown data chunks, the
+        """A planned (non-MDS) technique's way to `want` from `avail`,
+        cached by pattern: the chunks to read first, then what its
+        search (`_search_shingled`, `_search_layered`) solves them
+        by.  Raises ErasureCodeError when the code cannot."""
+        key = (want, avail)
+        plan = self._plan_cache.get(key)
+        if plan is not None:
+            return plan
+        plan = (self._search_layered if self._layers
+                else self._search_shingled)(want, avail)
+        if plan is None:
+            raise ErasureCodeError(
+                f"cannot decode {sorted(want)} from {sorted(avail)}")
+        if len(self._plan_cache) > 1024:
+            self._plan_cache.clear()
+        # the plan's own chunks decode by the same plan
+        self._plan_cache[key] = self._plan_cache[(want, plan[0])] = plan
+        return plan
+
+    def _plan_span(self, want: frozenset, avail: frozenset):
+        from ..utils import optracker
+        return optracker.span("ec.plan", want=sorted(want),
+                              present=sorted(avail))
+
+    def _note_plan(self, note, fetch: frozenset) -> None:
+        """A search's result on its `ec.plan` span and in the counters:
+        `reads`, the chunks the plan fetches, and `local`, 1 where they
+        are fewer than the k a whole decode reads."""
+        local = len(fetch) < self.k
+        note.update(reads=len(fetch), local=int(local))
+        self._note_plan_miss(local)
+
+    def _search_shingled(self, want: frozenset, avail: frozenset):
+        """(chunks to read, parities used, unknown data chunks, the
         inverse of the parities' rows restricted to the unknowns).
 
         Of the subsets of the available parities, the one that reads
         the fewest chunks among those whose rows, restricted to the
         data chunks they touch that are not available, are square and
         invertible over GF(2^8) (the reference's search for a decoding
-        matrix, ErasureCodeShec.cc shec_make_decoding_matrix).  Raises
-        ErasureCodeError when no subset is: more than c chunks lost,
-        in a pattern the shingles do not cover."""
-        key = (want, avail)
-        plan = self._plan_cache.get(key)
-        if plan is not None:
-            return plan
+        matrix, ErasureCodeShec.cc shec_make_decoding_matrix).  None
+        when no subset is: more than c chunks lost, in a pattern the
+        shingles do not cover."""
         k, cm, support = self.k, self.coding_matrix, self._support
         # data chunks to produce: wanted ones, and what a wanted
         # parity that is not available is computed from
@@ -742,38 +926,75 @@ class MatrixErasureCode(ErasureCode):
         # one equation a parity: fewer parities than unknowns cannot
         # decode, whatever they cover (the cheap refusal a gather asks
         # for after every arrival)
-        if len(parities) >= len(need0 - avail):
-            from ..utils import optracker
-            with optracker.span("ec.plan", want=sorted(want),
-                                present=sorted(avail)):
+        if len(parities) < len(need0 - avail):
+            return None
+        with self._plan_span(want, avail) as note:
+            for mask in range(1 << len(parities)):
+                ps = [p for i, p in enumerate(parities) if mask >> i & 1]
+                need = need0.union(*(support[p] for p in ps))
+                unknowns = sorted(need - avail)
+                if len(unknowns) != len(ps):
+                    continue
+                read = len(need & avail) + len(ps)
+                if best is not None and read >= best_read:
+                    continue
+                inv = np.zeros((0, 0), dtype=np.uint8)
+                if ps:
+                    try:
+                        inv = gf.gf_mat_inv(cm[np.ix_(ps, unknowns)])
+                    except np.linalg.LinAlgError:
+                        continue
+                fetch = (need & avail) | {p + k for p in ps} \
+                    | {p for p in want if p >= k and p in avail}
+                best, best_read = (frozenset(fetch), tuple(ps),
+                                   tuple(unknowns), inv), read
+            if best is not None:
+                self._note_plan(note, best[0])
+            else:
                 self._note_plan_miss()
-                for mask in range(1 << len(parities)):
-                    ps = [p for i, p in enumerate(parities) if mask >> i & 1]
-                    need = need0.union(*(support[p] for p in ps))
-                    unknowns = sorted(need - avail)
-                    if len(unknowns) != len(ps):
-                        continue
-                    read = len(need & avail) + len(ps)
-                    if best is not None and read >= best_read:
-                        continue
-                    inv = np.zeros((0, 0), dtype=np.uint8)
-                    if ps:
-                        try:
-                            inv = gf.gf_mat_inv(cm[np.ix_(ps, unknowns)])
-                        except np.linalg.LinAlgError:
-                            continue
-                    fetch = (need & avail) | {p + k for p in ps} \
-                        | {p for p in want if p >= k and p in avail}
-                    best, best_read = (frozenset(fetch), tuple(ps),
-                                       tuple(unknowns), inv), read
-        if best is None:
-            raise ErasureCodeError(
-                f"cannot decode {sorted(want)} from {sorted(avail)}")
-        if len(self._plan_cache) > 1024:
-            self._plan_cache.clear()
-        # the plan's own chunks decode by the same plan
-        self._plan_cache[key] = self._plan_cache[(want, best[0])] = best
         return best
+
+    def _layer_steps(self, known: set) -> list[tuple]:
+        """What the layers give from the chunks `known`, which grows
+        by it: [(layer, the inputs' worth of known chunks it reads, the
+        chunks it rebuilds)], local layers first and again until none
+        has anything to add (ErasureCodeLrc::decode_chunks walks its
+        layers from the last; a layer decodes when it lacks no more
+        chunks than it has coding chunks)."""
+        steps, grew = [], True
+        while grew:
+            grew = False
+            for li in reversed(range(len(self._layers))):
+                chunks, lk, _gen = self._layers[li]
+                have = [c for c in chunks if c in known]
+                if lk <= len(have) < len(chunks):
+                    lost = tuple(c for c in chunks if c not in known)
+                    steps.append((li, tuple(have[:lk]), lost))
+                    known.update(lost)
+                    grew = True
+        return steps
+
+    def _search_layered(self, want: frozenset, avail: frozenset):
+        """(chunks to read, the layer steps that give the rest): the
+        fewest available chunks from which the layers, one after the
+        other, rebuild what is wanted: for one lost chunk its local
+        group's l, for a read of the data what the global layer needs.
+        None where they cannot, whatever linear algebra could: the
+        code's promise is its layers'."""
+        if not want <= set(avail).union(
+                *(lost for _l, _r, lost in self._layer_steps(set(avail)))):
+            return None         # the cheap refusal of a gather's ask
+        must = want & avail
+        rest = sorted(avail - must)
+        with self._plan_span(want, avail) as note:
+            for n in range(len(rest) + 1):
+                for more in itertools.combinations(rest, n):
+                    known = set(must).union(more)
+                    steps = self._layer_steps(known)
+                    if want <= known:
+                        fetch = must.union(more)
+                        self._note_plan(note, fetch)
+                        return fetch, tuple(steps)
 
     def minimum_to_decode(self, want_to_read, available) -> list[int]:
         if not self.planned:
@@ -787,30 +1008,51 @@ class MatrixErasureCode(ErasureCode):
     def _planned_rows(self, want: Sequence[int],
                       present: Sequence[int]) -> np.ndarray:
         """The plan's solved system as a matrix over `present`: every
-        data chunk the plan touches is a combination of the chunks
-        read (itself, if it was read; for an unknown, the inverse's
-        row applied to each parity minus its known terms), a wanted
-        parity the product of its coding row with those.  Chunks of
-        `present` the plan does not read get zero columns."""
-        k, cm = self.k, self.coding_matrix
-        _fetch, ps, unknowns, inv = self._plan(frozenset(want),
-                                               frozenset(present))
+        chunk the plan touches is a combination of the chunks read
+        (itself, if it was read).  Chunks of `present` the plan does
+        not read get zero columns."""
+        plan = self._plan(frozenset(want), frozenset(present))
         col = {c: i for i, c in enumerate(present)}
         unit = np.eye(len(present), dtype=np.uint8)
+        made = (self._layered_rows if self._layers
+                else self._shingled_rows)(plan, col, unit)
+        return np.stack([unit[col[c]] if c in col else made(c)
+                         for c in want]).astype(np.uint8)
+
+    def _shingled_rows(self, plan: tuple, col: dict, unit: np.ndarray):
+        """An unknown data chunk is the inverse's row applied to each
+        parity minus its known terms; a wanted parity the product of
+        its coding row with the data."""
+        k, cm = self.k, self.coding_matrix
+        _fetch, ps, unknowns, inv = plan
         # row d: data chunk d as a combination of `present` (zero
         # while unknown, and for the chunks the plan does not touch)
-        data = np.zeros((k, len(present)), dtype=np.uint8)
-        for d in present:
+        data = np.zeros((k, len(col)), dtype=np.uint8)
+        for d in col:
             if d < k:
                 data[d] = unit[col[d]]
         if ps:
             rhs = unit[[col[p + k] for p in ps]] \
                 ^ gf.gf_matmul(cm[list(ps)], data)
             data[list(unknowns)] = gf.gf_matmul(inv, rhs)
-        return np.stack([
-            unit[col[c]] if c in col else data[c] if c < k
+        return lambda c: data[c] if c < k \
             else gf.gf_matmul(cm[c - k][None, :], data)[0]
-            for c in want]).astype(np.uint8)
+
+    def _layered_rows(self, plan: tuple, col: dict, unit: np.ndarray):
+        """Each step's layer, inverted over the chunks it reads, gives
+        its own inputs as combinations of `present`, and its
+        generator's rows the chunks it rebuilds."""
+        fetch, steps = plan
+        rows = {c: unit[col[c]] for c in fetch}
+        for li, read, lost in steps:
+            chunks, lk, gen = self._layers[li]
+            at = {c: i for i, c in enumerate(chunks)}
+            inputs = gf.gf_matmul(
+                gf.decode_matrix(gen, lk, [at[c] for c in read]),
+                np.stack([rows[c] for c in read]))
+            rows.update(zip(lost, gf.gf_matmul(
+                gen[[at[c] for c in lost]], inputs)))
+        return rows.__getitem__
 
     def _decode_rows(self, want: Sequence[int],
                      present: Sequence[int]) -> np.ndarray:
@@ -822,7 +1064,8 @@ class MatrixErasureCode(ErasureCode):
             return cached
         from ..utils import optracker
         with optracker.span("ec.plan", want=list(want),
-                            present=list(present)):
+                            present=list(present), reads=len(present),
+                            local=int(len(present) < self.k)):
             if self.rep == REP_BITS:
                 out = gf.bitmatrix_decode_rows(
                     self.gen_bits, self.k, self.w, list(want),

@@ -4,25 +4,42 @@ Semantics follow the reference
 (/root/reference/src/erasure-code/lrc/ErasureCodeLrc.cc): a `mapping`
 string assigns every chunk position a role ('D' data, anything else
 coding/pad), and `layers` is a JSON list of [layer_mapping, profile]
-pairs, each layer an independent sub-code run by another plugin over the
-positions its mapping marks 'D' (inputs) and 'c' (outputs).  The
-convenience k/m/l form generates one global layer plus
-(k+m)/l local layers exactly like parse_kml (:280-360), so a local
-failure repairs from l chunks instead of k.
+pairs, each layer an independent sub-code over the positions its
+mapping marks 'D' (inputs) and 'c' (outputs).  The convenience k/m/l
+form generates one global layer plus (k+m)/l local layers exactly like
+parse_kml (:280-360), so a local failure repairs from l chunks instead
+of k.
 
-minimum_to_decode picks, per missing chunk, the cheapest layer that can
-reconstruct it from available chunks (:554).
+Where every layer is a byte matrix the code IS the matrix codec's
+technique `lrc` (erasure/matrix_codec.py: the layers composed to one
+generator, the plan layer by layer, the chunk mapping), and this
+plugin is a factory over it: `plugin=lrc k=4 m=2 l=3` and `plugin=tpu
+technique=lrc k=4 m=2 l=3` give the same bytes at the same positions.
+A layer that does not compose (a packet technique, a sub-plugin with
+a decoder of its own) keeps the layered host code below: one sub-codec
+call a layer, minimum_to_decode the cheapest layer per missing chunk
+(:554).
 """
 
 from __future__ import annotations
 
-import json
 from typing import Mapping
 
 import numpy as np
 
 from .interface import ErasureCode, ErasureCodeError
+from .matrix_codec import (TECHNIQUES, MatrixErasureCode, TpuBackend,
+                           chunk_mapping, lrc_layout)
+from .plugin_jerasure import backend_from_profile
 from .registry import ErasureCodePlugin
+
+
+class ErasureCodeLrcMatrix(MatrixErasureCode):
+    DEFAULT_TECHNIQUE = "lrc"
+
+    def __init__(self, backend=None):
+        super().__init__(backend=backend or TpuBackend(),
+                         techniques={"lrc": TECHNIQUES["lrc"]})
 
 
 class _Layer:
@@ -49,144 +66,22 @@ class ErasureCodeLrc(ErasureCode):
     # -- init --------------------------------------------------------------
 
     def init(self, profile: Mapping[str, str]) -> None:
-        profile = dict(profile)
-        has_kml = any(profile.get(x, "-1") != "-1" for x in ("k", "m", "l"))
-        if has_kml:
-            if "layers" in profile or "mapping" in profile:
-                raise ErasureCodeError(
-                    "layers/mapping cannot be combined with k/m/l")
-            self._generate_kml(profile)
-        if "mapping" not in profile or "layers" not in profile:
-            raise ErasureCodeError("lrc requires mapping + layers (or k/m/l)")
-        self.mapping = profile["mapping"]
-        try:
-            layer_desc = json.loads(profile["layers"])
-        except json.JSONDecodeError as e:
-            raise ErasureCodeError(f"layers is not valid JSON: {e}") from e
-        if not isinstance(layer_desc, list) or not layer_desc:
-            raise ErasureCodeError("layers must be a non-empty JSON list")
-        self.k = sum(1 for ch in self.mapping if ch == "D")
+        self.mapping, layer_desc = lrc_layout(profile)
+        self.k = self.mapping.count("D")
         self.m = len(self.mapping) - self.k
         self.layers = []
-        for entry in layer_desc:
-            if not isinstance(entry, list) or len(entry) < 1:
-                raise ErasureCodeError(f"bad layer entry {entry!r}")
-            lmap = entry[0]
-            lprofile = self._parse_layer_profile(
-                entry[1] if len(entry) > 1 else "")
-            if len(lmap) != len(self.mapping):
-                raise ErasureCodeError(
-                    f"layer mapping {lmap!r} length != {len(self.mapping)}")
+        for lmap, lprofile in layer_desc:
             positions = [i for i, ch in enumerate(lmap) if ch in ("D", "c")]
-            lk = sum(1 for ch in lmap if ch == "D")
-            lm = sum(1 for ch in lmap if ch == "c")
+            lprofile = dict(lprofile)
             lprofile.setdefault("plugin", self.DEFAULT_SUBPLUGIN)
-            # layers are many SMALL codes (locals are single-XOR
-            # rows): the per-matrix device jit warm-up would dwarf the
-            # work, so sub-codecs pin the native host path — which
-            # runs XOR rows at memcpy speed — unless the profile
-            # explicitly asks for a device-routed layer backend
+            # layers are many SMALL codes: sub-codecs pin the native
+            # host path unless the profile explicitly asks for a
+            # device-routed layer backend
             lprofile.setdefault("backend", "host")
-            lprofile["k"] = str(lk)
-            lprofile["m"] = str(lm)
+            lprofile["k"] = str(lmap.count("D"))
+            lprofile["m"] = str(lmap.count("c"))
             sub = self._registry.factory(lprofile.pop("plugin"), lprofile)
             self.layers.append(_Layer(lmap, sub, positions))
-        # sanity: every coding position must be produced by some layer
-        produced = set()
-        for layer in self.layers:
-            produced |= set(layer.coding_positions)
-        missing = [i for i, ch in enumerate(self.mapping)
-                   if ch != "D" and i not in produced]
-        if missing:
-            raise ErasureCodeError(
-                f"mapping positions {missing} produced by no layer")
-        self._compose_matrix()
-
-    def _compose_matrix(self) -> None:
-        """Flatten the layer composition into ONE (m_total x k) coding
-        matrix over GF(2^8): the layered code is linear, so every
-        coding position is a fixed linear combination of the k data
-        chunks.  encode_chunks then runs a single region multiply —
-        one native/device dispatch instead of per-layer fancy-index
-        copies + sub-encodes (which cost more in memcpy than math).
-
-        Composition walks layers in order, tracking for each global
-        position its row vector over the data chunks (D positions are
-        unit vectors; a layer's parity rows are its coding matrix
-        times the rows of its data positions — matrix-matrix over
-        GF(2^8), so locals-over-parity compose correctly too)."""
-        from ..ops import gf
-        data_pos = [i for i, ch in enumerate(self.mapping) if ch == "D"]
-        k = len(data_pos)
-        n = len(self.mapping)
-        rows: dict[int, np.ndarray] = {}
-        for ci, pos in enumerate(data_pos):
-            unit = np.zeros(k, dtype=np.uint8)
-            unit[ci] = 1
-            rows[pos] = unit
-        tbl = gf.mul_table()
-        for layer in self.layers:
-            if not layer.coding_positions:
-                continue
-            cm = getattr(layer.codec, "coding_matrix", None)
-            # only plain GF(2^8) byte-matrix layers compose: a
-            # packetized/bitmatrix technique's coding_matrix has
-            # different region semantics (REP_PACKETS expands to a
-            # GF(2) schedule at apply time) and composing its entries
-            # as byte coefficients would encode garbage
-            rep = getattr(layer.codec, "rep", "bytes")
-            if cm is None or rep != "bytes" or any(
-                    p not in rows for p in layer.data_positions):
-                self._full_matrix = None     # non-byte-matrix layer:
-                return                       # keep the layered path
-            src = np.stack([rows[p] for p in layer.data_positions])
-            # parity rows = cm (lm x lk) x src (lk x k) over GF(2^8)
-            for ri, pos in enumerate(layer.coding_positions):
-                acc = np.zeros(k, dtype=np.uint8)
-                for j in range(src.shape[0]):
-                    acc ^= tbl[cm[ri, j]][src[j]]
-                rows[pos] = acc
-        coding_pos = [i for i, ch in enumerate(self.mapping)
-                      if ch != "D"]
-        self._full_matrix = np.stack([rows[p] for p in coding_pos])
-        # region math rides the same measured router as the matrix
-        # plugins (layer sub-codecs stay host-pinned for repair paths)
-        from .matrix_codec import TpuBackend
-        self._backend = TpuBackend()
-
-    @staticmethod
-    def _parse_layer_profile(text: str) -> dict[str, str]:
-        out: dict[str, str] = {}
-        for tok in text.split():
-            if "=" not in tok:
-                raise ErasureCodeError(f"bad layer profile token {tok!r}")
-            key, val = tok.split("=", 1)
-            out[key] = val
-        return out
-
-    def _generate_kml(self, profile: dict) -> None:
-        k = self.profile_int(profile, "k", -1)
-        m = self.profile_int(profile, "m", -1)
-        l = self.profile_int(profile, "l", -1)
-        if -1 in (k, m, l):
-            raise ErasureCodeError("all of k, m, l must be set")
-        if (k + m) % l:
-            raise ErasureCodeError("k + m must be a multiple of l")
-        groups = (k + m) // l
-        if k % groups or m % groups:
-            raise ErasureCodeError("k and m must be multiples of (k+m)/l")
-        kg, mg = k // groups, m // groups
-        profile["mapping"] = ("D" * kg + "_" * mg + "_") * groups
-        layers = [["".join(("D" * kg + "c" * mg + "_") for _ in range(groups)),
-                   ""]]
-        for i in range(groups):
-            row = ""
-            for j in range(groups):
-                row += ("D" * l + "c") if i == j else "_" * (l + 1)
-            layers.append([row, ""])
-        profile["layers"] = json.dumps(layers)
-        for key in ("k", "m", "l"):
-            profile.pop(key, None)
 
     # -- geometry ----------------------------------------------------------
 
@@ -194,11 +89,7 @@ class ErasureCodeLrc(ErasureCode):
         return len(self.mapping)
 
     def get_chunk_mapping(self) -> list[int]:
-        # data chunk i lives at the i-th 'D' position; coding chunk ids map
-        # to the remaining positions in order
-        data_pos = [i for i, ch in enumerate(self.mapping) if ch == "D"]
-        other_pos = [i for i, ch in enumerate(self.mapping) if ch != "D"]
-        return data_pos + other_pos
+        return chunk_mapping(self.mapping)
 
     def get_alignment(self) -> int:
         return self.k * max(layer.codec.get_alignment() // max(layer.codec.k, 1)
@@ -236,9 +127,6 @@ class ErasureCodeLrc(ErasureCode):
 
     def encode_chunks(self, data_chunks: np.ndarray) -> np.ndarray:
         data_chunks = np.asarray(data_chunks, dtype=np.uint8)
-        if getattr(self, "_full_matrix", None) is not None:
-            return self._backend.apply_bytes(self._full_matrix,
-                                             data_chunks)
         L = data_chunks.shape[1]
         n = self.get_chunk_count()
         buf = np.zeros((n, L), dtype=np.uint8)
@@ -324,7 +212,13 @@ class ErasureCodeLrcPlugin(ErasureCodePlugin):
         self._registry = registry
 
     def factory(self, profile):
-        return ErasureCodeLrc(self._registry)
+        try:
+            TECHNIQUES["lrc"][0](profile)
+        except ErasureCodeError:
+            # a layer that is no byte matrix (or a profile whose fault
+            # the layered init reports)
+            return ErasureCodeLrc(self._registry)
+        return ErasureCodeLrcMatrix(backend=backend_from_profile(profile))
 
 
 def __erasure_code_init__(registry, name):

@@ -9,6 +9,13 @@ Profile keys beyond the standard k/m/w/technique/packetsize:
   c=N                   technique=shec_multiple|shec_single only: the
                         lost chunks the shingled code survives
                         (0 < c <= m <= k; ErasureCodeShec's `c`)
+  l=N                   technique=lrc only: the reference's k/m/l form
+                        (ErasureCodeLrc::parse_kml): (k+m)/l local
+                        groups of l chunks and a local parity each, so
+                        k + m + (k+m)/l chunks an object, at the shard
+                        positions of its mapping (k=4 m=2 l=3:
+                        `DD__DD__`); `mapping` + `layers` in its place
+                        name the layers outright
   batch_stripes=N       coalesce-size hint for the shared device
                         pipeline: at most N stripes fuse into one
                         dispatch for this codec's channels (validated
@@ -56,9 +63,12 @@ class _Done:
         return self._v
 
 
-def _with_rep(fut, rep: str) -> dict | None:
+def _with_rep(fut, rep: str, rows: int) -> dict | None:
+    """The pipeline's phase stamps with what the codec knows of the
+    dispatch: the chunk representation it computed in and the rows of
+    its matrix (parity rows of an encode, rebuilt rows of a decode)."""
     ph = getattr(fut, "trace_phases", None)
-    return None if ph is None else dict(ph, rep=rep)
+    return None if ph is None else dict(ph, rep=rep, rows=rows)
 
 
 class _PipelinedEncode:
@@ -85,7 +95,8 @@ class _PipelinedEncode:
         raw future at resolve; None while unresolved / on the
         self-serve host fallback), with the chunk representation the
         dispatch computed in."""
-        return _with_rep(self._fut, self._codec.rep)
+        return _with_rep(self._fut, self._codec.rep,
+                         len(self._codec.coding_matrix))
 
     def result_parts(self, timeout=None):
         """(stripes, parity, crcs) WITHOUT materializing the joined
@@ -114,18 +125,19 @@ class _PipelinedEncode:
 
 
 class _PipelinedDecode:
-    __slots__ = ("_fut", "_host", "_rep")
+    __slots__ = ("_fut", "_host", "_rep", "_rows")
 
-    def __init__(self, fut, host, rep):
+    def __init__(self, fut, host, rep, rows):
         self._fut = fut
         self._host = host
         self._rep = rep
+        self._rows = rows
 
     @property
     def trace_phases(self) -> dict | None:
         """The pipeline's per-item phase stamps (set at resolve) —
         decode-path op spans (recovery rebuild device time)."""
-        return _with_rep(self._fut, self._rep)
+        return _with_rep(self._fut, self._rep, self._rows)
 
     def result(self, timeout=None):
         if timeout is None:
@@ -399,7 +411,7 @@ class ErasureCodeTpu(MatrixErasureCode):
                                     chunks.shape[2])
         return _PipelinedDecode(
             ec_pipeline.get().submit(chan, chunks, qos=qos),
-            lambda: chan.host_fn(chunks)[0], self.rep)
+            lambda: chan.host_fn(chunks)[0], self.rep, len(rows))
 
     def encode_with_crcs(self, data: np.ndarray):
         """(B, k, L) -> (parity (B, m, L), crcs (B, k+m) uint32), fused.

@@ -397,7 +397,21 @@ class OSDMonitor(PaxosService):
             # the committed map only changes when the inc commits
             import copy
             crush = copy.deepcopy(self.osdmap.crush)
-            rid = crush.make_erasure_rule(f"ec-{name}", k, km - k)
+            if profile.get("ruleset-locality"):
+                # a profile that asks for locality (the k/m/l form's
+                # groups of l+1 positions; without `l` there is no
+                # group to place, and the rule is refused) gets the
+                # reference's rule: each group in one bucket of that type
+                try:
+                    rid = crush.make_locality_rule(
+                        f"ec-{name}", k, km - k,
+                        int(profile.get("l", -1)) + 1,
+                        profile["ruleset-locality"],
+                        profile.get("ruleset-failure-domain", "host"))
+                except ValueError as e:
+                    return -22, f"bad profile: {e}", b""
+            else:
+                rid = crush.make_erasure_rule(f"ec-{name}", k, km - k)
             pool.crush_ruleset = rid
             self._pending().new_crush = denc.dumps(crush)
         else:

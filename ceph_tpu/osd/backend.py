@@ -291,12 +291,13 @@ class PGBackendBase:
                         else:
                             self.osd.send_osd(holder, orig[1])
                     if not state["waiting"] and "failed" not in state:
-                        # never ack a write fewer than k shards hold —
-                        # it would be unreconstructable if the applied
-                        # minority then dies; EAGAIN makes the client
-                        # retry against the re-peered interval
-                        k = self._ec_codec().get_data_chunk_count()
-                        if len(state.get("applied", ())) < k:
+                        # never ack a write its holders cannot decode
+                        # (fewer than k shards, or a set the code's
+                        # plan refuses) — it would be unreconstructable
+                        # if the applied minority then dies; EAGAIN
+                        # makes the client retry against the re-peered
+                        # interval
+                        if not self._ec_decodable(state.get("applied", ())):
                             state["failed"] = -11
                 elif state.get("kind") == "rep":
                     live = set(self.acting_live())

@@ -316,7 +316,9 @@ class ECBackend:
         if tail_len:
             chunks: dict[int, bytes] = {}
             remote: list[tuple[int, int]] = []
-            for i in range(k):
+            # data chunk i of the tail stripe lies at position at[i]
+            at = ecutil.chunk_shards(codec)
+            for i in at[:k]:
                 holder = self.acting[i] if i < len(self.acting) \
                     else ITEM_NONE
                 if holder == self.osd.whoami:
@@ -338,9 +340,8 @@ class ECBackend:
                     if i not in fetched:
                         return False
                     chunks[i] = fetched[i][0]
-            for i in range(k):
-                chunks[i] = chunks[i].ljust(L, b"\0")
-            old_tail = b"".join(chunks[i] for i in range(k))[:tail_len]
+            old_tail = b"".join(chunks[i].ljust(L, b"\0")
+                                for i in at[:k])[:tail_len]
         # -- encode the new tail region as its own stripe batch -----------
         # SUBMIT the tail encode to the shared device pipeline and
         # collect at the last moment: the op thread builds its log
@@ -383,16 +384,17 @@ class ECBackend:
         # Falls back to plain invalidation when the object was not
         # resident (append_through handles it).
         if prior is not None:
-            km = codec.get_chunk_count()
-            tail_rows = [np.frombuffer(tail_shards[c],
+            # the cache keeps chunks, in the codec's order
+            at = ecutil.chunk_shards(codec)
+            tail_rows = [np.frombuffer(tail_shards[p],
                                        dtype=np.uint8).reshape(-1, L)
-                         for c in range(km)]
+                         for p in at]
             hbm_cache.get().append_through(
                 self.cid, oid, tuple(prior), tuple(version), new_size,
                 L, full_before,
                 np.stack(tail_rows[:k], axis=1),
                 np.stack(tail_rows[k:], axis=1),
-                np.asarray(stripe_crcs))
+                np.asarray(stripe_crcs)[:, at])
         else:
             hbm_cache.get().invalidate(self.cid, oid)
         for shard, osd_id in enumerate(self.acting):
@@ -689,6 +691,50 @@ class ECBackend:
             rd = self._ec_read_step(rd, self._ec_read_fetch(rd))
         return rd
 
+    def _ec_repair_read(self, oid: str, lost: list[int],
+                        need_ver: tuple, qos: str | None = None):
+        """The shard files at positions `lost`, rebuilt from the shards
+        the codec's plan reads for THEM (lrc: the l others of a local
+        group; shec: a shingle) and from no others: ({position:
+        bytes}, the object's size).  None where that plan reads as
+        many shards as a read of the object does (the caller's whole
+        read serves as well, and takes the first set that decodes), or
+        a planned source did not answer at `need_ver`."""
+        codec = self._ec_codec()
+        live = [p for p, o in enumerate(self.acting)
+                if o != ITEM_NONE and p not in lost
+                and self.osd.osdmap.is_up(o)]
+        try:
+            plan = ecutil.minimum_shards(codec, live, lost)
+        except ErasureCodeError:
+            return None
+        if len(plan) >= codec.get_data_chunk_count():
+            return None
+        others = set(range(len(self.acting))) - set(plan)
+        rd = self._ec_read_begin(oid, others, need_ver, qos)
+        if not isinstance(rd, _EcRead):
+            return None         # the cache holds it: the caller's path
+        gather = self.osd.ec_fetch_shards(
+            self.pgid, oid, rd.targets, need_ver=need_ver)
+        for shard, (data, hi, ver) in gather.out.items():
+            rd.have[shard] = data
+            rd.vers[shard] = tuple(ver) if ver is not None else None
+            rd.hinfo = rd.hinfo or hi
+        gather.stamp(optracker.current(), chunks=sorted(rd.have))
+        got = {rd.vers.get(p) for p in plan}
+        sinfo = self._ec_sinfo(codec)
+        if rd.hinfo is None or got != {tuple(need_ver)} \
+                or rd.hinfo.get("stripe_unit") != sinfo.chunk_size:
+            return None
+        try:
+            return ecutil.rebuild_shards(
+                codec, sinfo, rd.have, lost, rd.hinfo["size"],
+                qos=qos), rd.hinfo["size"]
+        except Exception as e:
+            self.log.warn("local repair of %s shards %s failed: %s",
+                          oid, lost, e)
+            return None
+
     def _ec_read_begin(self, oid: str, exclude: set | None = None,
                        need_ver: tuple | None = None,
                        qos: str | None = None):
@@ -745,10 +791,8 @@ class ECBackend:
     def _ec_decodable(self, shards) -> bool:
         """Do these shards give the object?  The codec's word: what
         it cannot plan a decode of the data chunks from, it refuses."""
-        codec = self._ec_codec()
         try:
-            codec.minimum_to_decode(
-                range(codec.get_data_chunk_count()), shards)
+            ecutil.minimum_shards(self._ec_codec(), shards)
         except ErasureCodeError:
             return False
         return True
@@ -786,12 +830,13 @@ class ECBackend:
         k = codec.get_data_chunk_count()
         decodable = rd.hinfo is not None and self._ec_decodable(have)
         if decodable:
-            # the decode is handed the chunks it uses and no others:
+            # the decode is handed the shards it uses and no others:
             # the data chunks in hand, and what the codec's plan reads
             # to rebuild the rest
-            lost = [i for i in range(k) if i not in have]
-            used = {i for i in have if i < k}.union(
-                codec.minimum_to_decode(lost, have) if lost else ())
+            data = ecutil.chunk_shards(codec)[:k]
+            lost = [p for p in data if p not in have]
+            used = {p for p in data if p in have}.union(
+                ecutil.minimum_shards(codec, have, lost) if lost else ())
             have = {i: have[i] for i in sorted(used)}
         gather.stamp(optracker.current(), replans=rd.replans,
                      chunks=sorted(have))

@@ -2,8 +2,12 @@
 
 stripe_info_t (/root/reference/src/osd/ECUtil.h:35-85) gives the
 logical<->chunk offset algebra: an object is a sequence of stripes of
-stripe_width = k * chunk_size logical bytes; shard i's file is chunk i
-of every stripe, concatenated.  The reference encodes stripe-by-stripe
+stripe_width = k * chunk_size logical bytes; the shard file at acting
+position p is one chunk of every stripe, concatenated: chunk p, unless
+the codec maps its chunks (`get_chunk_mapping`: lrc lays `DD__DD__`),
+then the chunk whose mapping is p (`shard_chunks`).  Everything here
+that names a shard names a POSITION; chunk ids are the codec's own
+affair.  The reference encodes stripe-by-stripe
 (ECUtil::encode loop, ECUtil.cc:99-138) and chains per-shard CRC32C
 (HashInfo::append, ECUtil.cc:140-154).  Here the whole object's stripes
 form ONE (S, k, L) batch: a single fused device pass yields every
@@ -66,6 +70,32 @@ class StripeInfo:
         return self.stripe_count(logical_size) * self.chunk_size
 
 
+def shard_chunks(codec) -> list[int]:
+    """The chunk id held at each shard position: the inverse of the
+    codec's chunk mapping, the identity for a codec that has none."""
+    mapping = codec.get_chunk_mapping()
+    out = list(range(codec.get_chunk_count()))
+    for chunk, pos in enumerate(mapping):
+        out[pos] = chunk
+    return out
+
+
+def chunk_shards(codec) -> list[int]:
+    """The shard position of each chunk id (the codec's mapping)."""
+    return codec.get_chunk_mapping() or list(range(codec.get_chunk_count()))
+
+
+def minimum_shards(codec, have, want=None) -> list[int]:
+    """The shard positions the codec reads, among those in `have`, to
+    give the shards at positions `want` (default: the data chunks, a
+    client's read).  Raises ErasureCodeError where it cannot."""
+    at, of = chunk_shards(codec), shard_chunks(codec)
+    chunks = (range(codec.get_data_chunk_count()) if want is None
+              else [of[p] for p in want])
+    return sorted(at[c] for c in codec.minimum_to_decode(
+        chunks, [of[p] for p in have]))
+
+
 def fold_shard_crcs(stripe_crcs: np.ndarray, chunk_size: int,
                     upto: int | None = None) -> list[int]:
     """Fold the first `upto` stripes' chunk CRCs (S, km) into one
@@ -101,12 +131,13 @@ class EncodeHandle:
     sub-op messages (out-of-band CTM2 segments) and store applies
     without ever becoming per-shard bytes objects."""
 
-    __slots__ = ("_get", "_get_parts", "_src")
+    __slots__ = ("_get", "_get_parts", "_src", "_at")
 
-    def __init__(self, get, get_parts=None, src=None):
+    def __init__(self, get, get_parts=None, src=None, at=None):
         self._get = get
         self._get_parts = get_parts
         self._src = src             # codec handle: phase stamps source
+        self._at = at               # chunk id -> shard position, or None
 
     def result(self, timeout=None) -> tuple[list[memoryview], np.ndarray]:
         if self._get_parts is not None:
@@ -122,6 +153,14 @@ class EncodeHandle:
             allc, stripe_crcs = self._get(timeout)
             S, km, L = allc.shape
             shards = np.ascontiguousarray(allc.transpose(1, 0, 2))
+        if self._at:
+            # chunk c lies at position at[c]: the files are views, so
+            # only the order they are handed out in changes; the CRC
+            # columns follow their chunks
+            of = np.argsort(self._at)
+            order, stripe_crcs = of.tolist(), np.asarray(stripe_crcs)[:, of]
+        else:
+            order = range(km)
         # op tracing: turn the pipeline's phase stamps (coalesce wait,
         # H2D staging, device compute, D2H — or the host drain) into
         # spans on whatever op this thread is executing; free when
@@ -133,7 +172,7 @@ class EncodeHandle:
         # shard files (audited), rows are views of it
         shards = shards.reshape(km, S * L)
         copyaudit.note("ec.shard_layout", shards.nbytes)
-        return ([memoryview(shards[c]) for c in range(km)],
+        return ([memoryview(shards[c]) for c in order],
                 np.asarray(stripe_crcs))
 
 
@@ -141,8 +180,10 @@ def encode_object_async(codec, sinfo: StripeInfo, payload: bytes,
                         cache=None, qos=None) -> EncodeHandle:
     """Submit a whole-object encode; see EncodeHandle.
 
-    Shard i's file holds chunk i of every stripe (the reference's shard
-    layout); zero-padding of the tail stripe is part of the encoded
+    The shard file at position p holds one chunk of every stripe, the
+    one the codec maps there (the reference's shard layout; files and
+    CRC columns come back in POSITION order); zero-padding of the tail
+    stripe is part of the encoded
     state, as in ErasureCode::encode_prepare.  The raw (S, km) CRC
     matrix lets callers fold both the full-file CRC and the
     full-stripe-prefix CRC an append will chain from.
@@ -167,6 +208,7 @@ def encode_object_async(codec, sinfo: StripeInfo, payload: bytes,
         off += n
     copyaudit.note("ec.stage", plen)
     stripes = buf.reshape(S, sinfo.k, L)
+    at = codec.get_chunk_mapping() or None
     if hasattr(codec, "encode_stripes_with_crcs_async"):
         try:
             handle = codec.encode_stripes_with_crcs_async(
@@ -175,9 +217,9 @@ def encode_object_async(codec, sinfo: StripeInfo, payload: bytes,
             handle = codec.encode_stripes_with_crcs_async(stripes)
         parts = getattr(handle, "result_parts", None)
         return EncodeHandle(lambda t: handle.result(t),
-                            get_parts=parts, src=handle)
+                            get_parts=parts, src=handle, at=at)
     out = codec.encode_stripes_with_crcs(stripes)
-    return EncodeHandle(lambda t: out)
+    return EncodeHandle(lambda t: out, at=at)
 
 
 def encode_object_ex(codec, sinfo: StripeInfo, payload: bytes,
@@ -195,10 +237,96 @@ def encode_object(codec, sinfo: StripeInfo,
     return shards, fold_shard_crcs(stripe_crcs, sinfo.chunk_size)
 
 
+def _shard_arrays(codec, sinfo: StripeInfo, shards: dict[int, bytes],
+                  logical_size: int) -> tuple[dict[int, np.ndarray], int]:
+    """({chunk id: its (S, L) rows} of the whole shard files among
+    `shards`, which are keyed by position; S)."""
+    of = shard_chunks(codec)
+    shard_size = sinfo.logical_size_to_shard_size(logical_size)
+    S = shard_size // sinfo.chunk_size
+    return {of[int(p)]: np.frombuffer(as_buffer(s), dtype=np.uint8)
+            .reshape(S, sinfo.chunk_size)
+            for p, s in shards.items() if len(s) == shard_size}, S
+
+
+def _rebuild_chunks(codec, arrs: dict[int, np.ndarray], want: list[int],
+                    S: int, L: int, qos=None) -> None:
+    """Add the chunks `want` to `arrs`, rebuilt from the chunks the
+    codec's plan reads among those in it, in ONE batched device/host
+    pass across all stripes; only the rebuilt chunks materialize
+    (audited ``ec.decode_rebuild``)."""
+    present = codec.minimum_to_decode(want, arrs.keys())
+    if any(p not in arrs for p in present):
+        raise ErasureCodeError(
+            f"need chunks {present}, have {sorted(arrs)}")
+    if hasattr(codec, "decode_batch"):
+        stack = np.stack([arrs[p] for p in present], axis=1)
+        # pipeline-coalesced when available: concurrent rebuilds
+        # with one decode pattern share a device dispatch
+        if hasattr(codec, "decode_batch_async"):
+            try:
+                # `qos` tags the decode lane pick the same way the
+                # encode path tags re-encodes: a rebuild's decode
+                # rides @recovery under the repair cap, not the
+                # client best-effort class
+                handle = codec.decode_batch_async(
+                    want, present, stack, qos=qos)
+            except TypeError:   # non-pipeline codec: no qos kwarg
+                handle = codec.decode_batch_async(
+                    want, present, stack)
+            rebuilt = np.asarray(handle.result())
+            # decode-path phase spans (the PR 12 follow-up): the
+            # rebuild's device window (coalesce/H2D/compute/D2H or
+            # host drain) stamps the current op — a recovery
+            # rebuild's device time shows up under its
+            # recovery_wait breakdown instead of vanishing
+            from ..utils import optracker
+            optracker.note_pipeline_phases(
+                getattr(handle, "trace_phases", None))
+        else:
+            rebuilt = np.asarray(
+                codec.decode_batch(want, present, stack))
+        for idx, c in enumerate(want):
+            # (S, idx, L) slice is strided: the rebuilt chunk is
+            # the decode OUTPUT materializing — the only copy a
+            # degraded read pays, and only for the missing chunks
+            chunk = np.ascontiguousarray(rebuilt[:S, idx])
+            copyaudit.note("ec.decode_rebuild", chunk.nbytes)
+            arrs[c] = chunk
+    else:
+        for s in range(S):
+            out = codec.decode_chunks(
+                want, {p: arrs[p][s] for p in present})
+            for c in want:
+                arrs.setdefault(c, np.empty((S, L), dtype=np.uint8))
+                arrs[c][s] = out[c]
+        for c in want:
+            # same materialization as the batched path above —
+            # the per-read copy floor must not under-report for
+            # codecs without decode_batch
+            copyaudit.note("ec.decode_rebuild", arrs[c].nbytes)
+
+
+def rebuild_shards(codec, sinfo: StripeInfo, shards: dict[int, bytes],
+                   lost: list[int], logical_size: int,
+                   qos=None) -> dict[int, memoryview]:
+    """The shard files at positions `lost`, rebuilt from the shard
+    files in hand (keyed by position) WITHOUT the object in between:
+    the codec's plan for those chunks reads what it needs of them (a
+    local group's l for lrc) and the decode gives the lost chunks,
+    parities included, directly."""
+    of = shard_chunks(codec)
+    arrs, S = _shard_arrays(codec, sinfo, shards, logical_size)
+    _rebuild_chunks(codec, arrs, [of[p] for p in lost], S,
+                    sinfo.chunk_size, qos)
+    return {p: memoryview(arrs[of[p]]).cast("B") for p in lost}
+
+
 def decode_object(codec, sinfo: StripeInfo, shards: dict[int, bytes],
                   logical_size: int, qos=None):
-    """Reassemble logical bytes from >= k shard files as a ZERO-COPY
-    :class:`~ceph_tpu.utils.bufferlist.BufferList`.
+    """Reassemble logical bytes from the shard files in hand (keyed
+    by shard position: k of them, or the fewer a codec's plan reads)
+    as a ZERO-COPY :class:`~ceph_tpu.utils.bufferlist.BufferList`.
 
     Intact data shards contribute per-stripe chunk VIEWS straight over
     the shard buffers (the decode_concat fast path, without the join);
@@ -211,64 +339,10 @@ def decode_object(codec, sinfo: StripeInfo, shards: dict[int, bytes],
     from ..utils.bufferlist import BufferList
     k = codec.get_data_chunk_count()
     L = sinfo.chunk_size
-    shard_size = sinfo.logical_size_to_shard_size(logical_size)
-    usable = {int(i): s for i, s in shards.items() if len(s) == shard_size}
-    S = shard_size // L
-    want = [i for i in range(k) if i not in usable]
-    arrs: dict[int, np.ndarray] = {
-        i: np.frombuffer(as_buffer(s), dtype=np.uint8).reshape(S, L)
-        for i, s in usable.items()}
+    arrs, S = _shard_arrays(codec, sinfo, shards, logical_size)
+    want = [i for i in range(k) if i not in arrs]
     if want:
-        present = codec.minimum_to_decode(want, usable.keys())
-        if any(p not in arrs for p in present):
-            raise ErasureCodeError(
-                f"need chunks {present}, have {sorted(arrs)}")
-        if hasattr(codec, "decode_batch"):
-            stack = np.stack([arrs[p] for p in present], axis=1)
-            # pipeline-coalesced when available: concurrent rebuilds
-            # with one decode pattern share a device dispatch
-            if hasattr(codec, "decode_batch_async"):
-                try:
-                    # `qos` tags the decode lane pick the same way the
-                    # encode path tags re-encodes: a rebuild's decode
-                    # rides @recovery under the repair cap, not the
-                    # client best-effort class
-                    handle = codec.decode_batch_async(
-                        want, present, stack, qos=qos)
-                except TypeError:   # non-pipeline codec: no qos kwarg
-                    handle = codec.decode_batch_async(
-                        want, present, stack)
-                rebuilt = np.asarray(handle.result())
-                # decode-path phase spans (the PR 12 follow-up): the
-                # rebuild's device window (coalesce/H2D/compute/D2H or
-                # host drain) stamps the current op — a recovery
-                # rebuild's device time shows up under its
-                # recovery_wait breakdown instead of vanishing
-                from ..utils import optracker
-                optracker.note_pipeline_phases(
-                    getattr(handle, "trace_phases", None))
-            else:
-                rebuilt = np.asarray(
-                    codec.decode_batch(want, present, stack))
-            for idx, c in enumerate(want):
-                # (S, idx, L) slice is strided: the rebuilt chunk is
-                # the decode OUTPUT materializing — the only copy a
-                # degraded read pays, and only for the missing chunks
-                chunk = np.ascontiguousarray(rebuilt[:S, idx])
-                copyaudit.note("ec.decode_rebuild", chunk.nbytes)
-                arrs[c] = chunk
-        else:
-            for s in range(S):
-                out = codec.decode_chunks(
-                    want, {p: arrs[p][s] for p in present})
-                for c in want:
-                    arrs.setdefault(c, np.empty((S, L), dtype=np.uint8))
-                    arrs[c][s] = out[c]
-            for c in want:
-                # same materialization as the batched path above —
-                # the per-read copy floor must not under-report for
-                # codecs without decode_batch
-                copyaudit.note("ec.decode_rebuild", arrs[c].nbytes)
+        _rebuild_chunks(codec, arrs, want, S, L, qos)
     rope = BufferList()
     remaining = logical_size
     for s in range(S):

@@ -15,6 +15,8 @@ import threading
 import time
 from typing import Callable
 
+import numpy as np
+
 from ..msg import Message
 from ..store.objectstore import StoreError, Transaction
 from ..utils import denc, optracker
@@ -1296,10 +1298,19 @@ class RecoveryService:
         # the rebuild's decode lane bills the same class as its
         # re-encode: both halves of a repair sit under the repair cap
         from .daemon import RECOVERY_QOS_CLASS
+        qos = (RECOVERY_QOS_CLASS if self._qos_recovery is not None
+               else None)
+        # a code with locality first: the lost shards from the few
+        # their own plan reads, not from a read of the whole object
+        local = pg._ec_repair_read(oid, [s for s, _o in missing], need,
+                                   qos)
+        if local is not None:
+            self._ec_push_shards(pg, oid, need, missing, None,
+                                 rebuilt=local)
+            return True
         data = pg._ec_read_local(
             oid, exclude={s for s, _o in missing}, need_ver=need,
-            qos=(RECOVERY_QOS_CLASS
-                 if self._qos_recovery is not None else None))
+            qos=qos)
         if data is None:
             # sources not all at `need` yet (write still fanning out):
             # retry with backoff rather than stranding the stale shard
@@ -1317,7 +1328,8 @@ class RecoveryService:
 
     def _ec_push_shards(self, pg: PG, oid: str, version,
                         missing: list[tuple[int, int]],
-                        data: bytes | None) -> bool:
+                        data: bytes | None,
+                        rebuilt: tuple | None = None) -> bool:
         """Re-encode `data` and land the listed shards (local write or
         MPGPush) — shared by log-driven rebuild and scrub repair.
 
@@ -1327,7 +1339,11 @@ class RecoveryService:
         cached per-stripe chunk CRCs — no re-encode, no H2D.  A
         cache-trusting caller passes data=None (the payload itself
         never crosses the boundary); returns False only then, when
-        the entry vanished before its rows could be fetched."""
+        the entry vanished before its rows could be fetched.
+
+        `rebuilt` ({position: shard file}, object size) lands shard
+        files a local repair decoded directly: there is no object to
+        re-encode, and their CRCs are folded from their own bytes."""
         from ..ops import hbm_cache
         from . import ecutil
         codec = pg._ec_codec()
@@ -1335,18 +1351,27 @@ class RecoveryService:
         payloads: dict[int, bytes] = {}
         stripe_crcs = None
         size = 0
-        ent = hbm_cache.get().lookup(pg.cid, oid,
-                                     version=tuple(version))
-        if ent is not None and ent.chunk_size == sinfo.chunk_size \
+        cols = [shard for shard, _o in missing]
+        ent = None if rebuilt else hbm_cache.get().lookup(
+            pg.cid, oid, version=tuple(version))
+        if rebuilt:
+            from ..ops import crc32c as crc_mod
+            payloads, size = rebuilt
+            stripe_crcs = np.stack([crc_mod.crc32c_batch(np.frombuffer(
+                payloads[c], dtype=np.uint8).reshape(
+                    -1, sinfo.chunk_size)) for c in cols], axis=1)
+        elif ent is not None and ent.chunk_size == sinfo.chunk_size \
                 and (data is None or ent.size == len(data)):
-            for shard, _o in missing:
-                b = ent.shard_bytes(shard)
+            # the entry keeps chunks, in the codec's order
+            of = ecutil.shard_chunks(codec)
+            for shard in cols:
+                b = ent.shard_bytes(of[shard])
                 if b is None:
                     payloads.clear()     # chip buffer gone: re-encode
                     break
                 payloads[shard] = b
             else:
-                stripe_crcs = ent.crcs
+                stripe_crcs = ent.crcs[:, [of[c] for c in cols]]
                 size = ent.size
         if stripe_crcs is None:
             if data is None:
@@ -1361,12 +1386,15 @@ class RecoveryService:
                    else None)
             shards, stripe_crcs = ecutil.encode_object_ex(codec, sinfo,
                                                           data, qos=qos)
-            payloads = {shard: shards[shard] for shard, _o in missing}
+            payloads = {shard: shards[shard] for shard in cols}
+            stripe_crcs = np.asarray(stripe_crcs)[:, cols]
             size = len(data)
-        crcs = ecutil.fold_shard_crcs(stripe_crcs, sinfo.chunk_size)
-        prefix_crcs = ecutil.fold_shard_crcs(
+        # one CRC column a shard to land, and no others folded
+        crcs = dict(zip(cols, ecutil.fold_shard_crcs(
+            stripe_crcs, sinfo.chunk_size)))
+        prefix_crcs = dict(zip(cols, ecutil.fold_shard_crcs(
             stripe_crcs, sinfo.chunk_size,
-            upto=size // sinfo.stripe_width)
+            upto=size // sinfo.stripe_width)))
         with pg.lock:
             cur = pg.pglog.objects.get(oid)
         if cur is None or cur > tuple(version):

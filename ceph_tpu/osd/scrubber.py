@@ -135,8 +135,12 @@ class ScrubService:
                 ent = hbm_cache.get().lookup(pg.cid, base,
                                              version=tuple(cur))
                 if ent is not None:
-                    folds = ecutil.fold_shard_crcs(ent.crcs,
-                                                   ent.chunk_size)
+                    # the entry keeps chunks; a shard file is held to
+                    # the CRC of the chunk its position holds
+                    by_chunk = ecutil.fold_shard_crcs(ent.crcs,
+                                                      ent.chunk_size)
+                    folds = [by_chunk[c] for c in
+                             ecutil.shard_chunks(pg._ec_codec())]
             cached_folds[base] = folds
             return folds
 

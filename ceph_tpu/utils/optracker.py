@@ -185,10 +185,11 @@ def note_pipeline_phases(ph: dict | None) -> None:
     issue = ph.get("issue")
     if issue is not None and c0 is not None and c0 > issue:
         # what the dispatch computed: this submission's stripes, its
-        # share of the padded batch, the chunk representation
+        # share of the padded batch, the chunk representation, the
+        # rows of its matrix
         op.add_span("ec.device_compute", issue, c0,
-                    **{a: ph[a] for a in ("stripes", "padded", "rep")
-                       if a in ph})
+                    **{a: ph[a] for a in ("stripes", "padded", "rep",
+                                          "rows") if a in ph})
     if c0 is not None and c1 is not None and c1 > c0:
         op.add_span("ec.d2h", c0, c1)
     h0, h1 = ph.get("host0"), ph.get("host1")

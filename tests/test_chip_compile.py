@@ -58,6 +58,20 @@ def test_pallas_fused_encode_crc(one_chip, shape):
     assert text.count("tpu_custom_call") >= 3
 
 
+def test_pallas_fused_encode_crc_of_a_composed_layered_code(one_chip):
+    """What TpuBackend._make_fused serves `technique=lrc` k=4 m=2 l=3
+    on a TPU: the byte program with the layers' composed (4 x 4)
+    generator, four parity rows over four data chunks, at one 4 MiB
+    object's 256 stripes (ISSUE 34)."""
+    from ceph_tpu.erasure.registry import registry
+    matrix = registry.factory(
+        "lrc", {"k": "4", "m": "2", "l": "3"}).coding_matrix
+    assert matrix.shape == (4, 4)
+    fn = pallas_ec.make_encode_crc_fn(matrix, 4096, interpret=False)
+    text = _compile(fn, one_chip, (256, 4, 4096)).as_text()
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_packet_encode_crc(one_chip):
     """What TpuBackend._make_fused serves a packet-layout codec on a
     TPU: jerasure cauchy_good k=6 m=3 packetsize=32 at one 4 MiB

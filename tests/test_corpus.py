@@ -42,6 +42,8 @@ CONFIGS = [
              "packetsize": "32"}),
     ("shec", {"k": "5", "m": "3", "c": "2"}),
     ("lrc", {"k": "4", "m": "2", "l": "3"}),
+    # the same code as a technique of the tpu plugin: the same bytes
+    ("tpu", {"technique": "lrc", "k": "4", "m": "2", "l": "3"}),
 ]
 
 

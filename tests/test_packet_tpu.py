@@ -358,7 +358,7 @@ class TestCluster:
         served.n = 0
         oid, (span,) = wait_for(served, "a device-served write")
         assert span["args"] == {"stripes": 5, "padded": 8.0,
-                                "rep": "packets"}
+                                "rep": "packets", "rows": 3}
         names = {s["name"] for d in write_docs(cluster, oid)
                  for s in d["spans"]}
         assert {"ec.stage_h2d", "ec.device_compute", "ec.d2h"} <= names
