@@ -234,6 +234,58 @@ def crc32c_combine(crc_a: int, crc_b: int, len_b: int) -> int:
     return _bits_to_u32((A @ _u32_to_bits(crc_a)) % 2) ^ crc_b
 
 
+ADVANCE_TABLES_CAP = 64     # 4 KiB a table: 256 KiB at most
+
+
+@functools.lru_cache(maxsize=ADVANCE_TABLES_CAP)
+def advance_tables(nbytes: int) -> np.ndarray:
+    """advance_matrix(nbytes) as byte tables, flat (4 * 256,) uint32:
+    A . x = XOR_b T[256 b + byte b of x].  Entry 256 b + v is the XOR of
+    the matrix's columns 8 b + i over the set bits i of v, built by
+    doubling; the integer form of the product, for whole arrays."""
+    cols = np.packbits(advance_matrix(nbytes), axis=0,
+                       bitorder="little").T.copy().view("<u4")[:, 0]
+    T = np.zeros((4, 256), dtype=np.uint32)
+    for b in range(4):
+        for i in range(8):
+            T[b, 1 << i:2 << i] = T[b, :1 << i] ^ cols[8 * b + i]
+    return T.reshape(-1)
+
+
+_BYTE_LANES = np.arange(4, dtype=np.intp) * 256
+
+
+def crc32c_fold(crcs: np.ndarray, nbytes: int) -> np.ndarray:
+    """Chain-combine n CRCs of `nbytes`-long pieces along axis 0, every
+    column at once: (n, cols) -> (cols,) uint32, the value n - 1 calls of
+    crc32c_combine a column give (0 for n = 0, the empty chain).
+
+    The chain is linear over GF(2): XOR_s A^(n-1-s) . c_s with A =
+    advance_matrix(nbytes).  Reduced pairwise, x'[i] = A_w . x[2i] ^
+    x[2i+1] with w doubling: log2(n) steps of one gather and two XORs,
+    and one table a step (advance_tables(nbytes * 2^j), O(log n) of them
+    under a fixed cap).  An odd level takes a zero CRC in front, which
+    changes nothing (A . 0 = 0, and the chain carries no seed).  The
+    arithmetic is XOR on uint32, so it is exact at any n."""
+    x = np.asarray(crcs, dtype="<u4")
+    n, cols = x.shape
+    if n == 0:
+        return np.zeros(cols, dtype=np.uint32)
+    w = nbytes
+    while n > 1:
+        if n & 1:
+            x = np.concatenate([np.zeros((1, cols), dtype=x.dtype), x])
+            n += 1
+        n //= 2
+        x = x.reshape(n, 2, cols)
+        lanes = np.ascontiguousarray(x[:, 0]).view(np.uint8)
+        x = np.bitwise_xor.reduce(
+            advance_tables(w)[lanes.reshape(n, cols, 4) + _BYTE_LANES],
+            axis=-1) ^ x[:, 1]
+        w *= 2
+    return x[0]
+
+
 def crc32c_linear(seed: int, data: bytes) -> int:
     """Reference implementation of the matrix formulation (for tests)."""
     n = len(data)

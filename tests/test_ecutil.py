@@ -173,3 +173,64 @@ class TestDevicePassCounter:
         # PROBE_EVERY calls deliberately re-probes the loser)
         choices = [b.use_device(128 * 1024) for _ in range(5)]
         assert (sum(choices) >= 3) == (faster == "dev")
+
+
+class TestStoredHinfo:
+    """What a shard file's `hinfo` holds is the scalar chain's value,
+    byte for byte: the CRC of the file as it lies in the store and of
+    its full-stripe prefix, after a full write and after an append."""
+
+    @pytest.fixture(scope="class")
+    def cluster(self):
+        from ceph_tpu.vstart import MiniCluster
+        c = MiniCluster(num_mons=1, num_osds=3).start()
+        yield c
+        c.stop()
+
+    @staticmethod
+    def _hinfo_by_bytes(cluster, io, oid, size):
+        from ceph_tpu.osd.pg import HINFO_KEY, shard_oid
+        from ceph_tpu.utils import denc
+        m = cluster.leader().osdmon.osdmap
+        pgid = m.object_to_pg(io.pool_id, oid)
+        _up, acting = m.pg_to_up_acting_osds(pgid)
+        pg = cluster.osds[acting[0]].pgs[pgid]
+        sinfo = pg._ec_sinfo(pg._ec_codec())
+        prefix = size // sinfo.stripe_width * sinfo.chunk_size
+        for shard, osd_id in enumerate(acting):
+            store = cluster.osds[osd_id].store
+            name = shard_oid(oid, shard)
+            data = bytes(store.read(pg.cid, name))
+            assert len(data) == sinfo.logical_size_to_shard_size(size)
+            yield store.getattr(pg.cid, name, HINFO_KEY), denc.dumps(
+                {"size": size, "crc": crc_mod.crc32c(0, data),
+                 "crc_prefix": crc_mod.crc32c(0, data[:prefix]),
+                 "shard": shard, "stripe_unit": sinfo.chunk_size})
+
+    def test_hinfo_bytes_after_write_and_append(self, cluster):
+        import time
+        from ceph_tpu.client import RadosError
+        rados = cluster.client()
+        rados.create_ec_pool("hinfo-ec", "k2m1hi",
+                             {"plugin": "tpu", "k": 2, "m": 1,
+                              "technique": "reed_sol_van"}, pg_num=1)
+        io = rados.open_ioctx("hinfo-ec")
+        rng = np.random.default_rng(36)
+        # 37 full stripes and a partial one; the append crosses two more
+        body = rng.integers(0, 256, 37 * 8192 + 1234, dtype=np.uint8)
+        end = time.time() + 30
+        while True:
+            try:
+                io.write_full("obj", body.tobytes())
+                break
+            except RadosError:
+                if time.time() > end:
+                    raise
+                time.sleep(0.3)
+        for got, want in self._hinfo_by_bytes(cluster, io, "obj", len(body)):
+            assert bytes(got) == want
+        tail = rng.integers(0, 256, 2 * 8192 + 99, dtype=np.uint8)
+        io.append("obj", tail.tobytes())
+        for got, want in self._hinfo_by_bytes(cluster, io, "obj",
+                                              len(body) + len(tail)):
+            assert bytes(got) == want
