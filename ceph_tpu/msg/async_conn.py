@@ -33,13 +33,15 @@ import errno
 import random
 import socket
 import threading
+import time
 from typing import Callable
 
 from ..auth import cephx
 from ..utils import faults
 from .message import Message
 from .messenger import (AuthError, BANNER_MAGIC, Policy, _BANNER,
-                        _BANNER_REPLY, _forget, _pack_addr, _unpack_addr)
+                        _BANNER_REPLY, _forget, _pack_addr, _unpack_addr,
+                        encode_stamped)
 
 _READ = 1       # selectors.EVENT_READ
 _WRITE = 2      # selectors.EVENT_WRITE
@@ -534,14 +536,15 @@ class AsyncConnection:
         # to the owning loop through its wakeup pipe
         if threading.current_thread() is not self.worker:
             self.msgr.perf.inc("event_wakeups")
-        self.worker.call(self._queue_msg, msg)
+        self.worker.call(self._queue_msg, msg, time.monotonic())
 
-    def _queue_msg(self, msg: Message) -> None:
+    def _queue_msg(self, msg: Message, handoff: float) -> None:
         if self._closed:
             return
         msg.src = self.msgr.name
         self.out_seq += 1
-        frame = msg.encode_iov(self.out_seq)
+        frame = encode_stamped(msg, self.out_seq, handoff,
+                               len(self._queue))
         self.msgr.perf.inc("msg_send")
         self.msgr.perf.inc("bytes_send", sum(len(b) for b in frame))
         self._queue.append((self.out_seq, frame))

@@ -101,6 +101,38 @@ class Policy:
         return Policy(lossy=False)
 
 
+# What this process's wall clock read when its time.monotonic() read 0.
+# It rides every frame beside the sender's stamps: a receiver with the
+# same value shares the sender's monotonic clock (the same process) and
+# takes the stamps as they are; any other maps them through the two
+# wall clocks.
+MONO_EPOCH_NS = time.time_ns() - time.monotonic_ns()
+# hand-off and loop-took-it on time.monotonic(), frames queued ahead,
+# the sender's MONO_EPOCH_NS: one short bytes field, which costs an
+# encode and a decode a tenth of what a tuple of four does
+_SENT_STAMP = struct.Struct("<ddqq")
+
+
+def encode_stamped(msg: Message, seq: int, handoff: float,
+                   queued: int) -> list:
+    """The frame of a message a connection's loop thread has just
+    taken, with the sender's stamps in it (both stacks' `_queue_msg`):
+    `handoff`, when the calling thread gave the message to
+    `send_message`, and now, when the loop got round to it, both on
+    time.monotonic(); `queued`, the frames ahead of it in the
+    connection's queue; and this process's clock epoch.  They ride,
+    packed, as the plain field `sent_stamp` of a COPY's payload: the
+    caller's object may be on its way to other connections (pings, map
+    shares), each hand-off with stamps of its own, and `Message.encode*`
+    of an unsent message gives the bytes it always gave.  The frame keeps
+    them through a reconnect's requeue: that is part of its flight."""
+    out = msg.__class__.__new__(msg.__class__)
+    out.__dict__.update(msg.__dict__)
+    out.sent_stamp = _SENT_STAMP.pack(handoff, time.monotonic(), queued,
+                                      MONO_EPOCH_NS)
+    return out.encode_iov(seq)
+
+
 def stamp_received(msg: Message, stamps: tuple) -> None:
     """Leave on a received message when its frame arrived, as
     Message::recv_stamp / recv_complete_stamp do (src/msg/Message.h):
@@ -109,15 +141,33 @@ def stamp_received(msg: Message, stamps: tuple) -> None:
     time.monotonic(), with the reading thread's CPU clock at each, the
     frame's bytes and the socket reads that fed it between the two (a
     read that fed three small frames counts for each; a frame that
-    arrived whole inside an earlier frame's read counts 1).  Whoever
-    makes a tracked op of the message closes decode and dispatch
-    (osd/daemon.py: `msgr.recv`, `msgr.dispatch`).  Underscore attrs
-    never ride the wire, so a forwarded message does not carry them
-    on.  The loop thread serves every connection of its messenger, so
-    the CPU between the two stamps includes other frames it read
-    meanwhile."""
+    arrived whole inside an earlier frame's read counts 1).  In front
+    of them the way there, from the sender's `sent_stamp`
+    (`encode_stamped`), on THIS process's monotonic clock:
+    `_sent_stamp` (hand-off, loop took it, frames queued ahead, and
+    whether a leg came out negative and was clamped to 0: clocks of
+    two processes that disagree).  Whoever makes a tracked op of the
+    message closes decode and dispatch (osd/daemon.py `_note_recv`:
+    `msgr.handoff`, `msgr.wire`, `msgr.recv`, `msgr.dispatch`).
+    Underscore attrs never ride the wire, and `sent_stamp` is taken
+    off the message, so a forwarded message carries neither on.  The
+    loop thread serves every connection of its messenger, so the CPU
+    between the two stamps includes other frames it read meanwhile."""
     (msg._recv_stamp, msg._recv_cpu, msg._recv_complete_stamp,
      msg._recv_complete_cpu, msg._recv_bytes, msg._recv_reads) = stamps
+    sent = msg.__dict__.pop("sent_stamp", None)
+    try:
+        handoff, taken, queued, epoch = _SENT_STAMP.unpack(sent)
+    except (TypeError, struct.error):
+        return      # a peer that does not stamp, or a hostile field
+    if epoch != MONO_EPOCH_NS:
+        shift = (epoch - MONO_EPOCH_NS) / 1e9
+        handoff, taken = handoff + shift, taken + shift
+    skew = not handoff <= taken <= msg._recv_stamp
+    if skew:        # written so that a NaN is clamped too
+        taken = taken if taken <= msg._recv_stamp else msg._recv_stamp
+        handoff = handoff if handoff <= taken else taken
+    msg._sent_stamp = (handoff, taken, queued, skew)
 
 
 def _forget(conn) -> None:
@@ -178,14 +228,15 @@ class Connection:
     # -- sending (thread-safe entry) ---------------------------------------
 
     def send_message(self, msg: Message) -> None:
-        self.msgr._loop_call(self._queue_msg, msg)
+        self.msgr._loop_call(self._queue_msg, msg, time.monotonic())
 
-    def _queue_msg(self, msg: Message) -> None:
+    def _queue_msg(self, msg: Message, handoff: float) -> None:
         if self._closed:
             return
         msg.src = self.msgr.name
         self.out_seq += 1
-        frame = msg.encode_iov(self.out_seq)
+        frame = encode_stamped(msg, self.out_seq, handoff,
+                               len(self._queue))
         self.msgr.perf.inc("msg_send")
         self.msgr.perf.inc("bytes_send", sum(len(b) for b in frame))
         self._queue.append((self.out_seq, frame))

@@ -266,7 +266,7 @@ class ECBackend:
         with optracker.span("msgr.send", frames=1):
             self.osd.send_osd_reply(conn, MOSDECSubOpWriteReply(
                 reqid=msg.reqid, pgid=str(self.pgid), shard=msg.shard,
-                result=result))
+                result=result), msg)
 
     # ---- EC partial-stripe append (ECTransaction.h:201 model) -----------
     #
@@ -963,7 +963,7 @@ class ECBackend:
                         shard=msg.shard, result=-11, data=b"",
                         hinfo=None)
                     reply.rpc_tid = getattr(msg, "rpc_tid", None)
-                    self.osd.send_osd_reply(conn, reply)
+                    self.osd.send_osd_reply(conn, reply, msg)
                     return
                 shard_ver = have
             try:
@@ -992,7 +992,7 @@ class ECBackend:
                 result=result, data=data, hinfo=hinfo,
                 ver=(shard_ver if need_ver is not None else None))
             reply.rpc_tid = getattr(msg, "rpc_tid", None)
-            self.osd.send_osd_reply(conn, reply)
+            self.osd.send_osd_reply(conn, reply, msg)
 
     def _ec_read_park(self, conn, msg, rd: "_EcRead") -> None:
         """Send the step's sub-reads and give the worker back: the

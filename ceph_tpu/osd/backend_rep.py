@@ -93,14 +93,14 @@ class ReplicatedBackend:
         with self.lock:
             if self._already_applied(tuple(msg.log["ev"])):
                 self.osd.send_osd_reply(conn, MOSDRepOpReply(
-                    reqid=msg.reqid, pgid=str(self.pgid), result=0))
+                    reqid=msg.reqid, pgid=str(self.pgid), result=0), msg)
                 return
             if self._superseded(msg.log):
                 # our copy skipped this op (park expired or cap hit):
                 # ack — the primary's gather must complete — but heal
                 self._request_rep_heal(msg.log["oid"], msg)
                 self.osd.send_osd_reply(conn, MOSDRepOpReply(
-                    reqid=msg.reqid, pgid=str(self.pgid), result=0))
+                    reqid=msg.reqid, pgid=str(self.pgid), result=0), msg)
                 return
             if not _parked and self._park_if_gap(conn, msg, "rep"):
                 return            # replied when the gap fills/expires
@@ -115,7 +115,7 @@ class ReplicatedBackend:
             with optracker.span("msgr.send", frames=1):
                 self.osd.send_osd_reply(conn, MOSDRepOpReply(
                     reqid=msg.reqid, pgid=str(self.pgid),
-                    result=result))
+                    result=result), msg)
             if result == 0:
                 self._flush_parked(msg.log["oid"])
 

@@ -704,7 +704,15 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
             return
         self.msgr.send_message(msg, f"osd.{osd_id}", tuple(addr))
 
-    def send_osd_reply(self, conn, msg: Message) -> None:
+    def send_osd_reply(self, conn, msg: Message,
+                       req: Message | None = None) -> None:
+        """`req`: the traced request this answers (a sub-op write or
+        read, a push, a scrub scan).  Its trace id rides the reply, so
+        that the receiver's `reply` op (`_reply_op`) joins the
+        timeline of the op it completes a part of."""
+        trace = getattr(req, "trace", "")
+        if trace:
+            msg.trace = trace
         self.msgr.send_message(msg, conn.peer_name, conn.peer_addr)
 
     def reply_to_client(self, conn, msg: Message) -> None:
@@ -779,12 +787,16 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         # is waiting for).
         if isinstance(msg, (MOSDRepOpReply, MOSDECSubOpWriteReply)):
             pgid = PgId.parse(msg.pgid)
-            self.op_wq.queue(pgid, self._handle_gather_reply, msg)
+            trk = self._reply_op(msg)
+            if trk is not None:
+                trk.span_begin("queue", _t0=getattr(trk, "mstart", None))
+            self.op_wq.queue(pgid, self._run_reply, trk,
+                             self._handle_gather_reply, msg)
             return True
         if isinstance(msg, (MOSDECSubOpReadReply, MPGPushReply)) or (
                 isinstance(msg, MPGInfo) and msg.op in (
                     "info", "scanned", "log", "scanned_range")):
-            self._rpc_reply(msg)
+            self._run_reply(self._reply_op(msg), self._rpc_reply, msg)
             return True
         if isinstance(msg, MOSDOpReply):
             # we are the CLIENT here: a cache-tier promote/flush op we
@@ -897,20 +909,78 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
             return True
         return False
 
+    def _reply_op(self, msg):
+        """The tracked op of a reply that completes part of a tracked
+        op: one that carries the request's `trace` (`send_osd_reply`).
+        Kind `reply`, a doc of its own under that trace id and NOT
+        spans on the op that waits for it, whose `replica_wait`,
+        `gather_wait` or `scrub.peer_wait` would lose to them the self
+        time their metrics read.  An op like any other, as every
+        queued message is an OpRequest in the reference: in flight,
+        historic, slow if it waits past the complaint time.  None for a
+        reply nobody traces."""
+        trace = getattr(msg, "trace", "")
+        if not trace:
+            return None
+        what = type(msg).__name__
+        if isinstance(msg, MPGInfo):
+            what = f"{what}.{msg.op}"
+        shard = getattr(msg, "shard", None)
+        trk = self.op_tracker.create(
+            f"reply({what} {msg.src if shard is None else f's{shard}'}"
+            f" <- {msg.src})", trace_id=str(trace), kind="reply")
+        self._note_recv(trk, msg)
+        return trk
+
+    @staticmethod
+    def _run_reply(trk, handler: Callable, msg) -> None:
+        """A reply's handler under its op (`_reply_op`): `execute`
+        from where its `queue` ended (a write's replies wait on the op
+        shard) or from the op's start (the ones completed inline on
+        the messenger thread).  The op is NOT published as the
+        thread's current one: what the handler goes on to do (the
+        answer to the client, a gather's continuation) belongs to the
+        op that waited.  The doc finishes when the handler returns."""
+        if trk is None:
+            handler(msg)
+            return
+        t_dq = trk.span_end("queue")
+        trk.span_begin("execute", _t0=t_dq if t_dq is not None
+                       else getattr(trk, "mstart", None))
+        try:
+            handler(msg)
+        finally:
+            trk.finish()         # closes `execute`, with its cpu
+
     @staticmethod
     def _note_recv(trk, msg) -> None:
         """The messenger's part of a tracked op, from the stamps it
-        left on the message (msg/messenger.py `stamp_received`):
-        `msgr.recv` from header read to the last segment read and the
-        signature checked (args: the frame's bytes and the socket
-        reads that fed it), `msgr.dispatch` from there to the op's
-        creation (decode, dispatcher walk).  Both end at or before
-        `mstart`, so they lie inside no other span.  A loopback
-        message was never on a wire and has no stamps."""
+        left on the message (msg/messenger.py `stamp_received`): the
+        way there from the sender's two stamps, `msgr.handoff` from
+        the calling thread's hand-off to the sender's loop thread
+        taking the message (the wake-up, the loop's backlog, the wait
+        for the interpreter) and `msgr.wire` from there to the header
+        read here (encode, the wait behind the `queued` frames ahead
+        of it on the connection, sign, the socket write, the kernel,
+        this loop getting to the read; `skew` where the two processes'
+        clocks put a leg below 0 and it was clamped); then `msgr.recv`
+        from header read to the last segment read and the signature
+        checked (args: the frame's bytes and the socket reads that fed
+        it), `msgr.dispatch` from there to the op's creation (decode,
+        dispatcher walk).  Each begins where the one before ended and
+        all end at or before `mstart`, so they lie inside no other
+        span.  A loopback message was never on a wire and has no
+        stamps."""
         r0 = getattr(msg, "_recv_stamp", None)
         mstart = getattr(trk, "mstart", None)
         if r0 is None or mstart is None:
             return
+        sent = getattr(msg, "_sent_stamp", None)
+        if sent is not None:
+            handoff, taken, queued, skew = sent
+            trk.add_span("msgr.handoff", handoff, taken)
+            trk.add_span("msgr.wire", taken, r0, queued=queued,
+                         **({"skew": 1} if skew else {}))
         r1 = msg._recv_complete_stamp
         trk.add_span("msgr.recv", r0, r1,
                      _cpu=max(0.0, msg._recv_complete_cpu - msg._recv_cpu),
@@ -1050,7 +1120,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                     reqid=msg.reqid, pgid=msg.pgid, shard=msg.shard,
                     result=-2, data=b"", hinfo=None)
                 reply.rpc_tid = getattr(msg, "rpc_tid", None)
-                self.send_osd_reply(conn, reply)
+                self.send_osd_reply(conn, reply, msg)
             return
         if isinstance(msg, MOSDOp):
             if getattr(msg, "_trk", None) is not None:
@@ -1352,7 +1422,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                             epoch=self.osdmap.epoch,
                             info=self._scan_pg(pg, msg.deep))
             reply.rpc_tid = getattr(msg, "rpc_tid", None)
-            self.send_osd_reply(conn, reply)
+            self.send_osd_reply(conn, reply, msg)
         elif msg.op == "ec_omap":
             try:
                 omap = self.store.omap_get(pg.cid, shard_oid(msg.oid, 0))

@@ -250,7 +250,7 @@ class RecoveryService:
             reply = MPGPushReply(pgid=msg.pgid, oid=msg.oid,
                                  shard=msg.shard)
             reply.rpc_tid = getattr(msg, "rpc_tid", None)
-            self.send_osd_reply(conn, reply)
+            self.send_osd_reply(conn, reply, msg)
             return
         name = msg.oid if msg.shard is None else shard_oid(msg.oid, msg.shard)
         with pg.lock:
@@ -285,7 +285,7 @@ class RecoveryService:
             pg._wake_recovery_blocked(msg.oid)
         reply = MPGPushReply(pgid=msg.pgid, oid=msg.oid, shard=msg.shard)
         reply.rpc_tid = getattr(msg, "rpc_tid", None)
-        self.send_osd_reply(conn, reply)
+        self.send_osd_reply(conn, reply, msg)
 
     def pg_request_push(self, pgid: PgId, holder: int, oid: str,
                         front: bool = False) -> None:

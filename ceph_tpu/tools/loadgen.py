@@ -315,8 +315,10 @@ class LoadGen:
         # object's recovery pull (the blocked-op span)
         "recovery_wait": "recovery",
         "execute": "execute",
-        # frame receive + dispatch before the op exists, and the
-        # hand-off of sub-op / reply frames to the messenger
+        # the frame's way in before the op exists (the sender's
+        # hand-off, the wire, receive + dispatch), and the hand-off of
+        # sub-op / reply frames to the messenger
+        "msgr.handoff": "messenger", "msgr.wire": "messenger",
         "msgr.recv": "messenger", "msgr.dispatch": "messenger",
         "msgr.send": "messenger",
     }

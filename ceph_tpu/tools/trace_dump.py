@@ -20,6 +20,12 @@ from before ``mstart_ns`` fall back to the shared monotonic clock.
 
 PG scrubs (kinds ``scrub`` and ``scrub_scan``) get a process row of
 their own per daemon (``<daemon> scrub``), apart from its client ops.
+A ``reply`` doc (a sub-op's, a ``sub_read``'s or a scan's answer, an op
+of its own on the daemon that RECEIVED it) is drawn in that daemon's
+row under the trace id of the op it answers, a scan's answer in the
+scrub's row; its ``msgr.handoff`` and ``msgr.wire`` (the sender's
+stamps, on the receiver's clock) are slices in front of its
+``msgr.recv``, as on every doc that came off a wire.
 A span's own args (``msgr.recv``'s ``bytes`` and ``reads``, ``wal``'s
 ``blocks`` ...), its ``cpu`` (thread CPU seconds) and an op's
 ``attempt`` (the client's send count) ride as event args.
@@ -104,8 +110,8 @@ def chrome_trace(daemon_docs: dict[str, object]) -> dict:
         return op["mstart_ns"] / 1e9 - op.get("mstart", 0.0)
 
     def first_stamp(op: dict) -> float:
-        """An op's earliest stamp: its msgr.recv / msgr.dispatch spans
-        lie before its mstart."""
+        """An op's earliest stamp: its msgr.* spans of the way in lie
+        before its mstart."""
         return min([op.get("mstart", 0.0)] + [
             float(sp["t0"]) for sp in op.get("spans", []) if "t0" in sp])
 
@@ -114,8 +120,12 @@ def chrome_trace(daemon_docs: dict[str, object]) -> dict:
     def us(t: float) -> float:
         return round((t - base) * 1e6, 1)
 
+    scrub_traces = {op.get("trace_id") for _d, op in ops
+                    if op.get("kind") in SCRUB_KINDS}
     for daemon, op in ops:
-        if op.get("kind") in SCRUB_KINDS:
+        if op.get("kind") in SCRUB_KINDS or (
+                op.get("kind") == "reply"
+                and op.get("trace_id") in scrub_traces):
             daemon = f"{daemon} scrub"
         if daemon not in pids:
             pids[daemon] = len(pids) + 1

@@ -8,11 +8,23 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
     list of named **spans**: [t0, t1) intervals on the process-wide
     monotonic clock.  What is stamped, and where:
 
+      msgr.handoff, msgr.wire    osd/daemon.py, from the SENDER's two
+                                 stamps, which ride every frame
+                                 (msg/messenger.py `encode_stamped`,
+                                 both stacks): the calling thread's
+                                 hand-off to the sender's loop thread
+                                 taking the message, and from there to
+                                 the header read here (arg queued: the
+                                 frames ahead of it on the connection;
+                                 skew where two processes' clocks put a
+                                 leg below 0 and it was clamped).  On
+                                 every doc that came off a wire
       msgr.recv, msgr.dispatch   osd/daemon.py, from the stamps the
                                  messenger leaves on a received message
                                  (header read, last segment read and
-                                 signature checked); both end at or
-                                 before the op's ``mstart``
+                                 signature checked); these four follow
+                                 each other and end at or before the
+                                 op's ``mstart``
       queue, execute             osd/daemon.py (op-shard deque wait,
                                  dmClock stalls included; the handler)
       msgr.send                  osd/backend_ec.py, osd/backend_rep.py:
@@ -56,7 +68,16 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
     Sub-op writes and reads, scrub scans and recovery pushes carry the
     SAME trace id over the wire (a plain frame field, ``trace``), so
     per-daemon dumps correlate into one cross-daemon timeline
-    (tools/trace_dump.py -> chrome://tracing / Perfetto).  A resent
+    (tools/trace_dump.py -> chrome://tracing / Perfetto).  Their
+    ANSWERS carry it back (osd/daemon.py `send_osd_reply`), and the
+    daemon that receives one makes an op of kind ``reply`` of it,
+    ``reply(<message type> <shard or osd> <- osd.N)``: the four
+    messenger spans, then ``queue`` and ``execute`` (a write's replies
+    wait on the op shard) or one ``execute`` (the replies completed
+    inline on the messenger thread).  A doc of its own, never spans on
+    the op that waits: those would nest in its ``replica_wait``,
+    ``gather_wait`` or ``scrub.peer_wait``.  It shares the daemon's
+    rings with every other op.  A resent
     client op leaves one doc per send under one trace id; the doc's
     ``attempt`` field (the objecter's send count) tells them apart.
   * the spans of :data:`CPU_SPANS` (``execute`` and the ``scrub.*``

@@ -245,7 +245,8 @@ class TestSpanCompleteness:
             inside = []
             for s in op["spans"]:
                 assert s["t1"] >= s["t0"]
-                if s["name"] in ("msgr.recv", "msgr.dispatch"):
+                if s["name"] in ("msgr.handoff", "msgr.wire",
+                                 "msgr.recv", "msgr.dispatch"):
                     # the messenger's part: before the op existed
                     assert s["t1"] <= op["mstart"] + 1e-6
                     continue
@@ -521,6 +522,59 @@ class TestTraceDumpFields:
         assert by["osd_op(c:1 o ['writefull'])"] == rows["osd.1"]
         assert by["pg_scrub(1.0 deep=1)"] == rows["osd.1 scrub"]
         assert by["pg_scan(osd.2 1.0 deep=1)"] == rows["osd.1 scrub"]
+
+    def test_reply_docs_sit_under_the_op_they_answer(self):
+        """ISSUE 38: a `reply` doc in the row of the daemon that
+        received it, under the op's trace id (a scan's answer in the
+        scrub's row), its `msgr.handoff` and `msgr.wire` as slices in
+        front of `msgr.recv`, `queued` as an arg."""
+        from ceph_tpu.tools import trace_dump
+
+        def way_in(doc):
+            m = doc["mstart"]
+            doc["spans"][:0] = [
+                {"name": "msgr.handoff", "t0": m - 0.04, "t1": m - 0.03},
+                {"name": "msgr.wire", "t0": m - 0.03, "t1": m - 0.01,
+                 "args": {"queued": 3}},
+                {"name": "msgr.recv", "t0": m - 0.01, "t1": m - 0.005,
+                 "args": {"bytes": 90, "reads": 1}}]
+            return doc
+        write = self._doc("osd.1", "osd_op(c:1 o ['writefull'])",
+                          "client", 5.0)
+        ack = way_in(self._doc(
+            "osd.1", "reply(MOSDECSubOpWriteReply s1 <- osd.2)", "reply",
+            5.2))
+        scrub = self._doc("osd.1", "pg_scrub(1.0 deep=1)", "scrub", 6.0,
+                          trace_id="scrub:1:1.0:1")
+        scanned = way_in(self._doc(
+            "osd.1", "reply(MPGInfo.scanned osd.2 <- osd.2)", "reply", 6.3,
+            trace_id="scrub:1:1.0:1"))
+        events = trace_dump.chrome_trace(
+            {"osd.1": [write, ack, scrub, scanned]})["traceEvents"]
+        rows = {e["args"]["name"]: e["pid"] for e in events
+                if e["ph"] == "M" and e["name"] == "process_name"}
+        lanes = {(e["pid"], e["tid"]): e["args"]["name"] for e in events
+                 if e["ph"] == "M" and e["name"] == "thread_name"}
+        ops = {e["name"]: e for e in events if e["ph"] == "X"
+               and e.get("cat") != "span"}
+        for op, root, row, lane in (
+                (ack, write, "osd.1", "t:1"),
+                (scanned, scrub, "osd.1 scrub", "scrub:1:1.0:1")):
+            got, first = ops[op["description"]], ops[root["description"]]
+            assert got["cat"] == "reply"
+            assert (got["pid"], got["tid"]) == (first["pid"], first["tid"])
+            assert got["pid"] == rows[row]
+            assert lanes[got["pid"], got["tid"]] == lane
+            mine = [e for e in events if e.get("cat") == "span"
+                    and (e["pid"], e["tid"]) == (got["pid"], got["tid"])
+                    and e["name"].startswith("msgr.")]
+            assert [e["name"] for e in mine] == \
+                ["msgr.handoff", "msgr.wire", "msgr.recv"]
+            hand, wire, recv = mine
+            assert hand["ts"] + hand["dur"] == pytest.approx(wire["ts"])
+            assert wire["ts"] + wire["dur"] == pytest.approx(recv["ts"])
+            assert recv["ts"] + recv["dur"] <= got["ts"]
+            assert wire["args"] == {"queued": 3}
 
     def test_cpu_and_attempt_ride_as_args(self):
         from ceph_tpu.tools import trace_dump

@@ -344,7 +344,7 @@ class TestBackfillTargetDiscipline:
                 (holder, oid, front))
         sent = []
         orig_send = rosd.send_osd_reply
-        rosd.send_osd_reply = lambda conn, msg: sent.append(msg)
+        rosd.send_osd_reply = lambda conn, msg, req=None: sent.append(msg)
         try:
             with rpg.lock:
                 cur = rpg.pglog.objects["bft"]

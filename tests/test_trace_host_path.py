@@ -2,7 +2,9 @@
 peers' scans as tracked ops with `scrub.*` spans, the messenger's
 `msgr.recv` / `msgr.dispatch` / `msgr.send`, `sub_read` ops on the shard
 OSDs, CPU time on spans, the client's send count on op docs, and the
-jitted kernels by name."""
+jitted kernels by name.  ISSUE 38: the sender's stamps on every frame
+(`msgr.handoff`, `msgr.wire`) and a tracked op of kind `reply` for
+every answer to a traced request, on both messenger stacks."""
 
 import time
 
@@ -344,8 +346,8 @@ class TestMessengerSpans:
         # nothing on the primary's read doc but these (PR 28: the
         # parked gather's `gather_wait`, a decode pattern's `ec.plan`)
         assert {s["name"] for s in client["spans"]} <= \
-            {"msgr.recv", "msgr.dispatch", "queue", "execute",
-             "ec.coalesce", "ec.stage_h2d", "ec.device_compute", "ec.d2h",
+            {"msgr.handoff", "msgr.wire", "msgr.recv", "msgr.dispatch",
+             "queue", "execute", "ec.coalesce", "ec.stage_h2d", "ec.device_compute", "ec.d2h",
              "ec.host_encode", "recovery_wait", "gather_wait", "ec.plan"}
         # the read parked for its gather: an `execute` either side
         # of `gather_wait`, neither around a messenger span
@@ -479,6 +481,351 @@ def test_tracker_off_new_call_sites_are_inert(tmp_path):
     c = _boot(tmp_path, osd_enable_op_tracker=False)
     try:
         io = _ec_pool(c, "hp-off", pg_num=1)
+        io.write_full("o", b"z" * 16384)
+        from ceph_tpu.ops import hbm_cache
+        hbm_cache.get().clear()
+        assert io.read("o") == b"z" * 16384
+        (_pgid, _acting, pg), = _primary_pgs(c, io)
+        assert pg.scrub(deep=True)["inconsistent"] == []
+        for osd in c.osds.values():
+            assert osd.op_tracker.dump_historic_ops()["num_ops"] == 0
+            assert osd.op_tracker.num_inflight() == 0
+    finally:
+        c.stop()
+
+
+# ---------------------------------------------------------------------------
+# the way there and the way back (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+STACKS = ["blocking", "async"]
+
+
+@pytest.fixture(scope="module", params=STACKS)
+def stack(request, tmp_path_factory):
+    """(cluster, EC ioctx, replicated ioctx) on one messenger stack."""
+    c = _boot(tmp_path_factory.mktemp(f"legs-{request.param}"),
+              ms_type=request.param)
+    try:
+        io_ec = _ec_pool(c, "legs-ec", pg_num=1)
+        rados = c.client()
+        rados.create_pool("legs-rep", pg_num=1)
+        io_rep = rados.open_ioctx("legs-rep")
+        end = time.time() + 60
+        while True:
+            try:
+                io_rep.write_full("settle", b"s")
+                break
+            except RadosError:
+                if time.time() > end:
+                    raise
+                c.tick(0.3)
+        yield c, io_ec, io_rep
+    finally:
+        c.stop()
+
+
+def _replies(cluster, trace):
+    return [d for d in _docs(cluster, "reply") if d["trace_id"] == trace]
+
+
+def _check_way_in(doc):
+    """The four messenger spans of a doc that came off a wire, each
+    beginning where the one before it ended, all before `mstart`."""
+    (hand,) = _spans(doc, "msgr.handoff")
+    (wire,) = _spans(doc, "msgr.wire")
+    (recv,) = _spans(doc, "msgr.recv")
+    (disp,) = _spans(doc, "msgr.dispatch")
+    assert hand["t0"] <= hand["t1"] == wire["t0"] <= wire["t1"] \
+        == recv["t0"] <= recv["t1"] == disp["t0"] <= disp["t1"] \
+        <= doc["mstart"]
+    assert wire["args"]["queued"] >= 0 and "skew" not in wire["args"]
+    assert "args" not in hand and "cpu" not in hand and "cpu" not in wire
+    names = [s["name"] for s in doc["spans"]]
+    assert names[:4] == ["msgr.handoff", "msgr.wire", "msgr.recv",
+                         "msgr.dispatch"]
+
+
+class TestWaitLegs:
+    @pytest.mark.parametrize("pool", ["ec", "rep"])
+    def test_a_write_leaves_one_reply_doc_a_remote_shard(self, stack, pool):
+        cluster, io_ec, io_rep = stack
+        io = io_ec if pool == "ec" else io_rep
+        client, subs = _write_docs(cluster, io, f"legs-{pool}",
+                                   b"l" * 16384)
+        replies = _wait_docs(
+            lambda: _replies(cluster, client["trace_id"]), 2)
+        assert len(replies) == len(subs) == 2
+        (wait,) = _spans(client, "replica_wait")
+        kind = "MOSDECSubOpWriteReply" if pool == "ec" else "MOSDRepOpReply"
+        assert sorted(d["description"].rsplit("<- ", 1)[1][:-1]
+                      for d in replies) == sorted(d["daemon"] for d in subs)
+        for d in replies:
+            # on the daemon that received it, an op of its own
+            assert d["daemon"] == client["daemon"]
+            assert d["description"].startswith(f"reply({kind} ")
+            assert [s["name"] for s in d["spans"]] == [
+                "msgr.handoff", "msgr.wire", "msgr.recv", "msgr.dispatch",
+                "queue", "execute"]
+            _check_way_in(d)
+            (q,) = _spans(d, "queue")
+            (ex,) = _spans(d, "execute")
+            assert q["t0"] == d["mstart"] and q["t1"] == ex["t0"]
+            assert 0.0 <= ex["cpu"] <= ex["t1"] - ex["t0"] + CLOCK_GRAIN
+            assert ex["t1"] == pytest.approx(d["mstart"] + d["duration"],
+                                             abs=1e-3)
+            # handed over inside the shard's sub-op, after its commit
+            (sub,) = [x for x in subs
+                      if d["description"].endswith(f"<- {x['daemon']})")]
+            (send,) = _spans(sub, "msgr.send")
+            hand = _spans(d, "msgr.handoff")[0]
+            assert send["t0"] <= hand["t0"] <= send["t1"]
+            assert wait["t0"] <= ex["t0"] <= wait["t1"]
+        # the wait closes inside the last answer's handler, and the
+        # waiting op's own spans are what they were
+        last = max(replies, key=lambda d: _spans(d, "execute")[0]["t0"])
+        assert _spans(last, "execute")[0]["t1"] >= wait["t1"]
+        assert not [s for s in client["spans"]
+                    if s["name"] != "replica_wait" and _inside(s, wait)
+                    and s["name"].startswith("msgr.")]
+        for d in [client] + subs:
+            _check_way_in(d)
+
+    def test_an_ec_read_leaves_one_reply_doc_a_sub_read(self, stack):
+        from ceph_tpu.ops import hbm_cache
+        cluster, io_ec, _ = stack
+        io_ec.write_full("legs-read", b"g" * OBJECT_BYTES)
+        hbm_cache.get().clear()
+        assert io_ec.read("legs-read") == b"g" * OBJECT_BYTES
+        client = max((d for d in _docs(cluster, "client")
+                      if " legs-read " in d["description"]
+                      and "'read'" in d["description"]),
+                     key=lambda d: d["mstart"])
+        subs = _wait_docs(
+            lambda: [d for d in _docs(cluster, "subop")
+                     if d["trace_id"] == client["trace_id"]], 1)
+        replies = _wait_docs(
+            lambda: _replies(cluster, client["trace_id"]), len(subs))
+        assert subs and len(replies) == len(subs)
+        (wait,) = _spans(client, "gather_wait")
+        for d in replies:
+            assert d["daemon"] == client["daemon"]
+            assert d["description"].startswith(
+                "reply(MOSDECSubOpReadReply s")
+            # completed inline on the messenger thread: no queue
+            assert [s["name"] for s in d["spans"]] == [
+                "msgr.handoff", "msgr.wire", "msgr.recv", "msgr.dispatch",
+                "execute"]
+            _check_way_in(d)
+            assert _spans(d, "execute")[0]["t0"] == d["mstart"]
+            shard = d["description"].split()[1]
+            (sub,) = [x for x in subs
+                      if d["description"].endswith(f"<- {x['daemon']})")]
+            assert sub["description"].endswith(f" {shard})")
+            _check_way_in(sub)
+        # k=2 of m=1: the gather may complete before the third answer
+        on_time = [d for d in replies
+                   if _spans(d, "execute")[0]["t0"] <= wait["t1"]]
+        assert wait["args"]["used"] <= len(on_time) <= len(replies)
+        last = max(on_time, key=lambda d: _spans(d, "execute")[0]["t0"])
+        ex = _spans(last, "execute")[0]
+        assert ex["t0"] <= wait["t1"] <= ex["t1"]
+        assert _spans(last, "msgr.recv")[0]["args"]["bytes"] \
+            >= OBJECT_BYTES // 2
+
+    def test_a_deep_scrub_leaves_one_reply_doc_a_scan(self, stack):
+        cluster, io_ec, _ = stack
+        (_pgid, acting, pg), = _primary_pgs(cluster, io_ec)
+        assert pg.scrub(deep=True)["inconsistent"] == []
+        doc = max((d for d in _docs(cluster, "scrub")
+                   if d["description"] == f"pg_scrub({pg.pgid} deep=1)"),
+                  key=lambda d: d["mstart"])
+        scans = _wait_docs(
+            lambda: [d for d in _docs(cluster, "scrub_scan")
+                     if d["trace_id"] == doc["trace_id"]], len(acting) - 1)
+        replies = _replies(cluster, doc["trace_id"])
+        assert len(replies) == len(scans) == len(acting) - 1
+        waits = _spans(doc, "scrub.peer_wait")
+        for d in replies:
+            assert d["daemon"] == doc["daemon"]
+            sender = d["description"].rsplit("<- ", 1)[1][:-1]
+            assert d["description"] == \
+                f"reply(MPGInfo.scanned {sender} <- {sender})"
+            _check_way_in(d)
+            (ex,) = _spans(d, "execute")
+            # inside the wait for that peer, and nothing of it ON the
+            # scrub's doc
+            (wait,) = [w for w in waits
+                       if f"osd.{w['args']['osd']}" == sender]
+            assert wait["t0"] <= ex["t0"] <= wait["t1"]
+            (scan,) = [x for x in scans if x["daemon"] == sender]
+            _check_way_in(scan)
+            assert wait["t0"] <= _spans(scan, "msgr.handoff")[0]["t0"]
+        assert not [s for s in doc["spans"] if s["name"].startswith("msgr.")]
+
+    def test_old_self_times_are_what_they_were(self, stack):
+        """The new spans nest in nothing: a doc's self times by name,
+        as the readers compute them, equal those of the same doc with
+        the two new spans taken out."""
+        from benchmark.readers.span_self_time import self_times
+        cluster, io_ec, _ = stack
+        client, subs = _write_docs(cluster, io_ec, "legs-self",
+                                   b"s" * 16384)
+        for d in [client] + subs:
+            assert _spans(d, "msgr.handoff") and _spans(d, "msgr.wire")
+            old = [s for s in d["spans"]
+                   if s["name"] not in ("msgr.handoff", "msgr.wire")]
+            assert len(old) == len(d["spans"]) - 2
+            want = self_times(old)
+            got = [(n, t) for n, t in self_times(d["spans"])
+                   if n not in ("msgr.handoff", "msgr.wire")]
+            assert got == want
+
+
+@pytest.fixture(params=STACKS)
+def pair(request):
+    """Two messengers of one stack on real sockets, and what the second
+    received."""
+    import queue
+
+    from ceph_tpu.msg import Dispatcher, create_messenger
+
+    class Box(Dispatcher):
+        def __init__(self):
+            self.q = queue.Queue()
+
+        def ms_dispatch(self, conn, msg):
+            self.q.put(msg)
+            return True
+
+    made = []
+    for name in ("legs-a", "legs-b"):
+        m = create_messenger(name, Config({"ms_type": request.param}))
+        m.bind(("127.0.0.1", 0))
+        box = Box()
+        m.add_dispatcher_tail(box)
+        m.start()
+        made.append((m, box))
+    yield made
+    for m, _box in made:
+        m.shutdown()
+
+
+class TestSenderStamps:
+    def test_every_hand_off_is_stamped_and_the_object_is_not(self, pair):
+        from ceph_tpu.msg.messenger import MONO_EPOCH_NS
+        from ceph_tpu.osd.messages import MOSDPing
+        (a, abox), (b, bbox) = pair
+        ping = MOSDPing(op="ping", stamp=1.0, epoch=3)
+        ping.src = "legs-a"            # as the send path will name it
+        before = ping.encode(7)
+        t0 = time.monotonic()
+        a.send_message(ping, "legs-b", b.addr)
+        first = bbox.q.get(timeout=5)
+        time.sleep(0.05)
+        t1 = time.monotonic()
+        a.send_message(ping, "legs-b", b.addr)    # the same object again
+        second = bbox.q.get(timeout=5)
+        assert "sent_stamp" not in ping.__dict__
+        assert ping.encode(7) == before
+        for got, lo in ((first, t0), (second, t1)):
+            assert "sent_stamp" not in got.__dict__
+            handoff, taken, queued, skew = got._sent_stamp
+            assert lo <= handoff <= taken <= got._recv_stamp \
+                <= got._recv_complete_stamp
+            assert queued == 0 and skew is False
+            assert (got.op, got.stamp, got.epoch) == ("ping", 1.0, 3)
+        assert second._sent_stamp[0] >= t1 > first._recv_stamp
+        # loopback was never on a wire: no stamps of either side
+        b.send_message(ping, "legs-b", b.addr)
+        own = bbox.q.get(timeout=5)
+        assert not hasattr(own, "_sent_stamp")
+        assert not hasattr(own, "_recv_stamp")
+        assert MONO_EPOCH_NS > 0
+
+    def test_a_requeued_frame_keeps_its_first_stamps(self, pair):
+        """A frame the link lost goes out again after the reconnect
+        with the stamps of its first hand-off: the reconnect is part
+        of its flight, and shows as `msgr.wire`."""
+        from ceph_tpu.osd.messages import MOSDPing
+        from ceph_tpu.utils import faults
+        (a, _abox), (b, bbox) = pair
+        a.send_message(MOSDPing(op="ping", stamp=0.0, epoch=1),
+                       "legs-b", b.addr)
+        bbox.q.get(timeout=5)                     # the session is up
+        fs = faults.get()
+        rules = [fs.drop("legs-b", 1.0, src="legs-a")]
+        try:
+            t0 = time.monotonic()
+            a.send_message(MOSDPing(op="ping", stamp=1.0, epoch=1),
+                           "legs-b", b.addr)
+            end = time.time() + 5
+            while not fs.rules()[-1].hits and time.time() < end:
+                time.sleep(0.01)
+            t_lost = time.monotonic()
+            assert fs.rules()[-1].hits == 1       # written nowhere
+            time.sleep(0.2)
+            fs.clear(rules.pop())
+            rules.append(fs.socket_kill("legs-b", 1, src="legs-a"))
+            a.send_message(MOSDPing(op="ping", stamp=2.0, epoch=1),
+                           "legs-b", b.addr)
+            end = time.time() + 5
+            while not fs.rules()[-1].hits and time.time() < end:
+                time.sleep(0.01)
+            fs.clear(rules.pop())
+            got = [bbox.q.get(timeout=10), bbox.q.get(timeout=10)]
+        finally:
+            for r in rules:
+                fs.clear(r)
+        assert [m.stamp for m in got] == [1.0, 2.0]
+        handoff, taken, queued, skew = got[0]._sent_stamp
+        assert t0 <= handoff <= taken <= t_lost
+        assert got[0]._recv_stamp - taken >= 0.2 and not skew
+        assert a.perf.value("reconnects") >= 1
+
+    def test_clocks_of_two_processes(self):
+        """Another process's stamps come over through the two wall
+        clocks; a leg they put below 0 is clamped and marked."""
+        from ceph_tpu.msg import messenger
+        from ceph_tpu.osd.messages import MOSDPing
+
+        def received(sent_stamp, recv_at):
+            msg = MOSDPing(op="ping")
+            if isinstance(sent_stamp, tuple) and len(sent_stamp) == 4:
+                sent_stamp = messenger._SENT_STAMP.pack(*sent_stamp)
+            msg.sent_stamp = sent_stamp
+            messenger.stamp_received(
+                msg, (recv_at, 0.0, recv_at + 0.001, 0.0, 64, 1))
+            return msg
+        mine = messenger.MONO_EPOCH_NS
+        # a process whose monotonic clock started 100 s after ours
+        far = received((5.0, 5.5, 2, mine + 100 * 10**9), 106.0)
+        assert far._sent_stamp == pytest.approx((105.0, 105.5, 2, False))
+        late = received((5.0, 7.5, 0, mine + 100 * 10**9), 106.0)
+        assert late._sent_stamp == pytest.approx((105.0, 106.0, 0, True))
+        for junk in ("x", (1.0, 2.0), b"short", b"l" * 33, 7):
+            msg = received(junk, 9.0)
+            assert not hasattr(msg, "_sent_stamp")
+            assert "sent_stamp" not in msg.__dict__
+        trk = OpTracker(ManualClock(), history_size=4, daemon="osd.9")
+        from ceph_tpu.osd.daemon import OSDDaemon
+        op = trk.create("skewed")
+        late._recv_stamp = op.mstart - 0.002
+        late._recv_complete_stamp = op.mstart - 0.001
+        late._sent_stamp = (op.mstart - 0.004, op.mstart - 0.002, 0, True)
+        OSDDaemon._note_recv(op, late)
+        op.finish()
+        (doc,) = trk.dump_historic_ops()["ops"]
+        (wire,) = _spans(doc, "msgr.wire")
+        assert wire["args"] == {"queued": 0, "skew": 1}
+        assert wire["t0"] == wire["t1"]
+
+
+@pytest.mark.parametrize("ms_type", STACKS)
+def test_tracker_off_leaves_no_reply_docs(tmp_path, ms_type):
+    c = _boot(tmp_path, osd_enable_op_tracker=False, ms_type=ms_type)
+    try:
+        io = _ec_pool(c, "legs-off", pg_num=1)
         io.write_full("o", b"z" * 16384)
         from ceph_tpu.ops import hbm_cache
         hbm_cache.get().clear()

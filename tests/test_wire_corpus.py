@@ -146,6 +146,38 @@ def test_all_samples_roundtrip():
             denc.loads(blob)
 
 
+def test_the_send_path_leaves_a_messages_own_bytes_alone():
+    """ISSUE 38: the sender's stamps ride a frame as a field the send
+    path adds to a COPY (`messenger.encode_stamped`, both stacks), so
+    an unsent message, and one that was sent, encode to the archived
+    bytes; the receiver takes the field off again."""
+    from ceph_tpu.msg import messenger
+    from ceph_tpu.msg.message import Message
+    checked = 0
+    for name, blob in build_samples().items():
+        if blob[:4] not in (b"CTM1", b"CTM2"):
+            continue
+        msg = Message.decode_frame(blob)
+        seq = msg.seq
+        msg.src = ""
+        assert msg.encode(seq) == blob, name
+        frame = b"".join(bytes(b) for b in messenger.encode_stamped(
+            msg, seq, 12.5, 3))
+        assert "sent_stamp" not in msg.__dict__, name
+        assert msg.encode(seq) == blob, name
+        assert frame != blob
+        got = Message.decode_frame(frame)
+        sent = messenger._SENT_STAMP.unpack(got.__dict__["sent_stamp"])
+        assert sent[0] == 12.5 and sent[2] == 3 and sent[1] > 0
+        assert sent[3] == messenger.MONO_EPOCH_NS
+        messenger.stamp_received(
+            got, (sent[1] + 0.25, 0.0, sent[1] + 0.5, 0.0, len(frame), 1))
+        assert got._sent_stamp == (12.5, sent[1], 3, False)
+        assert got.encode(seq) == blob, name
+        checked += 1
+    assert checked >= 10
+
+
 if __name__ == "__main__":
     if "--create" in sys.argv:
         os.makedirs(os.path.dirname(CORPUS_PATH), exist_ok=True)
