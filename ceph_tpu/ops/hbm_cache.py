@@ -18,7 +18,12 @@ upload the encoded stripes simply STAY in HBM:
     re-uploaded, zero device dispatches);
   * recovery/degraded reads fetch the wanted shard rows D2H straight
     from the cached device arrays — no shard gather, no decode matmul,
-    no H2D.
+    no H2D;
+  * a read served from an entry is CHECKED where the entry lies: the
+    chip folds CRC32C over the resident data stripes while they are
+    copied out, and the copy serves only if the CRCs are the ones the
+    write's fused pass left (a disk read is held to its block and
+    shard CRCs; this is the same for HBM, at 4 bytes a chunk D2H).
 
 Coherence is enforced at the OBJECT STORE layer, not by trusting
 producers: every applied transaction is scanned
@@ -46,6 +51,7 @@ from __future__ import annotations
 
 import ast
 import threading
+import time
 from collections import OrderedDict
 
 import numpy as np
@@ -74,6 +80,44 @@ def _parse_ver(blob: bytes) -> tuple | None:
     except (ValueError, SyntaxError, UnicodeDecodeError, AttributeError):
         return None
     return tuple(ev) if isinstance(ev, tuple) else None
+
+
+# (stripes' shape, device) whose CRC program is compiled: a read never
+# compiles, it serves unchecked until the staging's warm-up is through
+_verify_ready: set[tuple] = set()
+
+
+def _device_of(arr):
+    """The device a resident array lies on; None for a host array or
+    a buffer that is gone."""
+    try:
+        return next(iter(arr.devices()))
+    except Exception:
+        return None
+
+
+def _verify_fn(dev_data):
+    """The compiled CRC fold for these resident stripes, or None
+    (host arrays, a shape not warm yet, a layout other than (S, k, L)
+    bytes)."""
+    if (tuple(dev_data.shape), _device_of(dev_data)) not in _verify_ready:
+        return None
+    from . import ec_kernels
+    return ec_kernels.make_crc_fn(int(dev_data.shape[-1]))
+
+
+def warm_verify(shape: tuple, device) -> None:
+    """Compile the CRC fold a cache-served read runs over an entry of
+    (S, k, L) uint8 stripes on `device` (the pipeline calls this on a
+    warm thread when an item of S rows is first staged)."""
+    key = (tuple(shape), device)
+    if key in _verify_ready or len(shape) != 3 or device is None:
+        return
+    import jax.numpy as jnp
+    from . import ec_kernels
+    fn = ec_kernels.make_crc_fn(int(shape[-1]))
+    np.asarray(fn(jnp.zeros(shape, dtype=jnp.uint8, device=device)))
+    _verify_ready.add(key)
 
 
 class CacheIntent:
@@ -130,13 +174,30 @@ class CacheEntry:
 
     def data_bytes(self):
         """The logical object payload, fetched D2H from the cached
-        data stripes (None if the device buffers are gone).  Returns a
+        data stripes (None if the device buffers are gone, or if the
+        stripes no longer have the CRCs they were written with: the
+        entry is dropped and the caller reads the shards).  Returns a
         zero-copy BufferList VIEW over the fetched array — the D2H
         fetch is the only materialization a cache-served read pays."""
         try:
+            # the chip folds the stripes' CRCs while they are copied out
+            fn = _verify_fn(self.dev_data)
+            t0 = time.monotonic()
+            folded = fn(self.dev_data) if fn is not None else None
             arr = np.ascontiguousarray(
                 np.asarray(self.dev_data, dtype=np.uint8))
             get().count_d2h(arr.nbytes)
+            if folded is not None:
+                t1 = time.monotonic()
+                same = np.array_equal(np.asarray(folded),
+                                      self.crcs[:, : self.k])
+                from ..utils import optracker
+                optracker.add_span("ec.device_compute", t0, t1,
+                                   stripes=self.stripes,
+                                   padded=self.stripes)
+                optracker.add_span("ec.d2h", t1, time.monotonic())
+                if not get().count_verified(self, same):
+                    return None
         except Exception:
             return None
         from ..utils.bufferlist import BufferList
@@ -171,7 +232,8 @@ class HbmStripeCache:
         self._pbytes = 0                    # pending (staged) entries
         self._c = {"hit": 0, "miss": 0, "evict": 0, "insert": 0,
                    "invalidate": 0, "lane_drops": 0, "bytes_d2h": 0,
-                   "read_bytes_served": 0, "append_throughs": 0}
+                   "read_bytes_served": 0, "append_throughs": 0,
+                   "verified": 0, "verify_fail": 0}
 
     # -- accounting (entry fetches call back in) ---------------------------
 
@@ -184,6 +246,15 @@ class HbmStripeCache:
         bench's read_cache_gbs numerator)."""
         with self._lock:
             self._c["read_bytes_served"] += int(n)
+
+    def count_verified(self, ent: CacheEntry, same: bool) -> bool:
+        """A read's device-side CRC check of `ent`: counted, and the
+        entry dropped where the stripes did not match."""
+        with self._lock:
+            self._c["verified" if same else "verify_fail"] += 1
+            if not same and self._entries.get((ent.cid, ent.oid)) is ent:
+                self._drop_locked((ent.cid, ent.oid))
+        return same
 
     # -- write path --------------------------------------------------------
 
@@ -267,13 +338,7 @@ class HbmStripeCache:
                                                dtype=np.uint8)
             head_d = ent.dev_data[:full_before]
             head_p = ent.dev_parity[:full_before]
-            dev = None
-            devs = getattr(ent.dev_data, "devices", None)
-            if callable(devs):
-                try:
-                    dev = next(iter(devs()))
-                except Exception:
-                    dev = None
+            dev = _device_of(ent.dev_data)
             if dev is not None:
                 # device-resident entry: upload only the tail and
                 # concatenate ON the chip (the prefix never moves)

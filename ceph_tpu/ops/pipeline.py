@@ -1431,7 +1431,9 @@ class EcDevicePipeline:
         compile, on a warm thread, the pairs that batches of two and
         more such items will need: otherwise the first dispatch in
         which two ops coalesce compiles them on the collector, seconds
-        or minutes into serving."""
+        or minutes into serving.  The same thread compiles the CRC
+        fold that checks an entry of n rows when a read is served
+        from it (`hbm_cache.warm_verify`)."""
         lane = disp.lane
         if lane.device is None:
             return              # host arrays: numpy views, no program
@@ -1453,8 +1455,17 @@ class EcDevicePipeline:
                        if next_bucket(j * n) * row_bytes
                        <= LANE_STAGE_BYTES})
 
+        # the data stripes of an item, as a read served from them folds
+        verify = [(n,) + like[0][0] for _idx, n, _like in keys
+                  if like[0][1] == np.uint8]
+
         def warm():
             import jax.numpy as jnp
+            try:
+                for shape in verify:
+                    hbm_cache.warm_verify(shape, lane.device)
+            except Exception as e:
+                note_warm_failure(f"cache verify {verify}", e)
             try:
                 for rows, n in todo:
                     for tail, dtype in like:

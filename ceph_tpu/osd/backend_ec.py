@@ -29,15 +29,22 @@ from .pglog import (HINFO_KEY, VER_KEY, ZERO_EV, _parse_ev, shard_oid,
 _UNREAD = object()      # _ec_read: the object's bytes not gathered yet
 
 
+# the steps of a reconstructing read, as `gather_wait`'s `widened` arg
+# has them: the shards the codec's plan names; every other acting
+# holder, the first set that decodes serving; any up osd, any shard
+PLANNED, WIDENED, SWEEP = 0, 1, 2
+
+
 class _EcRead:
     """One reconstructing read of an EC object, between its steps:
     the shards in hand (`have`, their applied versions where the read
     is version-gated, one `hinfo`), whom the next gather asks
-    (`targets`), and whether this is the last-resort sweep."""
+    (`targets`), the positions asked so far (`asked`), and which step
+    this is (`widened`: PLANNED, WIDENED or SWEEP)."""
 
     __slots__ = ("oid", "exclude", "need_ver", "qos", "interval", "have",
-                 "vers", "hinfo", "targets", "sweep", "strict_have",
-                 "replans")
+                 "vers", "hinfo", "targets", "asked", "widened",
+                 "strict_have", "replans")
 
     def __init__(self, oid, exclude, need_ver, qos, interval):
         self.oid, self.exclude, self.need_ver = oid, exclude, need_ver
@@ -46,7 +53,8 @@ class _EcRead:
         self.vers: dict[int, tuple] = {}      # shard -> applied version
         self.hinfo = None
         self.targets: list[tuple[int, int]] = []
-        self.sweep = False
+        self.asked: set[int] = set()
+        self.widened = PLANNED
         self.strict_have: set[int] = set()    # the acting pass's shards
         self.replans = 0        # k or more in hand and no decode yet
 
@@ -664,15 +672,18 @@ class ECBackend:
 
     #
     # A reconstructing read goes in steps: `_ec_read_begin` (HBM cache,
-    # the shards this OSD holds, whom to ask for the rest), one gather
-    # of sub-reads, `_ec_read_step` (decode — or, when the acting
-    # holders did not give a decodable set, the last-resort sweep,
-    # which is a second gather and a second step).  `_ec_read_local`
-    # walks them on the calling thread (rebuild, scrub repair, the
-    # append's read-modify-write); a client read PARKS between steps
-    # as a write parks in `replica_wait`: the op worker goes on to the
-    # next op, the gather's completion re-queues the read, and the
-    # `sub_read` ops it waits for are never queued behind it.
+    # the codec's plan over the live shards, this OSD's shard where
+    # the plan names it, whom to ask for the rest), one gather of
+    # sub-reads, `_ec_read_step` (reassemble or decode — or, when the
+    # plan's shards did not give the object, the widened step: every
+    # other acting holder, a second gather and a second step; and
+    # when those did not either, the last-resort sweep, a third).
+    # `_ec_read_local` walks them on the calling thread (rebuild,
+    # scrub repair, the append's read-modify-write); a client read
+    # PARKS between steps as a write parks in `replica_wait`: the op
+    # worker goes on to the next op, the gather's completion
+    # re-queues the read, and the `sub_read` ops it waits for are
+    # never queued behind it.
 
     def _ec_read_local(self, oid: str,
                        exclude: set | None = None,
@@ -710,6 +721,8 @@ class ECBackend:
             return None
         if len(plan) >= codec.get_data_chunk_count():
             return None
+        # with all but the plan's shards excluded the read has fewer
+        # than the data needs, and starts widened over just those
         others = set(range(len(self.acting))) - set(plan)
         rd = self._ec_read_begin(oid, others, need_ver, qos)
         if not isinstance(rd, _EcRead):
@@ -759,16 +772,53 @@ class ECBackend:
                     return data
         rd = _EcRead(oid, exclude or set(), need_ver, qos,
                      self.interval_epoch)
+        # PLAN FIRST (the reference's default, ECBackend
+        # get_min_avail_to_read_shards -> minimum_to_decode(want,
+        # available)): the first gather asks the shards the codec's
+        # plan names among the live ones and no others — a healthy
+        # pool's read the k data chunks, which concatenate and decode
+        # nothing; a degraded one the live data chunks and what the
+        # plan rebuilds the rest from — and this OSD's own shard file
+        # is read only where the plan names it.  Where the plan's set
+        # does not give the object (`_ec_read_step`) the read WIDENS
+        # to every other acting holder, the first set that decodes
+        # serving, and only after that SWEEPS.  A planned source that
+        # hangs without being marked down is waited for its RPC window
+        # before the read widens, as the reference's default does.
+        live = [p for p, o in enumerate(self.acting)
+                if o != ITEM_NONE and p not in rd.exclude
+                and self.osd.osdmap.is_up(o)]
+        try:
+            plan = set(ecutil.minimum_shards(self._ec_codec(), live))
+        except ErasureCodeError:
+            return self._ec_read_widen(rd)  # fewer live than it needs
+        self._ec_read_own(rd, plan)
+        if not rd.asked <= rd.have.keys():
+            # own planned shard unreadable, or behind `need_ver`
+            self.osd.perf.inc("ec_read_widened")
+            return self._ec_read_widen(rd)
+        rd.asked |= plan
+        rd.targets = [(p, self.acting[p])
+                      for p in sorted(plan - rd.have.keys())]
+        return rd
+
+    def _ec_read_own(self, rd: "_EcRead", only: set | None = None) -> None:
+        """Into `rd.have`: the shard files this OSD holds at the
+        read's positions (`only`: at those among them) that it has
+        not tried yet."""
         store = self.osd.store
         for shard, osd_id in enumerate(self.acting):
-            if osd_id != self.osd.whoami or shard in rd.exclude:
+            if osd_id != self.osd.whoami or shard in rd.exclude \
+                    or shard in rd.asked \
+                    or (only is not None and shard not in only):
                 continue
-            soid = shard_oid(oid, shard)
+            rd.asked.add(shard)
+            soid = shard_oid(rd.oid, shard)
             try:
-                if need_ver is not None:
+                if rd.need_ver is not None:
                     mine = _parse_ev(store.getattr(self.cid, soid,
                                                    VER_KEY))
-                    if mine is None or mine < tuple(need_ver):
+                    if mine is None or mine < tuple(rd.need_ver):
                         continue
                     rd.vers[shard] = mine
                 rd.have[shard] = store.read(self.cid, soid)
@@ -776,16 +826,21 @@ class ECBackend:
                                                     HINFO_KEY))
             except StoreError:
                 pass
-        # every other live holder is asked, and the first set that
-        # DECODES serves the read (the reference's fast_read): for an
-        # MDS code any k of the k+m shards (ECBackend
-        # get_min_avail_to_read_shards), for shec what its plan
-        # accepts.  A down holder costs nothing when the live ones
-        # do, and is still TRIED when they cannot (a wrongly-marked-
-        # down daemon may well answer).
+
+    def _ec_read_widen(self, rd: "_EcRead") -> "_EcRead":
+        """The widened step (the reference's fast_read rule): every
+        acting holder not asked yet is, and the first set that DECODES
+        serves the read — for an MDS code any k of the k+m shards,
+        for shec or lrc what its plan accepts.  A down holder costs
+        nothing when the live ones do, and is still TRIED when they
+        cannot (a wrongly-marked-down daemon may well answer).  What
+        the planned step brought stays in hand."""
+        rd.widened = WIDENED
+        self._ec_read_own(rd)
         rd.targets = [(s, o) for s, o in enumerate(self.acting)
-                      if o != ITEM_NONE and s not in rd.have
-                      and s not in rd.exclude and o != self.osd.whoami]
+                      if o != ITEM_NONE and s not in rd.asked
+                      and s not in rd.exclude]
+        rd.asked.update(s for s, _o in rd.targets)
         return rd
 
     def _ec_decodable(self, shards) -> bool:
@@ -818,7 +873,9 @@ class ECBackend:
 
     def _ec_read_step(self, rd: "_EcRead", gather):
         """After a gather: the object's bytes, None (unreadable), or
-        the `_EcRead` of the sweep to gather for next."""
+        the `_EcRead` to gather for next: the widened step after a
+        planned one that did not give the object, the sweep after
+        that."""
         oid, have = rd.oid, rd.have
         for shard, (data, hi, ver) in gather.out.items():
             have[shard] = data
@@ -838,10 +895,15 @@ class ECBackend:
             used = {p for p in data if p in have}.union(
                 ecutil.minimum_shards(codec, have, lost) if lost else ())
             have = {i: have[i] for i in sorted(used)}
-        gather.stamp(optracker.current(), replans=rd.replans,
-                     chunks=sorted(have))
+        gather.stamp(optracker.current(), widened=rd.widened,
+                     replans=rd.replans, chunks=sorted(have))
         if not decodable:
-            if rd.sweep:
+            if rd.widened == PLANNED:
+                # a planned source answered an error or ENOENT, timed
+                # out, or was behind `need_ver`
+                self.osd.perf.inc("ec_read_widened")
+                return self._ec_read_widen(rd)
+            if rd.widened == SWEEP:
                 return None
             # LAST-RESORT DEGRADED SWEEP: mid-remap (pg_temp release,
             # backfill in flight) shard files can sit on members the
@@ -864,7 +926,8 @@ class ECBackend:
             got = {rd.vers.get(s) for s in have}
             if len(got) != 1 or None in got:
                 self.log.info("%s of %s: mixed source versions %s; "
-                              "retrying", "degraded sweep" if rd.sweep
+                              "retrying", "degraded sweep"
+                              if rd.widened == SWEEP
                               else "rebuild read", oid, rd.vers)
                 return None
         # stripe-aware reassembly: intact data shards concatenate
@@ -878,7 +941,7 @@ class ECBackend:
             self.log.warn("decode %s failed: %s (have %s, size %s)",
                           oid, e, sorted(have), rd.hinfo.get("size"))
             return None
-        if rd.sweep:
+        if rd.widened == SWEEP:
             self._ec_sweep_served(rd)
         return data
 
@@ -891,7 +954,7 @@ class ECBackend:
         from under the acting order) even though the acting set's
         holders do not serve them."""
         sw = _EcRead(rd.oid, rd.exclude, cur, rd.qos, rd.interval)
-        sw.sweep, sw.strict_have = True, set(rd.have)
+        sw.widened, sw.strict_have = SWEEP, set(rd.have)
         km = self._ec_codec().get_chunk_count()
         store = self.osd.store
         for shard in range(km):        # any shard WE hold post-remap
@@ -1021,7 +1084,7 @@ class ECBackend:
                 return
             nxt = self._ec_read_step(rd, gather)
             if isinstance(nxt, _EcRead):
-                self._ec_read_park(conn, msg, nxt)      # the sweep
+                self._ec_read_park(conn, msg, nxt)  # widened, or the sweep
                 return
             self._ec_read(conn, msg, nxt)
 

@@ -190,6 +190,9 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      .add_u64_counter("recovery_blocked_ops")
                      .add_u64_counter("recovery_unblocked_ops")
                      .add_u64_counter("recovery_prio_promotions")
+                     # EC reads whose planned gather did not give the
+                     # object and that went on to the widened step
+                     .add_u64_counter("ec_read_widened")
                      .add_time_avg("op_latency")
                      .create_perf_counters())
         self.perf_collection.add(self.perf)

@@ -100,7 +100,8 @@ class ShardGather:
     def stamp(self, trk, **args) -> None:
         """The op's `gather_wait` span: from the last sub-read sent to
         completion, with what was asked, what was in hand then and
-        what came too late to matter."""
+        what came too late to matter; a read adds which of its steps
+        this was (`widened`) and what it decoded from (`chunks`)."""
         if trk is not None and self.asked:
             trk.add_span("gather_wait", self.t_sent, self.t_done,
                          asked=self.asked, used=len(self.out),

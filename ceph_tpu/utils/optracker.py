@@ -37,7 +37,10 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
                                  ec.device_compute carries args
                                  stripes, padded (the submission's
                                  rows and its share of the padded
-                                 batch) and, from a codec, rep
+                                 batch) and, from a codec, rep;
+                                 ops/hbm_cache.py stamps the same
+                                 pair for the CRC fold that checks
+                                 a cache-served read
       journal, wal, store_apply  store/filestore.py, store/blockstore.py,
                                  store/objectstore.py (BlockStore's wal
                                  carries args blocks, dev_writes: the

@@ -317,7 +317,8 @@ class MiniCluster:
             if mon is None:
                 return False
             osdmap = mon.osdmon.osdmap
-            return sum(1 for o in osdmap.osds.values() if o.up) >= n
+            # a snapshot: the mon's thread adds booting osds meanwhile
+            return sum(1 for o in list(osdmap.osds.values()) if o.up) >= n
         self._wait(up, timeout, f"fewer than {n} osds up")
 
     def wait_for_osd_down(self, osd_id: int, timeout: float = 30.0) -> None:

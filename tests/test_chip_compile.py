@@ -111,7 +111,10 @@ def test_xla_decode(one_chip, n_lost):
 # (rows, bytes): stripe-chunk rows, and whole shard files as deep
 # scrub folds them.  The 512 KiB shard file of a 4 MiB object compiles
 # too but takes ~36 s: run by hand (CHANGES.md, PR 23), not kept here.
-@pytest.mark.parametrize("shape", [(1408, 4096), (64, 64 << 10)])
+# (S, k, L): one object's resident data stripes, as a read served from
+# the HBM cache folds them.
+@pytest.mark.parametrize("shape", [(1408, 4096), (64, 64 << 10),
+                                   (128, K, 4096)])
 def test_xla_scrub_crc(one_chip, shape):
     fn = ec_kernels.make_crc_fn(shape[-1])
     compiled = _compile(fn, one_chip, shape)

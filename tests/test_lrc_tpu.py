@@ -7,6 +7,7 @@ gets.  The plain reference is the benchmark's
 (`benchmark/references/lrc.py`: layer by layer, numpy alone)."""
 
 import itertools
+import threading
 import time
 
 import numpy as np
@@ -293,7 +294,8 @@ OBJECT_BYTES = K * UNIT * 3 - 100
 CONF = {
     "mon_tick_interval": 0.5,
     "osd_heartbeat_interval": 0.5,
-    "osd_heartbeat_grace": 8.0,
+    # a muted OSD stays "up" for as long as a test mutes it
+    "osd_heartbeat_grace": 30.0,
     "mon_osd_min_down_reporters": 2,
     "mon_osd_down_out_interval": 600.0,
     "osd_op_history_size": 4096,
@@ -447,15 +449,30 @@ class TestServed:
         assert io.read("obj4") == payload(4)
 
     def test_osds_down_reads_degraded(self, cluster, io, cold):
-        """Two OSDs muted, not yet marked down: every object reads."""
+        """Two OSDs muted, not yet marked down: every object reads.
+        A read whose plan names a muted one waits that sub-read's
+        window before it widens, so the six go at once."""
         primaries = {placement(cluster, io, f"obj{i}")[1][0]
                      for i in range(6)}
         _pgid, acting, _pg = placement(cluster, io, "obj5")
         victims = [o for o in acting[1:] if o not in primaries][:2]
         for v in victims:
             faults.get().drop(f"osd.{v}", 1.0)
-        for i in range(6):
-            assert io.read(f"obj{i}") == payload(i)
+        out: dict = {}
+
+        def one(i: int) -> None:
+            try:
+                out[i] = io.read(f"obj{i}")
+            except RadosError as e:
+                out[i] = e
+
+        threads = [threading.Thread(target=one, args=(i,))
+                   for i in range(6)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60.0)
+        assert [i for i in range(6) if out.get(i) != payload(i)] == []
 
     @pytest.mark.parametrize("lost", [5, 2, 7])
     def test_recovery_rebuilds_one_shard_from_its_group(self, cluster, io,

@@ -66,7 +66,9 @@ class TestCounterSchema:
            # recovery pull, their resumes, and front-of-queue pull
            # promotions (blocked == unblocked at quiesce)
            "recovery_blocked_ops", "recovery_unblocked_ops",
-           "recovery_prio_promotions"}
+           "recovery_prio_promotions",
+           # EC reads that needed the widened step after the planned
+           "ec_read_widened"}
     MSGR = {"msg_send", "msg_recv", "bytes_send", "bytes_recv",
             "reconnects", "auth_failures", "auth_ticket_accepts",
             "auth_secret_accepts",

@@ -330,9 +330,11 @@ class TestMessengerSpans:
                       if " obj1 " in d["description"]
                       and "'read'" in d["description"]),
                      key=lambda d: d["mstart"])
+        (wait,) = _spans(client, "gather_wait")
         subs = _wait_docs(
             lambda: [d for d in _docs(cluster, "subop")
-                     if d["trace_id"] == client["trace_id"]], 1)
+                     if d["trace_id"] == client["trace_id"]],
+            wait["args"]["asked"])
         assert subs, "no sub_read op under the client's trace id"
         for d in subs:
             assert d["description"].startswith("sub_read(")
@@ -344,15 +346,17 @@ class TestMessengerSpans:
             (disp,) = _spans(d, "msgr.dispatch")
             assert disp["t1"] <= d["mstart"]
         # nothing on the primary's read doc but these (PR 28: the
-        # parked gather's `gather_wait`, a decode pattern's `ec.plan`)
+        # parked gather's `gather_wait`); a healthy read asks its
+        # plan's shards, the data chunks, and decodes nothing: no
+        # `ec.*` span, no `ec.plan`
         assert {s["name"] for s in client["spans"]} <= \
             {"msgr.handoff", "msgr.wire", "msgr.recv", "msgr.dispatch",
-             "queue", "execute", "ec.coalesce", "ec.stage_h2d", "ec.device_compute", "ec.d2h",
-             "ec.host_encode", "recovery_wait", "gather_wait", "ec.plan"}
+             "queue", "execute", "recovery_wait", "gather_wait"}
         # the read parked for its gather: an `execute` either side
         # of `gather_wait`, neither around a messenger span
         executes = _spans(client, "execute")
-        (wait,) = _spans(client, "gather_wait")
+        assert wait["args"]["widened"] == 0
+        assert wait["args"]["asked"] == wait["args"]["used"] == len(subs)
         assert len(executes) == 2
         assert executes[0]["t1"] <= wait["t1"] <= executes[1]["t0"]
         for ex in executes:
@@ -623,10 +627,14 @@ class TestWaitLegs:
                       if d["description"].endswith(f"<- {x['daemon']})")]
             assert sub["description"].endswith(f" {shard})")
             _check_way_in(sub)
-        # k=2 of m=1: the gather may complete before the third answer
+        # the plan's shards are asked and no others (k=2 of m=1: one
+        # where the primary holds a data chunk, two where the parity),
+        # so every answer is in time and used
         on_time = [d for d in replies
                    if _spans(d, "execute")[0]["t0"] <= wait["t1"]]
-        assert wait["args"]["used"] <= len(on_time) <= len(replies)
+        assert wait["args"]["widened"] == 0
+        assert wait["args"]["used"] == wait["args"]["asked"] \
+            == len(on_time) == len(replies)
         last = max(on_time, key=lambda d: _spans(d, "execute")[0]["t0"])
         ex = _spans(last, "execute")[0]
         assert ex["t0"] <= wait["t1"] <= ex["t1"]
