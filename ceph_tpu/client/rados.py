@@ -269,6 +269,12 @@ class Rados:
             raise RadosError(2, f"no such pool {pool_name}")
         return IoCtx(self, pool.id, pool_name)
 
+    def cache_flush_evict_all(self, pool_name: str) -> int:
+        """`rados -p <pool_name> cache-flush-evict-all` on a tier
+        pool; returns the objects left in it (0: the base alone holds
+        everything)."""
+        return self.open_ioctx(pool_name).cache_flush_evict_all()
+
     def status(self) -> str:
         rv, out, _ = self.mon_command({"prefix": "status"})
         return out
@@ -434,6 +440,44 @@ class IoCtx:
 
     def rm_omap_keys(self, oid: str, keys: list[str]) -> None:
         self._op(oid, [("omap_rm", list(keys))])
+
+    # -- cache tier (the operator's ops, on the TIER pool's ioctx) ----------
+
+    def cache_flush(self, oid: str) -> None:
+        """Flush a dirty object to the base pool; waits for a flush
+        already in flight (`rados cache-flush`)."""
+        self._op(oid, [("cache-flush",)])
+
+    def cache_try_flush(self, oid: str) -> None:
+        """The same, EBUSY where a flush is in flight or a write
+        overtook this one (`rados cache-try-flush`)."""
+        self._op(oid, [("cache-try-flush",)])
+
+    def cache_evict(self, oid: str) -> None:
+        """Drop a clean object from the tier; EBUSY on a dirty or
+        watched one (`rados cache-evict`)."""
+        self._op(oid, [("cache-evict",)])
+
+    def cache_flush_evict_all(self, tries: int = 8) -> int:
+        """Flush, then evict, every object the tier lists (`rados -p
+        <tier> cache-flush-evict-all`), until it lists none or `tries`
+        rounds did not empty it; returns the objects left.  An object
+        a write re-dirtied between its flush and its evict (EBUSY)
+        waits for the next round; one that went meanwhile (ENOENT) is
+        done."""
+        left = self.list_objects()
+        for _ in range(tries):
+            for oid in left:
+                try:
+                    self.cache_flush(oid)
+                    self.cache_evict(oid)
+                except RadosError as e:
+                    if e.errno not in (2, 16, 11, 110):
+                        raise
+            left = self.list_objects()
+            if not left:
+                break
+        return len(left)
 
     # -- reads -------------------------------------------------------------
 

@@ -679,8 +679,15 @@ class OSDMonitor(PaxosService):
     _POOL_SET_VARS = {
         "size": int, "min_size": int, "hit_set_count": int,
         "hit_set_period": float, "target_max_objects": int,
-        "pg_num": int,
+        "pg_num": int, "target_max_bytes": int,
+        "cache_target_dirty_ratio": float,
+        "cache_target_dirty_high_ratio": float,
+        "cache_target_full_ratio": float,
+        "cache_min_flush_age": float, "cache_min_evict_age": float,
     }
+    _POOL_RATIOS = ("cache_target_dirty_ratio",
+                    "cache_target_dirty_high_ratio",
+                    "cache_target_full_ratio")
 
     def _cmd_pool_set(self, cmd: dict):
         pool = self._pool_for_update(cmd.get("pool", ""))
@@ -707,8 +714,23 @@ class OSDMonitor(PaxosService):
             return -22, "hit_set_period must be > 0", b""
         if var == "hit_set_count" and val < 1:
             return -22, "hit_set_count must be >= 1", b""
-        if var == "target_max_objects" and val < 0:
-            return -22, "target_max_objects must be >= 0", b""
+        if var in ("target_max_objects", "target_max_bytes",
+                   "cache_min_flush_age", "cache_min_evict_age") \
+                and val < 0:
+            return -22, f"{var} must be >= 0", b""
+        if var in self._POOL_RATIOS and not 0.0 <= val <= 1.0:
+            return -22, f"{var} {val} outside [0, 1]", b""
+        # the high ratio may not lie under the dirty ratio, whichever
+        # of the two is being set (OSDMonitor prepare_command_pool_set)
+        if var == "cache_target_dirty_high_ratio" \
+                and val < pool.cache_target_dirty_ratio:
+            return -22, (f"{var} {val} under cache_target_dirty_ratio "
+                         f"{pool.cache_target_dirty_ratio}"), b""
+        if var == "cache_target_dirty_ratio" \
+                and val > pool.cache_target_dirty_high_ratio:
+            return -22, (f"{var} {val} over "
+                         f"cache_target_dirty_high_ratio "
+                         f"{pool.cache_target_dirty_high_ratio}"), b""
         if var == "pg_num":
             return self._cmd_pool_set_pg_num(pool, val)
         setattr(pool, var, val)

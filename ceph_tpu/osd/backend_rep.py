@@ -39,6 +39,8 @@ class ReplicatedBackend:
         except StoreError as e:
             self._reply(conn, msg, -e.errno, [])
             return
+        if self.is_tier:
+            self._tier_account(msg.oid)     # the agent's running counts
         # last_backfill routing: a backfill peer only receives ops for
         # objects at or below its watermark — anything beyond is
         # backfill-deferred (the resumed scan pushes the current

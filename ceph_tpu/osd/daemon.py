@@ -142,6 +142,10 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
             cost_aware=bool(self.conf.osd_ec_cost_aware_placement),
             hbm_cache_bytes=int(self.conf.osd_ec_hbm_cache_bytes),
             qos_cost_unit=int(self.conf.osd_qos_cost_bytes_unit))
+        # the tiering agent (OSDService::agent_entry): its thread
+        # starts with the first tier PG that has work
+        from .cache_tier import TierAgent
+        self.tier_agent = TierAgent(self)
         self._rpc_tid = itertools.count(1)
         self._rpc: dict = {}
         self._rpc_async: dict[int, Callable] = {}
@@ -193,6 +197,28 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      # EC reads whose planned gather did not give the
                      # object and that went on to the widened step
                      .add_u64_counter("ec_read_widened")
+                     # cache tiering, under the reference's names:
+                     # promotes, flushes (the agent's and the
+                     # operator's) and evicts started, objects marked
+                     # dirty and clean, failed ones, agent passes and
+                     # what they started, client ops a full tier held
+                     # back; and two that have to stay 0: evicts of a
+                     # dirty object (refused), objects that entered a
+                     # full tier
+                     .add_u64_counter("tier_promote")
+                     .add_u64_counter("tier_flush")
+                     .add_u64_counter("tier_evict")
+                     .add_u64_counter("tier_dirty")
+                     .add_u64_counter("tier_clean")
+                     .add_u64_counter("tier_try_flush_fail")
+                     .add_u64_counter("tier_flush_fail")
+                     .add_u64_counter("tier_promote_fail")
+                     .add_u64_counter("agent_wake")
+                     .add_u64_counter("agent_flush")
+                     .add_u64_counter("agent_evict")
+                     .add_u64_counter("tier_full_waits")
+                     .add_u64_counter("tier_evict_dirty")
+                     .add_u64_counter("tier_full_admit")
                      .add_time_avg("op_latency")
                      .create_perf_counters())
         self.perf_collection.add(self.perf)
@@ -230,6 +256,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         self.asok.register(
             "dump_historic_slow_ops",
             lambda c: self.op_tracker.dump_historic_slow_ops())
+        self.asok.register("tier status", lambda c: self._tier_status())
         self.asok.register("config show", lambda c: self.conf.dump())
         self.asok.register(
             "config set",
@@ -488,6 +515,17 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                     out["ec_codecs"][name]["crossover_bytes"] = xo
         return out
 
+    def _tier_status(self) -> dict:
+        """`tier status`: the agent's queue and ops in flight, and for
+        every tier PG this OSD is primary of its modes, its running
+        counts and what it started."""
+        with self.pg_lock:
+            pgs = [(pgid, pg) for pgid, pg in self.pgs.items()
+                   if pg.is_tier and pg.is_primary]
+        return {"agent_queue": [str(p) for p in self.tier_agent.queue],
+                "agent_ops": self.tier_agent.ops,
+                "pgs": {str(pgid): pg.tier_status() for pgid, pg in pgs}}
+
     # -- lifecycle ---------------------------------------------------------
 
     def start(self) -> None:
@@ -518,6 +556,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         if self._hb_timer:
             self._hb_timer.cancel()
         self.asok.shutdown()
+        self.tier_agent.stop()
         self.op_wq.stop()
         self.recovery_wq.stop()
         self.msgr.shutdown()
@@ -626,6 +665,9 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                             pg.split_pending = True
                 if pg is not None:
                     pg.update_acting(up, acting)
+                    if pg.is_tier and pg.is_primary:
+                        # new targets or ratios move the agent's modes
+                        self.op_wq.queue(pgid, pg.tier_map_changed)
             # collected AFTER the creation loop: a restarted daemon
             # only instantiates (reloads) its pgs in the loop above
             split_parents = [
@@ -1171,9 +1213,6 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         with self.pg_lock:
             stalled = [(pgid, pg) for pgid, pg in self.pgs.items()
                        if pg._inflight]
-            tiers = [(pgid, pg) for pgid, pg in self.pgs.items()
-                     if pg.is_primary and pg.pool is not None
-                     and pg.pool.tier_of >= 0]
         for pgid, pg in stalled:
             self.op_wq.queue(pgid, pg.check_inflight)
         # an incomplete copy must ASK to be made whole: after a fast
@@ -1218,10 +1257,6 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                 self.send_osd(live[0], MPGInfo(
                     op="request_peering", pgid=str(pgid),
                     epoch=self.osdmap.epoch))
-        # cache-tier agent: flush dirty objects / whiteouts, evict
-        # past target_max_objects (agent_work cadence rides the tick)
-        for pgid, pg in tiers:
-            self.op_wq.queue(pgid, pg.agent_work)
         # pg_temp reconcile: a temp-pinned pg (post-split child) whose
         # primary we are gets its CRUSH targets backfilled, then the
         # pin is released so placement converges to CRUSH

@@ -88,9 +88,23 @@ class Pool:
     cache_mode: str = "none"           # none | writeback | readonly
     hit_set_count: int = 4
     hit_set_period: float = 60.0
-    target_max_objects: int = 0        # agent trigger; 0 = no agent
+    # the agent's two targets, pool-wide (a PG works against its
+    # share, target / pg_num); 0 = that target is not set, and with
+    # neither set the agent of this pool is idle: it flushes and
+    # evicts nothing (agent_choose_mode)
+    target_max_objects: int = 0
+    target_max_bytes: int = 0
+    # shares of the PG's target: dirty above the first starts flushing
+    # (low), above the second flushes at full speed (high); objects
+    # above the third start evicting (some), at the target the tier
+    # is full (pg_pool_t cache_target_*_ratio_micro)
+    cache_target_dirty_ratio: float = 0.4
+    cache_target_dirty_high_ratio: float = 0.6
+    cache_target_full_ratio: float = 0.8
+    cache_min_flush_age: float = 0.0   # seconds since the last write
+    cache_min_evict_age: float = 0.0
 
-    DENC_VERSION = 3                   # v2: snaps; v3: tiering
+    DENC_VERSION = 4       # v2: snaps; v3: tiering; v4: agent ratios
 
     @staticmethod
     def _denc_upgrade(fields: dict, version: int) -> dict:
@@ -106,6 +120,13 @@ class Pool:
             fields.setdefault("hit_set_count", 4)
             fields.setdefault("hit_set_period", 60.0)
             fields.setdefault("target_max_objects", 0)
+        if version < 4:
+            fields.setdefault("target_max_bytes", 0)
+            fields.setdefault("cache_target_dirty_ratio", 0.4)
+            fields.setdefault("cache_target_dirty_high_ratio", 0.6)
+            fields.setdefault("cache_target_full_ratio", 0.8)
+            fields.setdefault("cache_min_flush_age", 0.0)
+            fields.setdefault("cache_min_evict_age", 0.0)
         return fields
 
     @property

@@ -424,6 +424,8 @@ class Peering:
                 # so a lost push is retried, never stranded
                 self._queue_missing_pulls(lus)
             self.active = True
+            if self.is_tier:
+                self._tier_activate()
             # rebuild the client-retry dedup table from the log's
             # reqid-carrying entries: a retry that lands on THIS
             # primary after a pg_temp cut re-replies instead of

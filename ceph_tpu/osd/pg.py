@@ -142,12 +142,7 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
         self._notify_reqs: dict[tuple, int] = {}   # reqid -> notify id
         self._notify_seq = 0
         # cache tiering (ReplicatedPG agent/promote + HitSet analogs)
-        self.hit_sets: list[list] = []     # [[start_ts, set(oids)]...]
-        self._promote_waiting: dict[str, list] = {}  # oid -> [(conn,msg)]
-        self._flushing: set[str] = set()
-        self._agent_hints: set[str] = set()  # oids likely dirty/whiteout
-        self._agent_tick = 0
-        self._int_tid = itertools.count(1)   # internal-op reqid tids
+        self._tier_init()
         self._load()
 
     # -- identity ----------------------------------------------------------
@@ -308,6 +303,7 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
                 self._failed_floor = None    # peering reconciles
                 self._drop_parked()          # dead interval's sub-ops
                 self._drop_recovery_blocked()   # clients re-send
+                self._drop_tier_waiters()
                 self._pull_queued_at.clear()    # new round re-pulls
                 self._heal_pushed_at.clear()
                 self.peer_last_backfill.clear()  # peering re-learns
@@ -634,7 +630,6 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
             # every client write in a writeback tier marks the object
             # dirty so the agent/flush knows to push it to the base
             msg.ops = list(msg.ops) + [("setxattr_raw", DIRTY_KEY, b"1")]
-            self._agent_hints.add(msg.oid)
         self.version += 1
         version = (self.interval_epoch, self.version)
         if self.is_ec:
@@ -699,7 +694,6 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
                     txn.touch(self.cid, oid)
                     txn.setattr(self.cid, oid, WHITEOUT_KEY, b"1")
                     txn.setattr(self.cid, oid, DIRTY_KEY, b"1")
-                    self._agent_hints.add(oid)
                 else:
                     if not self.is_ec:
                         self._snap_delete_txn(txn, oid, ss)

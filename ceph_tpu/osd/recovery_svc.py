@@ -284,6 +284,8 @@ class RecoveryService:
             # the push may have retired a `missing` claim client ops
             # are recovery-blocked on: resume them (no-op otherwise)
             pg._wake_recovery_blocked(msg.oid)
+            if pg.is_tier and pg.is_primary:
+                pg._tier_account(msg.oid, admitted=False)
         reply = MPGPushReply(pgid=msg.pgid, oid=msg.oid, shard=msg.shard)
         reply.rpc_tid = getattr(msg, "rpc_tid", None)
         self.send_osd_reply(conn, reply, msg)
@@ -1055,21 +1057,35 @@ class RecoveryService:
     # -- cache tiering: internal client ops to the base pool ---------------
 
     def base_pool_op(self, pool_id: int, oid: str, ops: list,
-                     done: Callable, timeout: float = 10.0) -> None:
-        """Async internal op against another pool's primary — the
-        tier agent's promote reads and flush writes (the reference
-        routes these through the Objecter with copy_from/flush ops;
-        here the OSD speaks the same client protocol directly).
+                     done: Callable, trk, span: str, fail: str,
+                     timeout: float = 10.0, **args) -> None:
+        """Async internal op against another pool's primary — a tier
+        PG's promote read or flush write (the reference routes these
+        through the Objecter with copy_from/flush ops; here the OSD
+        speaks the same client protocol directly).  The op is `span`
+        (`base_read`, `base_write`; `args` are its args) on `trk`, the
+        caller's tracked op of kind `tier_promote` or `tier_flush`;
+        one that timed out, found no primary or failed at the base
+        counts under `fail` (ENOENT is an answer, not a failure).
         done(reply_or_None) runs on the messenger/timer thread."""
+        trk.span_begin(span, **args)
+
+        def answered(reply) -> None:
+            result = None if reply is None else reply.result
+            trk.span_end(span, result=result)
+            if result not in (0, -2):
+                self.perf.inc(fail)
+            done(reply)
+
         pgid = self.osdmap.object_to_pg(pool_id, oid)
         primary = self.osdmap.pg_primary(pgid)
         if primary is None:
-            done(None)
+            answered(None)
             return
         msg = MOSDOp(tid=next(self._rpc_tid), pgid=str(pgid), oid=oid,
                      ops=ops, epoch=self.osdmap.epoch)
         msg._cache_internal = True
-        self._call_async(primary, msg, done, timeout=timeout)
+        self._call_async(primary, msg, answered, timeout=timeout)
 
     # -- EC shard fetch (degraded reads / rebuild) -------------------------
 

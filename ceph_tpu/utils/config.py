@@ -108,6 +108,10 @@ _opt("osd_client_message_size_cap", int, 500 << 20, "")
 _opt("osd_op_num_shards", int, 5, "sharded op queue shards")
 _opt("osd_op_num_threads_per_shard", int, 2, "")
 _opt("osd_recovery_max_active", int, 3, "")
+_opt("osd_agent_max_ops", int, 4,
+     "tiering agent: flushes and evicts in flight on one OSD")
+_opt("osd_agent_max_low_ops", int, 2,
+     "tiering agent: flushes in flight on one OSD in flush mode low")
 _opt("osd_recovery_block_retry", float, 1.0,
      "re-promotion cadence for client ops parked on a missing "
      "object's recovery pull (the op blocks instead of serving stale "

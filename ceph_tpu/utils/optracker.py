@@ -60,6 +60,36 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
                                  plan search, a decode matrix)
       recovery_wait, push_rpc, rebuild
                                  osd/pg.py, osd/recovery_svc.py
+      tier.lookup, tier.promote_wait, tier.full_wait
+                                 osd/cache_tier.py, on a client op's doc
+                                 in a cache tier's PG: tier.lookup once,
+                                 of no length (args hit, mode, bytes:
+                                 was the object in the tier, the PG's
+                                 evict mode, the bytes the op brings);
+                                 parked until the promoted copy is
+                                 installed; held back by a full tier
+                                 until an evict made room (an op woken
+                                 and parked again has several)
+      base_read, install         on the op of kind ``tier_promote``
+                                 (``tier_promote(<pgid> <oid>)``, under
+                                 the trace id of the client op that
+                                 missed): the whole-object read at the
+                                 base pool's primary (osd/recovery_svc.py
+                                 `base_pool_op`; arg result), then the
+                                 replicated write of the copy (arg
+                                 bytes; its `msgr.send`, `replica_wait`
+                                 and store spans nest inside it, its
+                                 sub-ops carry the trace id)
+      tier_read, base_write      on the op of kind ``tier_flush``
+                                 (trace id ``tier_flush:<osd>:<pgid>:
+                                 <n>``): the store read of the dirty
+                                 object (arg bytes), the write_full at
+                                 the base pool's primary (args bytes,
+                                 mode: the PG's flush mode, result)
+      tier.evict                 on the op of kind ``tier_agent``, one
+                                 per agent pass that evicted (args oid,
+                                 bytes, mode): the replicated removal,
+                                 started to committed
       scrub.list, scrub.cache_fold, scrub.read, scrub.stack,
       scrub.collect, scrub.peer_wait, scrub.compare
                                  osd/scrubber.py, on the primary's

@@ -128,14 +128,32 @@ def _setup_tier(rados, cluster, base: str, cache: str,
                  "tierpool": cache})
     _mon(rados, {"prefix": "osd tier cache-mode", "pool": cache,
                  "mode": mode})
+    _agent_on(rados, cache)
     _mon(rados, {"prefix": "osd tier set-overlay", "pool": base,
                  "overlaypool": cache})
+
+
+def _agent_on(rados, cache: str, objects: int = 4000):
+    """A pool with no target has no agent: give this one a target it
+    never fills, and a dirty ratio of 0, so that whatever is dirty is
+    flushed."""
+    _mon(rados, {"prefix": "osd pool set", "pool": cache,
+                 "var": "target_max_objects", "val": str(objects)})
+    _mon(rados, {"prefix": "osd pool set", "pool": cache,
+                 "var": "cache_target_dirty_ratio", "val": "0"})
 
 
 class TestWritebackTier:
     def test_write_lands_in_tier_then_flushes_to_base(self, cluster,
                                                       rados):
         _setup_tier(rados, cluster, "wb-base", "wb-cache")
+        # the agent is woken by the write itself: an age keeps the
+        # object dirty long enough to be looked at
+        _mon(rados, {"prefix": "osd pool set", "pool": "wb-cache",
+                     "var": "cache_min_flush_age", "val": "3.0"})
+        _wait_for(cluster, lambda: all(
+            o.osdmap.pool_by_name("wb-cache").cache_min_flush_age == 3.0
+            for o in cluster.osds.values()), "the age in every map")
         base_id = _pool_id(cluster, "wb-base")
         cache_id = _pool_id(cluster, "wb-cache")
         io = rados.open_ioctx("wb-base")      # overlay redirects

@@ -138,6 +138,47 @@ class TestConformance:
         assert store.getattr("c", "dst", "a1") == b"v1"
         assert store.omap_get("c", "dst") == {"k": b"v"}
 
+    @pytest.mark.parametrize("then", ["truncate", "remove", "move"])
+    def test_clone_of_a_source_emptied_next(self, store, then):
+        """The shapes an EC shard's rollback stash has (clone, then the
+        source rewritten whole or removed) and a rename: a store may
+        hand the data over instead of copying it; what is read
+        afterwards is what a copy would have left."""
+        body = bytes(range(256)) * 520 + b"tail"       # 130 blocks and a bit
+        store.apply_transaction(T().create_collection("c")
+                                .write("c", "src", 0, body)
+                                .setattr("c", "src", "a1", b"v1")
+                                .omap_setkeys("c", "src", {"k": b"v"}))
+        if then == "truncate":
+            store.apply_transaction(T().try_clone("c", "src", "dst")
+                                    .truncate("c", "src", 0)
+                                    .write("c", "src", 0, b"new body")
+                                    .setattr("c", "src", "a2", b"v2"))
+            assert store.read("c", "src") == b"new body"
+            assert store.getattrs("c", "src") == {"a1": b"v1", "a2": b"v2"}
+            assert store.omap_get("c", "src") == {"k": b"v"}
+        elif then == "remove":
+            store.apply_transaction(T().try_clone("c", "src", "dst")
+                                    .try_remove("c", "src"))
+            assert not store.exists("c", "src")
+        else:
+            store.apply_transaction(
+                T().collection_move_rename("c", "src", "c", "dst"))
+            assert not store.exists("c", "src")
+        assert store.read("c", "dst") == body
+        assert store.stat("c", "dst")["size"] == len(body)
+        assert store.getattr("c", "dst", "a1") == b"v1"
+        assert store.omap_get("c", "dst") == {"k": b"v"}
+        # the stash restored and dropped (a rollback), then gone
+        store.apply_transaction(T().try_clone("c", "dst", "src")
+                                .try_remove("c", "dst"))
+        assert store.read("c", "src") == body and not store.exists("c", "dst")
+        # a clone whose source keeps its data is a copy
+        store.apply_transaction(T().clone("c", "src", "kept")
+                                .truncate("c", "src", 4))
+        assert store.read("c", "kept") == body
+        assert store.read("c", "src") == body[:4]
+
     def test_xattrs(self, store):
         store.apply_transaction(T().create_collection("c")
                                 .setattr("c", "o", "n1", b"v1")
@@ -943,6 +984,89 @@ class TestBlockStoreExtents:
             payload[:77 * MIN_ALLOC]
         s.umount()
 
+    def test_onode_block_map_is_packed_and_the_old_form_still_reads(
+            self, tmp_path):
+        """The KV holds an onode's block map as one packed field; a
+        store whose onodes were written in the form before (the map a
+        plain dict through the generic encoder) mounts, verifies its
+        free list and reads, and rewrites an onode it touches."""
+        from ceph_tpu.store.blockstore import (P_ONODE, dump_onode,
+                                               load_onode)
+        from ceph_tpu.utils import denc
+        s = self._mk(tmp_path)
+        body = _seeded(300 * 1024 + 77, 33)
+        s.apply_transaction(T().write("c", "obj", 0, body)
+                            .setattr("c", "obj", "a", b"v"))
+        s.apply_transaction(T().touch("c", "empty"))
+        head = s._committed_onode("c", "obj")
+        blob = s.db.get(P_ONODE, "c/obj")
+        assert load_onode(blob) == head and len(head["blocks"]) == 76
+        assert len(blob) < 76 * 16 + 64 and b"blocks" not in blob
+        assert load_onode(dump_onode(s._committed_onode("c", "empty"))) \
+            == {"size": 0, "xattrs": {}, "blocks": {}}
+        # the same onodes as a store of before this form left them
+        kvt = s.db.transaction()
+        for oid in ("obj", "empty"):
+            kvt.set(P_ONODE, f"c/{oid}",
+                    denc.dumps(s._committed_onode("c", oid)))
+        s.db.submit_transaction(kvt, sync=True)
+        s.umount()
+        s2 = self._remount(tmp_path)
+        assert s2.counters["freelist_repairs"] == 0
+        assert s2.read("c", "obj") == body
+        assert s2.getattr("c", "obj", "a") == b"v"
+        assert s2.stat("c", "empty") == {"size": 0}
+        s2.apply_transaction(T().write("c", "obj", 4096, b"x" * 4096))
+        assert b"blocks" not in s2.db.get(P_ONODE, "c/obj")
+        assert s2.read("c", "obj") == \
+            body[:4096] + b"x" * 4096 + body[8192:]
+        s2.umount()
+
+    @pytest.mark.parametrize("then", ["truncate", "remove"])
+    def test_rollback_stash_takes_the_blocks(self, tmp_path, then):
+        """An EC shard's stash before a whole rewrite or a delete
+        (try_clone, then truncate to nothing or remove): the stash gets
+        the shard's blocks where they lie; the only device write is the
+        new body's, no block is read, and a remount reads both."""
+        s = self._mk(tmp_path)
+        old, new = _seeded(512 * 1024, 31), _seeded(512 * 1024, 32)
+        s.apply_transaction(T().write("c", "shard", 0, old)
+                            .setattr("c", "shard", "hinfo", b"h1"))
+        lay = dict(s._committed_onode("c", "shard")["blocks"])
+        calls = _count_pwrites(s)
+        reads = []
+        pread = s.dev.pread
+        s.dev.pread = lambda off, n: reads.append(n) or pread(off, n)
+        txn = T().try_clone("c", "shard", "stash")
+        if then == "truncate":
+            txn.truncate("c", "shard", 0).write("c", "shard", 0, new) \
+               .setattr("c", "shard", "hinfo", b"h2")
+        else:
+            txn.try_remove("c", "shard")
+        s.apply_transaction(txn)
+        s.dev.pread = pread
+        assert not reads
+        assert [n for _off, n in calls] == \
+            ([len(new)] if then == "truncate" else [])
+        assert s._committed_onode("c", "stash")["blocks"] == lay
+        assert s.getattr("c", "stash", "hinfo") == b"h1"
+        s.umount()
+        s2 = self._remount(tmp_path)
+        assert s2.read("c", "stash") == old
+        if then == "truncate":
+            assert s2.read("c", "shard") == new
+            assert s2.getattr("c", "shard", "hinfo") == b"h2"
+            live = {e[0] for o in ("shard", "stash") for e in
+                    s2._committed_onode("c", o)["blocks"].values()}
+            assert len(live) == 256
+        else:
+            assert not s2.exists("c", "shard")
+        # the stash trimmed: its blocks are free again and reused
+        s2.apply_transaction(T().try_remove("c", "stash"))
+        s2.apply_transaction(T().write("c", "next", 0, old))
+        assert s2.read("c", "next") == old
+        s2.umount()
+
     @pytest.mark.parametrize("case", ["short", "over_iov_max"])
     def test_gather_write_of_a_run_lands_whole(self, tmp_path, monkeypatch,
                                                case):
@@ -1288,10 +1412,9 @@ class TestBlockStoreOnodeCache:
     @staticmethod
     def _kv_head(s, oid, cid="c"):
         """The onode as the KV holds it, round the store."""
-        from ceph_tpu.store.blockstore import P_ONODE
-        from ceph_tpu.utils import denc
+        from ceph_tpu.store.blockstore import P_ONODE, load_onode
         blob = s.db.get(P_ONODE, f"{cid}/{oid}")
-        return None if blob is None else denc.loads(blob)
+        return None if blob is None else load_onode(blob)
 
     def _agrees_with_kv(self, s, oids, data=True):
         """`data=False` for a dead store: the blocks of a commit whose

@@ -68,7 +68,16 @@ class TestCounterSchema:
            "recovery_blocked_ops", "recovery_unblocked_ops",
            "recovery_prio_promotions",
            # EC reads that needed the widened step after the planned
-           "ec_read_widened"}
+           "ec_read_widened",
+           # cache tiering (the reference's names): promotes, flushes
+           # and evicts started, dirty/clean transitions, failures,
+           # agent passes and what they started, ops a full tier held
+           # back; the last two have to stay 0
+           "tier_promote", "tier_flush", "tier_evict", "tier_dirty",
+           "tier_clean", "tier_try_flush_fail", "tier_flush_fail",
+           "tier_promote_fail", "agent_wake", "agent_flush",
+           "agent_evict", "tier_full_waits", "tier_evict_dirty",
+           "tier_full_admit"}
     MSGR = {"msg_send", "msg_recv", "bytes_send", "bytes_recv",
             "reconnects", "auth_failures", "auth_ticket_accepts",
             "auth_secret_accepts",
