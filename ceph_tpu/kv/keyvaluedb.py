@@ -11,6 +11,13 @@ import abc
 from typing import Iterable, Iterator
 
 
+def after_prefix(prefix: str) -> str:
+    """The least key above every key that starts with `prefix`: the
+    `end` of a scan of those keys alone (`iterate(p, start)` with no
+    end reads the rest of the namespace)."""
+    return prefix[:-1] + chr(ord(prefix[-1]) + 1)
+
+
 class KVTransaction:
     """A write batch: (op, prefix, key, value) entries."""
 

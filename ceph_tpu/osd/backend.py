@@ -226,11 +226,13 @@ class PGBackendBase:
 
     def _log_and_apply(self, txn: Transaction, entry: dict) -> None:
         """Record the log entry and apply the txn as one unit: the
-        serialized log rides inside the txn, and a store failure
+        log's changed keys ride inside the txn, and a store failure
         un-records the in-memory entry — otherwise the log would claim
         a version whose data (and rollback stash) never persisted,
         and a later rewind would 'restore' from a stash that does not
-        exist, destroying the still-valid prior object."""
+        exist, destroying the still-valid prior object.  The keys the
+        failed txn carried are not believed written: the log saw no
+        apply, so its next persist writes every key anew."""
         oid = entry["oid"]
         # crash site: the op reached the pg but neither the log entry
         # nor the txn hit the store — after restart the object must

@@ -185,6 +185,13 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      .add_u64_counter("recovery_pushes")
                      .add_u64_counter("recovery_bytes")
                      .add_u64_counter("backfill_resumes")
+                     # the PG log as keys (pglog.persist_log): keys
+                     # and bytes handed to transactions, and how often
+                     # a log had to be written whole (a converted
+                     # blob, an adopted window, a failed transaction)
+                     .add_u64_counter("pglog_keys_written")
+                     .add_u64_counter("pglog_bytes_written")
+                     .add_u64_counter("pglog_full_rewrites")
                      # serve-during-repair: client ops parked on a
                      # missing object's recovery pull (and resumed
                      # after it lands — blocked == unblocked at

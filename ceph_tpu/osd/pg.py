@@ -41,8 +41,8 @@ from .messages import MOSDOpReply
 from .osdmap import PgId
 from .peering import Peering
 from .pglog import (DIRTY_KEY, HINFO_KEY, SNAPSET_KEY, VER_KEY,
-                    WHITEOUT_KEY, ZERO_EV, PGLog, clone_oid,
-                    shard_oid, snapdir_oid, stash_oid)
+                    WHITEOUT_KEY, ZERO_EV, PGLog, clone_oid, load_log,
+                    persist_log, shard_oid, snapdir_oid, stash_oid)
 from .snaps import SnapOps
 
 if TYPE_CHECKING:
@@ -211,14 +211,11 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
                 # and hands us the parent's completeness).
                 self.set_backfill_state(False)
             return
-        try:
-            blob = store.getattr(self.cid, "_pgmeta", "log")
-            self.pglog = PGLog.decode(
-                blob, max_entries=int(
-                    self.osd.conf.osd_pg_log_max_entries))
-            self.version = self.pglog.head[1]
-        except StoreError:
-            pass
+        log = load_log(store, self.cid, max_entries=int(
+            self.osd.conf.osd_pg_log_max_entries))
+        if log is not None:
+            self.pglog = log
+            self.version = log.head[1]
         try:
             vals = store.omap_get_values(self.cid, "_pgmeta", ["hitsets"])
             if "hitsets" in vals:
@@ -286,7 +283,19 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
             pass
 
     def _persist_log(self, txn: Transaction) -> None:
-        txn.setattr(self.cid, "_pgmeta", "log", self.pglog.encode())
+        """The log's changes since it was last persisted join `txn`:
+        what the caller then applies is the log and its data as one
+        unit (pglog.persist_log has the stored form)."""
+        keys, nbytes, whole = persist_log(self.pglog, self.osd.store,
+                                          self.cid, txn)
+        perf = self.osd.perf
+        perf.inc("pglog_keys_written", keys)
+        perf.inc("pglog_bytes_written", nbytes)
+        if whole:
+            perf.inc("pglog_full_rewrites")
+        txn.note_span("log_persists", 1)
+        txn.note_span("log_keys", keys)
+        txn.note_span("log_bytes", nbytes)
 
     # -- map updates -------------------------------------------------------
 

@@ -7,6 +7,7 @@ standalone value type the OSD, the backends and the tools all consume.
 
 from __future__ import annotations
 
+from ..store.objectstore import StoreError
 from ..utils import denc
 
 HINFO_KEY = "_hinfo"        # per-shard cumulative crc xattr (EC)
@@ -69,6 +70,58 @@ def stash_oid(soid: str, ev: tuple) -> str:
     return f"{soid}@{ev[0]}.{ev[1]}"
 
 
+# The stored form of a PGLog: keys under the omap of <cid>/_pgmeta, so
+# that a write puts the keys that changed and nothing else (the
+# reference's PGLog::_write_log_and_missing: one key an entry, named by
+# eversion_t::get_key_name so that key order is version order; the
+# dirty entries written, the trimmed ones removed).
+PGMETA = "_pgmeta"
+LOG_ATTR = "log"            # the old form: one blob, read and converted
+LOG_META_KEY = "log_meta"   # denc {"v": LOG_FORMAT, "tail": ev}
+LOG_FORMAT = 1
+ENTRY_PREFIX = "log."       # log.<epoch:010d>.<v:020d> -> denc entry
+INDEX_PREFIXES = (("obj.", "objects"), ("del.", "deleted"),
+                  ("mis.", "missing"))   # <prefix><oid> -> b"<epoch>.<v>"
+
+
+def entry_key(ev: tuple) -> str:
+    return f"{ENTRY_PREFIX}{ev[0]:010d}.{ev[1]:020d}"
+
+
+def _is_log_key(key: str) -> bool:
+    return key == LOG_META_KEY or key.startswith(ENTRY_PREFIX) or \
+        any(key.startswith(p) for p, _ in INDEX_PREFIXES)
+
+
+class _Index(dict):
+    """An oid -> ev map of the log that remembers which oids changed
+    since the log was last persisted, whoever changed them."""
+
+    __slots__ = ("touched",)
+
+    def __init__(self, *args):
+        super().__init__(*args)
+        self.touched: set[str] = set()
+
+    def __setitem__(self, oid, ev):
+        self.touched.add(oid)
+        dict.__setitem__(self, oid, ev)
+
+    def __delitem__(self, oid):
+        self.touched.add(oid)
+        dict.__delitem__(self, oid)
+
+    def pop(self, oid, *default):
+        if oid in self:
+            self.touched.add(oid)
+        return dict.pop(self, oid, *default)
+
+    def _whole(self, *args, **kw):
+        raise TypeError("a log index changes one oid at a time")
+
+    update = clear = popitem = setdefault = __ior__ = _whole
+
+
 class PGLog:
     """Bounded per-PG op log + object version index (osd/PGLog.{h,cc}).
 
@@ -100,12 +153,39 @@ class PGLog:
     MAX_ENTRIES = 2000
 
     def __init__(self, max_entries: int | None = None):
-        self.entries: list[dict] = []
-        self.objects: dict[str, tuple] = {}             # oid -> ev
-        self.deleted: dict[str, tuple] = {}             # oid -> ev
-        self.missing: dict[str, tuple] = {}             # oid -> needed ev
+        self._entries: list[dict] = []
+        self.objects: dict[str, tuple] = _Index()       # oid -> ev
+        self.deleted: dict[str, tuple] = _Index()       # oid -> ev
+        self.missing: dict[str, tuple] = _Index()       # oid -> needed ev
         self.tail: tuple = ZERO_EV      # entries cover (tail, head]
         self.max_entries = int(max_entries or self.MAX_ENTRIES)
+        # what changed since the log was last handed to a transaction
+        # (persist_log drains it): evs whose key is to be put, evs
+        # whose key is to be removed, the oids the three indexes
+        # remember themselves, and the tail against the one stored
+        self._put: set[tuple] = set()
+        self._cut: set[tuple] = set()
+        self._stored_tail: tuple | None = None   # None: no log_meta yet
+        # every key is to be written anew: the entries were replaced
+        # from outside, or the log was read from the old blob
+        self._whole = False
+        # handed to a transaction that has not been seen to apply
+        self._unconfirmed = False
+        # ev -> the entry's denc bytes: an entry is encoded once, when
+        # it is first persisted, however often its key is written
+        self._enc: dict[tuple, bytes] = {}
+
+    @property
+    def entries(self) -> list[dict]:
+        return self._entries
+
+    @entries.setter
+    def entries(self, entries: list[dict]) -> None:
+        """The window replaced from outside (a backfill adopting the
+        primary's): nothing is known of what the store holds of it."""
+        self._entries = entries
+        self._enc.clear()
+        self._whole = True
 
     def add(self, entry: dict) -> None:
         ev = tuple(entry["ev"])
@@ -114,17 +194,22 @@ class PGLog:
         entry["ev"] = ev
         if entry.get("prior") is not None:
             entry["prior"] = tuple(entry["prior"])
-        if self.entries and ev < self.entries[-1]["ev"]:
+        entries = self._entries
+        if entries and ev < entries[-1]["ev"]:
             # late delivery (sub-op resend raced a newer op): insert
             # in ev order — an appended stale entry would regress head
             # (the peering last_update vote) and break the monotonic
             # iteration _trim_rollback and _already_applied rely on
-            idx = len(self.entries)
-            while idx > 0 and self.entries[idx - 1]["ev"] > ev:
+            idx = len(entries)
+            while idx > 0 and entries[idx - 1]["ev"] > ev:
                 idx -= 1
-            self.entries.insert(idx, entry)
+            entries.insert(idx, entry)
         else:
-            self.entries.append(entry)
+            entries.append(entry)
+        # stored keys sort as evs do: a late entry is one more put
+        self._put.add(ev)
+        self._cut.discard(ev)
+        self._enc.pop(ev, None)
         # the version index tracks the NEWEST op per object; a stale
         # entry must not clobber it
         if entry["op"] == "delete":
@@ -139,10 +224,19 @@ class PGLog:
                     ev > self.deleted.get(oid, ZERO_EV):
                 self.objects[oid] = ev
                 self.deleted.pop(oid, None)
-        if len(self.entries) > self.max_entries:
-            cut = len(self.entries) - self.max_entries
-            self.tail = max(self.tail, self.entries[cut - 1]["ev"])
-            self.entries = self.entries[cut:]
+        if len(entries) > self.max_entries:
+            cut = len(entries) - self.max_entries
+            self.tail = max(self.tail, entries[cut - 1]["ev"])
+            self._forget(entries[:cut])
+            self._entries = entries[cut:]
+
+    def _forget(self, gone: list[dict]) -> None:
+        """Entries that left the window: their keys are to go too."""
+        for e in gone:
+            ev = e["ev"]
+            self._put.discard(ev)
+            self._cut.add(ev)
+            self._enc.pop(ev, None)
 
     def entries_since(self, ev: tuple) -> list[dict] | None:
         """Entries strictly newer than `ev`, oldest first — the
@@ -333,7 +427,7 @@ class PGLog:
 
     @property
     def head(self) -> tuple:
-        return self.entries[-1]["ev"] if self.entries else ZERO_EV
+        return self._entries[-1]["ev"] if self._entries else ZERO_EV
 
     def record_recovered(self, ev: tuple, oid: str,
                          shard: int | None = None) -> None:
@@ -357,13 +451,23 @@ class PGLog:
         """Drop (and return, newest first) entries newer than ev.
         Index fixups are the caller's job — it is applying rollbacks."""
         ev = tuple(ev)
-        divergent = [e for e in self.entries if e["ev"] > ev]
-        self.entries = [e for e in self.entries if e["ev"] <= ev]
+        divergent = [e for e in self._entries if e["ev"] > ev]
+        if divergent:
+            self._entries = [e for e in self._entries if e["ev"] <= ev]
+            self._forget(divergent)
         return list(reversed(divergent))
 
+    def _applied(self) -> None:
+        """The transaction the log was last handed to has applied."""
+        self._unconfirmed = False
+
     def encode(self) -> bytes:
-        return denc.dumps((self.entries, self.objects, self.deleted,
-                           self.tail, self.missing))
+        """The log as one blob: what the tools and the tests pass
+        around, and what a store written before the keyed form holds
+        (`load_log` reads it; nothing writes it to a store)."""
+        return denc.dumps((self._entries, dict(self.objects),
+                           dict(self.deleted), self.tail,
+                           dict(self.missing)))
 
     @staticmethod
     def decode(blob: bytes,
@@ -382,16 +486,135 @@ class PGLog:
             log.tail = tuple(entries[0]["ev"])
         else:
             log.tail = ZERO_EV
-        log.entries = []
         for e in entries:
             e = dict(e)
             e["ev"] = tuple(e["ev"])
             if e.get("prior") is not None:
                 e["prior"] = tuple(e["prior"])
-            log.entries.append(e)
-        log.objects = {o: tuple(v) for o, v in objects.items()}
-        log.deleted = {o: tuple(v) for o, v in deleted.items()}
+            log._entries.append(e)
+        log.objects = _Index((o, tuple(v)) for o, v in objects.items())
+        log.deleted = _Index((o, tuple(v)) for o, v in deleted.items())
         if len(fields) > 4:
-            log.missing = {o: tuple(v) for o, v in fields[4].items()}
+            log.missing = _Index((o, tuple(v))
+                                 for o, v in fields[4].items())
+        # whatever store this blob came from holds none of its keys
+        log._whole = True
         return log
 
+
+# -- the stored form: keys under _pgmeta's omap ----------------------------
+
+
+def _ev_bytes(ev: tuple) -> bytes:
+    return b"%d.%d" % (ev[0], ev[1])
+
+
+def _ev_from(blob: bytes) -> tuple:
+    epoch, _, v = blob.partition(b".")
+    return (int(epoch), int(v))
+
+
+def _entry_bytes(log: PGLog, e: dict) -> bytes:
+    blob = log._enc.get(e["ev"])
+    if blob is None:
+        blob = log._enc[e["ev"]] = denc.dumps(e)
+    return blob
+
+
+def persist_log(log: PGLog, store, cid: str, txn) -> tuple[int, int, bool]:
+    """Put what changed in `log` since it was last persisted into
+    `txn`, as keys of <cid>/_pgmeta's omap: the log rides in the
+    transaction of the data it describes, applied or not with it.
+    Returns (keys written, their bytes, whether every key was).
+
+    A write's share is its entry and its oid's index key, and at the
+    bound the new tail and the cut entry's key besides, whatever the
+    log holds.  Every key is written anew, over a clean slate, where
+    the log does not know what the store holds: read from the old
+    blob (which goes in the same transaction), its entries replaced
+    from outside, or handed to a transaction that was never seen to
+    apply.
+
+    The order is what a commit torn at a row boundary leaves behind:
+    index keys, then the tail, then entries oldest first, so that a
+    log entry never lands without what it claims."""
+    whole = log._whole or log._unconfirmed
+    sets: dict[str, bytes] = {}
+    rms: set[str] = {entry_key(ev) for ev in log._cut}
+    for prefix, name in INDEX_PREFIXES:
+        index = getattr(log, name)
+        for oid in (index.touched.union(index) if whole
+                    else index.touched):
+            ev = index.get(oid)
+            if ev is None:
+                rms.add(prefix + oid)
+            else:
+                sets[prefix + oid] = _ev_bytes(ev)
+        index.touched.clear()
+    if whole or log.tail != log._stored_tail:
+        sets[LOG_META_KEY] = denc.dumps({"v": LOG_FORMAT,
+                                         "tail": log.tail})
+        log._stored_tail = log.tail
+    if whole:
+        for e in log._entries:
+            sets[entry_key(e["ev"])] = _entry_bytes(log, e)
+        try:
+            rms.update(k for k in store.omap_get(cid, PGMETA)
+                       if _is_log_key(k))
+        except StoreError:
+            pass        # no _pgmeta yet: nothing to clear
+    elif log._put:
+        head = log._entries[-1] if log._entries else None
+        if head is not None and log._put == {head["ev"]}:
+            sets[entry_key(head["ev"])] = _entry_bytes(log, head)
+        else:                       # a merge, a late entry
+            put = log._put
+            for e in log._entries:
+                if e["ev"] in put:
+                    sets[entry_key(e["ev"])] = _entry_bytes(log, e)
+    rms.difference_update(sets)
+    log._put.clear()
+    log._cut.clear()
+    log._whole = False
+    log._unconfirmed = True
+    # (even when empty: it makes the _pgmeta the lines below need)
+    txn.omap_setkeys(cid, PGMETA, sets)
+    if rms:
+        txn.omap_rmkeys(cid, PGMETA, sorted(rms))
+    if whole:
+        txn.rmattr(cid, PGMETA, LOG_ATTR)   # the old blob, if it is there
+    txn.register_on_applied(log._applied)
+    return len(sets), sum(map(len, sets.values())), whole
+
+
+def load_log(store, cid: str,
+             max_entries: int | None = None) -> PGLog | None:
+    """The log a store holds for a PG: its keys where they are, else
+    the old blob (whose first persist lays the keys down and removes
+    it), else None."""
+    try:
+        omap = store.omap_get(cid, PGMETA)
+    except StoreError:
+        return None
+    if LOG_META_KEY not in omap:
+        try:
+            blob = store.getattr(cid, PGMETA, LOG_ATTR)
+        except StoreError:
+            return None
+        return PGLog.decode(blob, max_entries=max_entries)
+    meta = denc.loads(omap[LOG_META_KEY])
+    if meta["v"] > LOG_FORMAT:
+        raise denc.DencError(f"{cid}: pg log format {meta['v']} is "
+                             f"newer than this code's {LOG_FORMAT}")
+    log = PGLog(max_entries=max_entries)
+    log.tail = log._stored_tail = tuple(meta["tail"])
+    indexes = {p: getattr(log, name) for p, name in INDEX_PREFIXES}
+    for key in sorted(omap):
+        prefix = key[:4]            # the four prefixes are as long
+        if prefix == ENTRY_PREFIX:
+            e = denc.loads(omap[key])
+            log._entries.append(e)
+            log._enc[e["ev"]] = omap[key]
+        elif prefix in indexes:
+            dict.__setitem__(indexes[prefix], key[4:], _ev_from(omap[key]))
+    return log

@@ -79,7 +79,7 @@ from typing import Iterable
 
 import numpy as np
 
-from ..kv.keyvaluedb import KeyValueDB, KVTransaction
+from ..kv.keyvaluedb import KeyValueDB, KVTransaction, after_prefix
 from ..kv.memdb import MemDB
 from ..kv.sqlitedb import SqliteDB
 from ..ops.crc32c import crc32c, crc32c_batch
@@ -942,9 +942,8 @@ class BlockStore(ObjectStore):
     def _omap_items(self, st: dict, cid: str, oid: str) -> dict[str, bytes]:
         prefix = f"{cid}/{oid}/"
         out = {}
-        for key, val in self.db.iterate(P_OMAP, prefix):
-            if not key.startswith(prefix):
-                break
+        for key, val in self.db.iterate(P_OMAP, prefix,
+                                        after_prefix(prefix)):
             out[key[len(prefix):]] = val
         for key, val in st["omaps"].items():
             if key.startswith(prefix):
@@ -1157,9 +1156,8 @@ class BlockStore(ObjectStore):
             self._committed_onode(cid, oid)
             prefix = f"{cid}/{oid}/"
             out = {}
-            for key, val in self.db.iterate(P_OMAP, prefix):
-                if not key.startswith(prefix):
-                    break
+            for key, val in self.db.iterate(P_OMAP, prefix,
+                                            after_prefix(prefix)):
                 out[key[len(prefix):]] = val
             return out
 

@@ -48,7 +48,7 @@ from ..utils import copyaudit, denc
 from ..utils.dout import DoutLogger
 from ..utils.faults import CrashPoint
 from .memstore import MemStore
-from .objectstore import StoreError, Transaction
+from .objectstore import StoreError, Transaction, note_span_counts
 
 _REC = struct.Struct("<QQI")     # record header: len, seq, payload crc
 _SNAP_CRC = struct.Struct("<I")
@@ -190,12 +190,14 @@ class JournalFileStore(MemStore):
             # invariant replay reconstructs state by.  (HBM stripe
             # cache coherence scan runs before the apply; see
             # ObjectStore.queue_transactions for that rationale.)
-            with self._apply_lock, optracker.span("store_apply"):
+            with self._apply_lock, \
+                    optracker.span("store_apply") as late:
                 self._check_frozen()
                 for t in txns:
                     hbm_cache.note_store_txn(t.ops)
                 for i, t in enumerate(txns):
                     self._do_transaction(t)
+                    note_span_counts(late, t)
                     if i == 0:
                         # crash site: journaled, partially applied to
                         # the (volatile) state, never acked — replay

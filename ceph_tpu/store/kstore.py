@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import threading
 
-from ..kv.keyvaluedb import KeyValueDB
+from ..kv.keyvaluedb import KeyValueDB, after_prefix
 from ..kv.memdb import MemDB
 from ..kv.sqlitedb import SqliteDB
 from ..utils import denc
@@ -158,9 +158,8 @@ class KStore(ObjectStore):
         writes — later ops (remove/clone) must see earlier ones."""
         prefix = f"{cid}/{oid}/"
         out = {}
-        for key, val in self.db.iterate(P_OMAP, prefix):
-            if not key.startswith(prefix):
-                break
+        for key, val in self.db.iterate(P_OMAP, prefix,
+                                        after_prefix(prefix)):
             out[key[len(prefix):]] = val
         for key, val in st["omaps"].items():
             if key.startswith(prefix):
@@ -366,9 +365,8 @@ class KStore(ObjectStore):
             self._head(cid, oid)
             prefix = f"{cid}/{oid}/"
             out = {}
-            for key, val in self.db.iterate(P_OMAP, prefix):
-                if not key.startswith(prefix):
-                    break
+            for key, val in self.db.iterate(P_OMAP, prefix,
+                                            after_prefix(prefix)):
                 out[key[len(prefix):]] = val
             return out
 

@@ -35,6 +35,8 @@ class Transaction:
         self.ops: list[tuple] = []
         self.on_applied: list[Callable] = []
         self.on_commit: list[Callable] = []
+        # counts its maker wants on the span of its apply
+        self.span_counts: dict[str, int] = {}
 
     # -- collection ops ----------------------------------------------------
 
@@ -125,7 +127,14 @@ class Transaction:
         self.ops.extend(other.ops)
         self.on_applied.extend(other.on_applied)
         self.on_commit.extend(other.on_commit)
+        note_span_counts(self.span_counts, other)
         return self
+
+    def note_span(self, name: str, n: int) -> None:
+        """Add `n` to the arg `name` of the `store_apply` span this
+        transaction is applied under (what it carries, as its maker
+        counts it: the store does not know a log key from another)."""
+        self.span_counts[name] = self.span_counts.get(name, 0) + n
 
     def register_on_applied(self, cb: Callable) -> None:
         self.on_applied.append(cb)
@@ -136,6 +145,12 @@ class Transaction:
     @property
     def empty(self) -> bool:
         return not self.ops
+
+
+def note_span_counts(late: dict, txn: Transaction) -> None:
+    """An applied transaction's counts join its apply span's args."""
+    for name, n in txn.span_counts.items():
+        late[name] = late.get(name, 0) + n
 
 
 class ObjectStore(abc.ABC):
@@ -250,7 +265,7 @@ class ObjectStore(abc.ABC):
         """
         from ..ops import hbm_cache
         from ..utils import optracker
-        with self._apply_lock, optracker.span("store_apply"):
+        with self._apply_lock, optracker.span("store_apply") as late:
             self._check_frozen()
             self._maybe_crash("store.pre_apply")
             # coherence scan BEFORE the mutation applies: a concurrent
@@ -262,6 +277,7 @@ class ObjectStore(abc.ABC):
                 hbm_cache.note_store_txn(t.ops)
             for t in txns:
                 self._do_transaction(t)
+                note_span_counts(late, t)
             # tick bumps AFTER the apply: a concurrent listing taken
             # mid-apply carries the OLD tick and is invalidated by
             # this bump — bumping first would let a pre-apply listing

@@ -24,7 +24,7 @@ import json
 import sys
 
 from ..osd.pglog import (BACKFILL_ATTR, LES_ATTR, PGLog,
-                         decode_backfill_attr)
+                         decode_backfill_attr, load_log)
 from ..store import create as store_create
 from ..store.objectstore import StoreError
 
@@ -36,15 +36,13 @@ def _open_store(path: str):
 
 
 def load_pg_state(store, pgid: str) -> dict:
-    """Decode one pg's persisted peering state: the PGLog blob plus
+    """Decode one pg's persisted peering state: the PGLog (keys, or
+    the blob of a store not written since the keyed form) plus
     the last_backfill watermark and last_epoch_started stamps."""
     cid = f"pg_{pgid}"
     if not store.collection_exists(cid):
         raise StoreError(2, f"no collection {cid}")
-    try:
-        log = PGLog.decode(store.getattr(cid, "_pgmeta", "log"))
-    except StoreError:
-        log = PGLog()
+    log = load_log(store, cid) or PGLog()
     last_backfill = None        # None == complete
     try:
         last_backfill = decode_backfill_attr(

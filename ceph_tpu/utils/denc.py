@@ -158,6 +158,10 @@ def _encode(obj: Any, out: bytearray) -> None:
     else:
         klass = type(obj)
         if _registry.get(klass.__name__) is not klass:
+            if isinstance(obj, dict):
+                # a plain subclass (the pg log's tracking index) is
+                # the dict it holds, and decodes as one
+                return _encode(dict(obj), out)
             raise DencError(
                 f"type {klass.__name__} is not denc-encodable "
                 f"(register with @denc_type)")

@@ -62,6 +62,10 @@ class TestCounterSchema:
            "peering_auth_catchups", "peering_getlog_merges",
            "peering_divergent_rewinds", "peering_divergent_entries",
            "recovery_pushes", "recovery_bytes", "backfill_resumes",
+           # the PG log as keys: keys and bytes handed to
+           # transactions, logs that had to be written whole
+           "pglog_keys_written", "pglog_bytes_written",
+           "pglog_full_rewrites",
            # serve-during-repair: ops parked on a missing object's
            # recovery pull, their resumes, and front-of-queue pull
            # promotions (blocked == unblocked at quiesce)
@@ -107,6 +111,22 @@ class TestCounterSchema:
         osd = next(iter(cluster.osds.values()))
         assert set(osd.perf._schema) == self.OSD
         assert set(osd.msgr.perf._schema) == self.MSGR
+
+    def test_pglog_counters_move_with_writes(self, cluster, io):
+        """A write hands its log keys to its transaction on every
+        copy: a few keys and a few hundred bytes each, and on a
+        healthy cluster no log is ever written whole."""
+        def total(key):
+            return sum(o.perf.value(key) for o in cluster.osds.values())
+        keys0, bytes0 = (total("pglog_keys_written"),
+                         total("pglog_bytes_written"))
+        for i in range(4):
+            io.write_full(f"pglog-ctr-{i}", b"x" * 64)
+        copies = 4 * len(cluster.osds)
+        assert copies * 2 <= total("pglog_keys_written") - keys0 \
+            <= copies * 4
+        assert 0 < total("pglog_bytes_written") - bytes0 < copies * 512
+        assert total("pglog_full_rewrites") == 0
 
     def test_client_schema_complete(self, cluster, io):
         """The client's `perf dump`: the objecter block's counters plus

@@ -208,8 +208,10 @@ class TestPglogDump:
     log-authoritative peering debug surface for wedged soaks)."""
 
     @staticmethod
-    def _mk(path, entries, watermark=None, les=0):
-        from ceph_tpu.osd.pglog import PGLog
+    def _mk(path, entries, watermark=None, les=0, form="keys"):
+        """A stopped store holding one PG's log: as the keys an OSD
+        writes, or as the blob a store from before them holds."""
+        from ceph_tpu.osd.pglog import PGLog, persist_log
         from ceph_tpu.store import create as store_create
         from ceph_tpu.store.objectstore import Transaction
         s = store_create("filestore", str(path))
@@ -219,8 +221,11 @@ class TestPglogDump:
         for e in entries:
             log.add(dict(e))
         txn = (Transaction().create_collection("pg_7.0")
-               .touch("pg_7.0", "_pgmeta")
-               .setattr("pg_7.0", "_pgmeta", "log", log.encode()))
+               .touch("pg_7.0", "_pgmeta"))
+        if form == "keys":
+            persist_log(log, s, "pg_7.0", txn)
+        else:
+            txn.setattr("pg_7.0", "_pgmeta", "log", log.encode())
         if watermark is not None:
             txn.setattr("pg_7.0", "_pgmeta", "backfilling",
                         b"@" + watermark.encode())
@@ -230,7 +235,8 @@ class TestPglogDump:
         s.apply_transaction(txn)
         s.umount()
 
-    def test_dump_divergence_and_watermark(self, tmp_path):
+    @pytest.mark.parametrize("form", ["keys", "blob"])
+    def test_dump_divergence_and_watermark(self, tmp_path, form):
         import json
         from ceph_tpu.tools import pglog_dump
 
@@ -240,10 +246,12 @@ class TestPglogDump:
 
         self._mk(tmp_path / "a",
                  [e((1, 1), "x"), e((1, 2), "y"), e((2, 3), "z")],
-                 les=2)
+                 les=2, form=form)
+        # (the peer always in the other form: the tool reads either)
         self._mk(tmp_path / "b",
                  [e((1, 1), "x"), e((1, 2), "y"), e((1, 3), "w")],
-                 watermark="mmm", les=1)
+                 watermark="mmm", les=1,
+                 form="blob" if form == "keys" else "keys")
         rc, out = run_tool(pglog_dump.main,
                            ["--data-path", str(tmp_path / "a"),
                             "--pgid", "7.0", "--entries"])
