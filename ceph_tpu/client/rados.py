@@ -307,6 +307,44 @@ class IoCtx:
                              f"op on {oid}: errno {-reply.result}")
         return reply
 
+    # -- what the pool asks of a writer (librados
+    #    rados_ioctx_pool_requires_alignment2 / _required_alignment2) --------
+
+    def pool_requires_alignment(self) -> bool:
+        """An erasure-coded pool takes appends in whole stripes (but
+        for an object's last)."""
+        return self.pool_required_alignment() > 0
+
+    def pool_required_alignment(self) -> int:
+        """The pool's stripe width (k x stripe_unit of its profile) for
+        an erasure-coded pool, 0 for a replicated one."""
+        m = self.rados.monc.osdmap
+        pool = m.pools[self.pool_id]
+        if not pool.is_erasure:
+            return 0
+        profile = m.ec_profiles.get(pool.erasure_code_profile or "", {})
+        from ..osd.ecutil import DEFAULT_STRIPE_UNIT
+        return int(profile.get("k", 2)) * int(
+            profile.get("stripe_unit", DEFAULT_STRIPE_UNIT))
+
+    # -- compound ops (librados ObjectWriteOperation / ObjectReadOperation) --
+
+    def operate(self, oid: str, ops: list, want_version: bool = False):
+        """One op vector on one object, applied (or read) as a whole:
+        e.g. `[("cmpxattr", name, value), ("writefull", data),
+        ("setxattr", name, value), ...]`, which writes only where the
+        guard holds (ECANCELED otherwise), or `[("getxattrs",),
+        ("read", 0, 0)]`.  Returns the ops' outputs; with
+        `want_version`, (outputs, the version the write made the
+        object: the PG's, so two writes of one object compare)."""
+        reply = self._op(oid, [
+            (op[0], wrap_payload(op[1])) + tuple(op[2:])
+            if op[0] in ("writefull", "append") else tuple(op)
+            for op in ops])
+        if want_version:
+            return reply.outdata, tuple(reply.version)
+        return reply.outdata
+
     # -- self-managed snapshots --------------------------------------------
 
     def set_snap_context(self, seq: int, snaps: list[int]) -> None:

@@ -81,6 +81,18 @@ class MethodContext:
             return omap
         return {k: omap[k] for k in keys if k in omap}
 
+    def omap_get_vals(self, start_after: str = "", prefix: str = "",
+                      max_return: int = 0) -> dict:
+        """An ordered slice of the omap: keys after `start_after` that
+        begin with `prefix`, `max_return` at most (0: all)."""
+        from ..store.objectstore import StoreError
+        try:
+            return self._store.omap_get_vals(
+                self._pg.cid, self.oid, start_after=start_after,
+                prefix=prefix, max_return=max_return)
+        except StoreError:
+            return {}
+
     # -- writes (WR methods only) ------------------------------------------
 
     def _wr(self):
@@ -175,4 +187,4 @@ def cls_method(cls: str, method: str, flags: int):
 
 # built-in classes (the reference preloads its cls .so set at OSD boot)
 from . import (hello, kvstore, lock, log, numops, rbd,  # noqa: E402,F401
-               refcount, timeindex, version)  # noqa: E402,F401
+               refcount, rgw, timeindex, version)  # noqa: E402,F401

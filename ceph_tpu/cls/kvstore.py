@@ -121,19 +121,3 @@ def update_index(ctx: MethodContext) -> None:
         ctx.omap_rm([k for k in req["rm"] if k in present])
     if req.get("set"):
         ctx.omap_set({k: bytes(v) for k, v in req["set"].items()})
-
-
-@cls_method("kvstore", "append_log", WR)
-def append_log(ctx: MethodContext) -> bytes:
-    """{"entry": bytes} -> seq.  Atomic sequenced append (the cls_rgw
-    bilog/cls_log pattern): seq allocation and the entry write happen
-    in ONE in-OSD op, so concurrent writers can neither collide on a
-    seq nor clobber each other's entries."""
-    req = denc.loads(ctx.input)
-    if not ctx.exists():
-        ctx.create()
-    cur = ctx.omap_get(["\x00seq"])
-    seq = int(cur.get("\x00seq", b"0")) + 1
-    ctx.omap_set({"\x00seq": str(seq).encode(),
-                  f"{seq:020d}": bytes(req["entry"])})
-    return denc.dumps(seq)

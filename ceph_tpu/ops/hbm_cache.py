@@ -45,11 +45,25 @@ state.
 
 Capacity is bounded by ``osd_ec_hbm_cache_bytes`` (LRU on committed
 entries); 0 disables the cache entirely.
+
+A size mix runs on a CLOSED set of device programs.  An entry is a
+list of SEGMENTS, each a pair of device arrays of a power-of-two row
+bucket with the first resident row and the true row count kept beside
+it (:class:`Segment`): the item's own dispatch arrays where it rode
+alone, a ``dynamic_slice`` to the item's bucket with the start an
+operand where it shared a coalesced dispatch (:func:`item_arrays`: one
+program a (batch bucket, item bucket) pair), an uploaded tail where an
+append extended the entry (:meth:`HbmStripeCache.append_through`: a
+transfer, no program).  The CRC fold that checks a served read runs at
+a segment's bucket and the pad rows are left out of the comparison
+(one program a bucket).  ``stats()["programs"]`` counts the distinct
+programs acquired since boot: flat after warm-up is the sign.
 """
 
 from __future__ import annotations
 
 import ast
+import functools
 import threading
 import time
 from collections import OrderedDict
@@ -58,6 +72,9 @@ import numpy as np
 
 DEFAULT_CAPACITY = 64 << 20
 MAX_PENDING = 64
+# the most segments an entry grows to by appends: one more invalidates
+# it (a read fetches segment by segment)
+MAX_SEGMENTS = 8
 
 # per-shard version xattr (osd/pglog.py VER_KEY): the store-txn
 # coherence scan parses it to recognize same-version fan-out writes.
@@ -85,6 +102,15 @@ def _parse_ver(blob: bytes) -> tuple | None:
 # (stripes' shape, device) whose CRC program is compiled: a read never
 # compiles, it serves unchecked until the staging's warm-up is through
 _verify_ready: set[tuple] = set()
+# every distinct device program this module acquired since boot: the
+# CRC folds above, the item slices of `item_arrays`
+_programs: set[tuple] = set()
+_plock = threading.Lock()
+
+
+def _note_program(key: tuple) -> None:
+    with _plock:
+        _programs.add(key)
 
 
 def _device_of(arr):
@@ -107,9 +133,9 @@ def _verify_fn(dev_data):
 
 
 def warm_verify(shape: tuple, device) -> None:
-    """Compile the CRC fold a cache-served read runs over an entry of
-    (S, k, L) uint8 stripes on `device` (the pipeline calls this on a
-    warm thread when an item of S rows is first staged)."""
+    """Compile the CRC fold a cache-served read runs over a segment of
+    (bucket, k, L) uint8 stripes on `device` (the pipeline calls this
+    on a warm thread when an item of that bucket is first staged)."""
     key = (tuple(shape), device)
     if key in _verify_ready or len(shape) != 3 or device is None:
         return
@@ -118,6 +144,64 @@ def warm_verify(shape: tuple, device) -> None:
     fn = ec_kernels.make_crc_fn(int(shape[-1]))
     np.asarray(fn(jnp.zeros(shape, dtype=jnp.uint8, device=device)))
     _verify_ready.add(key)
+    _note_program(("verify",) + key)
+
+
+@functools.lru_cache(maxsize=None)
+def _slice_fn(bucket: int):
+    """Jitted: `bucket` rows of each of two batch arrays from row
+    `start` on, the start an operand (one program a pair of batch
+    shapes and bucket, whatever the offset)."""
+    import jax
+
+    def cut(data, parity, start):
+        return (jax.lax.dynamic_slice_in_dim(data, start, bucket, 0),
+                jax.lax.dynamic_slice_in_dim(parity, start, bucket, 0))
+
+    return jax.jit(cut)
+
+
+def item_arrays(dev_in, dev_parity, off: int, n: int, bucket: int):
+    """What the cache keeps of one item of a dispatch: (data, parity,
+    row0) where the item's `n` rows lie at rows [row0, row0 + n) of
+    both arrays.  `bucket` is the pipeline's row bucket of n.  An item
+    whose bucket is the batch's keeps the dispatch's own arrays (no
+    program); any other is cut out at its bucket, where a start the
+    slice would clamp is clamped here and the difference kept as
+    row0."""
+    rows = int(dev_in.shape[0])
+    if bucket >= rows:
+        return dev_in, dev_parity, off
+    if _device_of(dev_in) is None:      # host arrays: numpy views
+        return dev_in[off: off + n], dev_parity[off: off + n], 0
+    start = min(off, rows - bucket)
+    _note_program(("slice", tuple(dev_in.shape), tuple(dev_parity.shape),
+                   bucket, _device_of(dev_in)))
+    data, parity = _slice_fn(bucket)(dev_in, dev_parity, np.int32(start))
+    return data, parity, off - start
+
+
+def warm_item(like: tuple, device, bucket: int, batches) -> None:
+    """Compile what staging and serving an item of `bucket` rows needs
+    on `device`: the CRC fold at the bucket, and the slice out of each
+    of the batch buckets `batches` above it.  `like` is ((k, L), dtype),
+    ((m, L), dtype) of the data and parity arrays."""
+    import jax.numpy as jnp
+    (tail_d, dtype_d), (tail_p, dtype_p) = like
+    if np.dtype(dtype_d) == np.uint8:
+        warm_verify((bucket,) + tuple(tail_d), device)
+    for rows in batches:
+        if rows <= bucket:
+            continue
+        d = jnp.zeros((rows,) + tuple(tail_d), dtype=dtype_d, device=device)
+        p = jnp.zeros((rows,) + tuple(tail_p), dtype=dtype_p, device=device)
+        for a in item_arrays(d, p, rows - bucket, bucket, bucket)[:2]:
+            a.block_until_ready()
+
+
+def programs() -> int:
+    with _plock:
+        return len(_programs)
 
 
 class CacheIntent:
@@ -135,34 +219,60 @@ class CacheIntent:
         self.chunk_size = int(chunk_size)
 
 
+class Segment:
+    """Part of an entry: `rows` stripes at rows [row0, row0 + rows) of
+    a (bucket, k, L) data array and a (bucket, m, L) parity array,
+    both on one chip (or both numpy, for a host-served stage)."""
+
+    __slots__ = ("data", "parity", "row0", "rows")
+
+    def __init__(self, data, parity, row0: int, rows: int):
+        self.data, self.parity = data, parity
+        self.row0, self.rows = int(row0), int(rows)
+
+    @property
+    def nbytes(self) -> int:
+        return int(np.prod(self.data.shape)) + \
+            int(np.prod(self.parity.shape))
+
+    def cut(self, rows: int) -> "Segment":
+        """The first `rows` stripes of this segment (the arrays are
+        shared: nothing moves)."""
+        return Segment(self.data, self.parity, self.row0, rows)
+
+
 class CacheEntry:
     """One object's encoded stripes, device-resident.
 
-    dev_data (S, k, L) is the uploaded data batch, dev_parity
-    (S, m, L) the on-device encode output — both still on the chip
-    of pipeline lane `lane`; crcs (S, k+m) uint32 are the fused
-    kernel's per-stripe chunk CRCs (host-side, 4 bytes per chunk)."""
+    `segs` hold the uploaded data stripes and the on-device encode
+    output, in order, still on the chip of pipeline lane `lane`; crcs
+    (S, k+m) uint32 are the fused kernel's per-stripe chunk CRCs
+    (host-side, 4 bytes per chunk), of the true rows alone."""
 
     __slots__ = ("cid", "oid", "version", "size", "chunk_size", "k",
-                 "m", "dev_data", "dev_parity", "crcs", "lane",
-                 "nbytes", "committed")
+                 "m", "segs", "crcs", "lane", "nbytes", "committed")
 
     def __init__(self, intent: CacheIntent, lane: int, dev_data,
-                 dev_parity, crcs: np.ndarray):
+                 dev_parity, crcs: np.ndarray, row0: int = 0,
+                 segs: list | None = None):
+        crcs = np.asarray(crcs, dtype=np.uint32)
+        if segs is None:
+            # what a write stages: one segment, the stripes from
+            # `row0` on, as many as `crcs` has
+            segs = [Segment(dev_data, dev_parity, row0, crcs.shape[0])]
         self.cid = intent.cid
         self.oid = intent.oid
         self.version = intent.version
         self.size = intent.size
         self.chunk_size = intent.chunk_size
-        self.k = int(dev_data.shape[1])
-        self.m = int(dev_parity.shape[1])
-        self.dev_data = dev_data
-        self.dev_parity = dev_parity
-        self.crcs = np.asarray(crcs, dtype=np.uint32)
+        self.segs = list(segs)
+        self.k = int(segs[0].data.shape[1])
+        self.m = int(segs[0].parity.shape[1])
+        self.crcs = crcs
+        if sum(g.rows for g in self.segs) != self.crcs.shape[0]:
+            raise ValueError("segments and crcs disagree on the rows")
         self.lane = lane
-        self.nbytes = (int(np.prod(dev_data.shape))
-                       + int(np.prod(dev_parity.shape))
-                       + self.crcs.nbytes)
+        self.nbytes = sum(g.nbytes for g in self.segs) + self.crcs.nbytes
         self.committed = False
 
     @property
@@ -177,48 +287,64 @@ class CacheEntry:
         data stripes (None if the device buffers are gone, or if the
         stripes no longer have the CRCs they were written with: the
         entry is dropped and the caller reads the shards).  Returns a
-        zero-copy BufferList VIEW over the fetched array — the D2H
+        zero-copy BufferList VIEW over the fetched arrays — the D2H
         fetch is the only materialization a cache-served read pays."""
+        from ..utils.bufferlist import BufferList
+        rope, left, at = BufferList(), self.size, 0
         try:
-            # the chip folds the stripes' CRCs while they are copied out
-            fn = _verify_fn(self.dev_data)
+            # the chip folds the stripes' CRCs while they are copied
+            # out: at each segment's bucket, the pad rows left out of
+            # the comparison
+            fns = [_verify_fn(g.data) for g in self.segs]
+            check = all(fn is not None for fn in fns)
             t0 = time.monotonic()
-            folded = fn(self.dev_data) if fn is not None else None
-            arr = np.ascontiguousarray(
-                np.asarray(self.dev_data, dtype=np.uint8))
-            get().count_d2h(arr.nbytes)
-            if folded is not None:
+            folded = [fn(g.data) for fn, g in zip(fns, self.segs)] \
+                if check else None
+            arrs = [np.ascontiguousarray(np.asarray(g.data,
+                                                    dtype=np.uint8))
+                    for g in self.segs]
+            get().count_d2h(sum(a.nbytes for a in arrs))
+            if check:
                 t1 = time.monotonic()
-                same = np.array_equal(np.asarray(folded),
-                                      self.crcs[:, : self.k])
+                same = True
+                for f, g in zip(folded, self.segs):
+                    same = same and np.array_equal(
+                        np.asarray(f)[g.row0: g.row0 + g.rows],
+                        self.crcs[at: at + g.rows, : self.k])
+                    at += g.rows
                 from ..utils import optracker
-                optracker.add_span("ec.device_compute", t0, t1,
-                                   stripes=self.stripes,
-                                   padded=self.stripes)
+                optracker.add_span(
+                    "ec.device_compute", t0, t1, stripes=self.stripes,
+                    padded=sum(int(g.data.shape[0]) for g in self.segs))
                 optracker.add_span("ec.d2h", t1, time.monotonic())
                 if not get().count_verified(self, same):
                     return None
+            for a, g in zip(arrs, self.segs):
+                view = memoryview(
+                    a[g.row0: g.row0 + g.rows].reshape(-1))[:left]
+                if len(view):
+                    rope.append(view)
+                left -= len(view)
         except Exception:
             return None
-        from ..utils.bufferlist import BufferList
-        rope = BufferList(memoryview(arr.reshape(-1))[: self.size])
         get().count_read_hit_bytes(self.size)
         return rope
 
     def shard_bytes(self, shard: int) -> bytes | None:
         """One shard file's bytes (chunk `shard` of every stripe),
-        fetched D2H — only this shard's rows cross the boundary."""
+        fetched D2H — only this shard's column crosses the boundary."""
         try:
-            if shard < self.k:
-                arr = np.asarray(self.dev_data[:, shard],
-                                 dtype=np.uint8)
-            else:
-                arr = np.asarray(self.dev_parity[:, shard - self.k],
-                                 dtype=np.uint8)
+            rows = []
+            for g in self.segs:
+                src, col = (g.data, shard) if shard < self.k \
+                    else (g.parity, shard - self.k)
+                arr = np.asarray(src[:, col], dtype=np.uint8)
+                get().count_d2h(arr.nbytes)
+                rows.append(arr[g.row0: g.row0 + g.rows])
+            return (rows[0] if len(rows) == 1
+                    else np.concatenate(rows)).tobytes()
         except Exception:
             return None
-        get().count_d2h(arr.nbytes)
-        return arr.tobytes()
 
 
 class HbmStripeCache:
@@ -259,17 +385,21 @@ class HbmStripeCache:
     # -- write path --------------------------------------------------------
 
     def stage(self, intent: CacheIntent, lane: int, dev_data,
-              dev_parity, crcs: np.ndarray) -> None:
+              dev_parity, crcs: np.ndarray, row0: int = 0) -> None:
         """Pipeline collect-time staging: the entry exists but is NOT
         servable until the producer commits it (shard bytes on disk).
         `lane` is the index of the pipeline lane whose chip holds the
-        arrays."""
+        arrays; the item's stripes are their rows from `row0` on, as
+        many as `crcs` has."""
         if self.capacity <= 0:
             return
         try:
-            ent = CacheEntry(intent, lane, dev_data, dev_parity, crcs)
+            self._stage_entry(CacheEntry(intent, lane, dev_data,
+                                         dev_parity, crcs, row0))
         except Exception:
             return
+
+    def _stage_entry(self, ent: CacheEntry) -> None:
         if ent.nbytes > self.capacity:
             return
         key = (ent.cid, ent.oid)
@@ -310,17 +440,21 @@ class HbmStripeCache:
         from the resident whole-object stripes plus the tail encode's
         (S_tail, k, L) data / (S_tail, m, L) parity stripes — the
         untouched full-stripe prefix never leaves the chip, only the
-        tail crosses.  Stages a PENDING entry at `new_version` (the
-        producer commits once the shard tail bytes are on disk, the
-        same contract as a whole-object write); the store-txn scan
-        then drops the old committed entry (its version is not
-        attested) while the attested pending one survives.
+        tail crosses, as one more SEGMENT: a transfer and no program,
+        whatever the sizes.  The caller pads both tail arrays to the
+        pipeline's row bucket (`tail_crcs` has the true rows alone),
+        whose check the pipeline warmed when the tail was encoded.  Stages a PENDING
+        entry at `new_version` (the producer commits once the shard
+        tail bytes are on disk, the same contract as a whole-object
+        write); the store-txn scan then drops the old committed entry
+        (its version is not attested) while the attested pending one
+        survives.
 
         Returns False — after invalidating, so a stale whole-object
         entry can never outlive the append — when there is no
         resident entry at exactly `old_version` with this geometry,
-        or the device-side concatenation fails; the caller loses
-        nothing but the write-through."""
+        the entry would pass MAX_SEGMENTS, or the upload fails; the
+        caller loses nothing but the write-through."""
         key = (cid, oid)
         with self._lock:
             ent = self._entries.get(key) or self._pending.get(key)
@@ -332,40 +466,34 @@ class HbmStripeCache:
             self.invalidate(cid, oid)
             return False
         try:
-            tail_data = np.ascontiguousarray(tail_data,
-                                             dtype=np.uint8)
-            tail_parity = np.ascontiguousarray(tail_parity,
-                                               dtype=np.uint8)
-            head_d = ent.dev_data[:full_before]
-            head_p = ent.dev_parity[:full_before]
-            dev = _device_of(ent.dev_data)
+            segs, left = [], full_before
+            for g in ent.segs:
+                if left <= 0:
+                    break
+                segs.append(g if g.rows <= left else g.cut(left))
+                left -= segs[-1].rows
+            if len(segs) >= MAX_SEGMENTS:
+                raise ValueError("too many segments")
+            rows = int(np.asarray(tail_crcs).shape[0])
+            td = np.ascontiguousarray(tail_data, dtype=np.uint8)
+            tp = np.ascontiguousarray(tail_parity, dtype=np.uint8)
+            dev = _device_of(ent.segs[0].data)
             if dev is not None:
-                # device-resident entry: upload only the tail and
-                # concatenate ON the chip (the prefix never moves)
+                # device-resident entry: upload only the tail (the
+                # prefix never moves)
                 import jax
-                import jax.numpy as jnp
-                td = jax.device_put(tail_data, dev)
-                tp = jax.device_put(tail_parity, dev)
-                new_d = jnp.concatenate([head_d, td]) \
-                    if full_before else td
-                new_p = jnp.concatenate([head_p, tp]) \
-                    if full_before else tp
-            else:
-                new_d = np.concatenate(
-                    [np.asarray(head_d, dtype=np.uint8), tail_data]) \
-                    if full_before else tail_data
-                new_p = np.concatenate(
-                    [np.asarray(head_p, dtype=np.uint8), tail_parity]) \
-                    if full_before else tail_parity
+                td, tp = jax.device_put(td, dev), jax.device_put(tp, dev)
+            segs.append(Segment(td, tp, 0, rows))
             new_crcs = np.concatenate(
                 [np.asarray(ent.crcs)[:full_before],
                  np.asarray(tail_crcs, dtype=np.uint32)])
+            self._stage_entry(CacheEntry(
+                CacheIntent(cid, oid, tuple(new_version), int(new_size),
+                            chunk_size), ent.lane, None, None, new_crcs,
+                segs=segs))
         except Exception:
             self.invalidate(cid, oid)
             return False
-        intent = CacheIntent(cid, oid, tuple(new_version),
-                             int(new_size), chunk_size)
-        self.stage(intent, ent.lane, new_d, new_p, new_crcs)
         with self._lock:
             self._c["append_throughs"] += 1
         return True
@@ -569,6 +697,7 @@ class HbmStripeCache:
             out["bytes"] = self._bytes
             out["pending_bytes"] = self._pbytes
             out["capacity"] = self.capacity
+        out["programs"] = programs()
         return out
 
     def shrink_to_capacity(self) -> None:

@@ -110,7 +110,7 @@ DEFAULT_MAX_BATCH = 256
 DEFAULT_SPLIT_MIN = 4       # min stripes per per-chip shard of a split
 DEFAULT_SCRUB_WEIGHT = 0.25
 DEFAULT_COST_AWARE = True
-# the most bytes of one batch whose item slices _warm_item_slices
+# the most bytes of one batch whose item slices _warm_item_buckets
 # compiles ahead of serving
 LANE_STAGE_BYTES = 256 << 20
 # dmClock cost normalization for the dispatch-lane tenant picker
@@ -191,7 +191,8 @@ RESULT_TIMEOUT = 120.0
 
 
 def next_bucket(n: int) -> int:
-    """Power-of-two shape bucket for a batch of n stripes."""
+    """Power-of-two shape bucket for a batch of n stripes (what the
+    cache keeps of an item is cut at the item's own bucket)."""
     return 1 << (n - 1).bit_length() if n > 1 else 1
 
 
@@ -514,7 +515,7 @@ class EcDevicePipeline:
         self._qos_scrub = 0
         self._busy = 0                     # dispatches being processed
         self._stalled = False              # collectors wedged: host-only
-        self._slices_warm: set = set()     # see _warm_item_slices
+        self._slices_warm: set = set()     # see _warm_item_buckets
         self._running = False
         self._threads: list = []
         self._c = {
@@ -1402,45 +1403,51 @@ class EcDevicePipeline:
             self._device_failed_fetch(disp, e)
 
     def _stage_cache(self, disp: _Dispatch, outs: tuple) -> None:
-        """Keep cache-tagged items' stripes in HBM: device SLICES of
+        """Keep cache-tagged items' stripes in HBM: the item's rows of
         the already-uploaded input and the already-computed parity —
-        zero extra transfer.  Only row-split group parts skip (an
-        item's rows straddle part boundaries there) — placement cuts
-        cache-tagged batches at item boundaries precisely so their
-        parts arrive here as independent dispatches."""
+        zero extra transfer (`hbm_cache.item_arrays`: the dispatch's
+        own arrays, or a cut at the item's bucket with the offset an
+        operand).  Only row-split group parts skip (an item's rows
+        straddle part boundaries there) — placement cuts cache-tagged
+        batches at item boundaries precisely so their parts arrive
+        here as independent dispatches."""
         if disp.dev_in is None or len(disp.out) < 2 or \
-                not any(it.cache is not None for it in disp.items):
+                hbm_cache.get().capacity <= 0:
             return
-        self._warm_item_slices(disp)
+        self._warm_item_buckets(disp)
+        if not any(it.cache is not None for it in disp.items):
+            return
         off = 0
         for it in disp.items:
             if it.cache is not None:
                 try:
+                    data, parity, row0 = hbm_cache.item_arrays(
+                        disp.dev_in, disp.out[0], off, it.n,
+                        next_bucket(it.n))
                     hbm_cache.get().stage(
-                        it.cache, disp.lane.index,
-                        disp.dev_in[off: off + it.n],
-                        disp.out[0][off: off + it.n],
-                        outs[1][off: off + it.n])
+                        it.cache, disp.lane.index, data, parity,
+                        outs[1][off: off + it.n], row0)
                 except Exception:
                     pass        # cache is an optimization, never a fault
             off += it.n
 
-    def _warm_item_slices(self, disp: _Dispatch) -> None:
-        """The slices above are device programs, one a (batch rows,
-        item rows) pair.  When an item of n rows is first staged,
-        compile, on a warm thread, the pairs that batches of two and
-        more such items will need: otherwise the first dispatch in
-        which two ops coalesce compiles them on the collector, seconds
-        or minutes into serving.  The same thread compiles the CRC
-        fold that checks an entry of n rows when a read is served
-        from it (`hbm_cache.warm_verify`)."""
+    def _warm_item_buckets(self, disp: _Dispatch) -> None:
+        """What the cache keeps of an item is a device program a
+        (batch bucket, item bucket) pair, whatever the item's rows and
+        offset, and the CRC fold that checks a read served from it one
+        a bucket.  When an item of a bucket is first staged, compile
+        both on a warm thread (`hbm_cache.warm_item`): otherwise the
+        first dispatch in which such an item shares a batch compiles
+        on the collector, seconds or minutes into serving.  Untagged
+        items count too: an append's tail encode is one, and
+        `append_through` keeps its rows at that bucket."""
         lane = disp.lane
         if lane.device is None:
             return              # host arrays: numpy views, no program
         like = tuple((tuple(a.shape[1:]), np.dtype(a.dtype))
                      for a in (disp.dev_in, disp.out[0]))
-        keys = {(lane.index, it.n, like) for it in disp.items
-                if it.cache is not None}
+        keys = {(lane.index, next_bucket(it.n), like)
+                for it in disp.items}
         with self._lock:
             keys -= self._slices_warm
             self._slices_warm |= keys
@@ -1450,30 +1457,18 @@ class EcDevicePipeline:
         # LANE_STAGE_BYTES in bytes
         cap = disp.chan.max_coalesce or self.max_batch
         row_bytes = disp.dev_in.nbytes // disp.dev_in.shape[0]
-        todo = sorted({(next_bucket(j * n), n) for _idx, n, _like in keys
-                       for j in range(2, cap // n + 1)
-                       if next_bucket(j * n) * row_bytes
-                       <= LANE_STAGE_BYTES})
-
-        # the data stripes of an item, as a read served from them folds
-        verify = [(n,) + like[0][0] for _idx, n, _like in keys
-                  if like[0][1] == np.uint8]
+        batches = [1 << e for e in range(next_bucket(cap).bit_length())
+                   if (1 << e) <= cap
+                   and (1 << e) * row_bytes <= LANE_STAGE_BYTES]
+        buckets = sorted(b for _idx, b, _like in keys)
 
         def warm():
-            import jax.numpy as jnp
             try:
-                for shape in verify:
-                    hbm_cache.warm_verify(shape, lane.device)
+                for b in buckets:
+                    hbm_cache.warm_item(like, lane.device, b, batches)
             except Exception as e:
-                note_warm_failure(f"cache verify {verify}", e)
-            try:
-                for rows, n in todo:
-                    for tail, dtype in like:
-                        zeros = jnp.zeros((rows,) + tail, dtype=dtype,
-                                          device=lane.device)
-                        zeros[rows - n: rows].block_until_ready()
-            except Exception as e:
-                note_warm_failure(f"item slices {todo}", e)
+                note_warm_failure(f"cache programs of buckets {buckets}",
+                                  e)
 
         start_warm_thread(warm, "ec-slice-warm")
 

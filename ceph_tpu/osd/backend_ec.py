@@ -10,12 +10,15 @@ live in ecutil.py / ops/.
 
 from __future__ import annotations
 
+import time
+
 import numpy as np
 
 from ..crush.map import ITEM_NONE
 from ..erasure.interface import ErasureCodeError
 from ..ops import crc32c as crc_mod
 from ..ops import hbm_cache
+from ..ops import pipeline as ec_pipeline
 from ..store.objectstore import EIO, ENOENT, StoreError, Transaction
 from ..utils import denc, optracker
 from ..utils.bufferlist import BufferList
@@ -109,7 +112,7 @@ class ECBackend:
                 if data is None:
                     data = b""      # create-empty
             elif op[0] in ("delete", "setxattr", "omap_set",
-                           "omap_rm"):
+                           "omap_rm", "cmpxattr"):
                 continue
             else:
                 return "unsupported", None
@@ -119,9 +122,13 @@ class ECBackend:
         codec = self._ec_codec()
         km = codec.get_chunk_count()
         is_delete = any(op[0] == "delete" for op in msg.ops)
-        if not is_delete and \
-                self._ec_try_append(conn, msg, version, reqid, codec):
-            return
+        if not is_delete:
+            if self._ec_try_append(conn, msg, version, reqid, codec):
+                self.osd.perf.inc("ec_appends")
+                return
+            if msg.oid in self.pglog.objects and \
+                    any(op[0] == "append" for op in msg.ops):
+                self.osd.perf.inc("ec_append_fallbacks")
         payload = None
         meta_only = False
         if not is_delete:
@@ -302,6 +309,7 @@ class ECBackend:
         oid = msg.oid
         if oid not in self.pglog.objects or not delta:
             return False
+        t_append = time.monotonic()
         store = self.osd.store
         my_shard = self.role_of(self.osd.whoami)
         soid = shard_oid(oid, my_shard)
@@ -397,14 +405,20 @@ class ECBackend:
             tail_rows = [np.frombuffer(tail_shards[p],
                                        dtype=np.uint8).reshape(-1, L)
                          for p in at]
-            hbm_cache.get().append_through(
+            through = hbm_cache.get().append_through(
                 self.cid, oid, tuple(prior), tuple(version), new_size,
                 L, full_before,
-                np.stack(tail_rows[:k], axis=1),
-                np.stack(tail_rows[k:], axis=1),
+                ec_pipeline.pad_batch(np.stack(tail_rows[:k], axis=1)),
+                ec_pipeline.pad_batch(np.stack(tail_rows[k:], axis=1)),
                 np.asarray(stripe_crcs)[:, at])
         else:
+            through = False
             hbm_cache.get().invalidate(self.cid, oid)
+        # the tail path up to here: the old partial tail read, the tail
+        # encode, the entry extended in HBM (1) or invalidated (0)
+        optracker.add_span("ec.append", t_append, time.monotonic(),
+                           tail_read=int(bool(tail_len)),
+                           through=int(through), rows=S_tail)
         for shard, osd_id in enumerate(self.acting):
             if osd_id == ITEM_NONE:
                 continue
@@ -1072,6 +1086,12 @@ class ECBackend:
                 self.pgid, self.osd._handle_op, conn, msg,
                 lambda: self._ec_read_resume(conn, msg, rd, gather))
 
+        if trk is not None:
+            # the gather can complete on the messenger thread before
+            # this thread is back in `_handle_op`: `execute` ends here,
+            # in front of the wait, and not after its end
+            trk.span_end("execute")
+            msg._exec_token = None
         self._ec_read_fetch(rd, gathered)
 
     def _ec_read_resume(self, conn, msg, rd: "_EcRead", gather) -> None:
@@ -1088,6 +1108,23 @@ class ECBackend:
                 return
             self._ec_read(conn, msg, nxt)
 
+    def _ec_read_attrs(self, msg) -> dict:
+        """{index in the op vector: the answer (or the StoreError)} of
+        its `getxattr` / `getxattrs` ops, from this OSD's own shard."""
+        store = self.osd.store
+        soid = shard_oid(msg.oid, self.role_of(self.osd.whoami))
+        out: dict = {}
+        for idx, op in enumerate(msg.ops):
+            try:
+                if op[0] == "getxattr":
+                    out[idx] = store.getattr(self.cid, soid, "u." + op[1])
+                elif op[0] == "getxattrs":
+                    out[idx] = {k[2:]: v for k, v in store.getattrs(
+                        self.cid, soid).items() if k.startswith("u.")}
+            except StoreError as e:
+                out[idx] = e
+        return out
+
     def _ec_read(self, conn, msg, data=_UNREAD) -> None:
         """Serve a read-class op vector.  What needs the object's
         bytes gathers for them first and PARKS meanwhile (`data` is
@@ -1095,15 +1132,27 @@ class ECBackend:
         if data is _UNREAD and any(op[0] == "read" for op in msg.ops):
             rd = self._ec_read_begin(msg.oid)
             if isinstance(rd, _EcRead):
+                # a compound read (bytes + xattrs, a gateway's head
+                # read) answers ONE version: the xattrs are read now,
+                # with the shards this OSD holds, and not when the
+                # gather comes back, by when a write may have applied
+                msg._ec_attrs = self._ec_read_attrs(msg)
                 self._ec_read_park(conn, msg, rd)
                 return
             data = rd
         out = []
         result = 0
         store = self.osd.store
-        for op in msg.ops:
+        early = getattr(msg, "_ec_attrs", None)
+        if early is None:
+            early = self._ec_read_attrs(msg)
+        for idx, op in enumerate(msg.ops):
             try:
-                if op[0] == "read":
+                if idx in early:
+                    if isinstance(early[idx], StoreError):
+                        raise early[idx]
+                    out.append(early[idx])
+                elif op[0] == "read":
                     if data is None:
                         # an object the log holds and the live shards
                         # do not give is an I/O error (ECBackend
@@ -1135,15 +1184,6 @@ class ECBackend:
                         size = len(whole)
                     out.append({"size": size,
                                 "version": self._obj_version(msg.oid)})
-                elif op[0] == "getxattr":
-                    my = self.role_of(self.osd.whoami)
-                    out.append(store.getattr(
-                        self.cid, shard_oid(msg.oid, my), "u." + op[1]))
-                elif op[0] == "getxattrs":
-                    my = self.role_of(self.osd.whoami)
-                    out.append({k[2:]: v for k, v in store.getattrs(
-                        self.cid, shard_oid(msg.oid, my)).items()
-                        if k.startswith("u.")})
                 elif op[0] == "omap_get":
                     out.append(self.osd.ec_get_omap(self.pgid, msg.oid,
                                                     self.acting))

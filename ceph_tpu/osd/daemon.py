@@ -204,6 +204,11 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      # EC reads whose planned gather did not give the
                      # object and that went on to the widened step
                      .add_u64_counter("ec_read_widened")
+                     # EC appends that took the O(tail) path, and
+                     # `append` ops on an existing object that fell
+                     # back to the whole-object re-encode
+                     .add_u64_counter("ec_appends")
+                     .add_u64_counter("ec_append_fallbacks")
                      # cache tiering, under the reference's names:
                      # promotes, flushes (the agent's and the
                      # operator's) and evicts started, objects marked
@@ -1105,11 +1110,17 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         t_dq = trk.span_end("queue")
         trk.mark_event("dequeued")
         trk.span_begin("execute", _t0=t_dq)   # contiguous: no hole
+        # a read that parks closes this span itself, before it hands
+        # its gather over (pg._ec_read_park), and takes the token away:
+        # the resumed op's `execute` may be open by the time this
+        # thread is back here, and is not this thread's to close
+        msg._exec_token = token = object()
         try:
             with optracker.op_context(trk):
                 run()
         finally:
-            trk.span_end("execute")     # no-op if already finished
+            if getattr(msg, "_exec_token", None) is token:
+                trk.span_end("execute")     # no-op if already finished
             if not isinstance(msg, MOSDOp):
                 trk.finish()            # sub-op/push: fully served
 

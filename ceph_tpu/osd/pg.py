@@ -639,12 +639,35 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
             # every client write in a writeback tier marks the object
             # dirty so the agent/flush knows to push it to the base
             msg.ops = list(msg.ops) + [("setxattr_raw", DIRTY_KEY, b"1")]
+        if not self._cmpxattr_holds(msg):
+            self._reply(conn, msg, -125, [])      # ECANCELED
+            return
         self.version += 1
         version = (self.interval_epoch, self.version)
         if self.is_ec:
             self._ec_write(conn, msg, version, reqid)
         else:
             self._replicated_write(conn, msg, version, reqid)
+
+    def _cmpxattr_holds(self, msg) -> bool:
+        """The guards of a write's op vector (CEPH_OSD_OP_CMPXATTR, EQ
+        alone): `("cmpxattr", name, value)` holds where the object's
+        user xattr `name` is `value`, or, for None, where it has none
+        (no such object included).  All of them hold, or the vector is
+        not applied and answers ECANCELED.  Caller holds self.lock."""
+        for op in msg.ops:
+            if op[0] != "cmpxattr":
+                continue
+            oid = shard_oid(msg.oid, self.role_of(self.osd.whoami)) \
+                if self.is_ec else msg.oid
+            try:
+                have = bytes(self.osd.store.getattr(self.cid, oid,
+                                                    "u." + op[1]))
+            except StoreError:
+                have = None
+            if have != (None if op[2] is None else bytes(op[2])):
+                return False
+        return True
 
     def _record_completed(self, reqid, result: int, version,
                           outdata: list | None = None) -> None:
@@ -741,6 +764,8 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
                 txn.omap_rmkeys(self.cid, oid, op[1])
             elif name == "touch":
                 txn.touch(self.cid, oid)
+            elif name == "cmpxattr":
+                pass                # held: _do_write checked it
             elif name == "call":
                 kind_out: list = []
                 outdata.append(self._cls_call(txn, oid, op, kind_out))

@@ -28,7 +28,7 @@ import hmac
 import json
 import time
 
-from ..client.striper import StripedObject
+from ..utils.bufferlist import BufferList
 from . import ver_soid
 
 TOKEN_TTL = 900.0        # seconds a minted token stays valid
@@ -180,8 +180,13 @@ def _object(gw, req, method: str, cont: str, key: str,
         vid = ent.get("version_id", "null")
         data = b""
         if method == "GET":
-            data = StripedObject(gw.io,
-                                 ver_soid(cont, key, vid)).read()
+            got = gw._read_object(ver_soid(cont, key, vid))
+            if got is None:
+                gw._reply(req, 404, b"")
+                return
+            data = BufferList()
+            for piece in got[2]:
+                data.append(piece)
         gw._reply(req, 200, data, {
             "ETag": ent.get("etag", ""),
             "Last-Modified": ent.get("mtime", ""),

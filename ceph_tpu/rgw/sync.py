@@ -46,7 +46,7 @@ from xml.sax.saxutils import unescape
 from ..client.rados import RadosError
 from ..utils import denc, faults
 from ..utils.perf_counters import PerfCountersBuilder
-from . import auth_v4, index_oid
+from . import auth_v4
 
 SYNC_STATE_OID = "rgw.sync.state"     # omap: bucket -> marker state
 
@@ -148,13 +148,13 @@ class RGWSyncAgent:
 
     def _state(self) -> dict[str, dict]:
         try:
-            raw = self.gw.io.get_omap(SYNC_STATE_OID)
+            raw = self.gw.index_io.get_omap(SYNC_STATE_OID)
         except RadosError:
             return {}
         return {b: denc.loads(v) for b, v in raw.items()}
 
     def _save_state(self, bucket: str, st: dict) -> None:
-        self.gw.io.set_omap(SYNC_STATE_OID, {bucket: denc.dumps(st)})
+        self.gw.index_io.set_omap(SYNC_STATE_OID, {bucket: denc.dumps(st)})
 
     # -- sync passes -------------------------------------------------------
 
@@ -227,11 +227,7 @@ class RGWSyncAgent:
 
     def _mirror_bucket_meta(self, bucket: str) -> None:
         if not self.gw._bucket_exists(bucket):
-            self.gw._set_bucket_meta(bucket, {"created": ""})
-            try:
-                self.gw.io.write_full(index_oid(bucket), b"")
-            except RadosError:
-                pass
+            self.gw._create_bucket(bucket)
         try:
             vraw = self._req("GET", f"/{bucket}",
                              raw_query="versioning").decode()

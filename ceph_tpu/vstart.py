@@ -132,7 +132,9 @@ class MiniCluster:
         return mds
 
     def start_rgw(self, port: int = 0, access_key: str = "",
-                  secret_key: str = "", data_pool: str | None = None):
+                  secret_key: str = "", data_pool: str | None = None,
+                  index_pool: str | None = None,
+                  data_extra_pool: str | None = None):
         from .rgw import DATA_POOL, RGWDaemon
         # the gateway's objecter must never ABANDON an in-flight op: a
         # rados op that hits objecter_op_timeout client-side can still
@@ -156,7 +158,9 @@ class MiniCluster:
         # by the multisite sync agent (rgw/sync.py)
         rgw = RGWDaemon(cli, port=port, access_key=access_key,
                         secret_key=secret_key,
-                        data_pool=data_pool or DATA_POOL)
+                        data_pool=data_pool or DATA_POOL,
+                        index_pool=index_pool,
+                        data_extra_pool=data_extra_pool)
         self.rgws.append(rgw)
         rgw.start()
         return rgw

@@ -119,3 +119,37 @@ def test_xla_scrub_crc(one_chip, shape):
     fn = ec_kernels.make_crc_fn(shape[-1])
     compiled = _compile(fn, one_chip, shape)
     assert compiled.memory_analysis().temp_size_in_bytes < 4 << 30
+
+
+# What an S3 size mix runs on a k=4 m=2 pool (PR 42): the fused encode
+# at small and odd buckets, the cut of an item out of a coalesced
+# batch with its offset an OPERAND (one program a (batch, item) bucket
+# pair, whatever the offset), and the check of a cache-served read at
+# the item's bucket.
+@pytest.mark.parametrize("rows", [1, 2, 32, 64])
+def test_size_mix_encode_buckets(one_chip, rows):
+    fn = pallas_ec.make_encode_crc_fn(gf.reed_sol_van_matrix(4, 2), 4096,
+                                      interpret=False)
+    assert "tpu_custom_call" in _compile(
+        fn, one_chip, (rows, 4, 4096)).as_text()
+
+
+@pytest.mark.parametrize("batch,bucket", [(256, 32), (64, 1), (8, 4)])
+def test_size_mix_item_slice_takes_its_offset_as_an_operand(
+        one_chip, batch, bucket):
+    from ceph_tpu.ops import hbm_cache
+    args = [jax.ShapeDtypeStruct((batch, n, 4096), np.uint8,
+                                 sharding=one_chip) for n in (4, 2)]
+    start = jax.ShapeDtypeStruct((), np.int32, sharding=one_chip)
+    compiled = hbm_cache._slice_fn(bucket).lower(*args, start).compile()
+    text = compiled.as_text()
+    assert "dynamic-slice" in text or "dynamic_slice" in text
+    assert [tuple(o.shape) for o in compiled.out_info] == \
+        [(bucket, 4, 4096), (bucket, 2, 4096)]
+
+
+@pytest.mark.parametrize("bucket", [1, 32, 64])
+def test_size_mix_check_at_the_bucket(one_chip, bucket):
+    fn = ec_kernels.make_crc_fn(4096)
+    compiled = _compile(fn, one_chip, (bucket, 4, 4096))
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 28

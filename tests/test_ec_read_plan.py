@@ -249,7 +249,8 @@ def test_cache_served_read_is_checked_on_the_device(pool):
     assert spans["ec.device_compute"]["args"]["stripes"] == 2
     assert spans["ec.d2h"]["t0"] == spans["ec.device_compute"]["t1"]
     ent = hbm_cache.get().lookup(pool.pg.cid, "objc")
-    ent.dev_data = ent.dev_data.at[1, 0, 7].add(1)
+    seg = ent.segs[0]
+    seg.data = seg.data.at[seg.row0 + 1, 0, 7].add(1)
     assert pool.io.read("objc") == data
     _doc, gathers = pool.gathers("objc")
     assert [g["widened"] for g in gathers] == [0]

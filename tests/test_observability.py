@@ -72,7 +72,7 @@ class TestCounterSchema:
            "recovery_blocked_ops", "recovery_unblocked_ops",
            "recovery_prio_promotions",
            # EC reads that needed the widened step after the planned
-           "ec_read_widened",
+           "ec_read_widened", "ec_appends", "ec_append_fallbacks",
            # cache tiering (the reference's names): promotes, flushes
            # and evicts started, dirty/clean transitions, failures,
            # agent passes and what they started, ops a full tier held
