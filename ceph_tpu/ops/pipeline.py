@@ -88,6 +88,7 @@ crossover, and it stays meaningful when n chips serve in parallel.
 
 from __future__ import annotations
 
+import atexit
 import inspect
 import threading
 import time
@@ -177,6 +178,12 @@ def wait_warmups(timeout: float) -> bool:
     for t in threads:
         t.join(max(0.0, end - time.monotonic()))
     return not any(t.is_alive() for t in threads)
+
+
+# a warm thread still inside a device compile at interpreter teardown
+# aborts the process (EcDevicePipeline.stop waits for the same reason);
+# a process that never stops its pipeline waits here
+atexit.register(wait_warmups, 60.0)
 
 # liveness bounds: a device fetch that HANGS (no exception) must not
 # become a process-wide EC outage.  A lane whose collector sits inside
@@ -1692,21 +1699,50 @@ def _device_warm_key(device):
     return (getattr(device, "platform", "?"), getattr(device, "id", 0))
 
 
+def _crc_row_buckets(rows: int, cap: int | None) -> list[int]:
+    """The row buckets to compile when a padded batch of `rows`
+    misses: its own first (it is being served), then every power of
+    two up to the bucket of the channel's cap, where it has one."""
+    if not cap:
+        return [rows]
+    return [rows] + [1 << e for e in range(next_bucket(cap).bit_length())
+                     if 1 << e != rows]
+
+
 def _crc_device_fn(size: int):
     def device_fn(padded, device=None):
-        key = (size, tuple(padded.shape), _device_warm_key(device))
+        shape = tuple(padded.shape)
+        dev = _device_warm_key(device)
         with _crc_lock:
-            fn = _crc_fns.get(key)
+            fn = _crc_fns.get((size, shape, dev))
             if fn is None:
-                # negative-cache warm failures (TpuBackend does the
-                # same): re-warming every dispatch would churn a
-                # thread + a failing compile per batch
-                if key not in _crc_warming and \
-                        key not in _crc_warm_failed:
-                    _crc_warming.add(key)
-                    shape = tuple(padded.shape)
+                # The first miss of a size compiles EVERY row bucket
+                # its batches can come to, not this batch's alone:
+                # the acting OSDs' scans of one PG scrub run side by
+                # side and share a dispatch when timing has it, so
+                # which bucket a dispatch pads to (one scan's rows, or
+                # several scans' up to the channel's cap) is not known
+                # from the first.  A bucket first met after the
+                # warm-ups were waited out would compile while serving.
+                chan = _crc_channels.get(size)
+                todo = []
+                for n in _crc_row_buckets(
+                        shape[0], chan.max_coalesce if chan else None):
+                    key = (size, (n,) + shape[1:], dev)
+                    # negative-cache warm failures (TpuBackend does
+                    # the same): re-warming every dispatch would churn
+                    # a thread + a failing compile per batch
+                    if key not in _crc_fns and key not in _crc_warming \
+                            and key not in _crc_warm_failed:
+                        _crc_warming.add(key)
+                        todo.append(key[1])
+                if todo:
+                    # ONE thread, a program after another: they are
+                    # one jitted function, and every lane's device
+                    # misses for itself
                     start_warm_thread(
-                        lambda: _warm_crc(size, shape, device),
+                        lambda: [_warm_crc(size, s, device)
+                                 for s in todo],
                         "ec-crc-warm")
                 return None
         return (fn(padded),)

@@ -778,16 +778,37 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
     # -- generic peer RPC (blocking, used on worker threads only) ----------
 
     def _call(self, osd_id: int, msg: Message, timeout: float = 10.0):
-        tid = next(self._rpc_tid)
-        msg.rpc_tid = tid
+        tids = self._send_calls({osd_id: msg})
+        return self._wait_calls(
+            tids, time.monotonic() + timeout).get(osd_id)
+
+    def _send_calls(self, msgs: dict[int, Message]) -> dict[int, int]:
+        """Ask many peers at once: every {osd: message} gets its tid
+        and is sent before any is waited for; returns {osd: tid} for
+        `_wait_calls`."""
+        tids = {osd_id: next(self._rpc_tid) for osd_id in msgs}
         with self._rpc_cv:
-            self._rpc[tid] = None
-        self.send_osd(osd_id, msg)
+            for osd_id, tid in tids.items():
+                msgs[osd_id].rpc_tid = tid
+                self._rpc[tid] = None
+        for osd_id, msg in msgs.items():
+            self.send_osd(osd_id, msg)
+        return tids
+
+    def _wait_calls(self, tids: dict[int, int],
+                    deadline: float) -> dict[int, Message]:
+        """One wait until every tid of `_send_calls` has its answer or
+        `deadline` (time.monotonic()) has passed; {osd: reply} of those
+        that answered.  The others' tids are forgotten: a later answer
+        is dropped."""
         with self._rpc_cv:
-            ok = self._rpc_cv.wait_for(
-                lambda: self._rpc.get(tid) is not None, timeout)
-            result = self._rpc.pop(tid, None)
-        return result if ok else None
+            self._rpc_cv.wait_for(
+                lambda: all(self._rpc.get(tid) is not None
+                            for tid in tids.values()),
+                max(0.0, deadline - time.monotonic()))
+            got = {osd_id: self._rpc.pop(tid, None)
+                   for osd_id, tid in tids.items()}
+        return {osd_id: r for osd_id, r in got.items() if r is not None}
 
     # -- async peer RPC (never blocks a worker; timeouts on the clock) -----
 

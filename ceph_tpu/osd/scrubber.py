@@ -11,6 +11,7 @@ star's scrub-sized batches), authoritative-copy repair
 from __future__ import annotations
 
 import threading
+import time
 from concurrent.futures import TimeoutError as FuturesTimeout
 
 import numpy as np
@@ -21,6 +22,19 @@ from ..store.objectstore import StoreError, Transaction
 from ..utils import denc, optracker
 from .messages import MPGInfo
 from .pg import HINFO_KEY, PG, VER_KEY, shard_oid
+
+
+# The daemons of one process share one interpreter.  A scan is short
+# stretches of Python between calls that give the interpreter up (a KV
+# row, a device read, a checksum batch), and scans that run side by
+# side hand it round at every one of them: the eleven scans of one PG
+# scrub, started together, took 80-100 ms each where one alone takes
+# 10, and the scrub was slower than with one scan after another
+# (PERF.md §6, PR 43).  So the host part of a scan (list, fold, read,
+# stack, submit) takes turns, process-wide; what a scan WAITS for, its
+# dispatches on the chip and its answer on the wire, overlaps the
+# others' turns.
+_HOST_TURN = threading.Lock()
 
 
 class ScrubService:
@@ -80,32 +94,42 @@ class ScrubService:
 
     def _scan_pg(self, pg: PG, deep: bool) -> dict:
         """Local scrub scan: {oid_or_shard: (size, crc|None)}."""
-        out = {}
+        if pg.is_ec and deep:
+            return self._scan_ec_deep(pg)
+        with _HOST_TURN:
+            out = {}
+            for name in self._scan_list(pg):
+                if name.startswith("_pgmeta") or "@" in name:
+                    continue          # pg meta + EC rollback stashes
+                try:
+                    data = self.store.read(pg.cid, name)
+                except StoreError:
+                    continue
+                crc = crc_mod.crc32c(0, data) if deep else None
+                out[name] = (len(data), crc)
+            return out
+
+    def _scan_list(self, pg: PG) -> list[str]:
         with optracker.span("scrub.list") as note:
             try:
                 names = self.store.collection_list(pg.cid)
             except StoreError:
-                return out
+                names = []
             note["names"] = len(names)
-        if pg.is_ec and deep:
-            return self._scan_ec_deep(pg, names)
-        for name in names:
-            if name.startswith("_pgmeta") or "@" in name:
-                continue          # pg meta + EC rollback stashes
-            try:
-                data = self.store.read(pg.cid, name)
-            except StoreError:
-                continue
-            crc = crc_mod.crc32c(0, data) if deep else None
-            out[name] = (len(data), crc)
-        return out
+        return names
 
-    def _scan_ec_deep(self, pg: PG, names: list[str]) -> dict:
+    def _scan_ec_deep(self, pg: PG) -> dict:
         """TPU-batched shard verification through the shared EC device
         pipeline: shards group by size, every group's CRC batches are
-        submitted up front (overlapped dispatches; concurrent scrubs
-        on other PGs coalesce into the same mega-batches), results
-        gather at the end (the north-star scrub path).
+        submitted up front (overlapped dispatches), results gather at
+        the end (the north-star scrub path).  The concurrency there is:
+        the acting OSDs' scans of ONE PG scrub run side by side
+        (`_gather_scans`).  Where they share a process they take turns
+        at the host part (`_HOST_TURN`), so one scan's dispatch and
+        answer overlap the next one's reads, and two batches of a shard
+        size share a dispatch only where they are submitted within the
+        pipeline's coalesce window; PGs are still scrubbed
+        `osd_max_scrubs` at a time.
 
         HBM-cache fast path first: an object whose encoded stripes
         still sit on a chip (committed at the object's current
@@ -144,45 +168,6 @@ class ScrubService:
             cached_folds[base] = folds
             return folds
 
-        # two passes so that each is one span: first what the HBM
-        # cache can answer (lookup, fold, stat, getattr), then the
-        # store reads of everything else
-        to_read: list[str] = []
-        with optracker.span("scrub.cache_fold") as note:
-            hits = 0
-            for name in names:
-                if name.startswith("_pgmeta") or "@" in name:
-                    continue          # pg meta + EC rollback stashes
-                base, _, sfx = name.rpartition(".s")
-                folds = cache_folds(base) if sfx.isdigit() else None
-                if folds is None or int(sfx) >= len(folds):
-                    to_read.append(name)
-                    continue
-                try:
-                    size = self.store.stat(pg.cid, name)["size"]
-                    hinfo = denc.loads(self.store.getattr(
-                        pg.cid, name, HINFO_KEY))
-                except StoreError:
-                    continue
-                out[name] = (size, bool(folds[int(sfx)]
-                                        == hinfo["crc"]))
-                hits += 1
-            note.update(shards=hits, objects=sum(
-                1 for f in cached_folds.values() if f is not None))
-        with optracker.span("scrub.read") as note:
-            nbytes = 0
-            for name in to_read:
-                try:
-                    data = self.store.read(pg.cid, name)
-                    hinfo = denc.loads(self.store.getattr(pg.cid, name,
-                                                          HINFO_KEY))
-                except StoreError:
-                    continue
-                nbytes += len(data)
-                by_size.setdefault(len(data), []).append(
-                    (name, data, hinfo["crc"]))
-            note.update(shards=sum(len(g) for g in by_size.values()),
-                        bytes=nbytes)
         batch_max = int(self.conf.osd_deep_scrub_stripe_batch)
         pipe = ec_pipeline.get()
         pending: list = []
@@ -206,38 +191,118 @@ class ScrubService:
             for (name, _d, expected), got in zip(chunk, crcs):
                 out[name] = (size, bool(int(got) == expected))
 
-        for size, group in by_size.items():
-            if size == 0:
-                for name, _d, expected in group:
-                    out[name] = (0, 0 == expected)
-                continue
-            chan = ec_pipeline.crc_channel(size,
-                                           max_coalesce=batch_max)
-            for i in range(0, len(group), batch_max):
-                chunk = group[i:i + batch_max]
-                with optracker.span("scrub.stack", batches=1,
-                                    bytes=size * len(chunk)):
-                    arr = np.stack([np.frombuffer(d, dtype=np.uint8)
-                                    for _n, d, _c in chunk])
-                    pending.append((size, chunk, arr,
-                                    pipe.submit(chan, arr)))
-                # sliding window: keep a handful of batches in flight
-                # for dispatch overlap without queueing a second copy
-                # of the whole PG's shard bytes at once
-                if len(pending) >= 8:
-                    collect_one()
+        with _HOST_TURN:
+            names = self._scan_list(pg)
+            # two passes so that each is one span: first what the HBM
+            # cache can answer (lookup, fold, stat, getattr), then the
+            # store reads of everything else
+            to_read: list[str] = []
+            with optracker.span("scrub.cache_fold") as note:
+                hits = 0
+                for name in names:
+                    if name.startswith("_pgmeta") or "@" in name:
+                        continue          # pg meta + EC rollback stashes
+                    base, _, sfx = name.rpartition(".s")
+                    folds = cache_folds(base) if sfx.isdigit() else None
+                    if folds is None or int(sfx) >= len(folds):
+                        to_read.append(name)
+                        continue
+                    try:
+                        size = self.store.stat(pg.cid, name)["size"]
+                        hinfo = denc.loads(self.store.getattr(
+                            pg.cid, name, HINFO_KEY))
+                    except StoreError:
+                        continue
+                    out[name] = (size, bool(folds[int(sfx)]
+                                            == hinfo["crc"]))
+                    hits += 1
+                note.update(shards=hits, objects=sum(
+                    1 for f in cached_folds.values() if f is not None))
+            with optracker.span("scrub.read") as note:
+                nbytes = 0
+                for name in to_read:
+                    try:
+                        data = self.store.read(pg.cid, name)
+                        hinfo = denc.loads(self.store.getattr(pg.cid, name,
+                                                              HINFO_KEY))
+                    except StoreError:
+                        continue
+                    nbytes += len(data)
+                    by_size.setdefault(len(data), []).append(
+                        (name, data, hinfo["crc"]))
+                note.update(shards=sum(len(g) for g in by_size.values()),
+                            bytes=nbytes)
+            for size, group in by_size.items():
+                if size == 0:
+                    for name, _d, expected in group:
+                        out[name] = (0, 0 == expected)
+                    continue
+                chan = ec_pipeline.crc_channel(size,
+                                               max_coalesce=batch_max)
+                for i in range(0, len(group), batch_max):
+                    chunk = group[i:i + batch_max]
+                    with optracker.span("scrub.stack", batches=1,
+                                        bytes=size * len(chunk)):
+                        arr = np.stack([np.frombuffer(d, dtype=np.uint8)
+                                        for _n, d, _c in chunk])
+                        pending.append((size, chunk, arr,
+                                        pipe.submit(chan, arr)))
+                    # sliding window: keep a handful of batches in flight
+                    # for dispatch overlap without queueing a second copy
+                    # of the whole PG's shard bytes at once (a wait:
+                    # the turn goes to another scan meanwhile)
+                    if len(pending) >= 8:
+                        _HOST_TURN.release()
+                        try:
+                            collect_one()
+                        finally:
+                            _HOST_TURN.acquire()
         while pending:
             collect_one()
         return out
 
+    # how long a PG scrub waits for its peers' scans, from the asks
+    SCAN_TIMEOUT = 20.0
+
+    def _gather_scans(self, pg: PG, deep: bool) -> dict:
+        """{osd: scan} of the acting set, as PG::chunky_scrub goes
+        about it: ask every live acting OSD but this one for its scan
+        (NEW_CHUNK's _request_scrub_map to every replica), scan our
+        own shards while they work (BUILD_MAP), then wait once for all
+        the answers (WAIT_REPLICAS), so the peers' scans run side by
+        side (in one process: `_HOST_TURN`).
+
+        The scrub's trace id rides each request, so every peer's
+        `scrub_scan` op carries it.  ONE `scrub.peer_wait` span a PG
+        scrub, from the end of our own scan to the last answer (or to
+        SCAN_TIMEOUT after the asks): `peers` asked, `answered`,
+        `late` (no answer by then; such a peer is left out of the
+        result, the others' scans are used)."""
+        trk = optracker.current()
+        asks = {osd_id: MPGInfo(
+            op="scan", pgid=str(pg.pgid), deep=deep,
+            trace=getattr(trk, "trace_id", "") or "",
+            epoch=self.osdmap.epoch)
+            for osd_id in pg.acting_live()
+            if osd_id != self.whoami
+            and self.osdmap.get_addr(osd_id) is not None}
+        deadline = time.monotonic() + self.SCAN_TIMEOUT
+        tids = self._send_calls(asks)
+        try:
+            scans = {self.whoami: self._scan_pg(pg, deep)}
+        except BaseException:
+            self._wait_calls(tids, 0.0)     # forget the asks
+            raise
+        with optracker.span("scrub.peer_wait", peers=len(tids)) as note:
+            replies = self._wait_calls(tids, deadline)
+            note.update(answered=len(replies),
+                        late=len(tids) - len(replies))
+        for osd_id, reply in replies.items():
+            scans[osd_id] = reply.info
+        return scans
+
     def scrub_replicated_pg(self, pg: PG, deep: bool) -> dict:
-        my_scan = self._scan_pg(pg, deep)
-        peers = [o for o in pg.acting_live() if o != self.whoami]
-        scans = {self.whoami: my_scan}
-        for osd_id in peers:
-            reply = self._scan_peer(pg, osd_id, deep)
-            if reply is not None:
-                scans[osd_id] = reply.info
+        scans = self._gather_scans(pg, deep)
         inconsistent = []
         all_names = set()
         with optracker.span("scrub.compare") as note:
@@ -254,30 +319,10 @@ class ScrubService:
                         inconsistent=len(inconsistent))
         return {"checked": len(all_names), "inconsistent": inconsistent}
 
-    def _scan_peer(self, pg: PG, osd_id: int, deep: bool):
-        """Ask one peer for its scan and wait for it: one
-        `scrub.peer_wait` span a peer on the scrub's op, whose trace id
-        rides the request so the peer's `scrub_scan` op carries it."""
-        trk = optracker.current()
-        with optracker.span("scrub.peer_wait", osd=osd_id) as note:
-            reply = self._call(osd_id, MPGInfo(
-                op="scan", pgid=str(pg.pgid), deep=deep,
-                trace=getattr(trk, "trace_id", "") or "",
-                epoch=self.osdmap.epoch), timeout=20.0)
-            note["ok"] = int(reply is not None)
-        return reply
-
     def scrub_ec_pg(self, pg: PG) -> dict:
         """Each shard OSD verifies its shards against hinfo (deep);
         shards a holder should have but doesn't are flagged too."""
-        my_scan = self._scan_pg(pg, deep=True)
-        scans = {self.whoami: my_scan}
-        for osd_id in pg.acting_live():
-            if osd_id == self.whoami:
-                continue
-            reply = self._scan_peer(pg, osd_id, True)
-            if reply is not None:
-                scans[osd_id] = reply.info
+        scans = self._gather_scans(pg, deep=True)
         inconsistent = []
         checked = 0
         bases = set()

@@ -1184,9 +1184,8 @@ class BlockStore(ObjectStore):
             # seed the iterator at the resume point, or paging a big
             # collection (backfill/scrub) rescans from the front each
             # page — O(N^2/k) over the whole scan
-            for key, _v in self.db.iterate(P_ONODE, prefix + start):
-                if not key.startswith(prefix):
-                    break
+            for key, _v in self.db.iterate(P_ONODE, prefix + start,
+                                           after_prefix(prefix)):
                 name = key[len(prefix):]
                 if name > start:
                     names.append(name)

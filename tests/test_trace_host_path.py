@@ -134,14 +134,18 @@ class TestScrubOps:
                          if d["trace_id"] == trace], len(acting) - 1)
             assert sorted(d["daemon"] for d in scans) == \
                 sorted(f"osd.{o}" for o in acting[1:])
-            # the primary: its own scan, one wait per peer, the compare
+            # the primary: its own scan, the wait for the peers, the compare
             names = {s["name"] for s in doc["spans"]}
             assert {"scrub.list", "scrub.cache_fold", "scrub.read",
                     "scrub.peer_wait", "scrub.compare"} <= names
-            waits = _spans(doc, "scrub.peer_wait")
-            assert sorted(s["args"]["osd"] for s in waits) == \
-                sorted(acting[1:])
-            assert all(s["args"]["ok"] == 1 for s in waits)
+            # ONE wait a PG scrub: every peer asked before our own
+            # scan, all gathered after it
+            (wait,) = _spans(doc, "scrub.peer_wait")
+            assert wait["args"] == {"peers": len(acting) - 1,
+                                    "answered": len(acting) - 1,
+                                    "late": 0}
+            (own,) = _spans(doc, "scrub.read")
+            assert own["t1"] <= wait["t0"]
             (cmp_,) = _spans(doc, "scrub.compare")
             assert cmp_["args"]["checked"] == result["checked"]
             assert cmp_["args"]["inconsistent"] == 0
@@ -221,7 +225,9 @@ class TestScrubOps:
                      if d["trace_id"] == doc["trace_id"]], len(acting) - 1)
         assert len(scans) == len(acting) - 1
         assert all("deep=0" in d["description"] for d in scans)
-        assert len(_spans(doc, "scrub.peer_wait")) == len(acting) - 1
+        (wait,) = _spans(doc, "scrub.peer_wait")
+        assert wait["args"]["peers"] == wait["args"]["answered"] \
+            == len(acting) - 1
 
 
 # ---------------------------------------------------------------------------
@@ -653,7 +659,9 @@ class TestWaitLegs:
                      if d["trace_id"] == doc["trace_id"]], len(acting) - 1)
         replies = _replies(cluster, doc["trace_id"])
         assert len(replies) == len(scans) == len(acting) - 1
-        waits = _spans(doc, "scrub.peer_wait")
+        (wait,) = _spans(doc, "scrub.peer_wait")
+        assert wait["args"]["peers"] == wait["args"]["answered"] \
+            == len(acting) - 1
         for d in replies:
             assert d["daemon"] == doc["daemon"]
             sender = d["description"].rsplit("<- ", 1)[1][:-1]
@@ -661,14 +669,13 @@ class TestWaitLegs:
                 f"reply(MPGInfo.scanned {sender} <- {sender})"
             _check_way_in(d)
             (ex,) = _spans(d, "execute")
-            # inside the wait for that peer, and nothing of it ON the
-            # scrub's doc
-            (wait,) = [w for w in waits
-                       if f"osd.{w['args']['osd']}" == sender]
-            assert wait["t0"] <= ex["t0"] <= wait["t1"]
+            # asked before the scrub's own scan, answered by the end of
+            # the one wait, and nothing of it ON the scrub's doc
+            assert doc["mstart"] <= ex["t0"] <= wait["t1"]
             (scan,) = [x for x in scans if x["daemon"] == sender]
             _check_way_in(scan)
-            assert wait["t0"] <= _spans(scan, "msgr.handoff")[0]["t0"]
+            assert doc["mstart"] <= _spans(scan, "msgr.handoff")[0]["t0"] \
+                <= _spans(doc, "scrub.list")[0]["t0"]
         assert not [s for s in doc["spans"] if s["name"].startswith("msgr.")]
 
     def test_old_self_times_are_what_they_were(self, stack):
