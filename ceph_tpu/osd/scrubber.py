@@ -142,7 +142,6 @@ class ScrubService:
         from ..ops import hbm_cache
         from ..ops import pipeline as ec_pipeline
         from . import ecutil
-        by_size: dict[int, list[tuple[str, bytes, int]]] = {}
         out = {}
         cached_folds: dict[str, list[int] | None] = {}
 
@@ -173,7 +172,7 @@ class ScrubService:
         pending: list = []
 
         def collect_one() -> None:
-            size, chunk, arr, fut = pending.pop(0)
+            size, rows, arr, fut = pending.pop(0)
             with optracker.span("scrub.collect") as note:
                 try:
                     _path, (crcs,) = fut.result(
@@ -188,7 +187,7 @@ class ScrubService:
             # as ecutil notes them for writes
             optracker.note_pipeline_phases(
                 getattr(fut, "trace_phases", None))
-            for (name, _d, expected), got in zip(chunk, crcs):
+            for (name, expected), got in zip(rows, crcs):
                 out[name] = (size, bool(int(got) == expected))
 
         with _HOST_TURN:
@@ -219,44 +218,62 @@ class ScrubService:
                 note.update(shards=hits, objects=sum(
                     1 for f in cached_folds.values() if f is not None))
             with optracker.span("scrub.read") as note:
-                nbytes = 0
+                # a batch is made where its files are read: each file
+                # straight into its row, no copy of it after the
+                # store's (a file another size than its `stat` said,
+                # or one that does not read, is left out of the scan)
+                before = self.store.journal_stats()
+                by_size: dict[int, list[str]] = {}
                 for name in to_read:
                     try:
-                        data = self.store.read(pg.cid, name)
-                        hinfo = denc.loads(self.store.getattr(pg.cid, name,
-                                                              HINFO_KEY))
+                        size = self.store.stat(pg.cid, name)["size"]
                     except StoreError:
                         continue
-                    nbytes += len(data)
-                    by_size.setdefault(len(data), []).append(
-                        (name, data, hinfo["crc"]))
-                note.update(shards=sum(len(g) for g in by_size.values()),
-                            bytes=nbytes)
-            for size, group in by_size.items():
+                    by_size.setdefault(size, []).append(name)
+                batches = []
+                for size, group in by_size.items():
+                    for i in range(0, len(group), batch_max):
+                        part = group[i:i + batch_max]
+                        arr = np.empty((len(part), size), dtype=np.uint8)
+                        rows = []
+                        for name in part:
+                            try:
+                                if self.store.read_into(
+                                        pg.cid, name, arr[len(rows)]) != size:
+                                    continue
+                                hinfo = denc.loads(self.store.getattr(
+                                    pg.cid, name, HINFO_KEY))
+                            except StoreError:
+                                continue
+                            rows.append((name, hinfo["crc"]))
+                        if rows:
+                            batches.append((size, rows, arr[:len(rows)]))
+                note.update(shards=sum(len(b[1]) for b in batches),
+                            bytes=sum(b[2].size for b in batches))
+                after = self.store.journal_stats()
+                note.update({k: after[k] - before[k] for k in
+                             ("reads", "reads_whole_run") if k in after})
+            for size, rows, arr in batches:
                 if size == 0:
-                    for name, _d, expected in group:
+                    for name, expected in rows:
                         out[name] = (0, 0 == expected)
                     continue
                 chan = ec_pipeline.crc_channel(size,
                                                max_coalesce=batch_max)
-                for i in range(0, len(group), batch_max):
-                    chunk = group[i:i + batch_max]
-                    with optracker.span("scrub.stack", batches=1,
-                                        bytes=size * len(chunk)):
-                        arr = np.stack([np.frombuffer(d, dtype=np.uint8)
-                                        for _n, d, _c in chunk])
-                        pending.append((size, chunk, arr,
-                                        pipe.submit(chan, arr)))
-                    # sliding window: keep a handful of batches in flight
-                    # for dispatch overlap without queueing a second copy
-                    # of the whole PG's shard bytes at once (a wait:
-                    # the turn goes to another scan meanwhile)
-                    if len(pending) >= 8:
-                        _HOST_TURN.release()
-                        try:
-                            collect_one()
-                        finally:
-                            _HOST_TURN.acquire()
+                with optracker.span("scrub.stack", batches=1,
+                                    bytes=arr.size):
+                    pending.append((size, rows, arr,
+                                    pipe.submit(chan, arr)))
+                # sliding window: keep a handful of batches in flight
+                # for dispatch overlap without queueing a second copy
+                # of the whole PG's shard bytes at once (a wait:
+                # the turn goes to another scan meanwhile)
+                if len(pending) >= 8:
+                    _HOST_TURN.release()
+                    try:
+                        collect_one()
+                    finally:
+                        _HOST_TURN.acquire()
         while pending:
             collect_one()
         return out

@@ -2,10 +2,10 @@
 
 Model follows os/bluestore/BlueStore.cc semantics re-designed small:
 object data lives in a single raw block file at allocator-assigned
-extents; ALL metadata (onodes with per-block extent maps + checksums,
-omap, collections, the free list, the deferred-write WAL) lives in the
-KV tier (os/bluestore/BlueStore.h:413 Onode/Blob/Extent collapsed to a
-min_alloc-granularity block map).  The KV commit is the transaction's
+extents; ALL metadata (onodes with their block maps, a run of blocks an
+entry with a checksum a block, omap, collections, the free list, the
+deferred-write WAL) lives in the KV tier (os/bluestore/BlueStore.h:413
+Onode/Blob/Extent collapsed to a map of min_alloc-granularity runs).  The KV commit is the transaction's
 durability point, exactly like BlueStore's _kv_sync_thread:
 
   * big writes go copy-on-write to freshly allocated blocks, the device
@@ -18,10 +18,16 @@ durability point, exactly like BlueStore's _kv_sync_thread:
     replays any pending records (idempotent pwrites);
   * every min_alloc block carries a crc32c verified on read
     (BlueStore's per-blob csum); mismatch surfaces StoreError(EIO);
-  * a write's run of whole blocks is one extent: one allocation, one
-    native checksum call, and at commit one device write for blocks
-    that lie one behind the other.  The onode still maps a block at a
-    time and the crash sites below still tear by block;
+  * a write's run of whole blocks is one extent from end to end: one
+    allocation, one native checksum call, ONE entry in the onode's
+    block map (a run: first block, count, first device offset, the
+    blocks' checksums packed), one staged buffer, one device write,
+    one WAL target.  A read finds the run by bisection, reads it in
+    one device call, compares its checksums in one compare, and where
+    the request is the run's whole blocks hands back the buffer the
+    device read produced.  An overwrite, a hole punch or a truncate
+    splits the run it touches.  Checksums stay a 4 KiB block each and
+    the crash sites below still tear by block;
   * decoded onodes stay in the store beside the KV, as BlueStore keeps
     its onode cache: a bounded LRU written through at the commit point
     and nowhere else, so a look-up of an object this store committed
@@ -32,7 +38,8 @@ refcounting shared blobs (correctness-equivalent; COW sharing is a
 space optimization) - except where the transaction's next op empties
 or removes the source (an EC shard's rollback stash before a whole
 rewrite or a delete) and in a move: there the copy takes the source's
-blocks where they lie, a rename of the data - and the freelist is
+block map, the blocks where they lie, a rename of the data - and the
+freelist is
 persisted as one coalesced blob per commit rather than
 BitmapFreelistManager key-ranges — at this store's scale the blob is
 tiny and the swap is atomic by construction.
@@ -72,6 +79,7 @@ replay must still repair every acked write bit-exact.
 
 from __future__ import annotations
 
+import bisect
 import os
 import threading
 from collections import OrderedDict
@@ -82,7 +90,7 @@ import numpy as np
 from ..kv.keyvaluedb import KeyValueDB, KVTransaction, after_prefix
 from ..kv.memdb import MemDB
 from ..kv.sqlitedb import SqliteDB
-from ..ops.crc32c import crc32c, crc32c_batch
+from ..ops.crc32c import crc32c_batch
 from ..utils import denc
 from .objectstore import (EEXIST, EIO, ENOENT, ObjectStore, StoreError,
                           Transaction)
@@ -107,38 +115,106 @@ def _okey(cid: str, oid: str) -> str:
     return f"{cid}/{oid}"
 
 
-# one entry of an onode's packed block map
+# An onode's block map is a sorted list of RUNS: logical blocks that
+# lie one behind the other on the device,
+#     (first block#, blocks, first poff, csums)
+# with `csums` the blocks' crc32c values packed "<u4", four bytes a
+# block.  Runs are tuples of ints and bytes (a split or a join makes
+# new ones, nobody edits one) and the map is canonical: two runs that
+# touch in the object and on the device are one run.
+EVERYTHING = 1 << 62            # "to the end of the object", in blocks
+
+# the KV forms before the runs: one packed entry a block, and before
+# that a plain dict {block#: [poff, crc32c]}
 _MAP_ENTRY = np.dtype([("blk", "<u4"), ("poff", "<u8"), ("csum", "<u4")])
 
 
 def dump_onode(head: dict) -> bytes:
-    """An onode as the KV holds it.  The block map goes as one packed
-    field, sixteen bytes an entry (block#, poff, crc32c): a 4 MiB
-    object's 1,024 entries took the generic encoder some 3,000 calls
-    and 6 ms of interpreter a commit, and every 4 KiB write into such
-    an object commits its onode on each replica."""
-    blocks = head["blocks"]
-    rows = np.empty(len(blocks), dtype=_MAP_ENTRY)
-    if blocks:
-        rows["blk"] = np.fromiter(blocks, dtype="<u8", count=len(blocks))
-        ents = np.array(list(blocks.values()), dtype="<u8")
-        rows["poff"], rows["csum"] = ents[:, 0], ents[:, 1]
-    return denc.dumps({"size": head["size"], "xattrs": head["xattrs"],
-                       "map": memoryview(rows.view(np.uint8))})
+    """An onode as the KV holds it (form 2): the block map as two
+    packed fields, `runs` twenty-four bytes a run ("<u8" first block,
+    blocks, first poff) and `csums` the runs' checksums end to end, so
+    a shard file's map is some 540 bytes and its encoding no loop a
+    block (form 1 was sixteen bytes a block)."""
+    runs = head["runs"]
+    return denc.dumps({
+        "size": head["size"], "xattrs": head["xattrs"], "v": 2,
+        "runs": np.array([run[:3] for run in runs], dtype="<u8").tobytes(),
+        "csums": b"".join([run[3] for run in runs])})
 
 
 def load_onode(blob: bytes) -> dict:
-    """The onode of a KV value: `dump_onode`'s, or the form stores
-    written before it hold (the block map a plain dict)."""
+    """The onode of a KV value: `dump_onode`'s, or either form stores
+    written before it hold (a packed entry a block; a plain dict)."""
     head = denc.loads(blob)
+    if head.pop("v", None) == 2:
+        csums, at, runs = head.pop("csums"), 0, []
+        for blk, n, poff in np.frombuffer(
+                head["runs"], dtype="<u8").reshape(-1, 3).tolist():
+            runs.append((blk, n, poff, csums[at: at + 4 * n]))
+            at += 4 * n
+        head["runs"] = runs
+        return head
     packed = head.pop("map", None)
     if packed is not None:
         rows = np.frombuffer(packed, dtype=_MAP_ENTRY)
-        head["blocks"] = {
-            blk: [poff, csum] for blk, poff, csum in
-            zip(rows["blk"].tolist(), rows["poff"].tolist(),
-                rows["csum"].tolist())}
+        blk, poff, csum = rows["blk"], rows["poff"], rows["csum"]
+    else:
+        blocks = head.pop("blocks")
+        blk = np.fromiter(blocks, dtype="<u8", count=len(blocks))
+        ents = np.array(list(blocks.values()), dtype="<u8").reshape(-1, 2)
+        poff, csum = ents[:, 0], ents[:, 1]
+    head["runs"] = _runs_of_blocks(blk, poff, csum)
     return head
+
+
+def _runs_of_blocks(blk, poff, csum) -> list[tuple]:
+    """The canonical runs of a map given a block at a time (three
+    arrays, in any order): one vectorised look for where the next
+    block is not the next on the device, then a step a run."""
+    if not len(blk):
+        return []
+    order = np.argsort(blk, kind="stable")
+    blk = blk[order].astype(np.int64)
+    poff = poff[order].astype(np.int64)
+    raw = csum[order].astype("<u4").tobytes()
+    cuts = (np.flatnonzero((np.diff(blk) != 1)
+                           | (np.diff(poff) != MIN_ALLOC)) + 1).tolist()
+    starts, ends = [0] + cuts, cuts + [len(blk)]
+    return [(b, e - s, p, raw[4 * s: 4 * e]) for s, e, b, p in
+            zip(starts, ends, blk[starts].tolist(), poff[starts].tolist())]
+
+
+def _run_index(runs: list[tuple], blk: int) -> int:
+    """Where logical block `blk` is in the map: the index of the run
+    that holds it, else of the first run behind it (`len(runs)` if
+    none).  A one-element tuple sorts in front of every run that
+    starts at its block."""
+    i = bisect.bisect_left(runs, (blk + 1,)) - 1
+    if i >= 0 and blk < runs[i][0] + runs[i][1]:
+        return i
+    return i + 1
+
+
+def _insert_run(runs: list[tuple], run: tuple) -> None:
+    """Put a run into a map that holds none of its blocks, joined to a
+    neighbour it continues in the object and on the device."""
+    blk, n, poff, csums = run
+    i = _run_index(runs, blk)
+    if i < len(runs):
+        nblk, nn, npoff, ncsums = runs[i]
+        if nblk == blk + n and npoff == poff + n * MIN_ALLOC:
+            n, csums = n + nn, csums + ncsums
+            del runs[i]
+    if i:
+        pblk, pn, ppoff, pcsums = runs[i - 1]
+        if pblk + pn == blk and ppoff + pn * MIN_ALLOC == poff:
+            runs[i - 1] = (pblk, pn + n, ppoff, pcsums + csums)
+            return
+    runs.insert(i, (blk, n, poff, csums))
+
+
+def _map_blocks(head: dict) -> int:
+    return sum(run[1] for run in head["runs"])
 
 
 class ExtentAllocator:
@@ -291,16 +367,34 @@ class _Device:
             off += want
 
     def pread(self, off: int, length: int) -> bytes:
-        if self._f is not None:
-            self._f.seek(off)
-            data = self._f.read(length)
-            while len(data) < length:      # short of the end of file
-                more = self._f.read(length - len(data))
-                if not more:
-                    break
-                data += more
-            return data
-        return bytes(self._mem[off: off + length])
+        """One call where the file has the bytes (what comes back is
+        the read's own buffer); short only at the end of the file."""
+        if self._f is None:
+            return bytes(memoryview(self._mem)[off: off + length])
+        fd = self._f.fileno()
+        data = os.pread(fd, length, off)
+        while len(data) < length:
+            more = os.pread(fd, length - len(data), off + len(data))
+            if not more:
+                break
+            data += more
+        return data
+
+    def pread_into(self, off: int, buf: memoryview) -> int:
+        """Fill `buf` from the device at `off`: the bytes land where
+        the caller wants them.  Returns how many did."""
+        if self._f is None:
+            with memoryview(self._mem)[off: off + len(buf)] as have:
+                buf[: len(have)] = have
+                return len(have)
+        fd = self._f.fileno()
+        done = 0
+        while done < len(buf):
+            got = os.preadv(fd, [buf[done:]], off + done)
+            if not got:
+                break
+            done += got
+        return done
 
     def flush(self) -> None:
         if self._f is not None:
@@ -309,8 +403,9 @@ class _Device:
 
 class _OnodeCache:
     """Decoded committed onodes by okey, least recently used first out,
-    bounded by the block-map entries they hold.  A head in here is
-    shared: whoever gets one does not edit it."""
+    bounded by the blocks they map (a shard file of one run weighs its
+    128 blocks, and is a dict, a list and a tuple to the collector).
+    A head in here is shared: whoever gets one does not edit it."""
 
     def __init__(self, limit: int):
         self.limit = limit
@@ -329,21 +424,82 @@ class _OnodeCache:
     def put(self, okey: str, head: dict) -> None:
         self.drop(okey)
         self._heads[okey] = head
-        self._weight += 1 + len(head["blocks"])
+        self._weight += 1 + _map_blocks(head)
         while self._weight > self.limit:
             _okey, old = self._heads.popitem(last=False)
-            self._weight -= 1 + len(old["blocks"])
+            self._weight -= 1 + _map_blocks(old)
 
     def drop(self, okey: str) -> None:
         old = self._heads.pop(okey, None)
         if old is not None:
-            self._weight -= 1 + len(old["blocks"])
+            self._weight -= 1 + _map_blocks(old)
+
+
+class _Staged:
+    """The device writes a transaction has staged, an extent each:
+    (first poff, buffer, deferred), in the order they were staged (a
+    handful: a write is one, unless the free list is in pieces).  The
+    buffers are views of the caller's data until the commit; a
+    deferred one rides the KV commit as a WAL record, the others go to
+    the device before it.  Reads inside the transaction see them (the
+    overlay), and blocks freed in the transaction that wrote them are
+    cut out again, so they never reach the device."""
+
+    def __init__(self):
+        self.extents: list[tuple[int, memoryview, bool]] = []
+
+    def __bool__(self) -> bool:
+        return bool(self.extents)
+
+    def blocks(self) -> int:
+        return sum(len(view) for _p, view, _d in self.extents) // MIN_ALLOC
+
+    def of(self, deferred: bool) -> list[tuple[int, memoryview]]:
+        return [(poff, view) for poff, view, d in self.extents
+                if d == deferred]
+
+    def add(self, poff: int, view: memoryview, deferred: bool) -> None:
+        self.extents.append((poff, view, deferred))
+
+    def pieces(self, poff: int, length: int):
+        """[poff, poff + length) in order as (offset, staged view or
+        None, bytes): None where the device holds the bytes."""
+        end = poff + length
+        for start, view, _d in sorted(
+                (e for e in self.extents
+                 if e[0] < end and poff < e[0] + len(e[1])),
+                key=lambda e: e[0]):
+            if start > poff:
+                yield poff, None, start - poff
+                poff = start
+            upto = min(start + len(view), end)
+            yield poff, view[poff - start: upto - start], upto - poff
+            poff = upto
+        if poff < end:
+            yield poff, None, end - poff
+
+    def cut(self, poff: int, length: int) -> None:
+        """Take [poff, poff + length) out; what is left of a cut extent
+        keeps its place in the order."""
+        end = poff + length
+        left = []
+        for start, view, deferred in self.extents:
+            stop = start + len(view)
+            if stop <= poff or end <= start:
+                left.append((start, view, deferred))
+                continue
+            if start < poff:
+                left.append((start, view[: poff - start], deferred))
+            if end < stop:
+                left.append((end, view[end - start:], deferred))
+        self.extents = left
 
 
 class BlockStore(ObjectStore):
-    """Onode (decoded): {"size", "xattrs", "blocks": {block#: [poff,
-    crc32c]}} — absent block# = hole.  In the KV (P_ONODE) the block
-    map is packed: `dump_onode` / `load_onode`."""
+    """Onode (decoded): {"size", "xattrs", "runs": [(first block#,
+    blocks, first poff, csums), ...]}, the runs sorted, canonical and
+    never edited in place; a block no run holds is a hole.  In the KV
+    (P_ONODE) the map is packed: `dump_onode` / `load_onode`."""
 
     def __init__(self, path: str = "", deferred_max: int = DEFERRED_MAX):
         super().__init__()
@@ -355,7 +511,8 @@ class BlockStore(ObjectStore):
         self._lock = threading.RLock()
         self._wal_seq = 0
         self._wal_applied: list[str] = []   # applied, not yet trimmed
-        self._wal_poffs: set[int] = set()   # extents those records target
+        # the extents those records target, (poff, length) each
+        self._wal_extents: list[tuple[int, int]] = []
         # device writes since the last fsync barrier, with pre-images,
         # recorded only while crash rules are installed: the
         # fsync-reordering model rolls a seeded subset of them back at
@@ -374,10 +531,21 @@ class BlockStore(ObjectStore):
             "commits": 0,
             "onode_lookups": 0,     # of committed onodes, reads included
             "onode_hits": 0,
+            "onodes_committed": 0,  # onodes a commit wrote, and the
+            "runs_committed": 0,    # runs their block maps held
+            "reads": 0,             # of object data (not of nothing)
+            # ... that were the whole blocks of one run: one device
+            # read, whose buffer is what the caller got (`read`) or
+            # was the caller's own (`read_into`)
+            "reads_whole_run": 0,
         }
 
     def journal_stats(self) -> dict:
-        return dict(self.counters, kv_calls=self.db.calls)
+        c = self.counters
+        return dict(
+            c, kv_calls=self.db.calls,
+            runs_per_onode=c["runs_committed"] / max(1, c["onodes_committed"]),
+            read_whole_run_share=c["reads_whole_run"] / max(1, c["reads"]))
 
     def crash_sites(self) -> list[str]:
         return ["wal.pre_kv_commit", "wal.post_kv_commit",
@@ -451,27 +619,36 @@ class BlockStore(ObjectStore):
                 (poff, self.dev.pread(poff, len(data))))
         self.dev.pwrite(poff, data)
 
-    def _write_staged(self, staged: dict, tracked: bool) -> int:
-        """All device mutation of a commit funnels through here:
-        staged blocks (poff -> data, in the order they were staged)
-        that lie one behind the other go to the device in ONE call.
-        With crash tracking armed a run is written a block at a time,
-        each with its pre-image.  Returns the device calls made."""
+    def _write_staged(self, extents: list, tracked: bool) -> int:
+        """All device mutation of a commit funnels through here: the
+        staged extents ((poff, buffer), in the order they were staged)
+        go to the device a call each, and those that lie one behind
+        the other in ONE gather write.  With crash tracking armed an
+        extent is written a block at a time, each with its pre-image.
+        Returns the device calls made."""
         if tracked:
-            for poff, data in staged.items():
-                self._dev_write(poff, data, True)
-            return len(staged)
-        runs: list[tuple[int, list]] = []
-        end = None
-        for poff, data in staged.items():
-            if poff == end:
-                runs[-1][1].append(data)
+            calls = 0
+            for poff, view in extents:
+                for at in range(0, len(view), MIN_ALLOC):
+                    self._dev_write(poff + at, view[at: at + MIN_ALLOC],
+                                    True)
+                    calls += 1
+            return calls
+        calls = i = 0
+        while i < len(extents):
+            start, view = extents[i]
+            pieces, end = [view], start + len(view)
+            while i + 1 < len(extents) and extents[i + 1][0] == end:
+                i += 1
+                pieces.append(extents[i][1])
+                end += len(extents[i][1])
+            if len(pieces) == 1:
+                self.dev.pwrite(start, view)
             else:
-                runs.append((poff, [data]))
-            end = poff + len(data)
-        for start, pieces in runs:
-            self.dev.pwritev(start, pieces)
-        return len(runs)
+                self.dev.pwritev(start, pieces)
+            calls += 1
+            i += 1
+        return calls
 
     def _dev_flush(self) -> None:
         """fsync barrier: everything buffered is durable now."""
@@ -501,20 +678,22 @@ class BlockStore(ObjectStore):
         self._unflushed = []
         self.counters["fsync_reorder_windows"] += 1
 
-    def _torn_extent_crash(self, site: str,
-                           writes: dict[int, bytes]) -> None:
-        """Power loss mid-way through a batch of extent writes: a
-        seeded number of them land whole, one more lands TORN (a
-        prefix of the block), the rest never reach the device."""
+    def _torn_extent_crash(self, site: str, extents: list) -> None:
+        """Power loss mid-way through a batch of extent writes, torn a
+        block: of all their blocks a seeded number land whole, one
+        more lands TORN (a prefix of the block), the rest never reach
+        the device."""
         from ..utils import faults
         fs = faults.get()
         tracked = self._crash_tracking()
-        items = list(writes.items())
-        k = int(fs.torn_keep_fraction(self.owner) * len(items))
-        for poff, data in items[:k]:
+        blocks = [(poff + at, view[at: at + MIN_ALLOC])
+                  for poff, view in extents
+                  for at in range(0, len(view), MIN_ALLOC)]
+        k = int(fs.torn_keep_fraction(self.owner) * len(blocks))
+        for poff, data in blocks[:k]:
             self._dev_write(poff, data, tracked)
-        if k < len(items):
-            poff, data = items[k]
+        if k < len(blocks):
+            poff, data = blocks[k]
             keep = int(fs.torn_keep_fraction(self.owner) * len(data))
             self._dev_write(poff, data[:keep], tracked)
         self._panic(site)
@@ -559,7 +738,7 @@ class BlockStore(ObjectStore):
                 kvt.rmkey(P_WAL, key)
             self.db.submit_transaction(kvt, sync=True)
         self._wal_applied = []
-        self._wal_poffs = set()
+        self._wal_extents = []
         self._unflushed = []
 
     def _verify_freelist(self) -> None:
@@ -569,26 +748,28 @@ class BlockStore(ObjectStore):
         then overwrite live data.  Carve every referenced extent out
         of the free list (count repairs); leaked-but-unreferenced
         blocks are merely lost space, never corruption."""
-        referenced: set[int] = set()
-        for _key, blob in self.db.iterate(P_ONODE, ""):
-            for poff, _csum in load_onode(blob)["blocks"].values():
-                referenced.add(poff)
-        overlaps = [poff for poff in sorted(referenced)
-                    if self._freelist_contains(poff)]
-        for poff in overlaps:
-            ext = self.alloc.allocate_at(poff, MIN_ALLOC)
-            if ext:
-                self.counters["freelist_repairs"] += 1
+        referenced = sorted({
+            (poff, n * MIN_ALLOC)
+            for _key, blob in self.db.iterate(P_ONODE, "")
+            for _blk, n, poff, _csums in load_onode(blob)["runs"]})
+        # both lists are sorted: one pass finds where they overlap
+        overlaps, free, i = [], self.alloc.free, 0
+        for poff, length in referenced:
+            while i < len(free) and free[i][0] + free[i][1] <= poff:
+                i += 1
+            j = i
+            while j < len(free) and free[j][0] < poff + length:
+                lo = max(poff, free[j][0])
+                hi = min(poff + length, free[j][0] + free[j][1])
+                overlaps.append((lo, hi - lo))
+                j += 1
+        for poff, length in overlaps:
+            if self.alloc.allocate_at(poff, length):
+                self.counters["freelist_repairs"] += length // MIN_ALLOC
         if overlaps:
             kvt = self.db.transaction()
             kvt.set(P_SUPER, "freelist", denc.dumps(self.alloc.dump()))
             self.db.submit_transaction(kvt, sync=True)
-
-    def _freelist_contains(self, poff: int) -> bool:
-        for off, length in self.alloc.free:
-            if off <= poff < off + length:
-                return True
-        return False
 
     def _flush_deferred(self) -> None:
         """fsync the device, then drop applied WAL records — they are
@@ -604,7 +785,7 @@ class BlockStore(ObjectStore):
             kvt.rmkey(P_WAL, key)
         self.db.submit_transaction(kvt, sync=True)
         self._wal_applied = []
-        self._wal_poffs = set()
+        self._wal_extents = []
 
     # -- transaction application ------------------------------------------
 
@@ -616,11 +797,9 @@ class BlockStore(ObjectStore):
                 "new_colls": set(),
                 "rm_colls": set(),
                 "kvt": self.db.transaction(),
-                "pending": {},      # poff -> block bytes (this txn)
-                "direct": {},       # poff -> data, write-before-commit
-                "wal": {},          # poff -> data, rides the KV commit
-                "allocated": [],    # rollback on failure
-                "freed": [],        # released only at commit
+                "staged": _Staged(),    # this txn's device writes
+                "allocated": [],    # extents, rolled back on failure
+                "freed": [],        # extents, released only at commit
                 # where the counts stood: the wal span reports what
                 # this txn added (no other thread moves them under _lock)
                 "before": (self.db.calls, self.counters["onode_lookups"],
@@ -653,8 +832,14 @@ class BlockStore(ObjectStore):
         with optracker.span("wal") as late:
             # how often a run was one device call: blocks written by
             # this commit (COW and deferred) over the calls made
-            late["blocks"] = len(st["direct"]) + len(st["wal"])
+            late["blocks"] = st["staged"].blocks()
+            c = self.counters
+            onodes, runs = c["onodes_committed"], c["runs_committed"]
             late["dev_writes"] = self._commit_traced(st)
+            # how long the block maps are that the commit wrote: one
+            # run a shard file that lies in one extent
+            late["onodes"] = c["onodes_committed"] - onodes
+            late["runs"] = c["runs_committed"] - runs
             # how often the txn crossed into the KV tier (its look-ups
             # before this span opened included), and how many of its
             # onode look-ups the store answered from what it keeps
@@ -672,27 +857,30 @@ class BlockStore(ObjectStore):
         # record, trim the WAL first — otherwise a crash after the
         # extent is reused would replay stale bytes over live data
         # (BlueStore sequences deferred txns against reuse the same way).
-        if any(off in self._wal_poffs for off, _l in st["freed"]):
+        if self._wal_extents and any(
+                off < woff + wlen and woff < off + length
+                for off, length in st["freed"]
+                for woff, wlen in self._wal_extents):
             self._flush_deferred()
         # frees take effect with this commit; no further allocations
         # happen in this txn, so in-memory release is safe now
         self.alloc.release(st["freed"])
-        if st["direct"]:
+        direct, wal = st["staged"].of(False), st["staged"].of(True)
+        if direct:
             # crash site: power loss mid-way through the COW extent
             # writes — one block lands torn, but the committed onode
             # still points at the old block (old-or-new, never a mix)
             from ..utils import faults
             if faults.get().should_crash(self.owner, "alloc.mid_cow"):
-                self._torn_extent_crash("alloc.mid_cow", st["direct"])
-            dev_writes += self._write_staged(st["direct"], tracked)
+                self._torn_extent_crash("alloc.mid_cow", direct)
+            dev_writes += self._write_staged(direct, tracked)
             self._dev_flush()
         wal_key = None
-        if st["wal"]:
+        if wal:
             self._wal_seq += 1
             wal_key = f"{self._wal_seq:016x}"
             kvt.set(P_WAL, wal_key,
-                    denc.dumps(
-                        {"writes": [[o, d] for o, d in st["wal"].items()]}))
+                    denc.dumps({"writes": [[o, d] for o, d in wal]}))
         for okey, head in st["onodes"].items():
             if head is None:
                 kvt.rmkey(P_ONODE, okey)
@@ -720,10 +908,12 @@ class BlockStore(ObjectStore):
                 self._onodes.drop(okey)
             else:
                 self._onodes.put(okey, head)
+                self.counters["onodes_committed"] += 1
+                self.counters["runs_committed"] += len(head["runs"])
         if self._colls is not None:
             self._colls -= st["rm_colls"]
             self._colls |= st["new_colls"]
-        if st["wal"]:
+        if wal:
             # crash site: KV durable (the txn is committed), deferred
             # device applies never run — mount replays the WAL record
             self._maybe_crash("wal.post_kv_commit")
@@ -731,10 +921,10 @@ class BlockStore(ObjectStore):
             if faults.get().should_crash(self.owner, "wal.mid_apply"):
                 # crash site: power loss partway through the deferred
                 # applies, one extent torn mid-block; replay rewrites
-                self._torn_extent_crash("wal.mid_apply", st["wal"])
-            dev_writes += self._write_staged(st["wal"], tracked)
+                self._torn_extent_crash("wal.mid_apply", wal)
+            dev_writes += self._write_staged(wal, tracked)
             self._wal_applied.append(wal_key)
-            self._wal_poffs.update(st["wal"])
+            self._wal_extents.extend((o, len(d)) for o, d in wal)
             if len(self._wal_applied) >= WAL_FLUSH_EVERY:
                 self._flush_deferred()
         return dev_writes
@@ -784,10 +974,10 @@ class BlockStore(ObjectStore):
             return st["onodes"][okey]
         head = self._committed(okey)
         if head is not None:
-            # the txn edits a copy; block entries are replaced, never
-            # edited, so the map's own copy is enough
+            # the txn edits a copy; runs are replaced, never edited,
+            # so the map's own copy is enough
             head = {"size": head["size"], "xattrs": dict(head["xattrs"]),
-                    "blocks": dict(head["blocks"])}
+                    "runs": list(head["runs"])}
         st["onodes"][okey] = head
         return head
 
@@ -799,55 +989,88 @@ class BlockStore(ObjectStore):
             if cid not in st["new_colls"] and \
                     cid not in self._collections():
                 raise StoreError(ENOENT, f"no collection {cid}")
-            head = {"size": 0, "xattrs": {}, "blocks": {}}
+            head = {"size": 0, "xattrs": {}, "runs": []}
             st["onodes"][_okey(cid, oid)] = head
         return head
 
-    def _read_block_raw(self, st: dict, head: dict, blk: int) -> bytes:
-        """Current content of a logical block through the txn overlay.
-        Device reads ARE csum-verified: an RMW merge over silently
+    def _read_verified(self, poff: int, csums: bytes, blk: int, what: str,
+                       into: memoryview | None = None):
+        """The device blocks from `poff` on that `csums` are of, in
+        one device read, every block held to its own checksum by one
+        native call and ONE compare of the packed values; only a
+        mismatch is looked into, for the block to name (`blk` is the
+        first one's number in its object, `what` the rest of the
+        EIO's text).  Into the caller's buffer if it gives one, else
+        the read's own buffer is the result."""
+        length = len(csums) // 4 * MIN_ALLOC
+        if into is None:
+            data = self.dev.pread(poff, length)
+            got = len(data)
+        else:
+            data, got = into, self.dev.pread_into(poff, into)
+        if got != length:
+            raise StoreError(EIO, f"short read {what} block {blk}")
+        sums = crc32c_batch(np.frombuffer(
+            data, dtype=np.uint8).reshape(-1, MIN_ALLOC)).astype(
+                "<u4", copy=False)
+        if sums.tobytes() != csums:
+            bad = np.flatnonzero(sums != np.frombuffer(csums, dtype="<u4"))
+            raise StoreError(
+                EIO, f"csum mismatch {what} block {blk + int(bad[0])}")
+        return data
+
+    def _read_raw(self, st: dict, run: tuple, blk: int, n: int):
+        """Blocks `blk` .. `blk + n` of a run as the txn sees them:
+        what it staged itself, the rest from the device.  Device reads
+        ARE csum-verified: an RMW merge or a clone over silently
         corrupt bytes would otherwise re-seal them under a fresh valid
         crc and launder the corruption past every future read."""
-        ent = head["blocks"].get(blk)
-        if ent is None:
+        first, _n, poff, csums = run
+        poff += (blk - first) * MIN_ALLOC
+        got = []
+        for at, view, length in st["staged"].pieces(poff, n * MIN_ALLOC):
+            if view is None:
+                i = blk - first + (at - poff) // MIN_ALLOC
+                view = self._read_verified(
+                    at, csums[4 * i: 4 * i + length // MIN_ALLOC * 4],
+                    first + i, f"at {at:#x} for rmw,")
+            got.append(view)
+        return got[0] if len(got) == 1 else b"".join(got)
+
+    def _read_block_raw(self, st: dict, head: dict, blk: int):
+        """Current content of a logical block through the txn overlay
+        (nothing for a hole)."""
+        runs = head["runs"]
+        i = _run_index(runs, blk)
+        if i == len(runs) or runs[i][0] > blk:
             return b""
-        poff, csum = ent
-        if poff in st["pending"]:
-            return st["pending"][poff]
-        data = self.dev.pread(poff, MIN_ALLOC)
-        if crc32c(0, data) != csum:
-            raise StoreError(EIO, f"csum mismatch reading block {blk} "
-                                  f"at {poff:#x} for rmw")
-        return data
+        return self._read_raw(st, runs[i], blk, 1)
 
     def _put_run(self, st: dict, head: dict, blk: int, data,
                  deferred: bool) -> None:
         """COW a run of whole logical blocks from `blk` on as one
         extent: free the old blocks, allocate once, checksum the run in
-        one native call, then point the onode at each block and stage
-        its device write (slices of `data`, no copy).  A call a block
-        gives the GIL up a block, and on a host whose OSDs share one
-        interpreter every such call hands it round."""
+        one native call, then map it and stage its device write, a run
+        and a buffer an extent the allocator gave (one, unless the
+        free list is in pieces; slices of `data`, no copy).  A call a
+        block gives the GIL up a block, and on a host whose OSDs share
+        one interpreter every such call hands it round."""
         n, rest = divmod(len(data), MIN_ALLOC)
         assert n and not rest
-        blocks = head["blocks"]
-        for b in range(blk, blk + n):
-            old = blocks.get(b)
-            if old is not None:
-                self._free_block(st, old[0])
+        self._cut(st, head, blk, n)
         extents = self._allocate(st, n * MIN_ALLOC)
-        sums = crc32c_batch(np.frombuffer(
-            data, dtype=np.uint8).reshape(n, MIN_ALLOC)).tolist()
+        csums = crc32c_batch(np.frombuffer(
+            data, dtype=np.uint8).reshape(n, MIN_ALLOC)).astype(
+                "<u4", copy=False).tobytes()
         view = memoryview(data)
-        pending = st["pending"]
-        staged = st["wal"] if deferred else st["direct"]
         i = 0
-        for off, length in extents:
-            for poff in range(off, off + length, MIN_ALLOC):
-                blocks[blk + i] = [poff, sums[i]]
-                pending[poff] = staged[poff] = \
-                    view[i * MIN_ALLOC: (i + 1) * MIN_ALLOC]
-                i += 1
+        for poff, length in extents:
+            took = length // MIN_ALLOC
+            _insert_run(head["runs"], (blk + i, took, poff,
+                                       csums[4 * i: 4 * (i + took)]))
+            st["staged"].add(
+                poff, view[i * MIN_ALLOC: (i + took) * MIN_ALLOC], deferred)
+            i += took
 
     def _put_block(self, st: dict, head: dict, blk: int,
                    data, deferred: bool) -> None:
@@ -860,16 +1083,33 @@ class BlockStore(ObjectStore):
             data = block
         self._put_run(st, head, blk, data, deferred)
 
-    def _free_block(self, st: dict, poff: int) -> None:
-        st["freed"].append((poff, MIN_ALLOC))
-        st["pending"].pop(poff, None)
-        st["direct"].pop(poff, None)    # a same-txn write to a block we
-        st["wal"].pop(poff, None)       # just freed must not hit disk
-
-    def _drop_block(self, st: dict, head: dict, blk: int) -> None:
-        ent = head["blocks"].pop(blk, None)
-        if ent is not None:
-            self._free_block(st, ent[0])
+    def _cut(self, st: dict, head: dict, blk: int,
+             n: int = EVERYTHING) -> None:
+        """Take logical blocks `blk` .. `blk + n` out of the map and
+        free what they held: a run that reaches over either end is
+        split there, what is left of it one or two new runs.  Freed
+        space is released at the commit; a same-txn write to it must
+        not hit the device."""
+        runs, end = head["runs"], blk + n
+        i = j = _run_index(runs, blk)
+        left = []
+        while j < len(runs) and runs[j][0] < end:
+            first, count, poff, csums = runs[j]
+            lo, hi = max(first, blk), min(first + count, end)
+            at, length = poff + (lo - first) * MIN_ALLOC, \
+                (hi - lo) * MIN_ALLOC
+            st["freed"].append((at, length))
+            if st["staged"]:
+                st["staged"].cut(at, length)
+            if first < lo:
+                left.append((first, lo - first, poff,
+                             csums[: 4 * (lo - first)]))
+            if hi < first + count:
+                left.append((hi, first + count - hi,
+                             poff + (hi - first) * MIN_ALLOC,
+                             csums[4 * (hi - first):]))
+            j += 1
+        runs[i:j] = left
 
     def _write_span(self, st: dict, head: dict, offset: int,
                     data: bytes, zero: bool = False) -> None:
@@ -885,8 +1125,7 @@ class BlockStore(ObjectStore):
                 # together (a 512 KiB shard file is one run of 128)
                 take = whole * MIN_ALLOC
                 if zero:
-                    for b in range(blk, blk + whole):
-                        self._drop_block(st, head, b)   # punch holes
+                    self._cut(st, head, blk, whole)     # punch a hole
                 else:
                     self._put_run(st, head, blk, view[pos: pos + take],
                                   deferred)
@@ -898,7 +1137,7 @@ class BlockStore(ObjectStore):
                     cur.extend(b"\x00" * (boff + take - len(cur)))
                 cur[boff: boff + take] = view[pos: pos + take]
                 if zero and not any(cur):
-                    self._drop_block(st, head, blk)
+                    self._cut(st, head, blk, 1)
                 else:
                     self._put_block(st, head, blk, cur, deferred)
             pos += take
@@ -906,8 +1145,7 @@ class BlockStore(ObjectStore):
     def _purge(self, st: dict, cid: str, oid: str) -> None:
         head = self._load_onode(st, cid, oid)
         if head is not None:
-            for blk in list(head["blocks"]):
-                self._drop_block(st, head, blk)
+            self._cut(st, head, 0)
         st["onodes"][_okey(cid, oid)] = None
         for k in self._omap_items(st, cid, oid):
             st["omaps"][f"{cid}/{oid}/{k}"] = None
@@ -918,14 +1156,15 @@ class BlockStore(ObjectStore):
         """`take`: the source is about to lose its data (a move; a
         clone whose next op empties or removes the source, as an EC
         shard's rollback stash before a whole rewrite or a delete): the
-        copy takes the source's blocks where they lie and leaves it
-        empty, and nothing is read, checked or written a block."""
+        copy takes the source's block map, the blocks where they lie,
+        and leaves it empty; nothing is read, checked or written.
+        Else a copy goes a run at a time, as it was written."""
         self._purge(st, dcid, doid)
         new = {"size": src_head["size"],
-               "xattrs": dict(src_head["xattrs"]), "blocks": {}}
+               "xattrs": dict(src_head["xattrs"]), "runs": []}
         st["onodes"][_okey(dcid, doid)] = new
         if take:
-            new["blocks"], src_head["blocks"] = src_head["blocks"], {}
+            new["runs"], src_head["runs"] = src_head["runs"], []
             src_head["size"] = 0
             for k, val in omap.items():
                 st["omaps"][f"{dcid}/{doid}/{k}"] = val
@@ -933,9 +1172,9 @@ class BlockStore(ObjectStore):
         # deferred-vs-direct follows the TOTAL copied size, or a large
         # clone would smuggle its whole body into one KV WAL record
         deferred = src_head["size"] <= self.deferred_max
-        for blk in sorted(src_head["blocks"]):
-            data = self._read_block_raw(st, src_head, blk)
-            self._put_block(st, new, blk, data, deferred=deferred)
+        for run in list(src_head["runs"]):
+            self._put_run(st, new, run[0],
+                          self._read_raw(st, run, run[0], run[1]), deferred)
         for k, val in omap.items():
             st["omaps"][f"{dcid}/{doid}/{k}"] = val
 
@@ -995,20 +1234,17 @@ class BlockStore(ObjectStore):
             _, cid, oid, size = op
             head = self._onode(st, cid, oid, create=True)
             if size < head["size"]:
-                first_dead = (size + MIN_ALLOC - 1) // MIN_ALLOC
-                for blk in [b for b in head["blocks"] if b >= first_dead]:
-                    self._drop_block(st, head, blk)
+                self._cut(st, head, (size + MIN_ALLOC - 1) // MIN_ALLOC)
                 if size % MIN_ALLOC:
                     blk = size // MIN_ALLOC
-                    if blk in head["blocks"]:
-                        cur = self._read_block_raw(st, head, blk)
-                        kept = cur[: size % MIN_ALLOC]
-                        if any(kept):
-                            self._put_block(
-                                st, head, blk, kept,
-                                deferred=len(kept) <= self.deferred_max)
-                        else:
-                            self._drop_block(st, head, blk)
+                    cur = self._read_block_raw(st, head, blk)
+                    kept = cur[: size % MIN_ALLOC]
+                    if any(kept):
+                        self._put_block(
+                            st, head, blk, kept,
+                            deferred=len(kept) <= self.deferred_max)
+                    elif cur:
+                        self._cut(st, head, blk, 1)
             head["size"] = size
         elif kind in ("remove", "try_remove"):
             _, cid, oid = op
@@ -1076,61 +1312,102 @@ class BlockStore(ObjectStore):
             raise StoreError(ENOENT, f"no object {cid}/{oid}")
         return head
 
-    def read(self, cid: str, oid: str, offset: int = 0,
-             length: int = 0) -> bytes:
+    def _read_span(self, head: dict, offset: int, end: int,
+                   out: memoryview, what: str) -> None:
+        """Bytes [offset, end) of an object into `out`, a device read a
+        run: whole blocks land where they belong in `out`, a first or
+        last block the request takes part of goes through a buffer of
+        its run's, and what no run holds reads as zeros."""
+        runs = head["runs"]
+        first, last = offset // MIN_ALLOC, (end - 1) // MIN_ALLOC
+        pos = offset
+        for i in range(_run_index(runs, first), len(runs)):
+            blk, n, poff, csums = runs[i]
+            if blk > last:
+                break
+            lo, hi = max(blk, first), min(blk + n, last + 1)
+            want = max(offset, lo * MIN_ALLOC), min(end, hi * MIN_ALLOC)
+            if want[0] > pos:
+                out[pos - offset: want[0] - offset] = \
+                    b"\x00" * (want[0] - pos)
+            at, sums = poff + (lo - blk) * MIN_ALLOC, \
+                csums[4 * (lo - blk): 4 * (hi - blk)]
+            dest = out[want[0] - offset: want[1] - offset]
+            if len(dest) == (hi - lo) * MIN_ALLOC:
+                self._read_verified(at, sums, lo, what, into=dest)
+            else:
+                data = memoryview(self._read_verified(at, sums, lo, what))
+                dest[:] = data[want[0] - lo * MIN_ALLOC:
+                               want[1] - lo * MIN_ALLOC]
+            pos = want[1]
+        if pos < end:
+            out[pos - offset:] = b"\x00" * (end - pos)
+
+    def _read(self, cid: str, oid: str, offset: int, length: int,
+              into: memoryview | None):
+        """`read` (the bytes) and `read_into` (how many went into the
+        caller's buffer)."""
         self._maybe_eio(oid)
         with self._lock:
             head = self._committed_onode(cid, oid)
             size = head["size"]
-            if length == 0:
+            if into is not None:
+                length = len(into)
+            elif length == 0:
                 length = max(0, size - offset)
             end = min(offset + length, size)
             if end <= offset:
-                return b""
-            out = bytearray()
-            blocks = head["blocks"]
-            pos = offset
-            while pos < end:
-                blk = pos // MIN_ALLOC
-                ent = blocks.get(blk)
-                if ent is None:
-                    take = min(end, (blk + 1) * MIN_ALLOC) - pos
-                    out.extend(b"\x00" * take)
-                    pos += take
-                    continue
-                # one device read for a run of blocks that lie one
-                # behind the other on the device, as one COW write
-                # lays them (a 512 KiB shard file is 128), and one
-                # native call for their checksums: a read and a CRC a
-                # block are two calls a block that each give the GIL
-                # up, and on a host whose OSDs share one interpreter
-                # every such call hands it round (a 4 ms shard read
-                # took 440 ms with sixteen degraded reads in flight,
-                # chip run, PR 28).  Every block is still checked
-                # against its own checksum.
-                n = 1
-                while (blk + n) * MIN_ALLOC < end:
-                    nxt = blocks.get(blk + n)
-                    if nxt is None or nxt[0] != ent[0] + n * MIN_ALLOC:
-                        break
-                    n += 1
-                run = self.dev.pread(ent[0], n * MIN_ALLOC)
-                if len(run) != n * MIN_ALLOC:
-                    raise StoreError(
-                        EIO, f"short read {cid}/{oid} block {blk}")
-                sums = crc32c_batch(np.frombuffer(
-                    run, dtype=np.uint8).reshape(n, MIN_ALLOC))
-                for i in range(n):
-                    if int(sums[i]) != blocks[blk + i][1]:
-                        raise StoreError(
-                            EIO, f"csum mismatch {cid}/{oid} block "
-                                 f"{blk + i}")
-                run = memoryview(run)
-                base = blk * MIN_ALLOC
-                upto = min(end, base + n * MIN_ALLOC)
-                out.extend(run[pos - base: upto - base])
-                pos = upto
+                return b"" if into is None else 0
+            if into is not None:
+                into = into[: end - offset]
+            what = f"{cid}/{oid}"
+            self.counters["reads"] += 1
+            # Where ONE run holds the request (every read of a shard
+            # file: 128 blocks as one COW write laid them) it is one
+            # device read, one native call for the checksums and one
+            # compare, and where whole blocks are asked for the read's
+            # buffer is the answer: a read and a CRC a block are two
+            # calls a block that each give the GIL up, and on a host
+            # whose OSDs share one interpreter every such call hands
+            # it round (a 4 ms shard read took 440 ms with sixteen
+            # degraded reads in flight, chip run, PR 28), and a copy
+            # of 512 KiB holds it.  Every block is still checked
+            # against its own checksum.
+            runs = head["runs"]
+            first, last = offset // MIN_ALLOC, (end - 1) // MIN_ALLOC
+            i = _run_index(runs, first)
+            whole = offset % MIN_ALLOC == 0 and \
+                end - offset == (last + 1 - first) * MIN_ALLOC
+            if (whole or into is None) and i < len(runs) \
+                    and runs[i][0] <= first \
+                    and last < runs[i][0] + runs[i][1]:
+                blk, _n, poff, csums = runs[i]
+                data = self._read_verified(
+                    poff + (first - blk) * MIN_ALLOC,
+                    csums[4 * (first - blk): 4 * (last + 1 - blk)],
+                    first, what, into=into)
+                if whole:
+                    self.counters["reads_whole_run"] += 1
+                    return data if into is None else len(data)
+                lo = offset - first * MIN_ALLOC
+                return data[lo: lo + end - offset]
+            if into is not None:
+                self._read_span(head, offset, end, into, what)
+                return len(into)
+            out = bytearray(end - offset)
+            self._read_span(head, offset, end, memoryview(out), what)
             return bytes(out)
+
+    def read(self, cid: str, oid: str, offset: int = 0,
+             length: int = 0) -> bytes:
+        return self._read(cid, oid, offset, length, None)
+
+    def read_into(self, cid: str, oid: str, buf, offset: int = 0) -> int:
+        """Read from `offset` on into the caller's buffer, as much as
+        it takes or the object has, and say how much that was: where
+        whole blocks are asked for, the device read lands there and
+        nowhere else."""
+        return self._read(cid, oid, offset, 0, memoryview(buf).cast("B"))
 
     def stat(self, cid: str, oid: str) -> dict:
         with self._lock:

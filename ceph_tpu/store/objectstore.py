@@ -315,6 +315,18 @@ class ObjectStore(abc.ABC):
              length: int = 0) -> bytes:
         """length == 0 -> to EOF.  Raises StoreError(ENOENT)."""
 
+    def read_into(self, cid: str, oid: str, buf, offset: int = 0) -> int:
+        """Read from `offset` on into the caller's buffer, as much as
+        it takes or the object has, and say how much that was.  A
+        backend that can have its reads land there overrides this."""
+        buf = memoryview(buf).cast("B")
+        if not len(buf):
+            self.stat(cid, oid)         # ENOENT as a read's
+            return 0
+        data = self.read(cid, oid, offset, len(buf))
+        buf[: len(data)] = data
+        return len(data)
+
     @abc.abstractmethod
     def stat(self, cid: str, oid: str) -> dict: ...
 

@@ -45,7 +45,9 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
                                  store/objectstore.py (BlockStore's wal
                                  carries args blocks, dev_writes: the
                                  4 KiB blocks the commit wrote and the
-                                 device write calls it made for them)
+                                 device write calls it made for them;
+                                 onodes, runs: the onodes it wrote and
+                                 the runs of their block maps)
       replica_wait               osd/backend_ec.py, osd/backend_rep.py
                                  (sub-op round trip; closes at finish)
       gather_wait                osd/recovery_svc.py `ShardGather.stamp`,
@@ -94,7 +96,11 @@ osd/OpRequest.cc) grown from an event timeline into a span tracer:
       scrub.collect, scrub.peer_wait, scrub.compare
                                  osd/scrubber.py, on the primary's
                                  ``scrub`` op and each peer's
-                                 ``scrub_scan`` op
+                                 ``scrub_scan`` op (scrub.read: the
+                                 files read into the rows of their
+                                 batches, args shards, bytes, and from
+                                 a BlockStore reads, reads_whole_run;
+                                 scrub.stack: a batch's submit)
       paxos.propose, paxos.commit, execute (mon commands)
                                  mon/monitor.py
 

@@ -854,8 +854,9 @@ class TestBlockStoreCrashMatrix:
 
 # ---------------------------------------------------------------------------
 # BlockStore extents (ISSUE 29): a run of whole blocks is one
-# allocation, one checksum call and one device write; the onode, the
-# WAL record and the crash plane keep block granularity.
+# allocation, one checksum call and one device write; since ISSUE 44
+# one run of the onode's map and one WAL target too.  The checksums and
+# the crash plane keep block granularity.
 # ---------------------------------------------------------------------------
 
 
@@ -882,49 +883,67 @@ def _count_pwrites(s):
     return calls
 
 
+def _blocks(head):
+    """An onode's block map a block at a time, {block#: [poff, crc32c]}
+    in the map's order: what the decoded onode was before it held runs
+    (and the plain-dict KV form still is)."""
+    import numpy as np
+    from ceph_tpu.store.blockstore import MIN_ALLOC
+    return {blk + i: [poff + i * MIN_ALLOC, csum]
+            for blk, n, poff, csums in head["runs"]
+            for i, csum in enumerate(
+                np.frombuffer(csums, dtype="<u4").tolist())}
+
+
+def _dump_onode_as_before(head, form):
+    """An onode as the stores before ISSUE 44 wrote it: "dict", the
+    block map a plain dict through the generic encoder (before ISSUE
+    29), or "packed", sixteen bytes a block (ISSUE 29 to 43); the
+    parent's `dump_onode`, kept here."""
+    import numpy as np
+    from ceph_tpu.store.blockstore import _MAP_ENTRY
+    from ceph_tpu.utils import denc
+    blocks = _blocks(head)
+    if form == "dict":
+        return denc.dumps({"size": head["size"], "xattrs": head["xattrs"],
+                           "blocks": blocks})
+    rows = np.empty(len(blocks), dtype=_MAP_ENTRY)
+    if blocks:
+        rows["blk"] = np.fromiter(blocks, dtype="<u8", count=len(blocks))
+        ents = np.array(list(blocks.values()), dtype="<u8")
+        rows["poff"], rows["csum"] = ents[:, 0], ents[:, 1]
+    return denc.dumps({"size": head["size"], "xattrs": head["xattrs"],
+                       "map": memoryview(rows.view(np.uint8))})
+
+
 def _extents(head):
     """Runs of blocks that lie one behind the other on the device, in
-    logical order."""
-    from ceph_tpu.store.blockstore import MIN_ALLOC
-    runs, last = 0, None
-    for blk in sorted(head["blocks"]):
-        poff = head["blocks"][blk][0]
-        if last is None or poff != last + MIN_ALLOC:
-            runs += 1
-        last = poff
-    return runs
+    logical order: the map's runs, for an object without holes."""
+    return len(head["runs"])
 
 
 def _block_at_a_time_store(path):
     """A BlockStore with the parent's write path (ISSUE 29's "before"),
     kept here to write stores the way they were written: one allocation,
     one checksum call and one device write a 4 KiB block."""
-    from ceph_tpu.ops.crc32c import crc32c
     from ceph_tpu.store.blockstore import MIN_ALLOC, BlockStore
 
     class Old(BlockStore):
-        def _put_block(self, st, head, blk, data, deferred):
-            old = head["blocks"].get(blk)
-            if old is not None:
-                self._free_block(st, old[0])
-            data = bytes(data)
-            if len(data) < MIN_ALLOC:
-                data = data + b"\x00" * (MIN_ALLOC - len(data))
-            poff = self._allocate(st, MIN_ALLOC)[0][0]
-            head["blocks"][blk] = [poff, crc32c(0, data)]
-            st["pending"][poff] = data
-            (st["wal"] if deferred else st["direct"])[poff] = data
-
         def _put_run(self, st, head, blk, data, deferred):
             for i in range(len(data) // MIN_ALLOC):
-                self._put_block(
+                super()._put_run(
                     st, head, blk + i,
-                    data[i * MIN_ALLOC: (i + 1) * MIN_ALLOC], deferred)
+                    bytes(data[i * MIN_ALLOC: (i + 1) * MIN_ALLOC]),
+                    deferred)
 
-        def _write_staged(self, staged, tracked):
-            for poff, data in staged.items():
-                self._dev_write(poff, data, tracked)
-            return len(staged)
+        def _write_staged(self, extents, tracked):
+            calls = 0
+            for poff, data in extents:
+                for at in range(0, len(data), MIN_ALLOC):
+                    self._dev_write(poff + at, data[at: at + MIN_ALLOC],
+                                    tracked)
+                    calls += 1
+            return calls
 
     return Old(path)
 
@@ -967,15 +986,17 @@ class TestBlockStoreExtents:
         s.apply_transaction(T().write("c", "shard", 0, memoryview(payload)))
         assert len(calls) == 1 and calls[0][1] == len(payload)
         head = s._committed_onode("c", "shard")
-        assert sorted(head["blocks"]) == list(range(128))
-        for blk, (poff, csum) in head["blocks"].items():
+        assert [run[:2] for run in head["runs"]] == [(0, 128)]
+        blocks = _blocks(head)
+        assert sorted(blocks) == list(range(128))
+        for blk, (poff, csum) in blocks.items():
             block = payload[blk * MIN_ALLOC: (blk + 1) * MIN_ALLOC]
             assert csum == crc32c(0, block), blk
             assert s.dev.pread(poff, MIN_ALLOC) == block, blk
         assert s.read("c", "shard") == payload
         assert s.read("c", "shard", 5000, 300000) == payload[5000:305000]
         # one byte flipped under the store, in block 77
-        at = head["blocks"][77][0] + 1234
+        at = blocks[77][0] + 1234
         s.dev.pwrite(at, bytes([s.dev.pread(at, 1)[0] ^ 0x40]))
         with pytest.raises(StoreError) as ei:
             s.read("c", "shard")
@@ -984,15 +1005,16 @@ class TestBlockStoreExtents:
             payload[:77 * MIN_ALLOC]
         s.umount()
 
+    @pytest.mark.parametrize("form", ["dict", "packed"])
     def test_onode_block_map_is_packed_and_the_old_form_still_reads(
-            self, tmp_path):
-        """The KV holds an onode's block map as one packed field; a
-        store whose onodes were written in the form before (the map a
-        plain dict through the generic encoder) mounts, verifies its
-        free list and reads, and rewrites an onode it touches."""
+            self, tmp_path, form):
+        """The KV holds an onode's block map packed, a run an entry; a
+        store whose onodes were written in a form before (the map a
+        plain dict through the generic encoder; one packed entry a
+        block) mounts, verifies its free list and reads, and rewrites
+        an onode it touches."""
         from ceph_tpu.store.blockstore import (P_ONODE, dump_onode,
                                                load_onode)
-        from ceph_tpu.utils import denc
         s = self._mk(tmp_path)
         body = _seeded(300 * 1024 + 77, 33)
         s.apply_transaction(T().write("c", "obj", 0, body)
@@ -1000,15 +1022,17 @@ class TestBlockStoreExtents:
         s.apply_transaction(T().touch("c", "empty"))
         head = s._committed_onode("c", "obj")
         blob = s.db.get(P_ONODE, "c/obj")
-        assert load_onode(blob) == head and len(head["blocks"]) == 76
-        assert len(blob) < 76 * 16 + 64 and b"blocks" not in blob
+        assert load_onode(blob) == head
+        assert [run[:2] for run in head["runs"]] == [(0, 76)]
+        assert len(blob) < 76 * 4 + 24 + 64 and b"blocks" not in blob
         assert load_onode(dump_onode(s._committed_onode("c", "empty"))) \
-            == {"size": 0, "xattrs": {}, "blocks": {}}
+            == {"size": 0, "xattrs": {}, "runs": []}
         # the same onodes as a store of before this form left them
         kvt = s.db.transaction()
         for oid in ("obj", "empty"):
-            kvt.set(P_ONODE, f"c/{oid}",
-                    denc.dumps(s._committed_onode("c", oid)))
+            old = _dump_onode_as_before(s._committed_onode("c", oid), form)
+            assert load_onode(old) == s._committed_onode("c", oid)
+            kvt.set(P_ONODE, f"c/{oid}", old)
         s.db.submit_transaction(kvt, sync=True)
         s.umount()
         s2 = self._remount(tmp_path)
@@ -1017,7 +1041,10 @@ class TestBlockStoreExtents:
         assert s2.getattr("c", "obj", "a") == b"v"
         assert s2.stat("c", "empty") == {"size": 0}
         s2.apply_transaction(T().write("c", "obj", 4096, b"x" * 4096))
-        assert b"blocks" not in s2.db.get(P_ONODE, "c/obj")
+        now = s2.db.get(P_ONODE, "c/obj")
+        assert b"blocks" not in now and b"map" not in now
+        assert [run[:2] for run in load_onode(now)["runs"]] == \
+            [(0, 1), (1, 1), (2, 74)]
         assert s2.read("c", "obj") == \
             body[:4096] + b"x" * 4096 + body[8192:]
         s2.umount()
@@ -1032,7 +1059,7 @@ class TestBlockStoreExtents:
         old, new = _seeded(512 * 1024, 31), _seeded(512 * 1024, 32)
         s.apply_transaction(T().write("c", "shard", 0, old)
                             .setattr("c", "shard", "hinfo", b"h1"))
-        lay = dict(s._committed_onode("c", "shard")["blocks"])
+        lay = list(s._committed_onode("c", "shard")["runs"])
         calls = _count_pwrites(s)
         reads = []
         pread = s.dev.pread
@@ -1048,7 +1075,7 @@ class TestBlockStoreExtents:
         assert not reads
         assert [n for _off, n in calls] == \
             ([len(new)] if then == "truncate" else [])
-        assert s._committed_onode("c", "stash")["blocks"] == lay
+        assert s._committed_onode("c", "stash")["runs"] == lay
         assert s.getattr("c", "stash", "hinfo") == b"h1"
         s.umount()
         s2 = self._remount(tmp_path)
@@ -1057,7 +1084,7 @@ class TestBlockStoreExtents:
             assert s2.read("c", "shard") == new
             assert s2.getattr("c", "shard", "hinfo") == b"h2"
             live = {e[0] for o in ("shard", "stash") for e in
-                    s2._committed_onode("c", o)["blocks"].values()}
+                    _blocks(s2._committed_onode("c", o)).values()}
             assert len(live) == 256
         else:
             assert not s2.exists("c", "shard")
@@ -1070,8 +1097,9 @@ class TestBlockStoreExtents:
     @pytest.mark.parametrize("case", ["short", "over_iov_max"])
     def test_gather_write_of_a_run_lands_whole(self, tmp_path, monkeypatch,
                                                case):
-        """The one device write of a run is a gather write: a short one
-        is finished, and a run of more buffers than one call takes is
+        """Extents staged one behind the other on the device (here a
+        block a write of one transaction) go out in one gather write: a
+        short one is finished, and more buffers than one call takes are
         still one run."""
         import os
         from ceph_tpu.store import blockstore
@@ -1087,8 +1115,13 @@ class TestBlockStoreExtents:
         n = 20 if case == "short" else blockstore.IOV_MAX + 256
         payload = _seeded(n * blockstore.MIN_ALLOC, 11)
         calls = _count_pwrites(s)
-        s.apply_transaction(T().write("c", "o", 0, payload))
+        t = T()
+        for at in range(0, len(payload), blockstore.MIN_ALLOC):
+            t.write("c", "o", at, payload[at: at + blockstore.MIN_ALLOC])
+        s.apply_transaction(t)
         monkeypatch.undo()
+        assert [run[:2] for run in
+                s._committed_onode("c", "o")["runs"]] == [(0, n)]
         if case == "short":
             assert seen == [20] and len(calls) == 1 + 20
         else:
@@ -1119,7 +1152,7 @@ class TestBlockStoreExtents:
         calls = _count_pwrites(s)
         s.apply_transaction(T().write("c", "big", 0, payload))
         head = s._committed_onode("c", "big")
-        assert len(head["blocks"]) == blocks
+        assert sum(run[1] for run in head["runs"]) == blocks
         assert len(calls) == _extents(head) > 31
         assert sum(n for _off, n in calls) == len(payload)
         assert s.read("c", "big") == payload
@@ -1213,12 +1246,12 @@ class TestBlockStoreExtents:
             records = list(s.db.iterate(P_WAL, ""))
             assert len(records) == 1
             writes = denc.loads(records[0][1])["writes"]
-            assert [len(d) for _o, d in writes] == [MIN_ALLOC] * 8
+            assert [len(d) for _o, d in writes] == [8 * MIN_ALLOC]
             assert b"".join(d for _o, d in writes) == payload
             s.umount()
             s2 = self._remount(tmp_path)
             assert s2.counters["wal_records_replayed"] == 1
-            assert s2.counters["wal_torn_extent_repairs"] == 8
+            assert s2.counters["wal_torn_extent_repairs"] == 1
             assert s2.read("c", "o") == payload
             s2.umount()
             return
@@ -1226,8 +1259,8 @@ class TestBlockStoreExtents:
         s.apply_transaction(T().write("c", "o", 0, payload))
         assert len(calls) == 1 and calls[0][1] == len(payload)
         head = s._committed_onode("c", "o")
-        poffs = {p for p, _c in head["blocks"].values()}
-        assert len(s._wal_applied) == 1 and poffs <= s._wal_poffs
+        assert len(s._wal_applied) == 1 and s._wal_extents == \
+            [(poff, n * MIN_ALLOC) for _b, n, poff, _c in head["runs"]]
         record = s._wal_applied[0]
         flushed = []
         real = s._flush_deferred
@@ -1358,6 +1391,457 @@ class TestBlockStoreExtents:
         with open(tmp_path / "old" / "block", "rb") as f, \
                 open(tmp_path / "new" / "block", "rb") as g:
             assert f.read() == g.read()
+
+
+# ---------------------------------------------------------------------------
+# BlockStore's block map holds runs (ISSUE 44): a shard file is one run
+# from the write to the read; a partial overwrite, a hole punch or a
+# truncate splits the run it touches; the map is canonical whatever the
+# path that made it; every block is still held to its own checksum.
+# ---------------------------------------------------------------------------
+
+
+def _pattern(blk, n=4096):
+    """Block content no library's stream decides: the fixtures below
+    carry its checksums."""
+    return bytes((i * 7 + blk * 13 + (i >> 8)) % 251 for i in range(n))
+
+
+# One onode as the parent commit (PR 43) left it in the KV, in both
+# forms stores hold: "packed" is its `dump_onode`'s value (sixteen
+# bytes a block, in the order the blocks were written), "dict" the
+# generic encoding of the decoded head (stores from before ISSUE 29).
+# The object: size 40960, blocks {5, 2, 3, 0, 7, 8, 9} at the device
+# offsets of _OLD_LAYOUT, block b holding _pattern(b) but 5 (its first
+# 1000 bytes, zero-padded) and 8 (_pattern(18)); xattr a=v.
+_OLD_LAYOUT = {5: 0, 2: 8192, 3: 12288, 0: 16384, 7: 20480, 8: 4096,
+               9: 28672}
+_OLD_ONODES = {
+    "packed": (
+        b"\t\x03\x06\x04size\x03\x80\x80\x05\x06\x06xattrs\t\x01\x06\x01a"
+        b"\x05\x01v\x06\x03map\x05p\x05\x00\x00\x00\x00\x00\x00\x00\x00\x00"
+        b"\x00\x00\x11R\xa3%\x02\x00\x00\x00\x00 \x00\x00\x00\x00\x00\x00\x93"
+        b"\xf7(\x1c\x03\x00\x00\x00\x000\x00\x00\x00\x00\x00\x00+)\r\xfd\x00"
+        b"\x00\x00\x00\x00@\x00\x00\x00\x00\x00\x00\xf4\x0fp'\x07\x00\x00\x00"
+        b"\x00P\x00\x00\x00\x00\x00\x00\xff\xe1\x0c\x95\x08\x00\x00\x00\x00"
+        b"\x10\x00\x00\x00\x00\x00\x00hS!\x93\t\x00\x00\x00\x00p\x00\x00\x00"
+        b"\x00\x00\x00\xff\xf0\xd6\xc3"),
+    "dict": (
+        b"\t\x03\x06\x04size\x03\x80\x80\x05\x06\x06xattrs\t\x01\x06\x01a"
+        b"\x05\x01v\x06\x06blocks\t\x07\x03\n\x07\x02\x03\x00\x03\xa2\xc8\x9a"
+        b"\xda\x04\x03\x04\x07\x02\x03\x80\x80\x01\x03\xa6\xde\xc7\xc2\x03\x03"
+        b"\x06\x07\x02\x03\x80\xc0\x01\x03\xd6\xa4\xe9\xd0\x1f\x03\x00\x07\x02"
+        b"\x03\x80\x80\x02\x03\xe8\xbf\x80\xf7\x04\x03\x0e\x07\x02\x03\x80\xc0"
+        b"\x02\x03\xfe\x87\xe7\xd0\x12\x03\x10\x07\x02\x03\x80@\x03\xd0\xcd\x8a"
+        b"\xb2\x12\x03\x12\x07\x02\x03\x80\xc0\x03\x03\xfe\xc3\xb7\xbd\x18"),
+}
+_OLD_FREELIST = (b"\x07\x02\x07\x02\x03\x80\x80\x03\x03\x80@\x07\x02\x03\x80"
+                 b"\x80\x04\x03\x80\x80|")
+_OLD_SUPER = (b"\t\x02\x06\tmin_alloc\x03\x80@\x06\x08dev_size\x03\x80\x80"
+              b"\x80\x01")
+
+
+class _MapModel:
+    """What the store should hold, the plain way: an object is a size,
+    its xattrs and {block#: bytes of one block}; a block that is not
+    there is a hole."""
+    B = 4096
+
+    def __init__(self):
+        self.objs = {}
+
+    def obj(self, oid):
+        return self.objs.setdefault(oid, {"size": 0, "blocks": {}})
+
+    def write(self, oid, offset, data, zero=False):
+        o, B = self.obj(oid), self.B
+        pos = 0
+        while pos < len(data):
+            blk, boff = divmod(offset + pos, B)
+            take = min(len(data) - pos, B - boff)
+            cur = bytearray(o["blocks"].get(blk, bytes(B)))
+            cur[boff: boff + take] = data[pos: pos + take]
+            if zero and not any(cur):
+                o["blocks"].pop(blk, None)
+            else:
+                o["blocks"][blk] = bytes(cur)
+            pos += take
+        o["size"] = max(o["size"], offset + len(data))
+
+    def truncate(self, oid, size):
+        o, B = self.obj(oid), self.B
+        if size < o["size"]:
+            for blk in [b for b in o["blocks"] if b * B >= size]:
+                del o["blocks"][blk]
+            blk, keep = divmod(size, B)
+            if keep and blk in o["blocks"]:
+                kept = o["blocks"][blk][:keep]
+                if any(kept):
+                    o["blocks"][blk] = kept + bytes(B - keep)
+                else:
+                    del o["blocks"][blk]
+        o["size"] = size
+
+    def clone(self, src, dst):
+        o = self.objs[src]
+        self.objs[dst] = {"size": o["size"], "blocks": dict(o["blocks"])}
+
+    def read(self, oid):
+        o, B = self.objs[oid], self.B
+        out = bytearray(o["size"])
+        for blk, data in o["blocks"].items():
+            piece = data[: max(0, o["size"] - blk * B)]
+            out[blk * B: blk * B + len(piece)] = piece
+        return bytes(out)
+
+
+class TestBlockStoreRuns:
+
+    def _mk(self, tmp_path, disk=True):
+        from ceph_tpu.store.blockstore import BlockStore
+        s = BlockStore(str(tmp_path / "bs") if disk else "")
+        s.mkfs()
+        s.mount()
+        s.apply_transaction(T().create_collection("c"))
+        return s
+
+    # -- the map against a plain model ---------------------------------------
+
+    @staticmethod
+    def _agrees(s, model, rng):
+        import numpy as np
+        from ceph_tpu.store.blockstore import MIN_ALLOC
+        held = []
+        for oid, o in model.objs.items():
+            want = model.read(oid)
+            assert s.stat("c", oid) == {"size": o["size"]}, oid
+            assert s.read("c", oid) == want, oid
+            if want:
+                lo = int(rng.integers(0, len(want)))
+                n = int(rng.integers(1, len(want) - lo + 1))
+                assert s.read("c", oid, lo, n) == want[lo: lo + n], \
+                    (oid, lo, n)
+                buf = np.full(n + 3, 0xAA, dtype=np.uint8)
+                assert s.read_into("c", oid, buf[:n], lo) == n
+                assert buf.tobytes() == want[lo: lo + n] + b"\xaa" * 3
+            runs = s._committed_onode("c", oid)["runs"]
+            assert set(_blocks({"runs": runs})) == set(o["blocks"]), oid
+            for (blk, n, poff, csums), nxt in zip(runs, runs[1:] + [None]):
+                assert n > 0 and len(csums) == 4 * n
+                held.append((poff, n * MIN_ALLOC))
+                # sorted, apart, and joined wherever they could be
+                assert nxt is None or blk + n < nxt[0] or (
+                    blk + n == nxt[0] and poff + n * MIN_ALLOC != nxt[2])
+        assert sorted(s.collection_list("c")) == sorted(model.objs)
+        # the free list is the device less what the maps hold
+        taken = sorted(held + [tuple(e) for e in s.alloc.dump()])
+        assert all(a + n <= b for (a, n), (b, _m) in zip(taken, taken[1:]))
+        assert sum(n for _a, n in taken) == s.dev.size
+
+    @pytest.mark.parametrize("seed,disk", [(1, False), (2, False),
+                                           (3, False), (4, False),
+                                           (5, True), (6, True)])
+    def test_random_history_equals_a_block_model(self, tmp_path, seed, disk):
+        """Writes, zeros, truncates, clones, clones that take (an EC
+        shard's stash) and moves, one to four a transaction so that an
+        op meets what the ops before it staged: after every commit the
+        reads, the sizes, the block sets and the free list are the
+        model's, and the map is sorted and canonical."""
+        import numpy as np
+        rng = np.random.default_rng(seed)
+        s = self._mk(tmp_path, disk=disk)
+        model = _MapModel()
+        names = [f"o{i}" for i in range(5)]
+        span = 40 * 4096
+
+        def some_bytes(n):
+            return rng.integers(0, 256, n, dtype=np.uint8).tobytes()
+
+        def extent():
+            off = int(rng.integers(0, span))
+            if rng.random() < 0.5:
+                off -= off % 4096
+            n = int(rng.choice([1, 100, 4096, 8192, 5000, 40000, 70000]))
+            if rng.random() < 0.5:
+                n = max(1, n - n % 4096)
+            return off, n
+
+        for step in range(60):
+            t = T()
+            for _ in range(int(rng.integers(1, 5))):
+                kind = rng.choice(["write", "write", "write", "zero",
+                                   "truncate", "clone", "stash", "move",
+                                   "remove"])
+                oid = str(rng.choice(names))
+                have = oid in model.objs
+                other = str(rng.choice([n for n in names if n != oid]))
+                if kind == "write":
+                    off, n = extent()
+                    data = some_bytes(n)
+                    t.write("c", oid, off, data)
+                    model.write(oid, off, data)
+                elif kind == "zero":
+                    off, n = extent()
+                    t.zero("c", oid, off, n)
+                    model.write(oid, off, bytes(n), zero=True)
+                elif kind == "truncate":
+                    size = int(rng.integers(0, span))
+                    t.truncate("c", oid, size)
+                    model.truncate(oid, size)
+                elif kind == "clone" and have:
+                    t.clone("c", oid, other)
+                    model.clone(oid, other)
+                elif kind == "stash" and have:
+                    # the copy takes the blocks: the next op empties or
+                    # removes the source
+                    t.try_clone("c", oid, other)
+                    model.clone(oid, other)
+                    if rng.random() < 0.5:
+                        t.truncate("c", oid, 0)
+                        model.truncate(oid, 0)
+                    else:
+                        t.try_remove("c", oid)
+                        del model.objs[oid]
+                elif kind == "move" and have:
+                    t.collection_move_rename("c", oid, "c", other)
+                    model.clone(oid, other)
+                    del model.objs[oid]
+                elif kind == "remove" and have:
+                    t.remove("c", oid)
+                    del model.objs[oid]
+            s.apply_transaction(t)
+            self._agrees(s, model, rng)
+        assert s.journal_stats()["runs_per_onode"] > 1
+        s.umount()
+        if disk:
+            from ceph_tpu.store.blockstore import BlockStore
+            s2 = BlockStore(str(tmp_path / "bs"))
+            s2.mount()
+            assert s2.counters["freelist_repairs"] == 0
+            self._agrees(s2, model, rng)
+            s2.umount()
+
+    # -- stores of before ----------------------------------------------------
+
+    @pytest.mark.parametrize("form", ["packed", "dict"])
+    def test_parents_onode_bytes_mount_and_read(self, tmp_path, form):
+        from ceph_tpu.store.blockstore import (MIN_ALLOC, P_ONODE, P_SUPER,
+                                               BlockStore, load_onode)
+        s = self._mk(tmp_path)
+        s.umount()
+        # the parent's store, put down as it left it: its device, its
+        # superblock and free list, its onode
+        s = BlockStore(str(tmp_path / "bs"))
+        s.db.open()
+        s.dev.open()
+        s.dev.grow(1 << 20)
+        content = {blk: _pattern(blk) for blk in _OLD_LAYOUT}
+        content[5] = _pattern(5, 1000) + bytes(MIN_ALLOC - 1000)
+        content[8] = _pattern(18)
+        for blk, poff in _OLD_LAYOUT.items():
+            s.dev.pwrite(poff, content[blk])
+        s.dev.flush()
+        kvt = s.db.transaction()
+        kvt.set(P_ONODE, "c/obj", _OLD_ONODES[form])
+        kvt.set(P_SUPER, "freelist", _OLD_FREELIST)
+        kvt.set(P_SUPER, "super", _OLD_SUPER)
+        s.db.submit_transaction(kvt, sync=True)
+        s.dev.close()
+        s.db.close()
+        want = bytearray(40960)
+        for blk, data in content.items():
+            want[blk * MIN_ALLOC: (blk + 1) * MIN_ALLOC] = data
+        want = bytes(want)
+        assert load_onode(_OLD_ONODES[form])["runs"] == \
+            load_onode(_OLD_ONODES["packed"])["runs"]
+        s = BlockStore(str(tmp_path / "bs"))
+        s.mount()
+        assert s.counters["freelist_repairs"] == 0
+        assert s.stat("c", "obj") == {"size": 40960}
+        assert s.getattr("c", "obj", "a") == b"v"
+        assert s.read("c", "obj") == want
+        assert s.read("c", "obj", 8000, 20000) == want[8000:28000]
+        assert [run[:3] for run in s._committed_onode("c", "obj")["runs"]] \
+            == [(0, 1, 16384), (2, 2, 8192), (5, 1, 0), (7, 1, 20480),
+                (8, 1, 4096), (9, 1, 28672)]
+        # a write rewrites the onode in today's form; the rest stands
+        s.apply_transaction(T().write("c", "obj", 3 * MIN_ALLOC + 7, b"new"))
+        want = want[:3 * MIN_ALLOC + 7] + b"new" + want[3 * MIN_ALLOC + 10:]
+        assert s.read("c", "obj") == want
+        s.umount()
+        s = BlockStore(str(tmp_path / "bs"))
+        s.mount()
+        assert s.read("c", "obj") == want
+        blob = s.db.get(P_ONODE, "c/obj")
+        assert b"map" not in blob and b"blocks" not in blob
+        s.umount()
+
+    # -- every block against its own checksum ---------------------------------
+
+    @pytest.mark.parametrize("how", ["read", "read_into", "range",
+                                     "clone", "rmw"])
+    def test_flipped_byte_in_block_77_is_eio_naming_it(self, tmp_path, how):
+        import numpy as np
+        from ceph_tpu.store.blockstore import MIN_ALLOC
+        s = self._mk(tmp_path)
+        payload = _seeded(512 * 1024, 44)
+        s.apply_transaction(T().write("c", "shard", 0, payload))
+        (_blk, n, poff, _csums), = s._committed_onode("c", "shard")["runs"]
+        assert n == 128
+        at = poff + 77 * MIN_ALLOC + 4000
+        s.dev.pwrite(at, bytes([s.dev.pread(at, 1)[0] ^ 0x01]))
+        with pytest.raises(StoreError) as ei:
+            if how == "read":
+                s.read("c", "shard")
+            elif how == "read_into":
+                s.read_into("c", "shard", np.empty(len(payload), np.uint8))
+            elif how == "range":
+                s.read("c", "shard", 77 * MIN_ALLOC + 10, 5)
+            elif how == "clone":
+                s.apply_transaction(T().clone("c", "shard", "copy"))
+            else:
+                s.apply_transaction(
+                    T().write("c", "shard", 77 * MIN_ALLOC + 1, b"x"))
+        assert ei.value.errno == 5 and "block 77" in str(ei.value)
+        # the blocks either side read; nothing of a failed txn stays
+        assert s.read("c", "shard", 0, 77 * MIN_ALLOC) == \
+            payload[:77 * MIN_ALLOC]
+        assert s.read("c", "shard", 78 * MIN_ALLOC) == \
+            payload[78 * MIN_ALLOC:]
+        assert not s.exists("c", "copy")
+        s.umount()
+
+    # -- what a whole-file read and a shard commit cost the interpreter --------
+
+    @staticmethod
+    def _lines_run(fn):
+        """Lines of blockstore.py the interpreter ran inside `fn`: a
+        loop a block shows as some hundreds of them."""
+        import sys
+        from ceph_tpu.store import blockstore
+        seen = [0]
+
+        def tracer(frame, event, _arg):
+            if frame.f_code.co_filename != blockstore.__file__:
+                return None
+            if event == "line":
+                seen[0] += 1
+            return tracer
+        sys.settrace(tracer)
+        try:
+            fn()
+        finally:
+            sys.settrace(None)
+        return seen[0]
+
+    @pytest.mark.parametrize("what", ["read", "read_into", "commit"])
+    def test_whole_file_costs_a_run_not_its_blocks(self, tmp_path, what):
+        """In the style of ISSUE 36's count (crc32c_combine 1,397 -> 0):
+        a shard file's read makes no copy of it (the device read's
+        buffer is what comes back, or the caller's own is filled) and
+        runs the same few lines for 128 blocks as for 1,024; so does
+        its commit.  The parent ran some 900 lines a 128-block read."""
+        import tracemalloc
+        import numpy as np
+        s = self._mk(tmp_path)
+        # (room first: the device grows a MiB a step of the allocator)
+        s.apply_transaction(T().write("c", "room", 0, bytes(6 << 20)))
+        s.apply_transaction(T().remove("c", "room"))
+        lines = {}
+        for blocks in (128, 1024):
+            payload = _seeded(blocks * 4096, blocks)
+            oid = f"f{blocks}"
+
+            def commit():
+                s.apply_transaction(
+                    T().write("c", oid, 0, payload)
+                    .setattr("c", oid, "hinfo", b"h" * 40))
+            if what == "commit":
+                lines[blocks] = self._lines_run(commit)
+                assert s.read("c", oid) == payload
+                continue
+            commit()
+            buf = np.empty(len(payload), dtype=np.uint8)
+            if what == "read":
+                def read():
+                    return s.read("c", oid)
+            else:
+                def read():
+                    s.read_into("c", oid, buf)
+                    return buf
+            assert bytes(read()) == payload
+            before = s.journal_stats()
+            tracemalloc.start()
+            got = read()
+            _now, peak = tracemalloc.get_traced_memory()
+            tracemalloc.stop()
+            # at most the file itself, once (none of it for read_into)
+            assert peak <= (len(payload) if what == "read" else 0) + 32768
+            lines[blocks] = self._lines_run(read)
+            after = s.journal_stats()
+            assert after["reads"] - before["reads"] == 2
+            assert after["reads_whole_run"] - before["reads_whole_run"] == 2
+            assert bytes(got) == payload
+        assert lines[128] == lines[1024] < (400 if what == "commit"
+                                            else 120), lines
+        print(what, lines)
+        stats = s.journal_stats()
+        if what != "commit":
+            assert stats["read_whole_run_share"] == 1.0
+        s.umount()
+
+    def test_a_resident_shard_file_is_a_few_objects_to_the_collector(
+            self, tmp_path):
+        """The decoded onode of a shard file was 129 containers the
+        cycle collector tracks (a dict of 128 lists); as one run it is
+        its head, its xattrs and its list of runs."""
+        import gc
+        s = self._mk(tmp_path, disk=False)
+        for i in range(20):
+            s.apply_transaction(T().write("c", f"s{i}", 0,
+                                          _seeded(512 * 1024, i))
+                                .setattr("c", f"s{i}", "hinfo", b"h"))
+        gc.collect()        # a tuple of ints and bytes is untracked now
+
+        def tracked(obj, seen):
+            if id(obj) in seen or not gc.is_tracked(obj):
+                return 0
+            seen.add(id(obj))
+            return 1 + sum(tracked(r, seen) for r in gc.get_referents(obj))
+        heads = list(s._onodes._heads.values())
+        assert len(heads) >= 20
+        assert sum(tracked(h, set()) for h in heads) <= 3 * len(heads)
+        assert s._onodes._weight == sum(
+            1 + sum(run[1] for run in h["runs"]) for h in heads)
+        s.umount()
+
+    def test_wal_span_and_perf_dump_say_runs(self, tmp_path):
+        from ceph_tpu.utils import optracker
+        from ceph_tpu.utils.clock import ManualClock
+        s = self._mk(tmp_path)
+        trk = optracker.OpTracker(ManualClock(), history_size=8)
+
+        def wal_args(txn):
+            op = trk.create("w")
+            with optracker.op_context(op):
+                s.apply_transaction(txn)
+            op.finish()
+            doc = trk.dump_historic_ops()["ops"][-1]
+            (wal,) = [sp for sp in doc["spans"] if sp["name"] == "wal"]
+            return wal["args"]
+        args = wal_args(T().write("c", "big", 0, _seeded(4 << 20, 1))
+                        .touch("c", "meta"))
+        assert (args["onodes"], args["runs"], args["blocks"],
+                args["dev_writes"]) == (2, 1, 1024, 1)
+        # a 4 KiB write into it splits its run in three (the RBD write
+        # into a 4 MiB object): three runs, one block, one device write
+        args = wal_args(T().write("c", "big", 300 * 4096, b"x" * 4096))
+        assert (args["onodes"], args["runs"], args["blocks"],
+                args["dev_writes"]) == (1, 3, 1, 1)
+        stats = s.journal_stats()
+        assert stats["onodes_committed"] == 3 and stats["runs_committed"] == 4
+        assert stats["runs_per_onode"] == pytest.approx(4 / 3)
+        s.umount()
 
 
 # ---------------------------------------------------------------------------
@@ -1583,7 +2067,8 @@ class TestBlockStoreOnodeCache:
                                           want[f"o{i:02d}"]))
             assert s._onodes._weight <= 40 and len(s._onodes) <= 10
         assert s._onodes._weight == sum(
-            1 + len(h["blocks"]) for h in s._onodes._heads.values())
+            1 + sum(run[1] for run in h["runs"])
+            for h in s._onodes._heads.values())
         # the newest is resident, the oldest went and reads back
         calls = s.db.calls
         assert s.read("c", "o29") == want["o29"]
@@ -1636,7 +2121,7 @@ class TestBlockStoreOnodeCache:
     def _poison(self, s):
         """A head the KV does not hold, put where a surviving cache
         would serve it from."""
-        s._onodes.put("c/bystander", {"size": 3, "blocks": {},
+        s._onodes.put("c/bystander", {"size": 3, "runs": [],
                                       "xattrs": {"poison": b"1"}})
         assert s.getattrs("c", "bystander") == {"poison": b"1"}
 

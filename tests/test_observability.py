@@ -437,7 +437,9 @@ class TestPerfCounters:
         bstats = bs.journal_stats()
         for key in ("wal_records_replayed", "wal_torn_extent_repairs",
                     "freelist_repairs", "fsync_reorder_windows",
-                    "kv_calls", "commits", "onode_lookups", "onode_hits"):
+                    "kv_calls", "commits", "onode_lookups", "onode_hits",
+                    "onodes_committed", "runs_committed", "runs_per_onode",
+                    "reads", "reads_whole_run", "read_whole_run_share"):
             assert key in bstats, key
         assert set(bs.crash_sites()) >= {
             "wal.pre_kv_commit", "wal.post_kv_commit",
