@@ -156,7 +156,7 @@ def available() -> bool:
 
 def crc32c_hw() -> bool:
     """True when the hardware crc32 instruction tier is serving
-    (SSE4.2 compiled in + CPU support) — bench/perf observability."""
+    (SSE4.2 compiled in + CPU support) — perf observability."""
     lib = get_lib()
     if lib is not None:
         try:

@@ -162,7 +162,7 @@ uint32_t ceph_tpu_crc32c(uint32_t seed, const uint8_t* data, size_t len) {
 }
 
 // 1 = the hardware crc32 instruction path is compiled in and the CPU
-// supports it (observability: perf dump / bench report which tier ran)
+// supports it (observability: perf dump reports which tier ran)
 int ceph_tpu_crc32c_hw(void) {
 #ifdef CEPH_TPU_HW_CRC
   return have_sse42() ? 1 : 0;

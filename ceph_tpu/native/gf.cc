@@ -4,7 +4,7 @@
 // (erasure-code/isa/isa-l/erasure_code/*.asm.s): multiply-accumulate a
 // byte region by a constant via 2x 4-bit nibble tables — the classic
 // pshufb formulation, written so the compiler auto-vectorizes.  Used as
-// the host EC baseline (bench.py vs_baseline) and the small-op fast
+// the host EC baseline (BASELINE.md's criterion) and the small-op fast
 // path where a device dispatch would cost more than it saves.
 
 #include <cstddef>
@@ -87,7 +87,7 @@ void ceph_tpu_gf_encode(const uint8_t* matrix, size_t rows, size_t k,
 }  // extern "C"
 
 // ---------------------------------------------------------------------------
-// AVX2 pshufb encode — the honest ISA-L stand-in for bench baselines.
+// AVX2 pshufb encode — the honest ISA-L stand-in for the host baseline.
 // Same algorithm as isa-l's gf_{2..6}vect_dot_prod_avx2 (vpshufb on the
 // two nibble tables, xor-accumulate), with parity accumulators held in
 // registers across the k data rows so data is read once per 32-byte

@@ -394,8 +394,9 @@ def encode_readback_bytes(B: int, k: int, m: int, L: int) -> int:
     """Exact D2H bytes one fused encode+CRC dispatch of a (B, k, L)
     batch fetches: the (B, m, L) parity block plus the 4-byte CRC per
     chunk — the data shards the host already holds are NEVER echoed
-    back.  bench --smoke gates the transfer plane's bytes_d2h counter
-    on this identity."""
+    back.  tests/test_hbm_cache.py
+    (test_encode_stages_entry_and_counts_transfer) holds the transfer
+    plane's bytes_d2h counter to this identity."""
     return B * m * L + 4 * B * (k + m)
 
 

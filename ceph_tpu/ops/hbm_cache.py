@@ -368,8 +368,8 @@ class HbmStripeCache:
             self._c["bytes_d2h"] += int(n)
 
     def count_read_hit_bytes(self, n: int) -> None:
-        """Logical payload bytes a read served from the cache (the
-        bench's read_cache_gbs numerator)."""
+        """Logical payload bytes a read served from the cache
+        (``read_bytes_served`` in ``stats()``)."""
         with self._lock:
             self._c["read_bytes_served"] += int(n)
 

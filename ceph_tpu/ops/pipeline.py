@@ -58,8 +58,8 @@ This module is the shared dispatcher all producers feed:
     parity-only: the fused kernel never echoes data shards, so per
     dispatch exactly ``S_pad * k * L`` bytes go up and
     ``S_pad * (m * L + 4 * (k + m))`` bytes come down — the
-    ``bytes_h2d`` / ``bytes_d2h`` counters prove it (bench --smoke
-    gates on the exact identity).
+    ``bytes_h2d`` / ``bytes_d2h`` counters prove it
+    (tests/test_hbm_cache.py holds them to the exact identity).
   * **HBM stripe cache** — an encode submission tagged with a
     :class:`~ceph_tpu.ops.hbm_cache.CacheIntent` leaves its uploaded
     data and computed parity ON the chip (device slices, no extra
@@ -570,7 +570,7 @@ class EcDevicePipeline:
                     self._c["devset_errors"] += 1
                 # collectors of retired device sets have exited by
                 # now; drop them so repeated reset_devices sweeps
-                # (bench chip-count sweep) cannot grow this unbounded
+                # (a sweep over chip counts) cannot grow this unbounded
                 self._threads = [t for t in self._threads
                                  if t.is_alive()]
                 for lane in ds.lanes:
@@ -585,8 +585,8 @@ class EcDevicePipeline:
 
     def reset_devices(self, device_shards=_UNSET) -> None:
         """Rebuild the device set on next dispatch: clears quarantine
-        latches and (optionally) re-caps the shard count — bench's
-        chip-count sweep and tests that quarantined lanes use this."""
+        latches and (optionally) re-caps the shard count — tests that
+        quarantined lanes or sweep chip counts use this."""
         self.flush(timeout=10.0)
         with self._lock:
             if device_shards is not _UNSET:

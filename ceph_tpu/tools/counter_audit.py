@@ -1,8 +1,8 @@
 """Static counter-coverage lint: every perf counter the code declares
 or increments must be pinned by the observability test schema.
 
-The perf-dump surface is load-bearing (bench gates, health flags, the
-mgr export) — a counter added in a hot path but absent from
+The perf-dump surface is load-bearing (the benchmark's readers, health
+flags, the mgr export) — a counter added in a hot path but absent from
 tests/test_observability.py ships untested and undocumented: nothing
 fails when a refactor silently stops incrementing it.  This pass
 (tier-1 via tests/test_counter_audit.py, the copy_audit pattern):

@@ -1,7 +1,7 @@
 """Open-loop multi-tenant load harness (the "millions of users" probe).
 
-bench.py's closed-loop rows measure how fast ONE submitter can push
-the pipeline; a serving system is judged by what happens when load
+A closed loop measures how fast its submitters can push the
+pipeline; a serving system is judged by what happens when load
 ARRIVES ON ITS OWN CLOCK.  This generator is:
 
   * **open-loop** — every op has a scheduled arrival time drawn from a
@@ -33,7 +33,7 @@ queue wait (dmClock stalls included) vs device (EC pipeline phases)
 vs journal/WAL vs replica-wait — so a p99 regression names the layer
 that moved, not just the number.
 
-Typical use (bench.py --load, tests/test_loadgen.py):
+Typical use (tests/test_loadgen.py):
 
     spec = TenantSpec("gold", rate=50, duration=5.0, obj_count=64)
     gen = LoadGen([spec], seed=7)

@@ -19,8 +19,9 @@ still materialized on the host:
 
 Every such site calls :func:`note` with the byte count; ``perf dump``
 exposes the totals plus ``host_copies_per_write`` (copies amortized
-over the daemon's write ops), and ``bench.py --smoke`` gates the
-per-write copy count so a copy regression in the hot path fails CI
+over the daemon's write ops), and tests/test_observability.py
+(test_data_path_copy_counters) holds the per-write and per-read copy
+counts to their budgets, so a copy regression in the hot path fails CI
 loudly instead of silently re-widening the kernel<->e2e gap.
 
 Counters are process-wide (the write path spans client, messenger, OSD

@@ -269,3 +269,30 @@ class TestConnectionChurn:
                 f"{ms_type}: fd leak {fds} > {base_fds}"
         finally:
             c.stop()
+
+    def test_256_sessions_multiplex_onto_the_worker_pool(self):
+        """The high-fan-in drill: 256 full client sessions (messenger +
+        monc + objecter each) all open at once against an
+        ms_type=async cluster, a seeded quarter of them churning
+        (open, op, close, reopen) on the way.  Every scheduled op
+        completes with no error; the thread peak grows by less than a
+        thread per session (sessions multiplex onto the epoll workers,
+        the growth is the storm's own driver pool); threads and FDs
+        are back to the pre-storm baseline once every session has
+        closed.  Counts only: no latency is held."""
+        from ceph_tpu.tools.loadgen import run_conn_storm
+        from ceph_tpu.vstart import MiniCluster
+        sessions = 256
+        c = MiniCluster(num_mons=1, num_osds=3,
+                        conf=Config({"ms_type": "async"})).start()
+        try:
+            res = run_conn_storm(c, sessions, seed=0xC044)
+        finally:
+            c.stop()
+        assert res["ms_type"] == "async" and res["event_workers"] >= 1
+        assert res["sessions"] == sessions and res["churned"] >= 1
+        assert res["errors"] == 0
+        assert res["completed"] == res["expected"]
+        assert res["peak_threads"] - res["base_threads"] < sessions
+        assert res["quiesce_threads"] <= res["base_threads"]
+        assert res["quiesce_fds"] <= res["base_fds"]

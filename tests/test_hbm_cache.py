@@ -429,9 +429,17 @@ class TestPipelineIntegration:
                 assert ent.shard_bytes(K + j) == \
                     expect_parity[:, j].tobytes()
             assert np.array_equal(ent.crcs, np.asarray(crcs))
-            # cached reads are D2H-only: pipeline h2d must not move
+            # what a deep scrub folds from the cache is the host's
+            # CRC32C of every chunk, data and parity
+            chunks = np.concatenate([data, expect_parity], axis=1)
+            assert np.array_equal(
+                ent.crcs, np.stack([crc32c_batch(chunks[s])
+                                    for s in range(2)]))
+            # cached reads are D2H-only: pipeline h2d must not move,
+            # and the lookup counts as a hit in the pipeline's block
             st2 = pipe.stats()
             assert st2["bytes_h2d"] == st1["bytes_h2d"]
+            assert st2["cache_hit"] == st1["cache_hit"] + 1
         finally:
             pipe.stop()
 

@@ -258,3 +258,23 @@ class TestCacheServedReads:
         th.join(timeout=60)
         assert not th.is_alive()
         assert not errors, errors
+
+    def test_seeded_round_on_the_ec_pool_completes_without_error(
+            self, cluster, ec_io):
+        """An open-loop round of reads, overwrites and appends against
+        the plugin=tpu pool of a live cluster: every offered op
+        completes, none fails, and the reads stay inside the read-side
+        copy floor (a cache-served or intact read copies nothing)."""
+        from ceph_tpu.utils import copyaudit
+        gen = LoadGen([TenantSpec(
+            ec_io.pool_name, rate=80, duration=2.0, obj_count=16,
+            zipf_s=1.1, read_frac=0.6, payload=8192,
+            append_frac=0.1)], seed=0x510AD)
+        c0 = copyaudit.snapshot()
+        rep = gen.run({ec_io.pool_name: ec_io})
+        c1 = copyaudit.snapshot()
+        assert rep["completed"] == sum(rep["offered"].values()) > 0
+        assert sum(p["errors"] for p in rep["pools"].values()) == 0
+        reads = c1["reads"] - c0["reads"]
+        assert reads > 0
+        assert c1["read_copies"] - c0["read_copies"] <= reads

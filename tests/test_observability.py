@@ -9,6 +9,7 @@ import time
 
 import pytest
 
+from ceph_tpu.client import RadosError
 from ceph_tpu.utils.admin_socket import admin_command
 from ceph_tpu.utils.clock import ManualClock
 from ceph_tpu.utils.optracker import OpTracker
@@ -33,21 +34,25 @@ def cluster(tmp_path_factory):
     c.stop()
 
 
+def _settle(ctx, oid="warm", body=b"x"):
+    """Write until the new pool's PGs are active."""
+    end = time.time() + 20
+    while True:
+        try:
+            ctx.write_full(oid, body)
+            return
+        except RadosError:
+            if time.time() > end:
+                raise
+            time.sleep(0.3)
+
+
 @pytest.fixture(scope="module")
 def io(cluster):
     rados = cluster.client()
     rados.create_pool("obs", pg_num=4)
     ctx = rados.open_ioctx("obs")
-    from ceph_tpu.client import RadosError
-    end = time.time() + 20
-    while True:
-        try:
-            ctx.write_full("warm", b"x")
-            break
-        except RadosError:
-            if time.time() > end:
-                raise
-            time.sleep(0.3)
+    _settle(ctx)
     return ctx
 
 
@@ -217,16 +222,7 @@ class TestPerfCounters:
         cluster.client().create_ec_pool(
             "obsec", "k2m1", {"plugin": "tpu", "k": 2, "m": 1})
         ioe = cluster.client().open_ioctx("obsec")
-        from ceph_tpu.client import RadosError
-        end = time.time() + 20
-        while True:
-            try:
-                ioe.write_full("e", b"ec" * 3000)
-                break
-            except RadosError:
-                if time.time() > end:
-                    raise
-                time.sleep(0.3)
+        _settle(ioe, "e", b"ec" * 3000)
         dumps = [o.asok.execute("perf dump") for o in
                  cluster.osds.values()]
         assert any(d.get("ec_codecs") for d in dumps)
@@ -238,16 +234,7 @@ class TestPerfCounters:
         rados.create_ec_pool(
             "obsecp", "k2m1p", {"plugin": "tpu", "k": 2, "m": 1})
         ioe = rados.open_ioctx("obsecp")
-        from ceph_tpu.client import RadosError
-        end = time.time() + 20
-        while True:
-            try:
-                ioe.write_full("p0", b"pipe" * 2000)
-                break
-            except RadosError:
-                if time.time() > end:
-                    raise
-                time.sleep(0.3)
+        _settle(ioe, "p0", b"pipe" * 2000)
         for i in range(1, 6):
             ioe.write_full(f"p{i}", bytes([i]) * 6000)
         dump = next(iter(cluster.osds.values())).asok.execute(
@@ -296,10 +283,22 @@ class TestPerfCounters:
                         "errors", "inflight", "quarantined"):
                 assert key in dev, key
 
+    # what one op may materialize on the host (utils/copyaudit.py
+    # sites).  A write: `ec.stage` (the payload rope into the encode
+    # staging buffer), `ec.shard_layout` (stripe-major to shard-major),
+    # and one spare for a journaled store's WAL flatten.  A read: the
+    # one chunk a degraded read rebuilds (`ec.decode_rebuild`); an
+    # intact read copies nothing.
+    COPIES_PER_WRITE = 3.0
+    COPIES_PER_READ = 1.0
+
     def test_data_path_copy_counters(self, cluster, io):
         """The zero-copy plane's audit block: perf dump reports where
         payload bytes still materialize, amortized per write AND per
-        read op (the PR 9 read-side floor)."""
+        read op (the PR 9 read-side floor), and EC writes and reads,
+        intact and degraded, stay inside their copy budgets."""
+        from ceph_tpu.ops import hbm_cache
+        from ceph_tpu.utils import copyaudit, faults
         io.write_full("dp0", b"copyaudit" * 400)
         io.read("dp0")
         dump = next(iter(cluster.osds.values())).asok.execute(
@@ -316,6 +315,45 @@ class TestPerfCounters:
         assert dp["reads"] >= 1
         # replicated/intact reads are view-served: no read-site copies
         assert dp["host_copies_per_read"] >= 0
+        rados = cluster.client()
+        rados.create_ec_pool("obsdp", "dpk2m1",
+                             {"plugin": "tpu", "k": 2, "m": 1})
+        ec = rados.open_ioctx("obsdp")
+        _settle(ec)
+        # past the out-of-band threshold, not a multiple of the stripe
+        body = bytes(range(256)) * 49
+        n = 8
+
+        def moved(before, after, key):
+            return after[key] - before[key]
+
+        s0 = copyaudit.snapshot()
+        for i in range(n):
+            ec.write_full(f"dp{i}", body)
+        s1 = copyaudit.snapshot()
+        for i in range(n):
+            assert bytes(ec.read(f"dp{i}")) == body
+        s2 = copyaudit.snapshot()
+        faults.get().store_eio("osd.*", "dp0.s0")
+        try:
+            hbm_cache.get().clear()    # or the cache serves, no shard asked
+            for _ in range(n):
+                assert bytes(ec.read("dp0")) == body
+        finally:
+            faults.get().reset()
+        s3 = copyaudit.snapshot()
+        writes = moved(s0, s1, "writes")
+        assert writes >= n
+        staged = s1["sites"]["ec.stage"]["copies"] - \
+            s0["sites"].get("ec.stage", {"copies": 0})["copies"]
+        assert staged >= n                  # the counters really count
+        assert moved(s0, s1, "host_copies") / writes <= \
+            self.COPIES_PER_WRITE
+        assert moved(s1, s2, "reads") >= n
+        assert moved(s1, s2, "read_copies") == 0
+        assert moved(s2, s3, "reads") >= n
+        assert 1 <= moved(s2, s3, "read_copies") <= \
+            self.COPIES_PER_READ * moved(s2, s3, "reads")
 
     def test_qos_block_schema(self, cluster, io):
         """Per-pool QoS surfaces in perf dump: the op-queue dmClock

@@ -85,6 +85,19 @@ def _partial_ec_write(cluster, io, oid: str, payload: bytes,
     return pgid, acting, primary
 
 
+def _settle(io) -> None:
+    """Write until the new pool's PGs are active."""
+    end = time.time() + 60
+    while True:
+        try:
+            io.write_full("settle", b"s")
+            return
+        except Exception:
+            if time.time() > end:
+                raise
+            time.sleep(0.3)
+
+
 def _wait_read(io, oid: str, timeout: float = 30.0) -> bytes:
     from ceph_tpu.client import RadosError
     end = time.time() + timeout
@@ -325,15 +338,7 @@ class TestAuthorityProof:
         rados = cluster.client()
         rados.create_pool("authp", pg_num=4, size=3, min_size=2)
         io = rados.open_ioctx("authp")
-        end = time.time() + 60
-        while True:
-            try:
-                io.write_full("settle", b"s")
-                break
-            except Exception:
-                if time.time() > end:
-                    raise
-                time.sleep(0.3)
+        _settle(io)
         io.write_full("dup", b"v1")
         m = cluster.leader().osdmon.osdmap
         pgid = m.object_to_pg(io.pool_id, "dup")
@@ -452,15 +457,7 @@ class TestReplicatedDivergentRewind:
         rados = cluster.client()
         rados.create_pool("rewindp", pg_num=4, size=3, min_size=2)
         io = rados.open_ioctx("rewindp")
-        end = time.time() + 60
-        while True:
-            try:
-                io.write_full("settle", b"s")
-                break
-            except Exception:
-                if time.time() > end:
-                    raise
-                time.sleep(0.3)
+        _settle(io)
         ledger = DurabilityLedger()
         filler = {f"fill{i:02d}": bytes([i]) * 32768 for i in range(12)}
         for oid, body in filler.items():
@@ -592,3 +589,115 @@ class TestReplicatedTriangle:
             cluster.tick(0.3)
             time.sleep(0.05)
         assert io.read("tri-obj") == b"authoritative-content"
+
+
+class TestLogBoundsPeering:
+    """Peering exchanges LOG BOUNDS, and recovery follows the log's
+    divergence: neither grows with the number of objects in the PG.
+    Held by counting messages, bytes and pushes, never by timing."""
+
+    @staticmethod
+    def _one_pg_pool(cluster, name):
+        rados = cluster.client()
+        rados.create_pool(name, pg_num=1, size=3, min_size=2)
+        io = rados.open_ioctx(name)
+        _settle(io)
+        m = cluster.leader().osdmon.osdmap
+        pgid = m.object_to_pg(io.pool_id, "settle")
+        _up, acting = m.pg_to_up_acting_osds(pgid)
+        return io, pgid, [o for o in acting if o >= 0]
+
+    @staticmethod
+    def _pushes(cluster):
+        return sum(o._perf_dump()["osd"]["recovery_pushes"]
+                   for o in cluster.osds.values())
+
+    def test_clean_repeer_costs_the_same_at_ten_times_the_objects(
+            self, cluster, monkeypatch):
+        """A clean re-peer of one PG at 8 and at 80 objects: the same
+        messages (a query and an info per peer, an activate per peer),
+        the same bytes within the width of a version number, and not
+        one recovery push.  An O(objects) term in the info exchange,
+        the election or the delta would show in all three."""
+        io, pgid, acting = self._one_pg_pool(cluster, "peerflat")
+        osd = cluster.osds[acting[0]]
+        pg = osd.get_pg(pgid)
+        sent: list[tuple] = []
+        for o in cluster.osds.values():
+            def spy(msg, peer_name, peer_addr,
+                    _send=o.msgr.send_message):
+                if getattr(msg, "pgid", None) == str(pgid):
+                    sent.append((type(msg).__name__,
+                                 getattr(msg, "op", ""),
+                                 len(msg.encode())))
+                _send(msg, peer_name, peer_addr)
+            monkeypatch.setattr(o.msgr, "send_message", spy)
+
+        def repeer():
+            """(messages by kind, bytes, pushes) of one clean round."""
+            del sent[:]
+            pushes0 = self._pushes(cluster)
+            with pg.lock:
+                pg.active = False
+            osd.queue_peering(pgid)
+            end = time.time() + 30
+            while not pg.active and time.time() < end:
+                time.sleep(0.01)
+            assert pg.active
+            with pg.lock:       # the round's last sends are made under it
+                round_ = list(sent)
+            return (sorted(kind for *kind, _n in round_),
+                    sum(n for *_kind, n in round_),
+                    self._pushes(cluster) - pushes0)
+
+        written = 0
+        rounds = []
+        for count in (8, 80):
+            while written < count:
+                io.write_full(f"o{written:04d}", b"x" * 64)
+                written += 1
+            rounds.append(repeer())
+        (kinds, nbytes, pushes), (kinds10x, nbytes10x, pushes10x) = rounds
+        peers = len(acting) - 1
+        assert kinds == kinds10x == sorted(
+            [["MPGInfo", op] for op in ("query", "info", "activate")]
+            * peers)
+        assert pushes == pushes10x == 0
+        # a version number ten times as large may take a byte more in
+        # each of the bounds a message carries
+        assert abs(nbytes10x - nbytes) <= 8 * len(kinds)
+
+    def test_recovery_bytes_follow_the_divergence(self, cluster):
+        """K objects vanish from one replica of a PG that holds many
+        more: re-peering heals exactly those, and the bytes recovery
+        pushes stay within 3 x K payloads, whatever the PG holds."""
+        io, pgid, acting = self._one_pg_pool(cluster, "peerdiv")
+        for i in range(40):
+            io.write_full(f"fill{i:03d}", bytes([i]) * 4096)
+        K, payload = 6, 1 << 15
+        bodies = {f"div{i:03d}": bytes([0x40 + i]) * payload
+                  for i in range(K)}
+        for oid, body in bodies.items():
+            io.write_full(oid, body)
+        posd, vosd = (cluster.osds[o] for o in acting[:2])
+        vpg = vosd.get_pg(pgid)
+        with vpg.lock:
+            for oid in bodies:
+                vosd.store.apply_transaction(
+                    Transaction().remove(vpg.cid, oid))
+                vpg.pglog.objects.pop(oid, None)
+                vpg.pglog.entries = [e for e in vpg.pglog.entries
+                                     if e["oid"] != oid]
+        before = posd._perf_dump()["osd"]["recovery_bytes"]
+        posd.get_pg(pgid).start_peering()
+        end = time.time() + 60
+        healed = False
+        while not healed and time.time() < end:
+            healed = all(
+                vosd.store.exists(vpg.cid, oid)
+                and bytes(vosd.store.read(vpg.cid, oid)) == body
+                for oid, body in bodies.items())
+            time.sleep(0.1)
+        assert healed
+        pushed = posd._perf_dump()["osd"]["recovery_bytes"] - before
+        assert K * payload <= pushed <= 3 * K * payload

@@ -13,8 +13,8 @@ Covered here:
   * a dup-op resend arriving while its first copy is recovery-blocked
     does not re-execute;
   * the stale-read oracle + storm-window slicing the recovery-storm
-    drill (tools/loadgen.run_recovery_storm, bench --smoke gate)
-    is built from;
+    drill (tools/loadgen.run_recovery_storm) is built from, and the
+    drill itself: an OSD killed and reborn under open-loop load;
   * perf dump `qos.recovery` (the @recovery class's grants/stalls).
 """
 
@@ -550,3 +550,48 @@ class TestWindowReport:
         full = gen.window_report(0.0, 10.0)
         assert full["p"]["ops"] == 4
         assert full["p"]["stale_reads"] == 1
+
+
+class TestRecoveryStorm:
+    def test_osd_kill_and_rebirth_under_open_loop_load(self):
+        """The end-to-end durability drill: two tenants offer seeded
+        open-loop load to size-3 / min_size-2 pools while one OSD is
+        killed abruptly and reborn.  No client op fails, no read
+        returns superseded bytes, every op that blocked on a recovery
+        pull resumed, the ledger's acked writes (one of them made
+        while degraded) read back bit-exact, and the cluster comes
+        back clean.  Counts and completion only: no latency is held."""
+        from ceph_tpu.tools.loadgen import TenantSpec, run_recovery_storm
+        conf = dict(CONF, osd_pool_qos_gold="40:4:0",
+                    objecter_op_timeout=60.0)
+        c = MiniCluster(num_mons=1, num_osds=3,
+                        conf=Config(conf)).start()
+        try:
+            rados = c.client()
+            ios = {}
+            for name in ("gold", "bulk"):
+                rados.create_pool(name, pg_num=8, size=3, min_size=2)
+                ios[name] = rados.open_ioctx(name)
+                _settle(ios[name])
+            tenants = [
+                TenantSpec("gold", rate=30, duration=6.0, obj_count=16,
+                           zipf_s=1.1, read_frac=0.6, payload=8192),
+                TenantSpec("bulk", rate=15, duration=6.0, obj_count=16,
+                           zipf_s=0.9, read_frac=0.3, payload=16384),
+            ]
+            res = run_recovery_storm(c, ios, tenants, seed=0x570A,
+                                     kill_at=1.5, revive_after=1.2,
+                                     clean_timeout=120.0)
+        finally:
+            c.stop()
+        report = res["report"]
+        assert report["completed"] == sum(report["offered"].values())
+        assert res["errors"] == 0
+        assert res["stale_reads"] == 0
+        assert res["recovery_blocked_ops"] == \
+            res["recovery_unblocked_ops"]
+        assert res["ledger_ok"], res["ledger_detail"]
+        # the storm really happened: ops were offered between the kill
+        # and the clean cluster, and wait_for_clean returned
+        assert sum(w["ops"] for w in res["storm"].values()) > 0
+        assert res["recovery_wall_s"] > 0
