@@ -702,7 +702,8 @@ class ECBackend:
     def _ec_read_local(self, oid: str,
                        exclude: set | None = None,
                        need_ver: tuple | None = None,
-                       qos: str | None = None) -> bytes | None:
+                       qos: str | None = None,
+                       got: dict | None = None) -> bytes | None:
         """Read + decode an EC object, fetching shards from peers.
         `exclude` drops known-bad shards (scrub repair: a corrupt
         local shard must not poison the reconstruction); `need_ver`
@@ -710,21 +711,29 @@ class ECBackend:
         not applied the target version yet must not contribute);
         `qos` names the dmClock class any decode dispatch bills
         against (rebuild reads ride @recovery under the repair cap,
-        like the rebuild's re-encode)."""
+        like the rebuild's re-encode); into `got`, {position: bytes}
+        of the shard files the last step had in hand (none where the
+        HBM cache served)."""
         rd = self._ec_read_begin(oid, exclude, need_ver, qos)
         while isinstance(rd, _EcRead):
+            step = rd
             rd = self._ec_read_step(rd, self._ec_read_fetch(rd))
+            if got is not None:
+                got.clear()
+                got.update({p: len(b) for p, b in step.have.items()})
         return rd
 
     def _ec_repair_read(self, oid: str, lost: list[int],
-                        need_ver: tuple, qos: str | None = None):
+                        need_ver: tuple, qos: str | None = None,
+                        got: dict | None = None):
         """The shard files at positions `lost`, rebuilt from the shards
         the codec's plan reads for THEM (lrc: the l others of a local
         group; shec: a shingle) and from no others: ({position:
         bytes}, the object's size).  None where that plan reads as
         many shards as a read of the object does (the caller's whole
         read serves as well, and takes the first set that decodes), or
-        a planned source did not answer at `need_ver`."""
+        a planned source did not answer at `need_ver`.  Into `got`,
+        {position: bytes} of the shard files it read."""
         codec = self._ec_codec()
         live = [p for p, o in enumerate(self.acting)
                 if o != ITEM_NONE and p not in lost
@@ -748,9 +757,11 @@ class ECBackend:
             rd.vers[shard] = tuple(ver) if ver is not None else None
             rd.hinfo = rd.hinfo or hi
         gather.stamp(optracker.current(), chunks=sorted(rd.have))
-        got = {rd.vers.get(p) for p in plan}
+        if got is not None:
+            got.update({p: len(b) for p, b in rd.have.items()})
+        vers = {rd.vers.get(p) for p in plan}
         sinfo = self._ec_sinfo(codec)
-        if rd.hinfo is None or got != {tuple(need_ver)} \
+        if rd.hinfo is None or vers != {tuple(need_ver)} \
                 or rd.hinfo.get("stripe_unit") != sinfo.chunk_size:
             return None
         try:

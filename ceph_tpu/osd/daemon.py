@@ -97,6 +97,11 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         self.backfill_lock = threading.Lock()
         self._backfills_active: set = set()
         self._rmtemp_active: set = set()
+        # pgid -> EC rebuilds queued, running or waiting for a retry
+        # (queue_ec_rebuild): a PG with any is recovering, not clean
+        self._rebuilds_pending: dict = {}
+        # numbers the backfill rounds' ops (their trace ids)
+        self._backfill_round_seq = itertools.count(1)
         # pgid -> last REAL-time incomplete-copy nudge (see _heartbeat)
         self._nudge_last: dict = {}
         # per-pool QoS (dmClock reservation/weight/limit service
@@ -185,6 +190,16 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      .add_u64_counter("recovery_pushes")
                      .add_u64_counter("recovery_bytes")
                      .add_u64_counter("backfill_resumes")
+                     # what a repair did: backfill rounds and the
+                     # objects they pushed or rebuilt; EC rebuilds by
+                     # where the lost shard came from (the HBM cache's
+                     # rows, a local repair's few chunks, a read of
+                     # the whole object and a re-encode)
+                     .add_u64_counter("backfill_rounds")
+                     .add_u64_counter("backfill_objects")
+                     .add_u64_counter("rebuild_cache_served")
+                     .add_u64_counter("rebuild_local")
+                     .add_u64_counter("rebuild_full")
                      # the PG log as keys (pglog.persist_log): keys
                      # and bytes handed to transactions, and how often
                      # a log had to be written whole (a converted
@@ -1371,7 +1386,9 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                 if live < want:
                     states += ["undersized", "degraded"]
                 elif pg.active:
-                    states.append("clean")
+                    # a full acting set is not clean while a member is
+                    # being backfilled or shards are being rebuilt
+                    states.append(self.pg_repairing(pgid) or "clean")
                 stats[str(pgid)] = {
                     "state": "+".join(states),
                     "objects": len(pg.pglog.objects),

@@ -374,18 +374,31 @@ class RecoveryService:
                 pg.peer_last_backfill.pop(target, None)
         seg = mine["objects"]
         end = mine["end"]           # "" == ran off the end of our space
+        # one tracked op a round: the listing above, the peer's view
+        # of the range and the compare.  The objects it finds to push
+        # are ops of their own (`_rebuild_op`).
+        self.perf.inc("backfill_rounds")
+        trk = self.op_tracker.create(
+            f"backfill_scan({pgid} -> osd.{target} after={cursor!r})",
+            trace_id=f"backfill:{pgid}:osd.{target}:"
+                     f"{next(self._backfill_round_seq)}",
+            kind="recovery")
+        trk.span_begin("backfill.scan", _t0=getattr(trk, "mstart", None))
         # the peer's view of the SAME range (upto-bounded, not
         # limit-bounded: deletions hiding past our batch edge would
         # otherwise be missed)
+        trk.span_begin("backfill.scan_range", target=target)
         reply = self._call(target, MPGInfo(
             op="scan_range", pgid=str(pgid), after=cursor, upto=end,
             limit=0, epoch=self.osdmap.epoch), timeout=10.0)
+        trk.span_end("backfill.scan_range")
         if reply is None or reply.info.get("unknown"):
             # peer silent or map-lagged (pg not instantiated yet):
             # give the slot back and retry shortly — pushes to a
             # pg-less OSD would vanish
             self.log.warn("backfill of osd.%d stalled at %r; retrying",
                           target, cursor)
+            trk.finish()
             release()
             self.clock.timer(
                 2.0, lambda: self.queue_backfill(pgid, target,
@@ -393,6 +406,11 @@ class RecoveryService:
             return
         theirs = {o: tuple(v) for o, v in
                   (reply.info.get("objects", {}) or {}).items()}
+        todo = [(oid, tuple(ev)) for oid, ev in seg.items()
+                if theirs.get(oid) is None or theirs[oid] < tuple(ev)]
+        trk.span_end("backfill.scan", objects=len(seg), pushed=len(todo),
+                     skipped=len(seg) - len(todo))
+        trk.finish()
         shard = None
         if pg.is_ec:
             shard = pg.role_of(target)
@@ -407,19 +425,16 @@ class RecoveryService:
                               "in %s; abandoning", target, pgid)
                 release()
                 return
-        for oid, ev in seg.items():
-            ev = tuple(ev)
-            tv = theirs.get(oid)
-            if tv is not None and tv >= ev:
-                continue
+        for oid, ev in todo:
             state["pushed"] += 1
+            self.perf.inc("backfill_objects")
             # pushes go INLINE (we already hold the backfill's
             # reservation slot), so they ride the same FIFO connection
             # as the final backfill_done marker — the peer can never
             # be marked complete ahead of a still-queued push
             if pg.is_ec:
-                if not self._ec_rebuild(pgid, oid, ev,
-                                        [(shard, target)],
+                if not self._rebuild_op(f"backfill:{pgid}:{oid}", pgid,
+                                        oid, ev, [(shard, target)],
                                         retry=False):
                     # sources busy (concurrent write): the re-scan
                     # below picks this object up again
@@ -1260,31 +1275,66 @@ class RecoveryService:
             self.log.info("ec role audit %s: %d shard rebuilds queued",
                           pgid, queued)
 
+    def _rebuild_pending(self, pgid: PgId, by: int) -> None:
+        with self.backfill_lock:
+            n = self._rebuilds_pending.get(pgid, 0) + by
+            if n > 0:
+                self._rebuilds_pending[pgid] = n
+            else:
+                self._rebuilds_pending.pop(pgid, None)
+
+    def pg_repairing(self, pgid: PgId) -> str:
+        """What repair this primary still owes the PG: "backfilling"
+        (a session to a member is queued or running), "recovering"
+        (rebuilds of single objects are), or "" for none."""
+        with self.backfill_lock:
+            if any(key[0] == pgid for key in self._backfills_active):
+                return "backfilling"
+            return "recovering" if pgid in self._rebuilds_pending else ""
+
     def queue_ec_rebuild(self, pgid: PgId, oid: str, version: int,
                          missing: list[tuple[int, int]],
                          attempt: int = 0, front: bool = False) -> None:
+        self._rebuild_pending(pgid, +1)
+
         def work(release: Callable) -> None:
             def run() -> None:
-                # traced like a push: the rebuild runs under its own
-                # recovery op, so the decode/encode pipeline phases it
-                # pays (device compute, H2D/D2H) land as ec.* spans in
-                # the op dumps — a recovery rebuild's device time is
-                # attributable, not invisible background work
-                from ..utils import optracker
-                trk = self.op_tracker.create(
-                    f"rebuild({pgid} {oid} v={version})",
-                    trace_id=f"rebuild:{pgid}:{oid}", kind="recovery")
                 try:
-                    with optracker.op_context(trk), \
-                            optracker.span("rebuild"):
-                        self._ec_rebuild(pgid, oid, version, missing,
-                                         attempt)
+                    # the positions in the id: two rebuilds of one
+                    # object (a role audit's) are two ops, two ids
+                    self._rebuild_op(
+                        f"rebuild:{pgid}:{oid}:"
+                        + ".".join(f"s{s}" for s, _o in missing),
+                        pgid, oid, version, missing, attempt)
                 finally:
-                    trk.finish()
                     release()
+                    self._rebuild_pending(pgid, -1)
             self.op_wq.queue(pgid, run)
 
         self._recovery.request(work, front=front)
+
+    def _rebuild_op(self, trace_id: str, pgid: PgId, oid: str, version,
+                    missing: list[tuple[int, int]], attempt: int = 0,
+                    retry: bool = True) -> bool:
+        """One rebuilt object is one recovery op, whichever way it was
+        queued (a log-driven rebuild, `rebuild:<pgid>:<oid>:s<position>`;
+        an object of a backfill round, `backfill:<pgid>:<oid>`): `rebuild` is
+        this thread's part, with `rebuild.read`, the `ec.*` phases of
+        the decode and the re-encode, and `rebuild.encode` inside it;
+        a `rebuild.push` runs from the send to the target's ack and
+        keeps the op open until then (`_ec_push_shards`).  The
+        sub-reads and the push carry the trace id, so the shard OSDs'
+        `sub_read` ops and the target's `push` op (its `store_apply` /
+        `wal` spans) correlate under it."""
+        trk = self.op_tracker.create(
+            f"rebuild({pgid} {oid} v={version})", trace_id=trace_id,
+            kind="recovery")
+        try:
+            with optracker.op_context(trk), optracker.span("rebuild"):
+                return self._ec_rebuild(pgid, oid, version, missing,
+                                        attempt, retry)
+        finally:
+            trk.finish()
 
     def _ec_rebuild(self, pgid: PgId, oid: str, version: int,
                     missing: list[tuple[int, int]],
@@ -1311,35 +1361,47 @@ class RecoveryService:
         # arrays (data=None — no shard gather, no decode, and the full
         # payload never crosses the boundary); False = no usable entry
         if self._ec_push_shards(pg, oid, need, missing, None):
+            self.perf.inc("rebuild_cache_served")
             return True
         # the rebuild's decode lane bills the same class as its
         # re-encode: both halves of a repair sit under the repair cap
         from .daemon import RECOVERY_QOS_CLASS
         qos = (RECOVERY_QOS_CLASS if self._qos_recovery is not None
                else None)
-        # a code with locality first: the lost shards from the few
-        # their own plan reads, not from a read of the whole object
-        local = pg._ec_repair_read(oid, [s for s, _o in missing], need,
-                                   qos)
+        got: dict = {}
+        with optracker.span("rebuild.read") as read:
+            # a code with locality first: the lost shards from the few
+            # their own plan reads, not from a read of the whole object
+            local = pg._ec_repair_read(oid, [s for s, _o in missing],
+                                       need, qos, got)
+            data = None if local is not None else pg._ec_read_local(
+                oid, exclude={s for s, _o in missing}, need_ver=need,
+                qos=qos, got=got)
+            read.update(path="local" if local is not None else "full",
+                        chunks=len(got), bytes_read=sum(got.values()))
         if local is not None:
+            self.perf.inc("rebuild_local")
             self._ec_push_shards(pg, oid, need, missing, None,
                                  rebuilt=local)
             return True
-        data = pg._ec_read_local(
-            oid, exclude={s for s, _o in missing}, need_ver=need,
-            qos=qos)
         if data is None:
             # sources not all at `need` yet (write still fanning out):
             # retry with backoff rather than stranding the stale shard
             if retry and attempt < 6:
-                self.clock.timer(
-                    0.3 * (attempt + 1),
-                    lambda: self.queue_ec_rebuild(
-                        pgid, oid, need, missing, attempt + 1))
+                # pending across the wait, so the PG never looks
+                # recovered between two attempts
+                self._rebuild_pending(pgid, +1)
+
+                def again() -> None:
+                    self.queue_ec_rebuild(pgid, oid, need, missing,
+                                          attempt + 1)
+                    self._rebuild_pending(pgid, -1)
+                self.clock.timer(0.3 * (attempt + 1), again)
             elif retry:
                 self.log.warn("cannot rebuild %s/%s: undecodable",
                               pgid, oid)
             return False
+        self.perf.inc("rebuild_full")
         self._ec_push_shards(pg, oid, need, missing, data)
         return True
 
@@ -1369,6 +1431,8 @@ class RecoveryService:
         stripe_crcs = None
         size = 0
         cols = [shard for shard, _o in missing]
+        trk = optracker.current()
+        t_lookup = time.monotonic()
         ent = None if rebuilt else hbm_cache.get().lookup(
             pg.cid, oid, version=tuple(version))
         if rebuilt:
@@ -1390,6 +1454,11 @@ class RecoveryService:
             else:
                 stripe_crcs = ent.crcs[:, [of[c] for c in cols]]
                 size = ent.size
+                if data is None:
+                    # a rebuild that trusted the cache: this was its read
+                    optracker.add_span("rebuild.read", t_lookup,
+                                       time.monotonic(), path="cache",
+                                       chunks=0, bytes_read=0)
         if stripe_crcs is None:
             if data is None:
                 return False
@@ -1401,8 +1470,9 @@ class RecoveryService:
             from .daemon import RECOVERY_QOS_CLASS
             qos = (RECOVERY_QOS_CLASS if self._qos_recovery is not None
                    else None)
-            shards, stripe_crcs = ecutil.encode_object_ex(codec, sinfo,
-                                                          data, qos=qos)
+            with optracker.span("rebuild.encode", bytes=len(data)):
+                shards, stripe_crcs = ecutil.encode_object_ex(
+                    codec, sinfo, data, qos=qos)
             payloads = {shard: shards[shard] for shard in cols}
             stripe_crcs = np.asarray(stripe_crcs)[:, cols]
             size = len(data)
@@ -1438,7 +1508,9 @@ class RecoveryService:
                 txn.write(pg.cid, soid, 0, payload)
                 txn.setattr(pg.cid, soid, HINFO_KEY, hinfo)
                 txn.setattr(pg.cid, soid, VER_KEY, ver)
-                with pg.lock:
+                with pg.lock, optracker.span(
+                        "rebuild.push", shard=shard, target=osd_id,
+                        bytes=len(payload)):
                     cur2 = pg.pglog.objects.get(oid)
                     if cur2 is None or cur2 > tuple(version):
                         # deleted or rewritten while we were encoding:
@@ -1453,10 +1525,33 @@ class RecoveryService:
                     # object's missing claim can resume
                     pg._wake_recovery_blocked(oid)
             else:
-                self.send_osd(osd_id, MPGPush(
+                push = MPGPush(
                     pgid=str(pg.pgid), oid=oid, version=version,
                     data=payload,
                     xattrs={HINFO_KEY: hinfo, VER_KEY: ver}, omap={},
-                    shard=shard, epoch=self.osdmap.epoch))
+                    shard=shard, epoch=self.osdmap.epoch)
+                # the op this thread serves (a rebuild; none for a
+                # caller that runs under no op) stays open until the
+                # target has answered: `rebuild.push` is the send, the
+                # way there, the target's commit and the way back.
+                # Nothing waits here; the push rides the connection in
+                # order with what follows it
+                if trk is not None:
+                    push.trace = trk.trace_id
+                    trk.hold()
+                self._call_async(osd_id, push, self._push_acked(
+                    trk, time.monotonic(), shard, osd_id, len(payload)),
+                    timeout=10.0)
         return True
+
+    @staticmethod
+    def _push_acked(trk, t_send: float, shard: int, target: int,
+                    nbytes: int) -> Callable:
+        def acked(reply) -> None:
+            if trk is not None:
+                trk.add_span("rebuild.push", t_send, time.monotonic(),
+                             shard=shard, target=target, bytes=nbytes,
+                             acked=reply is not None)
+                trk.release()
+        return acked
 

@@ -174,9 +174,9 @@ _tls = threading.local()
 
 # the spans that read the thread's CPU clock at both ends (see the
 # module docstring for why not all): an op's handler on its op-shard
-# thread, and the phases of a PG scrub
+# thread, a rebuild's part on its worker, and the phases of a PG scrub
 CPU_SPANS = frozenset((
-    "execute", "scrub.list", "scrub.cache_fold", "scrub.read",
+    "execute", "rebuild", "scrub.list", "scrub.cache_fold", "scrub.read",
     "scrub.stack", "scrub.collect", "scrub.peer_wait", "scrub.compare"))
 
 
@@ -267,7 +267,7 @@ def note_pipeline_phases(ph: dict | None) -> None:
 class TrackedOp:
     __slots__ = ("desc", "trace_id", "kind", "attempt", "start", "mstart",
                  "mstart_ns", "mend", "events", "spans", "_open",
-                 "_tracker", "_id", "_done", "_slock")
+                 "_tracker", "_id", "_done", "_slock", "_holds")
 
     def __init__(self, tracker: "OpTracker", desc: str, now: float,
                  trace_id: str = "", kind: str = "client",
@@ -287,6 +287,9 @@ class TrackedOp:
         self.mend: float | None = None
         self._id = 0
         self._done = False
+        # answers still awaited on other threads (`hold`), and whether
+        # `finish` was asked for meanwhile
+        self._holds = [0, False]
         self.events: list[tuple[float, float, str]] = [
             (now, self.mstart, "initiated")]
         # closed spans: [name, t0, t1, args-or-None, cpu-or-None]
@@ -364,11 +367,28 @@ class TrackedOp:
 
     # -- lifecycle ---------------------------------------------------------
 
+    def hold(self) -> None:
+        """Keep the op open for an answer that another thread will
+        stamp onto it (a recovery push's ack): a `finish` asked for
+        meanwhile takes effect at the last `release`."""
+        with self._slock:
+            self._holds[0] += 1
+
+    def release(self) -> None:
+        with self._slock:
+            self._holds[0] -= 1
+            due = self._holds[0] <= 0 and self._holds[1]
+        if due:
+            self.finish()
+
     def finish(self) -> None:
         now_m = time.monotonic()
         now_c = self._tracker.clock.now()
         with self._slock:
             if self._done:
+                return
+            if self._holds[0] > 0:
+                self._holds[1] = True
                 return
             while self._open:                # auto-close (replica_wait
                 nm, t0, args, tid, cpu0 = self._open.pop()  # ends at reply)
@@ -437,6 +457,12 @@ class _NullOp:
 
     def add_span(self, name: str, t0: float, t1: float,
                  _cpu: float | None = None, **args) -> None:
+        pass
+
+    def hold(self) -> None:
+        pass
+
+    def release(self) -> None:
         pass
 
     def finish(self) -> None:

@@ -331,51 +331,68 @@ class MiniCluster:
             return mon is not None and not mon.osdmon.osdmap.is_up(osd_id)
         self._wait(down, timeout, f"osd.{osd_id} still up")
 
+    def unclean_pgs(self) -> list[str] | None:
+        """Which leg of the clean predicate refuses which PG, one line
+        each (a copy that is being backfilled with its watermark);
+        empty when the cluster is clean, None while there is no mon
+        leader to ask.  Clean is full acting sets in the map AND — for
+        daemons this cluster holds in-process — every copy recovered.
+        The mapping alone is NOT clean: right after a crash-restart
+        the map looks whole while the reborn daemon is still catching
+        up / being backfilled, and a verify racing that window reads
+        from an incomplete primary."""
+        mon = self._leader_or_none()
+        if mon is None:
+            return None
+        osdmap = mon.osdmon.osdmap
+        out = []
+        for pgid in osdmap.all_pgs():
+            _up, acting = osdmap.pg_to_up_acting_osds(pgid)
+            live = [o for o in acting if o >= 0]
+            if len(live) < osdmap.pools[pgid.pool].size:
+                out.append(f"{pgid}: acting {acting} short")
+            for osd_id in live:
+                osd = self.osds.get(osd_id)
+                pg = osd.pgs.get(pgid) if osd else None
+                if pg is None:
+                    out.append(f"{pgid}: no copy on osd.{osd_id}")
+                    continue
+                # `missing`: the log CLAIMS versions whose data has
+                # not landed (catch-up/rewind pulls in flight): a
+                # "clean" report here let a verify read race the pull
+                # — the transient behind the historical "deg: ACKED
+                # write lost" flake
+                repairing = osd_id == live[0] and osd.pg_repairing(pgid)
+                why = [leg for leg, bad in (
+                    (f"backfilling, watermark {pg.last_backfill!r}",
+                     not pg.backfill_complete),
+                    (f"missing {len(pg.pglog.missing)}", pg.pglog.missing),
+                    ("primary not active",
+                     osd_id == live[0] and not pg.active),
+                    (f"primary {repairing}", repairing),
+                    ("catch-up pending", osd_id == live[0] and
+                     getattr(pg, "_catchup_pending", None))) if bad]
+                if why:
+                    out.append(f"{pgid} on osd.{osd_id}: " + ", ".join(why))
+        # no recovery machinery still in flight anywhere
+        out += [f"osd.{o.whoami}: backfills active "
+                f"{sorted('->'.join(map(str, key)) for key in o._backfills_active)}"
+                for o in self.osds.values()
+                if getattr(o, "_backfills_active", None)]
+        return out
+
     def wait_for_clean(self, timeout: float = 30.0) -> None:
-        """All PGs of all pools active+clean: full acting sets in the
-        map AND — for daemons this cluster holds in-process — every
-        copy recovered.  The mapping alone is NOT clean: right after a
-        crash-restart the map looks whole while the reborn daemon is
-        still catching up / being backfilled, and a verify racing that
-        window reads from an incomplete primary."""
-        def clean() -> bool:
-            mon = self._leader_or_none()
-            if mon is None:
-                return False
-            osdmap = mon.osdmon.osdmap
-            for pgid in osdmap.all_pgs():
-                pool = osdmap.pools[pgid.pool]
-                up, acting = osdmap.pg_to_up_acting_osds(pgid)
-                live = [o for o in acting if o >= 0]
-                if len(live) < pool.size:
-                    return False
-                primary = live[0]
-                for osd_id in live:
-                    osd = self.osds.get(osd_id)
-                    if osd is None:
-                        return False
-                    pg = osd.pgs.get(pgid)
-                    if pg is None or not pg.backfill_complete:
-                        return False
-                    if pg.pglog.missing:
-                        # the log CLAIMS versions whose data has not
-                        # landed (catch-up/rewind pulls in flight): a
-                        # "clean" report here let a verify read race
-                        # the pull — the exact transient behind the
-                        # historical "deg: ACKED write lost" flake
-                        # (reads now also block on the pull; this
-                        # keeps the clean predicate honest too)
-                        return False
-                    if osd_id == primary and (
-                            not pg.active or
-                            getattr(pg, "_catchup_pending", None)):
-                        return False
-            # no recovery machinery still in flight anywhere
-            for osd in self.osds.values():
-                if getattr(osd, "_backfills_active", None):
-                    return False
-            return True
-        self._wait(clean, timeout, "cluster not clean")
+        """All PGs of all pools active+clean (`unclean_pgs` empty); on
+        timeout the error names what was not."""
+        try:
+            self._wait(lambda: self.unclean_pgs() == [], timeout,
+                       "cluster not clean")
+        except TimeoutError:
+            left = self.unclean_pgs()
+            raise TimeoutError(
+                f"cluster not clean after {timeout:.0f}s: "
+                + ("no mon leader" if left is None
+                   else "; ".join(left) or "clean now")) from None
 
     # -- clients -----------------------------------------------------------
 

@@ -1,0 +1,295 @@
+"""A failed OSD marked out of a `plugin=tpu` pool that holds data, with
+readers on it (PR 47; the benchmark cell `k8m3-4m-backfill-rand-read`
+at a small size): the operator's three steps and nothing else
+(`kill_osd`, `mark_osd_down`, `mark_osd_out`), then the map change,
+CRUSH, peering, backfill and the shard rebuilds do the rest.
+
+Held here, for a Reed-Solomon k=8 m=3 pool on 13 OSDs and an lrc k=4
+m=2 l=3 pool on 10: every read during the repair returns the written
+bytes; the cluster comes clean, and `wait_for_clean` does not say so
+before the last rebuild; every position of every object equals the
+benchmark's plain reference, shard file and CRC; a rebuilt object is
+ONE recovery op whichever way it was queued, with `rebuild`,
+`rebuild.read` (`path`, `chunks`, `bytes_read`), `rebuild.encode` where
+it re-encoded and `rebuild.push` (`shard`, `target`, `bytes`, acked);
+a backfill round is one with `backfill.scan` (`objects`, `pushed`,
+`skipped`) and the target's `scan_range` wait inside; the counters add
+up; lrc rebuilds a lost shard from the three others of its group.
+"""
+
+import threading
+import time
+
+import numpy as np
+import pytest
+
+from benchmark.references import lrc as lrc_reference
+from benchmark.references import reed_sol_van as rs_reference
+from ceph_tpu.client import RadosError
+from ceph_tpu.osd.pg import shard_oid
+from ceph_tpu.osd.pglog import HINFO_KEY
+from ceph_tpu.utils import denc
+from ceph_tpu.utils.config import Config
+from ceph_tpu.vstart import MiniCluster
+
+OBJECT_BYTES = 64 * 1024
+OBJECTS = 36
+UNIT = 4096
+POOLS = {
+    "rs-k8m3": {
+        "osds": 13, "width": 11, "reference": rs_reference,
+        "profile": {"plugin": "tpu", "technique": "reed_sol_van",
+                    "k": "8", "m": "3", "host_cutover": "1"}},
+    "lrc-k4m2l3": {
+        "osds": 10, "width": 8, "reference": lrc_reference,
+        "profile": {"plugin": "tpu", "technique": "lrc", "k": "4",
+                    "m": "2", "l": "3", "host_cutover": "1"}},
+}
+COUNTERS = ("backfill_rounds", "backfill_objects", "rebuild_cache_served",
+            "rebuild_local", "rebuild_full", "recovery_pushes")
+
+
+class Repaired:
+    """One cluster after the drill, and what was seen on the way."""
+
+
+def counters(cluster) -> dict:
+    out = dict.fromkeys(COUNTERS, 0)
+    for osd in cluster.osds.values():
+        block = osd.asok.execute("perf dump")["osd"]
+        for name in COUNTERS:
+            out[name] += block[name]
+    return out
+
+
+@pytest.fixture(scope="module", params=sorted(POOLS))
+def repaired(request):
+    spec = POOLS[request.param]
+    # a log shorter than a PG's history, so the new member is
+    # backfilled; a ring that keeps every op of the drill
+    cluster = MiniCluster(
+        num_mons=1, num_osds=spec["osds"], store_kind="memstore",
+        conf=Config({"osd_heartbeat_interval": 0.5,
+                     "osd_heartbeat_grace": 5.0,
+                     "mon_osd_min_down_reporters": 2,
+                     "osd_pg_log_max_entries": 4,
+                     "osd_backfill_scan_batch": 4,
+                     "osd_op_history_size": 20000})).start()
+    try:
+        rados = cluster.client()
+        rados.create_ec_pool("bf", "bf-profile", dict(
+            spec["profile"], stripe_unit=str(UNIT)), pg_num=4)
+        io = rados.open_ioctx("bf")
+        end = time.time() + 60
+        while True:
+            try:
+                io.write_full("settle", b"s")
+                break
+            except RadosError:
+                assert time.time() < end
+                time.sleep(0.3)
+        io.remove_object("settle")
+        rng = np.random.default_rng(47)
+        objects = {f"obj{i:03d}": rng.integers(
+            0, 256, OBJECT_BYTES, dtype=np.uint8).tobytes()
+            for i in range(OBJECTS)}
+        for name, data in objects.items():
+            io.write_full(name, data)
+        cluster.wait_for_clean(timeout=60)
+        osdmap = cluster.leader().osdmon.osdmap
+        pgids = [p for p in osdmap.all_pgs() if p.pool == io.pool_id]
+        before = {p: list(osdmap.pg_to_up_acting_osds(p)[1])
+                  for p in pgids}
+        members = {o for a in before.values() for o in a}
+        victim = min(members - {a[0] for a in before.values()})
+
+        out = Repaired()
+        out.bad_reads, out.reads = [], 0
+        stop = threading.Event()
+
+        def reader(seed: int) -> None:
+            pick = np.random.default_rng(seed)
+            names = sorted(objects)
+            while not stop.is_set():
+                name = names[int(pick.integers(len(names)))]
+                try:
+                    if io.read(name) != objects[name]:
+                        out.bad_reads.append(f"{name}: other bytes")
+                except RadosError as e:
+                    out.bad_reads.append(f"{name}: {e}")
+                out.reads += 1
+
+        readers = [threading.Thread(target=reader, args=(i,), daemon=True)
+                   for i in range(4)]
+        for t in readers:
+            t.start()
+        start = counters(cluster)
+        cluster.kill_osd(victim)
+        cluster.mark_osd_down(victim)
+        cluster.mark_osd_out(victim)
+        cluster._wait(lambda: all(
+            victim not in cluster.leader().osdmon.osdmap
+            .pg_to_up_acting_osds(p)[1] for p in pgids), 60,
+            "the victim is still acting")
+        cluster.wait_for_clean(timeout=240)
+        out.repairing_at_clean = [
+            osd.pg_repairing(p) for osd in cluster.osds.values()
+            for p in pgids]
+        stop.set()
+        for t in readers:
+            t.join(30)
+        end = counters(cluster)
+        out.delta = {k: end[k] - start[k] for k in COUNTERS}
+        out.docs = [d for osd in cluster.osds.values() for d in
+                    osd.asok.execute("dump_historic_ops")["ops"]]
+        out.cluster, out.io, out.spec = cluster, io, spec
+        out.objects, out.victim = objects, victim
+        out.pgids, out.before = pgids, before
+        out.osdmap = cluster.leader().osdmon.osdmap
+        yield out
+    finally:
+        cluster.stop()
+
+
+def rebuild_docs(r) -> list:
+    return [d for d in r.docs if d["kind"] == "recovery"
+            and d["description"].startswith("rebuild(")]
+
+
+def spans(doc: dict, name: str) -> list:
+    return [s for s in doc["spans"] if s["name"] == name]
+
+
+def test_every_read_during_the_repair_is_bit_exact(repaired):
+    assert repaired.reads > 0
+    assert repaired.bad_reads == []
+    for name, data in repaired.objects.items():
+        assert repaired.io.read(name) == data
+
+
+def test_clean_means_whole_sets_and_no_repair_left(repaired):
+    r = repaired
+    assert not any(r.repairing_at_clean)
+    assert r.cluster.unclean_pgs() == []
+    remapped = 0
+    for pgid in r.pgids:
+        _up, acting = r.osdmap.pg_to_up_acting_osds(pgid)
+        assert len(set(acting)) == r.spec["width"]
+        assert r.victim not in acting
+        assert all(r.osdmap.is_up(o) and r.osdmap.is_in(o) for o in acting)
+        remapped += r.victim in r.before[pgid]
+    assert remapped >= 1
+
+
+def test_every_position_equals_the_reference(repaired):
+    r = repaired
+    config = {"pool_profile": r.spec["profile"], "stripe_unit": UNIT,
+              "shards": r.spec["width"]}
+    files = 0
+    for name, data in r.objects.items():
+        pgid = r.osdmap.object_to_pg(r.io.pool_id, name)
+        _up, acting = r.osdmap.pg_to_up_acting_osds(pgid)
+        want = r.spec["reference"].stored(data, config)
+        assert len(want) == r.spec["width"]
+        for pos, (want_data, want_crc) in enumerate(want):
+            osd = r.cluster.osds[acting[pos]]
+            cid = osd.pgs[pgid].cid
+            soid = shard_oid(name, pos)
+            assert bytes(osd.store.read(cid, soid)) == want_data, soid
+            hinfo = denc.loads(osd.store.getattr(cid, soid, HINFO_KEY))
+            assert want_crc is None or hinfo["crc"] == want_crc, soid
+            files += 1
+    assert files == OBJECTS * r.spec["width"]
+
+
+def test_a_rebuilt_object_is_one_op_with_its_spans(repaired):
+    docs = rebuild_docs(repaired)
+    assert docs
+    ids = [d["trace_id"] for d in docs]
+    assert len(set(ids)) == len(ids)
+    assert {i.split(":")[0] for i in ids} <= {"backfill", "rebuild"}
+    assert any(i.startswith("backfill:") for i in ids)
+    chunk_file = OBJECT_BYTES // int(repaired.spec["profile"]["k"])
+    for d in docs:
+        (whole,) = spans(d, "rebuild")
+        assert "cpu" in whole
+        (read,) = spans(d, "rebuild.read")
+        assert read["args"]["path"] in ("cache", "local", "full")
+        assert whole["t0"] <= read["t0"] and read["t1"] <= whole["t1"]
+        if read["args"]["path"] == "cache":
+            assert read["args"]["bytes_read"] == 0
+        else:
+            assert read["args"]["bytes_read"] == \
+                read["args"]["chunks"] * chunk_file
+        if read["args"]["path"] == "full":
+            (encode,) = spans(d, "rebuild.encode")
+            assert encode["args"]["bytes"] == OBJECT_BYTES
+            assert read["t1"] <= encode["t0"]
+        pushes = spans(d, "rebuild.push")
+        assert pushes
+        for p in pushes:
+            assert p["args"]["bytes"] == chunk_file
+            assert p["args"].get("acked", True)
+            assert p["t0"] >= whole["t0"]
+    # the decode and the re-encode left their phases on these docs
+    assert any(s["name"].startswith("ec.") for d in docs
+               for s in d["spans"])
+
+
+def test_the_target_and_the_sources_share_the_rebuilds_trace_id(repaired):
+    docs = repaired.docs
+    ids = {d["trace_id"] for d in rebuild_docs(repaired)}
+    pushed = [d for d in docs if d["kind"] == "recovery"
+              and d["description"].startswith("push(")
+              and d["trace_id"] in ids]
+    assert pushed
+    assert all(spans(d, "execute") and spans(d, "store_apply")
+               for d in pushed)
+    assert any(d["kind"] == "subop" and d["trace_id"] in ids
+               and "sub_read(" in d["description"] for d in docs)
+    assert any(d["kind"] == "reply" and d["trace_id"] in ids
+               and "MPGPushReply" in d["description"] for d in docs)
+
+
+def test_a_backfill_round_is_one_op(repaired):
+    rounds = [d for d in repaired.docs if d["kind"] == "recovery"
+              and d["description"].startswith("backfill_scan(")]
+    assert len(rounds) == repaired.delta["backfill_rounds"] > 0
+    assert len({d["trace_id"] for d in rounds}) == len(rounds)
+    pushed = 0
+    for d in rounds:
+        (scan,) = spans(d, "backfill.scan")
+        (wait,) = spans(d, "backfill.scan_range")
+        assert scan["t0"] <= wait["t0"] and wait["t1"] <= scan["t1"]
+        args = scan["args"]
+        assert args["objects"] == args["pushed"] + args["skipped"]
+        pushed += args["pushed"]
+    assert pushed == repaired.delta["backfill_objects"] > 0
+    assert pushed == sum(1 for d in rebuild_docs(repaired)
+                         if d["trace_id"].startswith("backfill:"))
+
+
+def test_the_counters_add_up(repaired):
+    delta = repaired.delta
+    by_path = {"cache": "rebuild_cache_served", "local": "rebuild_local",
+               "full": "rebuild_full"}
+    seen = dict.fromkeys(by_path.values(), 0)
+    for d in rebuild_docs(repaired):
+        (read,) = spans(d, "rebuild.read")
+        seen[by_path[read["args"]["path"]]] += 1
+    assert {k: delta[k] for k in seen} == seen
+    assert sum(seen.values()) == len(rebuild_docs(repaired))
+    assert delta["recovery_pushes"] >= sum(seen.values())
+
+
+def test_a_code_with_locality_repairs_from_its_group(repaired):
+    reads = [spans(d, "rebuild.read")[0]["args"]
+             for d in rebuild_docs(repaired)]
+    if repaired.spec["profile"]["technique"] != "lrc":
+        assert {a["path"] for a in reads} <= {"full", "cache"}
+        assert all(a["chunks"] == 8 for a in reads if a["path"] == "full")
+        return
+    local = [a for a in reads if a["path"] == "local"]
+    assert local and repaired.delta["rebuild_local"] == len(local)
+    assert all(a["chunks"] == 3 and
+               a["bytes_read"] == 3 * OBJECT_BYTES // 4 for a in local)

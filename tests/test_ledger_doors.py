@@ -112,38 +112,6 @@ def swift_door(rgw):
     return SwiftDoor(f"http://127.0.0.1:{rgw.port}", container="sdoor")
 
 
-def _unclean_pgs(cluster) -> list[str]:
-    """Which leg of `wait_for_clean`'s predicate refuses which PG: what
-    the "cluster not clean" of this file never said (ROADMAP "Known
-    failures and flakes", PR 46)."""
-    osdmap = cluster.leader().osdmon.osdmap
-    out = []
-    for pgid in osdmap.all_pgs():
-        _up, acting = osdmap.pg_to_up_acting_osds(pgid)
-        live = [o for o in acting if o >= 0]
-        if len(live) < osdmap.pools[pgid.pool].size:
-            out.append(f"{pgid}: acting {acting} short")
-        for osd_id in live:
-            osd = cluster.osds.get(osd_id)
-            pg = osd.pgs.get(pgid) if osd else None
-            if pg is None:
-                out.append(f"{pgid}: no copy on osd.{osd_id}")
-                continue
-            why = [leg for leg, bad in (
-                ("backfill incomplete", not pg.backfill_complete),
-                (f"missing {len(pg.pglog.missing)}", pg.pglog.missing),
-                ("primary not active",
-                 osd_id == live[0] and not pg.active),
-                ("catch-up pending", osd_id == live[0] and
-                 getattr(pg, "_catchup_pending", None))) if bad]
-            if why:
-                out.append(f"{pgid} on osd.{osd_id}: " + ", ".join(why))
-    out += [f"osd.{o.whoami}: backfills active {o._backfills_active}"
-            for o in cluster.osds.values()
-            if getattr(o, "_backfills_active", None)]
-    return out
-
-
 class TestFrontDoorLedgers:
     def test_acked_mutations_survive_osd_crash_on_every_door(
             self, cluster, fs_door, rgw_door, swift_door):
@@ -184,7 +152,7 @@ class TestFrontDoorLedgers:
             cluster.restart_osd(1, timeout=240)
         except TimeoutError:
             print("[ledger-doors] unclean: "
-                  + "; ".join(_unclean_pgs(cluster)))
+                  + "; ".join(cluster.unclean_pgs() or []))
             raise
         freport = fsl.verify(fs_door, retry_window=180, on_retry=retry)
         assert freport["checked"] == 4, freport

@@ -67,6 +67,11 @@ class TestCounterSchema:
            "peering_auth_catchups", "peering_getlog_merges",
            "peering_divergent_rewinds", "peering_divergent_entries",
            "recovery_pushes", "recovery_bytes", "backfill_resumes",
+           # what a repair did: backfill rounds and their objects, EC
+           # rebuilds by where the lost shard came from
+           # (tests/test_backfill_under_reads.py holds their sums)
+           "backfill_rounds", "backfill_objects", "rebuild_cache_served",
+           "rebuild_local", "rebuild_full",
            # the PG log as keys: keys and bytes handed to
            # transactions, logs that had to be written whole
            "pglog_keys_written", "pglog_bytes_written",
