@@ -390,7 +390,7 @@ class ErasureCodeTpu(MatrixErasureCode):
         """Pipeline-coalesced shard rebuild: concurrent recovery ops
         reconstructing with the same decode pattern share a dispatch.
         `qos` names the dmClock class the decode lane bills against
-        (rebuild decodes ride @recovery, like the re-encode)."""
+        (rebuild decodes ride @recovery)."""
         want, present = list(want), list(present)
         rows = self._decode_rows(want, present)
         chunks = np.ascontiguousarray(chunks, dtype=np.uint8)

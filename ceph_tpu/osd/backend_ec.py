@@ -43,14 +43,18 @@ class _EcRead:
     the shards in hand (`have`, their applied versions where the read
     is version-gated, one `hinfo`), whom the next gather asks
     (`targets`), the positions asked so far (`asked`), and which step
-    this is (`widened`: PLANNED, WIDENED or SWEEP)."""
+    this is (`widened`: PLANNED, WIDENED or SWEEP).  `want` is what it
+    reads FOR: None for the object's bytes, else the positions whose
+    shard files it rebuilds, which are never among its sources."""
 
-    __slots__ = ("oid", "exclude", "need_ver", "qos", "interval", "have",
-                 "vers", "hinfo", "targets", "asked", "widened",
+    __slots__ = ("oid", "exclude", "need_ver", "qos", "interval", "want",
+                 "have", "vers", "hinfo", "targets", "asked", "widened",
                  "strict_have", "replans")
 
-    def __init__(self, oid, exclude, need_ver, qos, interval):
-        self.oid, self.exclude, self.need_ver = oid, exclude, need_ver
+    def __init__(self, oid, exclude, need_ver, qos, interval, want=None):
+        self.oid, self.need_ver = oid, need_ver
+        self.want = None if want is None else sorted(want)
+        self.exclude = set(exclude or ()) | set(self.want or ())
         self.qos, self.interval = qos, interval
         self.have: dict[int, bytes] = {}
         self.vers: dict[int, tuple] = {}      # shard -> applied version
@@ -692,8 +696,11 @@ class ECBackend:
     # plan's shards did not give the object, the widened step: every
     # other acting holder, a second gather and a second step; and
     # when those did not either, the last-resort sweep, a third).
-    # `_ec_read_local` walks them on the calling thread (rebuild,
-    # scrub repair, the append's read-modify-write); a client read
+    # `_ec_read_local` walks them on the calling thread: the append's
+    # read-modify-write for the object's bytes, and a rebuild and
+    # scrub repair for the shard files of the positions they lost
+    # (`want`: the plan, "decodable" and the last step are then for
+    # those positions, and no object is put together).  A client read
     # PARKS between steps as a write parks in `replica_wait`: the op
     # worker goes on to the next op, the gather's completion
     # re-queues the read, and the `sub_read` ops it waits for are
@@ -703,18 +710,25 @@ class ECBackend:
                        exclude: set | None = None,
                        need_ver: tuple | None = None,
                        qos: str | None = None,
-                       got: dict | None = None) -> bytes | None:
-        """Read + decode an EC object, fetching shards from peers.
+                       got: dict | None = None,
+                       want: list[int] | None = None):
+        """Read an EC object, fetching shards from peers: its bytes,
+        or with `want` (positions) the shard files AT those positions,
+        ({position: shard file}, the object's size), decoded from the
+        shards the codec's plan reads for THEM (Reed-Solomon: k of the
+        others; lrc: the l others of a local group; shec: a shingle)
+        with no object in between.  None where the shards do not give
+        it.  The wanted positions are never sources.
+
         `exclude` drops known-bad shards (scrub repair: a corrupt
-        local shard must not poison the reconstruction); `need_ver`
+        shard must not poison the reconstruction); `need_ver`
         version-gates every source shard (rebuild: a peer that has
         not applied the target version yet must not contribute);
         `qos` names the dmClock class any decode dispatch bills
-        against (rebuild reads ride @recovery under the repair cap,
-        like the rebuild's re-encode); into `got`, {position: bytes}
-        of the shard files the last step had in hand (none where the
-        HBM cache served)."""
-        rd = self._ec_read_begin(oid, exclude, need_ver, qos)
+        against (rebuild reads ride @recovery under the repair cap);
+        into `got`, {position: bytes} of the shard files the last
+        step had in hand (none where the HBM cache served)."""
+        rd = self._ec_read_begin(oid, exclude, need_ver, qos, want)
         while isinstance(rd, _EcRead):
             step = rd
             rd = self._ec_read_step(rd, self._ec_read_fetch(rd))
@@ -723,60 +737,13 @@ class ECBackend:
                 got.update({p: len(b) for p, b in step.have.items()})
         return rd
 
-    def _ec_repair_read(self, oid: str, lost: list[int],
-                        need_ver: tuple, qos: str | None = None,
-                        got: dict | None = None):
-        """The shard files at positions `lost`, rebuilt from the shards
-        the codec's plan reads for THEM (lrc: the l others of a local
-        group; shec: a shingle) and from no others: ({position:
-        bytes}, the object's size).  None where that plan reads as
-        many shards as a read of the object does (the caller's whole
-        read serves as well, and takes the first set that decodes), or
-        a planned source did not answer at `need_ver`.  Into `got`,
-        {position: bytes} of the shard files it read."""
-        codec = self._ec_codec()
-        live = [p for p, o in enumerate(self.acting)
-                if o != ITEM_NONE and p not in lost
-                and self.osd.osdmap.is_up(o)]
-        try:
-            plan = ecutil.minimum_shards(codec, live, lost)
-        except ErasureCodeError:
-            return None
-        if len(plan) >= codec.get_data_chunk_count():
-            return None
-        # with all but the plan's shards excluded the read has fewer
-        # than the data needs, and starts widened over just those
-        others = set(range(len(self.acting))) - set(plan)
-        rd = self._ec_read_begin(oid, others, need_ver, qos)
-        if not isinstance(rd, _EcRead):
-            return None         # the cache holds it: the caller's path
-        gather = self.osd.ec_fetch_shards(
-            self.pgid, oid, rd.targets, need_ver=need_ver)
-        for shard, (data, hi, ver) in gather.out.items():
-            rd.have[shard] = data
-            rd.vers[shard] = tuple(ver) if ver is not None else None
-            rd.hinfo = rd.hinfo or hi
-        gather.stamp(optracker.current(), chunks=sorted(rd.have))
-        if got is not None:
-            got.update({p: len(b) for p, b in rd.have.items()})
-        vers = {rd.vers.get(p) for p in plan}
-        sinfo = self._ec_sinfo(codec)
-        if rd.hinfo is None or vers != {tuple(need_ver)} \
-                or rd.hinfo.get("stripe_unit") != sinfo.chunk_size:
-            return None
-        try:
-            return ecutil.rebuild_shards(
-                codec, sinfo, rd.have, lost, rd.hinfo["size"],
-                qos=qos), rd.hinfo["size"]
-        except Exception as e:
-            self.log.warn("local repair of %s shards %s failed: %s",
-                          oid, lost, e)
-            return None
-
     def _ec_read_begin(self, oid: str, exclude: set | None = None,
                        need_ver: tuple | None = None,
-                       qos: str | None = None):
-        """The object's bytes if the HBM cache holds them, else the
+                       qos: str | None = None,
+                       want: list[int] | None = None):
+        """The object's bytes if the HBM cache holds them (a read of
+        the bytes alone: the entry is not asked for positions `want`,
+        whose callers ask it through `_ec_push_shards`), else the
         read's state after the local shards: an `_EcRead` to gather
         for."""
         # HBM stripe cache fast path: a committed entry at the
@@ -787,7 +754,7 @@ class ECBackend:
         # (corruption included) invalidated it, so excluded-shard
         # callers still get pre-corruption truth.
         cur = self.pglog.objects.get(oid)
-        if cur is not None and \
+        if want is None and cur is not None and \
                 (need_ver is None or tuple(need_ver) <= tuple(cur)):
             ent = hbm_cache.get().lookup(self.cid, oid,
                                          version=tuple(cur))
@@ -795,8 +762,8 @@ class ECBackend:
                 data = ent.data_bytes()
                 if data is not None:
                     return data
-        rd = _EcRead(oid, exclude or set(), need_ver, qos,
-                     self.interval_epoch)
+        rd = _EcRead(oid, exclude, need_ver, qos, self.interval_epoch,
+                     want)
         # PLAN FIRST (the reference's default, ECBackend
         # get_min_avail_to_read_shards -> minimum_to_decode(want,
         # available)): the first gather asks the shards the codec's
@@ -814,7 +781,8 @@ class ECBackend:
                 if o != ITEM_NONE and p not in rd.exclude
                 and self.osd.osdmap.is_up(o)]
         try:
-            plan = set(ecutil.minimum_shards(self._ec_codec(), live))
+            plan = set(ecutil.minimum_shards(self._ec_codec(), live,
+                                             rd.want))
         except ErasureCodeError:
             return self._ec_read_widen(rd)  # fewer live than it needs
         self._ec_read_own(rd, plan)
@@ -868,11 +836,12 @@ class ECBackend:
         rd.asked.update(s for s, _o in rd.targets)
         return rd
 
-    def _ec_decodable(self, shards) -> bool:
-        """Do these shards give the object?  The codec's word: what
-        it cannot plan a decode of the data chunks from, it refuses."""
+    def _ec_decodable(self, shards, want=None) -> bool:
+        """Do these shards give the object (the positions `want`)?
+        The codec's word: what it cannot plan a decode of the data
+        chunks (of those positions) from, it refuses."""
         try:
-            ecutil.minimum_shards(self._ec_codec(), shards)
+            ecutil.minimum_shards(self._ec_codec(), shards, want)
         except ErasureCodeError:
             return False
         return True
@@ -884,22 +853,23 @@ class ECBackend:
 
         def enough(fetched: set) -> bool:
             shards = fetched | rd.have.keys()
-            if self._ec_decodable(shards):
+            if self._ec_decodable(shards, rd.want):
                 return True
             if len(shards) >= k:
                 rd.replans += 1     # as many as an MDS code asks: not
             return False            # a set this code decodes, go on
 
-        if rd.hinfo is not None and self._ec_decodable(rd.have):
+        if rd.hinfo is not None and self._ec_decodable(rd.have, rd.want):
             rd.targets = []         # what this OSD holds is enough
         return self.osd.ec_fetch_shards(
             self.pgid, rd.oid, rd.targets, need_ver=rd.need_ver,
             enough=enough, done=done)
 
     def _ec_read_step(self, rd: "_EcRead", gather):
-        """After a gather: the object's bytes, None (unreadable), or
-        the `_EcRead` to gather for next: the widened step after a
-        planned one that did not give the object, the sweep after
+        """After a gather: what the read is for (the object's bytes;
+        the shard files at `rd.want` and the object's size), None
+        (unreadable), or the `_EcRead` to gather for next: the widened
+        step after a planned one that did not give it, the sweep after
         that."""
         oid, have = rd.oid, rd.have
         for shard, (data, hi, ver) in gather.out.items():
@@ -910,8 +880,13 @@ class ECBackend:
                 rd.hinfo = hi
         codec = self._ec_codec()
         k = codec.get_data_chunk_count()
-        decodable = rd.hinfo is not None and self._ec_decodable(have)
-        if decodable:
+        decodable = rd.hinfo is not None and \
+            self._ec_decodable(have, rd.want)
+        if decodable and rd.want is not None:
+            # a rebuild is handed what the plan for its positions reads
+            used = ecutil.minimum_shards(codec, have, rd.want)
+            have = {i: have[i] for i in used}
+        elif decodable:
             # the decode is handed the shards it uses and no others:
             # the data chunks in hand, and what the codec's plan reads
             # to rebuild the rest
@@ -960,8 +935,13 @@ class ECBackend:
         sinfo = ecutil.StripeInfo(
             k, rd.hinfo.get("stripe_unit") or len(next(iter(have.values()))))
         try:
-            data = ecutil.decode_object(codec, sinfo, have,
-                                        rd.hinfo["size"], qos=rd.qos)
+            if rd.want is None:
+                data = ecutil.decode_object(codec, sinfo, have,
+                                            rd.hinfo["size"], qos=rd.qos)
+            else:
+                data = ecutil.rebuild_shards(
+                    codec, sinfo, have, rd.want, rd.hinfo["size"],
+                    qos=rd.qos), rd.hinfo["size"]
         except Exception as e:
             self.log.warn("decode %s failed: %s (have %s, size %s)",
                           oid, e, sorted(have), rd.hinfo.get("size"))
@@ -978,7 +958,8 @@ class ECBackend:
         shards exist somewhere (a remap in flight moved the roles out
         from under the acting order) even though the acting set's
         holders do not serve them."""
-        sw = _EcRead(rd.oid, rd.exclude, cur, rd.qos, rd.interval)
+        sw = _EcRead(rd.oid, rd.exclude, cur, rd.qos, rd.interval,
+                     rd.want)
         sw.widened, sw.strict_have = SWEEP, set(rd.have)
         km = self._ec_codec().get_chunk_count()
         store = self.osd.store

@@ -193,8 +193,8 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      # what a repair did: backfill rounds and the
                      # objects they pushed or rebuilt; EC rebuilds by
                      # where the lost shard came from (the HBM cache's
-                     # rows, a local repair's few chunks, a read of
-                     # the whole object and a re-encode)
+                     # rows; decoded from fewer than k shards, a local
+                     # repair; decoded from as many as a read asks)
                      .add_u64_counter("backfill_rounds")
                      .add_u64_counter("backfill_objects")
                      .add_u64_counter("rebuild_cache_served")
@@ -395,7 +395,7 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         # (the picker charges each pick by its head batch's staged
         # bytes): a tenant saturating encodes must not monopolize
         # device lanes either.  The @recovery class rides along, so a
-        # rebuild's re-encode (tagged by recovery_svc) is throttleable
+        # rebuild's decode (tagged by recovery_svc) is throttleable
         # on the device plane exactly like its pushes on the op shards.
         from ..ops import pipeline as ec_pipeline
         ec_pipeline.configure_qos(

@@ -259,8 +259,7 @@ def _rebuild_chunks(codec, arrs: dict[int, np.ndarray], want: list[int],
         # with one decode pattern share a device dispatch
         if hasattr(codec, "decode_batch_async"):
             try:
-                # `qos` tags the decode lane pick the same way the
-                # encode path tags re-encodes: a rebuild's decode
+                # `qos` tags the decode lane pick: a rebuild's decode
                 # rides @recovery under the repair cap, not the
                 # client best-effort class
                 handle = codec.decode_batch_async(
@@ -306,9 +305,13 @@ def rebuild_shards(codec, sinfo: StripeInfo, shards: dict[int, bytes],
                    qos=None) -> dict[int, memoryview]:
     """The shard files at positions `lost`, rebuilt from the shard
     files in hand (keyed by position) WITHOUT the object in between:
-    the codec's plan for those chunks reads what it needs of them (a
-    local group's l for lrc) and the decode gives the lost chunks,
-    parities included, directly."""
+    every code's rebuild and scrub repair.  The codec's plan for those
+    chunks reads what it needs of the files (k of them for
+    Reed-Solomon and cauchy, a local group's l for lrc, a shingle for
+    shec) and ONE decode of len(lost) rows gives the lost chunks,
+    parities included, directly: byte for byte the files an encode of
+    the object lays out, so their CRC columns are `crc32c_batch` of
+    their own rows."""
     of = shard_chunks(codec)
     arrs, S = _shard_arrays(codec, sinfo, shards, logical_size)
     _rebuild_chunks(codec, arrs, [of[p] for p in lost], S,

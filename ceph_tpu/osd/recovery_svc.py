@@ -1319,9 +1319,14 @@ class RecoveryService:
         """One rebuilt object is one recovery op, whichever way it was
         queued (a log-driven rebuild, `rebuild:<pgid>:<oid>:s<position>`;
         an object of a backfill round, `backfill:<pgid>:<oid>`): `rebuild` is
-        this thread's part, with `rebuild.read`, the `ec.*` phases of
-        the decode and the re-encode, and `rebuild.encode` inside it;
-        a `rebuild.push` runs from the send to the target's ack and
+        this thread's part, with `rebuild.read` (the gather of what
+        the codec's plan reads for the lost positions, and the ONE
+        decode that gives their shard files: its `ec.*` phases land
+        here too) and `rebuild.encode` inside it, which re-encodes
+        nothing: it covers the CRC columns of the rebuilt files from
+        their own bytes, their fold and the `hinfo` (`bytes` = the
+        bytes rebuilt, `positions`); a cache-served rebuild has none.
+        A `rebuild.push` runs from the send to the target's ack and
         keeps the op open until then (`_ec_push_shards`).  The
         sub-reads and the push carry the trace id, so the shard OSDs'
         `sub_read` ops and the target's `push` op (its `store_apply` /
@@ -1358,33 +1363,31 @@ class RecoveryService:
         # HBM-cache fast path first: with the object's encoded stripes
         # still on a chip at exactly the target version, the push
         # fetches only the missing shards' rows D2H from the cached
-        # arrays (data=None — no shard gather, no decode, and the full
-        # payload never crosses the boundary); False = no usable entry
-        if self._ec_push_shards(pg, oid, need, missing, None):
+        # arrays (no shard gather, no decode, and the payload never
+        # crosses the boundary); False = no usable entry
+        if self._ec_push_shards(pg, oid, need, missing):
             self.perf.inc("rebuild_cache_served")
             return True
-        # the rebuild's decode lane bills the same class as its
-        # re-encode: both halves of a repair sit under the repair cap
+        # the rebuild's decode rides the repair's class on the EC
+        # dispatch lanes (bytes-weighted), so a repair storm cannot
+        # monopolize the device plane any more than the op shards
         from .daemon import RECOVERY_QOS_CLASS
         qos = (RECOVERY_QOS_CLASS if self._qos_recovery is not None
                else None)
         got: dict = {}
         with optracker.span("rebuild.read") as read:
-            # a code with locality first: the lost shards from the few
-            # their own plan reads, not from a read of the whole object
-            local = pg._ec_repair_read(oid, [s for s, _o in missing],
-                                       need, qos, got)
-            data = None if local is not None else pg._ec_read_local(
-                oid, exclude={s for s, _o in missing}, need_ver=need,
-                qos=qos, got=got)
-            read.update(path="local" if local is not None else "full",
+            # the lost positions' shard files from the shards the
+            # codec's plan reads for THEM (k for Reed-Solomon, a local
+            # group's l for lrc, a shingle for shec): one gather, one
+            # decode, no object in between
+            rebuilt = pg._ec_read_local(
+                oid, need_ver=need, qos=qos, got=got,
+                want=[s for s, _o in missing])
+            local = rebuilt is not None and \
+                len(got) < pg._ec_codec().get_data_chunk_count()
+            read.update(path="local" if local else "full",
                         chunks=len(got), bytes_read=sum(got.values()))
-        if local is not None:
-            self.perf.inc("rebuild_local")
-            self._ec_push_shards(pg, oid, need, missing, None,
-                                 rebuilt=local)
-            return True
-        if data is None:
+        if rebuilt is None:
             # sources not all at `need` yet (write still fanning out):
             # retry with backoff rather than stranding the stale shard
             if retry and attempt < 6:
@@ -1401,87 +1404,76 @@ class RecoveryService:
                 self.log.warn("cannot rebuild %s/%s: undecodable",
                               pgid, oid)
             return False
-        self.perf.inc("rebuild_full")
-        self._ec_push_shards(pg, oid, need, missing, data)
+        self.perf.inc("rebuild_local" if local else "rebuild_full")
+        self._ec_push_shards(pg, oid, need, missing, rebuilt)
         return True
 
     def _ec_push_shards(self, pg: PG, oid: str, version,
                         missing: list[tuple[int, int]],
-                        data: bytes | None,
                         rebuilt: tuple | None = None) -> bool:
-        """Re-encode `data` and land the listed shards (local write or
-        MPGPush) — shared by log-driven rebuild and scrub repair.
+        """Land the listed shards (local write or MPGPush), shared by
+        rebuild and scrub repair.
 
-        When the HBM stripe cache still holds this object at exactly
-        `version`, the shard payloads come straight off the chip (D2H
-        of only the missing shards' rows) and the CRCs fold from the
-        cached per-stripe chunk CRCs — no re-encode, no H2D.  A
-        cache-trusting caller passes data=None (the payload itself
-        never crosses the boundary); returns False only then, when
-        the entry vanished before its rows could be fetched.
+        `rebuilt` is what `pg._ec_read_local(want=...)` decoded for
+        them, ({position: shard file}, the object's size): nothing is
+        re-encoded, the files' CRC columns come from their own bytes
+        (`crc32c_batch` a stripe's chunk, folded as the encode's are),
+        under the span `rebuild.encode` (`bytes` rebuilt,
+        `positions`).
 
-        `rebuilt` ({position: shard file}, object size) lands shard
-        files a local repair decoded directly: there is no object to
-        re-encode, and their CRCs are folded from their own bytes."""
+        Without it the shards are asked of the HBM stripe cache: where
+        it still holds this object at exactly `version`, the payloads
+        come straight off the chip (D2H of only the missing shards'
+        rows) and the CRCs fold from the cached per-stripe chunk CRCs:
+        no gather, no decode, no H2D.  False where it has no entry, or
+        the entry vanished before its rows could be fetched: the
+        caller then reads."""
+        from ..ops import crc32c as crc_mod
         from ..ops import hbm_cache
         from . import ecutil
         codec = pg._ec_codec()
         sinfo = pg._ec_sinfo(codec)
-        payloads: dict[int, bytes] = {}
-        stripe_crcs = None
-        size = 0
         cols = [shard for shard, _o in missing]
         trk = optracker.current()
-        t_lookup = time.monotonic()
-        ent = None if rebuilt else hbm_cache.get().lookup(
-            pg.cid, oid, version=tuple(version))
-        if rebuilt:
-            from ..ops import crc32c as crc_mod
+
+        def hinfos(stripe_crcs, size: int) -> dict[int, bytes]:
+            # one CRC column a shard to land, and no others folded
+            crcs = ecutil.fold_shard_crcs(stripe_crcs, sinfo.chunk_size)
+            prefix_crcs = ecutil.fold_shard_crcs(
+                stripe_crcs, sinfo.chunk_size,
+                upto=size // sinfo.stripe_width)
+            return {shard: denc.dumps({
+                "size": size, "crc": crc, "crc_prefix": prefix,
+                "shard": shard, "stripe_unit": sinfo.chunk_size})
+                for shard, crc, prefix in zip(cols, crcs, prefix_crcs)}
+
+        if rebuilt is not None:
             payloads, size = rebuilt
-            stripe_crcs = np.stack([crc_mod.crc32c_batch(np.frombuffer(
-                payloads[c], dtype=np.uint8).reshape(
-                    -1, sinfo.chunk_size)) for c in cols], axis=1)
-        elif ent is not None and ent.chunk_size == sinfo.chunk_size \
-                and (data is None or ent.size == len(data)):
+            with optracker.span(
+                    "rebuild.encode", positions=len(cols),
+                    bytes=sum(len(payloads[c]) for c in cols)):
+                hinfo = hinfos(np.stack([crc_mod.crc32c_batch(
+                    np.frombuffer(payloads[c], dtype=np.uint8).reshape(
+                        -1, sinfo.chunk_size)) for c in cols], axis=1),
+                    size)
+        else:
+            t_lookup = time.monotonic()
+            ent = hbm_cache.get().lookup(pg.cid, oid,
+                                         version=tuple(version))
+            if ent is None or ent.chunk_size != sinfo.chunk_size:
+                return False
             # the entry keeps chunks, in the codec's order
             of = ecutil.shard_chunks(codec)
+            payloads = {}
             for shard in cols:
-                b = ent.shard_bytes(of[shard])
-                if b is None:
-                    payloads.clear()     # chip buffer gone: re-encode
-                    break
-                payloads[shard] = b
-            else:
-                stripe_crcs = ent.crcs[:, [of[c] for c in cols]]
-                size = ent.size
-                if data is None:
-                    # a rebuild that trusted the cache: this was its read
-                    optracker.add_span("rebuild.read", t_lookup,
-                                       time.monotonic(), path="cache",
-                                       chunks=0, bytes_read=0)
-        if stripe_crcs is None:
-            if data is None:
-                return False
-            # the rebuild's re-encode is RECOVERY work: with
-            # osd_qos_recovery set it rides the @recovery class on the
-            # EC dispatch lanes too (bytes-weighted), so a repair storm
-            # cannot monopolize the device plane any more than it can
-            # the op shards
-            from .daemon import RECOVERY_QOS_CLASS
-            qos = (RECOVERY_QOS_CLASS if self._qos_recovery is not None
-                   else None)
-            with optracker.span("rebuild.encode", bytes=len(data)):
-                shards, stripe_crcs = ecutil.encode_object_ex(
-                    codec, sinfo, data, qos=qos)
-            payloads = {shard: shards[shard] for shard in cols}
-            stripe_crcs = np.asarray(stripe_crcs)[:, cols]
-            size = len(data)
-        # one CRC column a shard to land, and no others folded
-        crcs = dict(zip(cols, ecutil.fold_shard_crcs(
-            stripe_crcs, sinfo.chunk_size)))
-        prefix_crcs = dict(zip(cols, ecutil.fold_shard_crcs(
-            stripe_crcs, sinfo.chunk_size,
-            upto=size // sinfo.stripe_width)))
+                payloads[shard] = ent.shard_bytes(of[shard])
+                if payloads[shard] is None:
+                    return False        # chip buffer gone
+            hinfo = hinfos(ent.crcs[:, [of[c] for c in cols]], ent.size)
+            # a rebuild that trusted the cache: this was its read
+            optracker.add_span("rebuild.read", t_lookup,
+                               time.monotonic(), path="cache",
+                               chunks=0, bytes_read=0)
         with pg.lock:
             cur = pg.pglog.objects.get(oid)
         if cur is None or cur > tuple(version):
@@ -1490,12 +1482,6 @@ class RecoveryService:
             # must not read as version (0,0) and pass the gate)
             return True
         for shard, osd_id in missing:
-            hinfo = denc.dumps({
-                "size": size,
-                "crc": crcs[shard],
-                "crc_prefix": prefix_crcs[shard],
-                "shard": shard,
-                "stripe_unit": sinfo.chunk_size})
             payload = payloads[shard]
             self._note_recovery_push(len(payload))
             # the healed shard must carry the version xattr too, or
@@ -1506,7 +1492,7 @@ class RecoveryService:
                 soid = shard_oid(oid, shard)
                 txn.truncate(pg.cid, soid, 0)
                 txn.write(pg.cid, soid, 0, payload)
-                txn.setattr(pg.cid, soid, HINFO_KEY, hinfo)
+                txn.setattr(pg.cid, soid, HINFO_KEY, hinfo[shard])
                 txn.setattr(pg.cid, soid, VER_KEY, ver)
                 with pg.lock, optracker.span(
                         "rebuild.push", shard=shard, target=osd_id,
@@ -1528,8 +1514,8 @@ class RecoveryService:
                 push = MPGPush(
                     pgid=str(pg.pgid), oid=oid, version=version,
                     data=payload,
-                    xattrs={HINFO_KEY: hinfo, VER_KEY: ver}, omap={},
-                    shard=shard, epoch=self.osdmap.epoch)
+                    xattrs={HINFO_KEY: hinfo[shard], VER_KEY: ver},
+                    omap={}, shard=shard, epoch=self.osdmap.epoch)
                 # the op this thread serves (a rebuild; none for a
                 # caller that runs under no op) stays open until the
                 # target has answered: `rebuild.push` is the send, the

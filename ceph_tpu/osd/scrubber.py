@@ -436,9 +436,11 @@ class ScrubService:
         return repaired
 
     def repair_ec_pg(self, pg: PG, inconsistent: list) -> int:
-        """Shard-granular EC repair: decode each damaged object from
-        its surviving shards (known-bad ones excluded) and rebuild the
-        bad shards in place (osd-scrub-repair.sh
+        """Shard-granular EC repair: the bad shards of each damaged
+        object are the positions a rebuild has lost (the HBM cache's
+        rows where it still holds the object, else decoded from what
+        the codec's plan reads among the others, the known-bad ones
+        never a source) and land in place (osd-scrub-repair.sh
         TEST_corrupt_and_repair_jerasure/lrc scenarios)."""
         by_oid: dict[str, set] = {}
         for item in inconsistent:
@@ -447,17 +449,25 @@ class ScrubService:
                 by_oid.setdefault(base, set()).add(int(sfx))
         repaired = 0
         for oid, bad_shards in sorted(by_oid.items()):
-            with pg.lock:
-                version = pg.pglog.objects.get(oid, (0, 0))
-                data = pg._ec_read_local(oid, exclude=bad_shards)
-            if data is None:
-                self.log.warn("repair: %s unrecoverable without "
-                              "shards %s", oid, sorted(bad_shards))
-                continue
             targets = [(s, pg.acting[s]) for s in sorted(bad_shards)
                        if s < len(pg.acting)
                        and pg.acting[s] != ITEM_NONE]
-            self._ec_push_shards(pg, oid, version, targets, data)
+            if not targets:
+                continue
+            with pg.lock:
+                version = pg.pglog.objects.get(oid, (0, 0))
+            if self._ec_push_shards(pg, oid, version, targets):
+                repaired += 1
+                continue
+            with pg.lock:
+                rebuilt = pg._ec_read_local(
+                    oid, exclude=bad_shards,
+                    want=[s for s, _o in targets])
+            if rebuilt is None:
+                self.log.warn("repair: %s unrecoverable without "
+                              "shards %s", oid, sorted(bad_shards))
+                continue
+            self._ec_push_shards(pg, oid, version, targets, rebuilt)
             repaired += 1
         return repaired
 

@@ -10,8 +10,10 @@ bytes; the cluster comes clean, and `wait_for_clean` does not say so
 before the last rebuild; every position of every object equals the
 benchmark's plain reference, shard file and CRC; a rebuilt object is
 ONE recovery op whichever way it was queued, with `rebuild`,
-`rebuild.read` (`path`, `chunks`, `bytes_read`), `rebuild.encode` where
-it re-encoded and `rebuild.push` (`shard`, `target`, `bytes`, acked);
+`rebuild.read` (`path`, `chunks`, `bytes_read`), ONE decode of the lost
+positions and no re-encode (PR 48), `rebuild.encode` (the rebuilt
+files' CRCs and hinfo) and `rebuild.push` (`shard`, `target`, `bytes`,
+acked);
 a backfill round is one with `backfill.scan` (`objects`, `pushed`,
 `skipped`) and the target's `scan_range` wait inside; the counters add
 up; lrc rebuilds a lost shard from the three others of its group.
@@ -26,9 +28,11 @@ import pytest
 from benchmark.references import lrc as lrc_reference
 from benchmark.references import reed_sol_van as rs_reference
 from ceph_tpu.client import RadosError
+from ceph_tpu.osd import ecutil
 from ceph_tpu.osd.pg import shard_oid
-from ceph_tpu.osd.pglog import HINFO_KEY
-from ceph_tpu.utils import denc
+from ceph_tpu.osd.pglog import HINFO_KEY, VER_KEY
+from ceph_tpu.store.objectstore import Transaction
+from ceph_tpu.utils import copyaudit, denc
 from ceph_tpu.utils.config import Config
 from ceph_tpu.vstart import MiniCluster
 
@@ -123,7 +127,12 @@ def repaired(request):
                    for i in range(4)]
         for t in readers:
             t.start()
+        # the drill's docs and counters start here: the ring also holds
+        # what set-up rebuilt (the `settle` object, written while the
+        # pool still peered), which no delta below counts
+        out.began = time.monotonic()
         start = counters(cluster)
+        audit = copyaudit.snapshot()["sites"]
         cluster.kill_osd(victim)
         cluster.mark_osd_down(victim)
         cluster.mark_osd_out(victim)
@@ -138,10 +147,33 @@ def repaired(request):
         stop.set()
         for t in readers:
             t.join(30)
-        end = counters(cluster)
+        # clean is not yet quiet: a push may still be on its way to
+        # its ack (the op stays in flight, the counter has counted),
+        # and a peering round after clean may resume a session from
+        # its watermark for one more round that finds nothing to
+        # push.  Counters and docs are taken when no recovery op is in
+        # flight, and taken again if one started meanwhile.
+
+        def quiet() -> bool:
+            return not any(
+                osd.pg_repairing(p) for osd in cluster.osds.values()
+                for p in pgids) and not any(
+                op["kind"] == "recovery"
+                for osd in cluster.osds.values()
+                for op in osd.asok.execute("dump_ops_in_flight")["ops"])
+
+        while True:
+            cluster._wait(quiet, 60, "recovery ops still in flight")
+            end = counters(cluster)
+            out.docs = [d for osd in cluster.osds.values() for d in
+                        osd.asok.execute("dump_historic_ops")["ops"]]
+            if quiet() and counters(cluster) == end:
+                break
         out.delta = {k: end[k] - start[k] for k in COUNTERS}
-        out.docs = [d for osd in cluster.osds.values() for d in
-                    osd.asok.execute("dump_historic_ops")["ops"]]
+        # host bytes materialized over the drill, by the audit's site
+        out.audit_delta = {
+            site: v["bytes"] - audit.get(site, {"bytes": 0})["bytes"]
+            for site, v in copyaudit.snapshot()["sites"].items()}
         out.cluster, out.io, out.spec = cluster, io, spec
         out.objects, out.victim = objects, victim
         out.pgids, out.before = pgids, before
@@ -151,9 +183,15 @@ def repaired(request):
         cluster.stop()
 
 
-def rebuild_docs(r) -> list:
+def drill_docs(r, prefix: str) -> list:
+    """The recovery docs of the drill whose description starts so."""
     return [d for d in r.docs if d["kind"] == "recovery"
-            and d["description"].startswith("rebuild(")]
+            and d["description"].startswith(prefix)
+            and d["mstart"] >= r.began]
+
+
+def rebuild_docs(r) -> list:
+    return drill_docs(r, "rebuild(")
 
 
 def spans(doc: dict, name: str) -> list:
@@ -202,14 +240,31 @@ def test_every_position_equals_the_reference(repaired):
     assert files == OBJECTS * r.spec["width"]
 
 
+def pushed_docs(r) -> list:
+    """The rebuild docs that pushed.  One without a push is a rebuild
+    whose gather did not come back at the object's version (a backfill
+    round's returns after its `rebuild.read`, before any push or
+    counter, and the session's rescan rebuilds the object again under
+    the same id; a log-driven one retries): `unpushed_docs`."""
+    return [d for d in rebuild_docs(r) if spans(d, "rebuild.push")]
+
+
+def unpushed_docs(r) -> list:
+    return [d for d in rebuild_docs(r) if not spans(d, "rebuild.push")]
+
+
+def shard_file(r) -> int:
+    return OBJECT_BYTES // int(r.spec["profile"]["k"])
+
+
 def test_a_rebuilt_object_is_one_op_with_its_spans(repaired):
-    docs = rebuild_docs(repaired)
+    docs = pushed_docs(repaired)
     assert docs
     ids = [d["trace_id"] for d in docs]
-    assert len(set(ids)) == len(ids)
+    assert sorted(set(ids)) == sorted(ids)
     assert {i.split(":")[0] for i in ids} <= {"backfill", "rebuild"}
     assert any(i.startswith("backfill:") for i in ids)
-    chunk_file = OBJECT_BYTES // int(repaired.spec["profile"]["k"])
+    chunk_file = shard_file(repaired)
     for d in docs:
         (whole,) = spans(d, "rebuild")
         assert "cpu" in whole
@@ -218,22 +273,80 @@ def test_a_rebuilt_object_is_one_op_with_its_spans(repaired):
         assert whole["t0"] <= read["t0"] and read["t1"] <= whole["t1"]
         if read["args"]["path"] == "cache":
             assert read["args"]["bytes_read"] == 0
+            assert not spans(d, "rebuild.encode")
         else:
             assert read["args"]["bytes_read"] == \
                 read["args"]["chunks"] * chunk_file
-        if read["args"]["path"] == "full":
+            # nothing is re-encoded: the span is the CRC columns of
+            # the rebuilt files, their fold and the hinfo
             (encode,) = spans(d, "rebuild.encode")
-            assert encode["args"]["bytes"] == OBJECT_BYTES
+            positions = len(spans(d, "rebuild.push"))
+            assert encode["args"] == {"positions": positions,
+                                      "bytes": positions * chunk_file}
             assert read["t1"] <= encode["t0"]
+            assert encode["t1"] <= whole["t1"]
         pushes = spans(d, "rebuild.push")
-        assert pushes
         for p in pushes:
             assert p["args"]["bytes"] == chunk_file
             assert p["args"].get("acked", True)
             assert p["t0"] >= whole["t0"]
-    # the decode and the re-encode left their phases on these docs
+    # the decode left its phases on these docs
     assert any(s["name"].startswith("ec.") for d in docs
                for s in d["spans"])
+
+
+def test_a_rebuild_without_a_push_is_followed_by_one_with(repaired):
+    """A doc that pushed nothing read and stopped there: no encode, no
+    counter; a later doc of the same id lands the shard."""
+    pushed = {}
+    for d in pushed_docs(repaired):
+        pushed[d["trace_id"]] = spans(d, "rebuild")[0]["t0"]
+    for d in unpushed_docs(repaired):
+        (whole,) = spans(d, "rebuild")
+        assert not spans(d, "rebuild.encode"), d
+        assert all(a["args"]["path"] == "full"
+                   for a in spans(d, "rebuild.read")), d
+        assert pushed.get(d["trace_id"], 0) > whole["t0"], d
+
+
+def test_a_rebuild_is_one_plan_one_gather_set_and_one_decode(repaired):
+    """A rebuild decodes the lost positions and nothing else: ONE pass
+    of the pipeline (on the device `ec.device_compute` with `rows` =
+    the positions rebuilt over the object's stripes; `ec.host_encode`
+    where the host served it, as it may any pass here on the CPU),
+    from the shards the codec's plan reads for those positions, and no
+    object is staged or laid out again."""
+    r = repaired
+    chunk_file = shard_file(r)
+    stripes = chunk_file // UNIT
+    k = int(r.spec["profile"]["k"])
+    docs = [d for d in pushed_docs(r)
+            if spans(d, "rebuild.read")[0]["args"]["path"] != "cache"]
+    assert docs
+    for d in docs:
+        positions = len(spans(d, "rebuild.push"))
+        on_device = spans(d, "ec.device_compute")
+        assert len(on_device) + len(spans(d, "ec.host_encode")) == 1, d
+        for s in on_device:
+            assert s["args"]["rows"] == positions
+            assert s["args"]["stripes"] == stripes
+        # the last gather's decode set is the plan's for the rebuilt
+        # position, which is not in it: fewer than k where the code
+        # has locality, never more than an object's read
+        read = spans(d, "rebuild.read")[0]["args"]
+        lost = {p["args"]["shard"] for p in spans(d, "rebuild.push")}
+        used = spans(d, "gather_wait")[-1]["args"]["chunks"]
+        assert not lost & set(used)
+        if read["path"] == "local":
+            assert len(used) == read["chunks"] < k
+        else:
+            assert read["chunks"] >= k >= len(used)
+    # no rebuild staged an object or laid out k+m files: the copy
+    # audit's sites of the encode moved for the set-up's writes alone
+    sites = r.audit_delta
+    assert sites.get("ec.stage", 0) == 0, sites
+    assert sites.get("ec.shard_layout", 0) == 0, sites
+    assert sites.get("ec.decode_rebuild", 0) >= len(docs) * chunk_file
 
 
 def test_the_target_and_the_sources_share_the_rebuilds_trace_id(repaired):
@@ -252,8 +365,7 @@ def test_the_target_and_the_sources_share_the_rebuilds_trace_id(repaired):
 
 
 def test_a_backfill_round_is_one_op(repaired):
-    rounds = [d for d in repaired.docs if d["kind"] == "recovery"
-              and d["description"].startswith("backfill_scan(")]
+    rounds = drill_docs(repaired, "backfill_scan(")
     assert len(rounds) == repaired.delta["backfill_rounds"] > 0
     assert len({d["trace_id"] for d in rounds}) == len(rounds)
     pushed = 0
@@ -270,21 +382,22 @@ def test_a_backfill_round_is_one_op(repaired):
 
 
 def test_the_counters_add_up(repaired):
+    """The counters follow `path` one for one over the docs that
+    pushed (a rebuild that read and could not decode counts nowhere)."""
     delta = repaired.delta
     by_path = {"cache": "rebuild_cache_served", "local": "rebuild_local",
                "full": "rebuild_full"}
     seen = dict.fromkeys(by_path.values(), 0)
-    for d in rebuild_docs(repaired):
+    for d in pushed_docs(repaired):
         (read,) = spans(d, "rebuild.read")
         seen[by_path[read["args"]["path"]]] += 1
     assert {k: delta[k] for k in seen} == seen
-    assert sum(seen.values()) == len(rebuild_docs(repaired))
     assert delta["recovery_pushes"] >= sum(seen.values())
 
 
 def test_a_code_with_locality_repairs_from_its_group(repaired):
     reads = [spans(d, "rebuild.read")[0]["args"]
-             for d in rebuild_docs(repaired)]
+             for d in pushed_docs(repaired)]
     if repaired.spec["profile"]["technique"] != "lrc":
         assert {a["path"] for a in reads} <= {"full", "cache"}
         assert all(a["chunks"] == 8 for a in reads if a["path"] == "full")
@@ -293,3 +406,66 @@ def test_a_code_with_locality_repairs_from_its_group(repaired):
     assert local and repaired.delta["rebuild_local"] == len(local)
     assert all(a["chunks"] == 3 and
                a["bytes_read"] == 3 * OBJECT_BYTES // 4 for a in local)
+
+
+def test_a_rebuild_widens_past_a_planned_source_that_is_behind(repaired):
+    """The plan for the lost position names a source that has not
+    applied the object's version: the version gate passes it by, the
+    read widens as an object's read does, and the shard file that
+    lands is the reference's.  (After the drill, on the clean pool;
+    the source's stamp is put back.)"""
+    r = repaired
+    name = sorted(r.objects)[0]
+    pgid = r.osdmap.object_to_pg(r.io.pool_id, name)
+    _up, acting = r.osdmap.pg_to_up_acting_osds(pgid)
+    primary = r.cluster.osds[acting[0]]
+    pg = primary.pgs[pgid]
+    cur = tuple(pg.pglog.objects[name])
+    lost = r.spec["width"] - 1
+    live = [p for p in range(r.spec["width"]) if p != lost]
+    plan = ecutil.minimum_shards(pg._ec_codec(), live, [lost])
+    behind = max(plan)                      # a peer's, not position 0
+    assert behind != 0 and lost not in plan
+
+    def stamp(position, ver):
+        r.cluster.osds[acting[position]].store.apply_transaction(
+            Transaction().setattr(pg.cid, shard_oid(name, position),
+                                  VER_KEY, repr(ver).encode()))
+
+    holder = r.cluster.osds[acting[lost]]
+    soid = shard_oid(name, lost)
+    holder.store.apply_transaction(Transaction().remove(pg.cid, soid))
+    asked, real = [], primary.ec_fetch_shards
+
+    def spy(pgid_, oid_, targets, **kw):
+        asked.append(sorted(s for s, _o in targets))
+        return real(pgid_, oid_, targets, **kw)
+
+    primary.ec_fetch_shards = spy
+    widened = primary.asok.execute("perf dump")["osd"]["ec_read_widened"]
+    try:
+        stamp(behind, (cur[0], cur[1] - 1))
+        assert primary._ec_rebuild(pgid, name, cur, [(lost, acting[lost])],
+                                   retry=False)
+    finally:
+        del primary.ec_fetch_shards
+        stamp(behind, cur)
+    # the plan's peers first, then every other holder but the lost
+    # one (position 0 is the primary's own file, read and not asked)
+    assert asked == [[p for p in plan if p != 0],
+                     [p for p in live if p not in plan and p != 0]]
+    assert primary.asok.execute("perf dump")["osd"]["ec_read_widened"] \
+        == widened + 1
+    end = time.time() + 30
+    while not holder.store.exists(pg.cid, soid):
+        assert time.time() < end, "the push never landed"
+        time.sleep(0.05)
+    config = {"pool_profile": r.spec["profile"], "stripe_unit": UNIT,
+              "shards": r.spec["width"]}
+    want_data, want_crc = r.spec["reference"].stored(
+        r.objects[name], config)[lost]
+    assert bytes(holder.store.read(pg.cid, soid)) == want_data
+    hinfo = denc.loads(holder.store.getattr(pg.cid, soid, HINFO_KEY))
+    assert want_crc is None or hinfo["crc"] == want_crc
+    assert hinfo["shard"] == lost and hinfo["size"] == OBJECT_BYTES
+    assert r.io.read(name) == r.objects[name]

@@ -5,6 +5,8 @@ encode/decode roundtrips across stripes, HashInfo-style cumulative CRC
 equality, and the fused-device-pass counter the OSD path asserts.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -234,3 +236,110 @@ class TestStoredHinfo:
         for got, want in self._hinfo_by_bytes(cluster, io, "obj",
                                               len(body) + len(tail)):
             assert bytes(got) == want
+
+
+# ---------------------------------------------------------------------------
+# a rebuild decodes the lost positions and nothing else (PR 48)
+# ---------------------------------------------------------------------------
+
+REBUILD_UNIT = 4096
+REBUILD_CODES = {
+    "rs-k8m3": ({"technique": "reed_sol_van", "k": "8", "m": "3"}, 11),
+    "cauchy-k6m3": ({"technique": "cauchy_good", "k": "6", "m": "3",
+                     "packetsize": "32"}, 9),
+    "lrc-k4m2l3": ({"technique": "lrc", "k": "4", "m": "2", "l": "3"}, 8),
+    "shec-k8m4c3": ({"technique": "shec_multiple", "k": "8", "m": "4",
+                     "c": "3"}, 12),
+    "rs-k2m1": ({"technique": "reed_sol_van", "k": "2", "m": "1"}, 3),
+}
+ONE_LOST = [pytest.param(code, (pos,), id=f"{code}-p{pos}")
+            for code, (_profile, width) in REBUILD_CODES.items()
+            for pos in range(width)]
+# several at once (a log-driven rebuild after several losses): an
+# (r, k) decode, data and parity together
+SEVERAL_LOST = [pytest.param("rs-k8m3", (0, 9), id="rs-k8m3-p0.9"),
+                pytest.param("rs-k8m3", (3, 5, 10), id="rs-k8m3-p3.5.10"),
+                pytest.param("cauchy-k6m3", (1, 8), id="cauchy-k6m3-p1.8"),
+                pytest.param("lrc-k4m2l3", (0, 4), id="lrc-k4m2l3-p0.4"),
+                pytest.param("shec-k8m4c3", (2, 11), id="shec-k8m4c3-p2.11")]
+
+
+class TestRebuildShards:
+    """`ecutil.rebuild_shards` from the codec's plan for the lost
+    positions gives the shard files an encode of the object lays out,
+    byte for byte, and `crc32c_batch` of their rows folds to the
+    encode's CRCs: what `_ec_push_shards` lands for a rebuild."""
+
+    @pytest.fixture(scope="class")
+    def encoded(self):
+        @functools.lru_cache(maxsize=None)
+        def get(code: str):
+            profile, width = REBUILD_CODES[code]
+            codec = registry.factory("tpu", dict(profile))
+            assert codec.get_chunk_count() == width
+            si = ecutil.StripeInfo(codec.get_data_chunk_count(),
+                                   REBUILD_UNIT)
+            # a tail stripe that is padded
+            size = si.stripe_width * 5 - 1000
+            payload = np.random.default_rng(48).integers(
+                0, 256, size, dtype=np.uint8).tobytes()
+            shards, stripe_crcs = ecutil.encode_object_ex(
+                codec, si, payload)
+            return (codec, si, size, [bytes(s) for s in shards],
+                    np.asarray(stripe_crcs))
+        return get
+
+    @pytest.mark.parametrize("code, lost", ONE_LOST + SEVERAL_LOST)
+    def test_rebuilt_file_and_crc_equal_the_encodes(self, encoded, code,
+                                                    lost):
+        codec, si, size, shards, stripe_crcs = encoded(code)
+        lost = list(lost)
+        live = [p for p in range(len(shards)) if p not in lost]
+        plan = ecutil.minimum_shards(codec, live, lost)
+        assert not set(plan) & set(lost)
+        calls = []
+        real = codec.decode_batch_async
+
+        def spy(want, present, stack, qos=None):
+            calls.append((len(want), stack.shape[0]))
+            return real(want, present, stack, qos=qos)
+
+        codec.decode_batch_async = spy
+        try:
+            out = ecutil.rebuild_shards(
+                codec, si, {p: shards[p] for p in plan}, lost, size)
+        finally:
+            del codec.decode_batch_async
+        # one decode of len(lost) rows over the object's stripes
+        assert calls == [(len(lost), si.stripe_count(size))]
+        assert sorted(out) == sorted(lost)
+        full = size // si.stripe_width
+        for p in lost:
+            assert bytes(out[p]) == shards[p]
+            col = crc_mod.crc32c_batch(np.frombuffer(
+                out[p], dtype=np.uint8).reshape(-1, REBUILD_UNIT))[:, None]
+            assert ecutil.fold_shard_crcs(col, REBUILD_UNIT) == \
+                ecutil.fold_shard_crcs(stripe_crcs[:, [p]], REBUILD_UNIT) \
+                == [crc_mod.crc32c(0, shards[p])]
+            assert ecutil.fold_shard_crcs(col, REBUILD_UNIT, upto=full) == \
+                ecutil.fold_shard_crcs(stripe_crcs[:, [p]], REBUILD_UNIT,
+                                       upto=full)
+
+    @pytest.mark.parametrize("code", sorted(REBUILD_CODES))
+    def test_the_plan_reads_no_more_than_an_objects_read(self, encoded,
+                                                         code):
+        """Reed-Solomon and cauchy read k for any position (7 data +
+        the first parity for a lost data chunk, the k data chunks for
+        a lost parity); lrc and shec read fewer where a group or a
+        shingle covers the position."""
+        codec, _si, _size, shards, _crcs = encoded(code)
+        k = codec.get_data_chunk_count()
+        reads = []
+        for lost in range(len(shards)):
+            live = [p for p in range(len(shards)) if p != lost]
+            reads.append(len(ecutil.minimum_shards(codec, live, [lost])))
+        assert max(reads) <= k
+        if code in ("lrc-k4m2l3", "shec-k8m4c3"):
+            assert min(reads) < k
+        else:
+            assert reads == [k] * len(shards)

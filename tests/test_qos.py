@@ -488,10 +488,11 @@ class TestRecoveryQosClass:
 
 
 class TestRecoveryDecodeLane:
-    """The rebuild's DECODE half must sit under the repair cap too:
-    reconstructing a dead shard from survivors tags its pipeline
-    dispatch with the "@recovery" class, exactly like the re-encode —
-    otherwise repair reads escape osd_qos_recovery."""
+    """The rebuild's decode, since PR 48 its one pass through the
+    pipeline, must sit under the repair cap: reconstructing a dead
+    shard from survivors tags its pipeline dispatch with the
+    "@recovery" class — otherwise repair reads escape
+    osd_qos_recovery."""
 
     def test_rebuild_decode_rides_recovery_class(self):
         from ceph_tpu.ops import pipeline as ec_pipeline

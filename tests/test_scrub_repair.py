@@ -165,6 +165,104 @@ class TestECRepair:
         assert io.read("pribad") == payload
 
 
+class TestECRepairIsARebuild:
+    """Scrub repair reads for the positions it found bad and pushes
+    what the decode gave (PR 48): the same call a rebuild makes, no
+    object put together, nothing re-encoded; file and hinfo land bit
+    for bit as the write laid them out."""
+
+    @pytest.fixture(scope="class")
+    def io(self, cluster, rados):
+        rados.create_ec_pool("ec-fix2", "fix2_k2m1",
+                             {"plugin": "tpu", "k": 2, "m": 1})
+        return _settle(rados, cluster, "ec-fix2")
+
+    @pytest.mark.parametrize("pos", [0, 1, 2],
+                             ids=["data-own", "data-peer", "parity"])
+    def test_a_corrupt_shard_lands_bit_exact(self, cluster, io, pos):
+        from ceph_tpu.ops import hbm_cache
+        from ceph_tpu.osd import ecutil
+        from ceph_tpu.osd.pglog import HINFO_KEY
+        from ceph_tpu.utils import copyaudit
+        oid = f"rebuilt{pos}"
+        io.write_full(oid, bytes(range(251)) * 67)
+        pgid, pg = _primary_pg(cluster, io.pool_id, oid)
+        cid, soid = f"pg_{pgid}", f"{oid}.s{pos}"
+        holder = cluster.osds[_holders(cluster, pgid)[pos]]
+        good = bytes(holder.store.read(cid, soid))
+        hinfo = holder.store.getattr(cid, soid, HINFO_KEY)
+        holder.store.apply_transaction(
+            Transaction().write(cid, soid, 11, b"\xff\x00\xff\x00"))
+        assert bytes(holder.store.read(cid, soid)) != good
+        hbm_cache.get().clear()     # the gather's way, not the cache's
+        reads, real = [], pg._ec_read_local
+        rebuilds, real_rebuild = [], ecutil.rebuild_shards
+
+        def read_spy(oid_, **kw):
+            reads.append((oid_, sorted(kw["exclude"]), kw["want"]))
+            return real(oid_, **kw)
+
+        def rebuild_spy(codec, sinfo, shards, lost, size, qos=None):
+            rebuilds.append((sorted(shards), list(lost)))
+            return real_rebuild(codec, sinfo, shards, lost, size, qos=qos)
+
+        pg._ec_read_local = read_spy
+        ecutil.rebuild_shards = rebuild_spy
+        staged = copyaudit.snapshot()["sites"].get("ec.stage",
+                                                   {"copies": 0})
+        try:
+            result = pg.scrub(deep=True, repair=True)
+        finally:
+            del pg._ec_read_local
+            ecutil.rebuild_shards = real_rebuild
+        assert result["repaired"] >= 1
+        assert result["clean_after_repair"], result
+        # one read for the bad position, from the two others
+        assert reads == [(oid, [pos], [pos])]
+        assert rebuilds == [([p for p in range(3) if p != pos], [pos])]
+        assert copyaudit.snapshot()["sites"].get(
+            "ec.stage", {"copies": 0})["copies"] == staged["copies"]
+        assert bytes(holder.store.read(cid, soid)) == good
+        assert holder.store.getattr(cid, soid, HINFO_KEY) == hinfo
+        assert io.read(oid) == bytes(range(251)) * 67
+
+    def test_the_cache_is_asked_first(self, cluster, io):
+        """Where the HBM cache still holds the object the repair takes
+        the bad shard's rows from it and reads nothing."""
+        oid = "cachefirst"
+        io.write_full(oid, b"Z" * 20000)
+        pgid, pg = _primary_pg(cluster, io.pool_id, oid)
+        holder = cluster.osds[_holders(cluster, pgid)[1]]
+        holder.store.apply_transaction(
+            Transaction().write(f"pg_{pgid}", f"{oid}.s1", 3, b"\x01\x02"))
+        order = []
+        real_push = pg.osd._ec_push_shards
+        real_read = pg._ec_read_local
+
+        def push_spy(pg_, oid_, version, missing, rebuilt=None):
+            order.append(("push", rebuilt is not None))
+            return real_push(pg_, oid_, version, missing, rebuilt)
+
+        def read_spy(oid_, **kw):
+            order.append(("read", kw.get("want")))
+            return real_read(oid_, **kw)
+
+        pg.osd._ec_push_shards = push_spy
+        pg._ec_read_local = read_spy
+        try:
+            result = pg.scrub(deep=True, repair=True)
+        finally:
+            del pg.osd._ec_push_shards
+            del pg._ec_read_local
+        assert result["clean_after_repair"], result
+        assert order[0] == ("push", False)
+        # no cache entry on this pool's host-served writes: the read
+        # for the position follows, and its push
+        assert order in ([("push", False)],
+                         [("push", False), ("read", [1]), ("push", True)])
+        assert io.read(oid) == b"Z" * 20000
+
+
 class TestRepairCommand:
     def test_pg_repair_mon_command(self, cluster, rados):
         rados.create_pool("cmd-fix", pg_num=4)
