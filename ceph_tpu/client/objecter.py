@@ -123,9 +123,14 @@ class Objecter(Dispatcher):
                      .add_u64_counter("op_resend_map")
                      .add_u64_counter("op_resend_eagain")
                      .add_u64_counter("conn_kick")
+                     # targets the map's placement table answered,
+                     # and those CRUSH had to work out
+                     .add_u64_counter("placement_hit")
+                     .add_u64_counter("placement_miss")
                      .create_perf_counters())
         msgr.add_dispatcher_head(self)
         monc.on_osdmap = self._on_map
+        monc.count_placement(self.perf)
 
     @property
     def osdmap(self) -> OSDMap:

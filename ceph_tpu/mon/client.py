@@ -27,6 +27,7 @@ class MonClient(Dispatcher):
         self.log = DoutLogger("monc", msgr.name)
         self.osdmap = OSDMap()
         self.on_osdmap: Callable[[OSDMap], None] | None = None
+        self._placement_perf = None      # see count_placement
         # pool ids whose CREATION we observed arrive as an incremental
         # chained onto a map we already held — for these, and only
         # these, an empty pg copy is known to be the complete initial
@@ -84,6 +85,13 @@ class MonClient(Dispatcher):
                     target=self._renew_loop, daemon=True,
                     name=f"monc-renew-{self.msgr.name}")
                 self._sub_thread.start()
+
+    def count_placement(self, perf) -> None:
+        """The owner's counters take `placement_hit` / `placement_miss`
+        of the map held here, and of every full map that replaces
+        it."""
+        self._placement_perf = perf
+        self.osdmap.count_placement(perf)
 
     def sub_want_osdmap(self, start: int = 0) -> None:
         self.subscribe({"osdmap": start})
@@ -328,6 +336,7 @@ class MonClient(Dispatcher):
                 self.pool_births_witnessed.difference_update(
                     set(full.pools) - set(self.osdmap.pools))
                 self.osdmap = full
+                full.count_placement(self._placement_perf)
         for blob in msg.incrementals:
             inc = denc.loads(blob)
             if not isinstance(inc, OSDMapIncremental):

@@ -246,8 +246,13 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      .add_u64_counter("tier_full_waits")
                      .add_u64_counter("tier_evict_dirty")
                      .add_u64_counter("tier_full_admit")
+                     # PG mappings the map's placement table answered,
+                     # and those CRUSH had to work out
+                     .add_u64_counter("placement_hit")
+                     .add_u64_counter("placement_miss")
                      .add_time_avg("op_latency")
                      .create_perf_counters())
+        self.monc.count_placement(self.perf)
         self.perf_collection.add(self.perf)
         self.perf_collection.add(self.msgr.perf)
         self.op_tracker = OpTracker(

@@ -208,6 +208,13 @@ class OSDMap:
             fields.setdefault("mds_ranks", {})
         return fields
 
+    # The placement table (see _raw_osds).  Class defaults, not
+    # __init__'s: denc fills a decoded map's __dict__ without calling
+    # it.  Both are the instance's own, under names denc leaves out of
+    # the encoding; a copy starts without them (__getstate__).
+    _placement = None        # (inputs, {pgid: (ruleset, size, raw)})
+    _placement_perf = None   # the owner's counters, or nobody counts
+
     def __init__(self):
         self.epoch = 0
         self.fsid = ""
@@ -337,25 +344,70 @@ class OSDMap:
         raw = rjenkins_hash(objname.encode())
         return PgId(pool_id, pool.raw_pg_to_pg(raw))
 
-    def _weight_map(self) -> dict[int, int]:
-        wm = {}
-        for osd in self.crush.devices:
-            info = self.osds.get(osd)
-            wm[osd] = info.state_weight() if info else 0
-        return wm
+    def _placement_inputs(self) -> tuple:
+        """All that do_rule reads besides the pool and the PG, by
+        content: the CRUSH map's buckets, rules and tunables, and the
+        weight each of its devices has now (in / out, reweight).  The
+        placement table holds for as long as this compares equal,
+        whoever changed the map and however."""
+        c = self.crush
+        t = c.tunables
+        osds = self.osds
+        return (
+            [(o, osds[o].state_weight() if o in osds else 0)
+             for o in sorted(c.devices)],
+            [(i, b.id, b.alg, b.type, tuple(b.items), tuple(b.weights))
+             for i, b in list(c.buckets.items())],
+            [[(s.op, s.arg1, s.arg2) for s in r.steps] for r in c.rules],
+            (t.choose_total_tries, t.choose_local_tries,
+             t.choose_local_fallback_tries, t.chooseleaf_descend_once,
+             t.chooseleaf_vary_r, t.chooseleaf_stable),
+            c.max_devices)
+
+    def count_placement(self, perf) -> None:
+        """Count this map's look-ups on `perf`: `placement_hit` for an
+        answer the table gave, `placement_miss` for one CRUSH worked
+        out."""
+        self._placement_perf = perf
+
+    def _raw_osds(self, pgid: PgId) -> tuple:
+        """do_rule's answer for the PG, from the table while the
+        inputs it was worked out for stand."""
+        pool = self.pools[pgid.pool]
+        ruleset, size = pool.crush_ruleset, pool.size
+        inputs = self._placement_inputs()
+        table = self._placement
+        if table is None or table[0] != inputs:
+            table = self._placement = (inputs, {})
+        held = table[1].get(pgid)
+        perf = self._placement_perf
+        if held is not None and held[0] == ruleset and held[1] == size:
+            if perf is not None:
+                perf.inc("placement_hit")
+            return held[2]
+        if perf is not None:
+            perf.inc("placement_miss")
+        raw = tuple(do_rule(self.crush, ruleset,
+                            crush_hash32_2(pgid.seed, pgid.pool), size,
+                            dict(inputs[0])))
+        # kept only if the map stood still meanwhile (another thread
+        # may apply an incremental), and only for a PG the pool has
+        if pgid.seed < pool.pg_num and self._placement_inputs() == inputs:
+            if held is None and len(table[1]) >= sum(
+                    p.pg_num for p in self.pools.values()):
+                # it holds PGs of a pool that went or shrank: start over
+                table = self._placement = (inputs, {})
+            table[1][pgid] = (ruleset, size, raw)
+        return raw
 
     def pg_to_raw_osds(self, pgid: PgId) -> list[int]:
         """CRUSH mapping, ignoring up/down (OSDMap.cc:1530)."""
-        pool = self.pools[pgid.pool]
-        pps = crush_hash32_2(pgid.seed, pgid.pool)
-        out = do_rule(self.crush, pool.crush_ruleset, pps, pool.size,
-                      self._weight_map())
-        return out
+        return list(self._raw_osds(pgid))
 
     def pg_to_up_acting_osds(self, pgid: PgId) -> tuple[list[int], list[int]]:
         """(up, acting): up = crush result filtered to up osds; acting =
         pg_temp override if present, else up (OSDMap.cc:1702)."""
-        raw = self.pg_to_raw_osds(pgid)
+        raw = self._raw_osds(pgid)
         pool = self.pools[pgid.pool]
         if pool.is_erasure:
             # positions matter: keep holes as ITEM_NONE
@@ -381,6 +433,11 @@ class OSDMap:
 
     def encode(self) -> bytes:
         return denc.dumps(self)
+
+    def __getstate__(self) -> dict:
+        # copy / deepcopy / pickle: what the encoding holds
+        return {k: v for k, v in self.__dict__.items()
+                if not k.startswith("_")}
 
     @staticmethod
     def decode(data: bytes) -> "OSDMap":

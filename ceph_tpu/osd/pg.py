@@ -314,6 +314,10 @@ class PG(ReplicatedBackend, ECBackend, CacheTier, SnapOps, Peering,
                 self._drop_recovery_blocked()   # clients re-send
                 self._drop_tier_waiters()
                 self._pull_queued_at.clear()    # new round re-pulls
+                # a catch-up of the dead interval stops polling
+                # (`_poll_catchup`): what it still waited for is in the
+                # log's missing set, and this interval's round pulls it
+                self._catchup_pending = {}
                 self._heal_pushed_at.clear()
                 self.peer_last_backfill.clear()  # peering re-learns
                 self.active = False

@@ -266,6 +266,34 @@ def test_resent_op_latency_does_not_enter_the_estimate(cluster, client):
 # -- the reset ----------------------------------------------------------------
 
 
+def test_placement_lookups_are_in_both_perf_dumps(cluster, client):
+    """`placement_hit` / `placement_miss`: in the client's `perf dump`
+    beside `op_send`, in every OSD's under `osd`.  A client's first
+    send to a PG is worked out by CRUSH, every later one looked up;
+    an OSD works each PG out once at the epoch that brings the pool
+    and looks it up at every later one."""
+    rados, io = client
+    oid = "placed"
+    pgid = rados.objecter.osdmap.object_to_pg(io.pool_id, oid)
+    before = _counters(rados)
+    assert before["placement_miss"] >= 1          # `settle` was placed
+    m = rados.objecter.osdmap
+    held = m._placement is not None and pgid in m._placement[1]
+    for _ in range(5):
+        io.write_full(oid, b"p" * 64)
+    after = _counters(rados)
+    sends = after["op_send"] - before["op_send"]
+    missed = after["placement_miss"] - before["placement_miss"]
+    assert sends >= 5 and missed == (0 if held else 1)
+    assert after["placement_hit"] - before["placement_hit"] \
+        == sends - missed
+    pgs = len(rados.objecter.osdmap.all_pgs())
+    for osd in cluster.osds.values():
+        dump = osd.asok.execute("perf dump")["osd"]
+        assert dump["placement_miss"] >= pgs
+        assert dump["placement_hit"] >= 0
+
+
 def _reset_link(rados, peer: str) -> None:
     """Lose the client's socket to `peer` under it, as a peer's reset
     or a dead route would."""

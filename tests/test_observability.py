@@ -91,7 +91,10 @@ class TestCounterSchema:
            "tier_clean", "tier_try_flush_fail", "tier_flush_fail",
            "tier_promote_fail", "agent_wake", "agent_flush",
            "agent_evict", "tier_full_waits", "tier_evict_dirty",
-           "tier_full_admit"}
+           "tier_full_admit",
+           # PG mappings answered by the map's placement table, and
+           # those CRUSH worked out (tests/test_osdmap_placement_cache.py)
+           "placement_hit", "placement_miss"}
     MSGR = {"msg_send", "msg_recv", "bytes_send", "bytes_recv",
             "reconnects", "auth_failures", "auth_ticket_accepts",
             "auth_secret_accepts",
@@ -106,7 +109,7 @@ class TestCounterSchema:
     # change, an EAGAIN) and connections marked down as silent
     OBJECTER = {"op_send", "op_resend", "op_resend_timer",
                 "op_resend_reset", "op_resend_map", "op_resend_eagain",
-                "conn_kick"}
+                "conn_kick", "placement_hit", "placement_miss"}
     MON = {"elections_won", "elections_lost", "commands"}
     PAXOS = {"collect", "begin", "commit", "lease"}
     # multisite replication agent: rounds attempted, per-bucket/round

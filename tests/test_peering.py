@@ -701,3 +701,22 @@ class TestLogBoundsPeering:
         assert healed
         pushed = posd._perf_dump()["osd"]["recovery_bytes"] - before
         assert K * payload <= pushed <= 3 * K * payload
+
+
+class TestCatchUpMarker:
+    def test_an_interval_change_voids_a_catch_up_under_way(self, cluster):
+        """A primary that was polling for its catch-up pulls when the
+        map took it out of the acting set and gave it back (a reborn
+        OSD marked down once more by late failure reports) stops
+        polling: the round's marker goes with its interval, or
+        `wait_for_clean` waits for it for ever."""
+        io, pgid, acting = TestLogBoundsPeering._one_pg_pool(cluster,
+                                                             "flap")
+        pg = cluster.osds[acting[0]].get_pg(pgid)
+        with pg.lock:
+            pg._catchup_pending = {"settle": (99, 99)}
+        assert any("catch-up pending" in line
+                   for line in cluster.unclean_pgs())
+        cluster.mark_osd_down(acting[0])
+        cluster.wait_for_clean(60)
+        assert io.read("settle")
