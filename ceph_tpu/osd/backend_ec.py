@@ -49,7 +49,7 @@ class _EcRead:
 
     __slots__ = ("oid", "exclude", "need_ver", "qos", "interval", "want",
                  "have", "vers", "hinfo", "targets", "asked", "widened",
-                 "strict_have", "replans")
+                 "strict_have", "replans", "planned")
 
     def __init__(self, oid, exclude, need_ver, qos, interval, want=None):
         self.oid, self.need_ver = oid, need_ver
@@ -64,6 +64,7 @@ class _EcRead:
         self.widened = PLANNED
         self.strict_have: set[int] = set()    # the acting pass's shards
         self.replans = 0        # k or more in hand and no decode yet
+        self.planned = 0        # chunks the first plan named
 
 
 class ECBackend:
@@ -690,8 +691,9 @@ class ECBackend:
 
     #
     # A reconstructing read goes in steps: `_ec_read_begin` (HBM cache,
-    # the codec's plan over the live shards, this OSD's shard where
-    # the plan names it, whom to ask for the rest), one gather of
+    # the codec's plan over the live shards the primary does not know
+    # to be empty, this OSD's shard where the plan names it, whom to
+    # ask for the rest), one gather of
     # sub-reads, `_ec_read_step` (reassemble or decode — or, when the
     # plan's shards did not give the object, the widened step: every
     # other acting holder, a second gather and a second step; and
@@ -710,7 +712,7 @@ class ECBackend:
                        exclude: set | None = None,
                        need_ver: tuple | None = None,
                        qos: str | None = None,
-                       got: dict | None = None,
+                       told: dict | None = None,
                        want: list[int] | None = None):
         """Read an EC object, fetching shards from peers: its bytes,
         or with `want` (positions) the shard files AT those positions,
@@ -726,15 +728,19 @@ class ECBackend:
         not applied the target version yet must not contribute);
         `qos` names the dmClock class any decode dispatch bills
         against (rebuild reads ride @recovery under the repair cap);
-        into `got`, {position: bytes} of the shard files the last
-        step had in hand (none where the HBM cache served)."""
+        into `told`: `have`, {position: bytes} of the shard files the
+        last step had in hand (none where the HBM cache served),
+        `planned`, the chunks the first plan named, and `widened`, 1
+        where a step after the planned one was taken."""
         rd = self._ec_read_begin(oid, exclude, need_ver, qos, want)
         while isinstance(rd, _EcRead):
             step = rd
             rd = self._ec_read_step(rd, self._ec_read_fetch(rd))
-            if got is not None:
-                got.clear()
-                got.update({p: len(b) for p, b in step.have.items()})
+            if told is not None:
+                told.update(
+                    have={p: len(b) for p, b in step.have.items()},
+                    planned=step.planned,
+                    widened=int(step.widened != PLANNED))
         return rd
 
     def _ec_read_begin(self, oid: str, exclude: set | None = None,
@@ -780,11 +786,20 @@ class ECBackend:
         live = [p for p, o in enumerate(self.acting)
                 if o != ITEM_NONE and p not in rd.exclude
                 and self.osd.osdmap.is_up(o)]
+        # the plan is made over the shards that HOLD the object (the
+        # reference builds the available set from the shards not
+        # missing it, and takes a backfill target only up to its
+        # last_backfill): the cheapest set that can work, asked once.
+        # What the primary cannot know, a holder that is behind, is
+        # still what widening is for
+        empty = self._ec_known_empty(oid)
+        live = [p for p in live if p not in empty]
         try:
             plan = set(ecutil.minimum_shards(self._ec_codec(), live,
                                              rd.want))
         except ErasureCodeError:
             return self._ec_read_widen(rd)  # fewer live than it needs
+        rd.planned = len(plan)
         self._ec_read_own(rd, plan)
         if not rd.asked <= rd.have.keys():
             # own planned shard unreadable, or behind `need_ver`
@@ -794,6 +809,15 @@ class ECBackend:
         rd.targets = [(p, self.acting[p])
                       for p in sorted(plan - rd.have.keys())]
         return rd
+
+    def _ec_known_empty(self, oid: str) -> set[int]:
+        """The acting positions this primary KNOWS do not hold `oid`
+        yet: a backfill target the object lies beyond the frontier of
+        (`should_send_op`), and a position at which a rebuild of it is
+        still owed (a role audit's, a backfill round's)."""
+        return self.osd.rebuilds_owed(self.pgid, oid) | {
+            p for p, o in enumerate(self.acting)
+            if not self.should_send_op(o, oid)}
 
     def _ec_read_own(self, rd: "_EcRead", only: set | None = None) -> None:
         """Into `rd.have`: the shard files this OSD holds at the
@@ -961,6 +985,7 @@ class ECBackend:
         sw = _EcRead(rd.oid, rd.exclude, cur, rd.qos, rd.interval,
                      rd.want)
         sw.widened, sw.strict_have = SWEEP, set(rd.have)
+        sw.planned = rd.planned
         km = self._ec_codec().get_chunk_count()
         store = self.osd.store
         for shard in range(km):        # any shard WE hold post-remap

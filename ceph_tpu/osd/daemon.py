@@ -97,8 +97,12 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         self.backfill_lock = threading.Lock()
         self._backfills_active: set = set()
         self._rmtemp_active: set = set()
-        # pgid -> EC rebuilds queued, running or waiting for a retry
-        # (queue_ec_rebuild): a PG with any is recovering, not clean
+        # pgid -> {oid: [positions]}: the shard rebuilds this primary
+        # still owes the PG, one entry a rebuild queued, running or
+        # waiting for a retry (queue_ec_rebuild) and one an object a
+        # backfill round found to push (`_rebuild_owed`).  A PG with
+        # any is recovering, not clean, and a rebuild's plan reads no
+        # position its object is still owed at (`rebuilds_owed`)
         self._rebuilds_pending: dict = {}
         # numbers the backfill rounds' ops (their trace ids)
         self._backfill_round_seq = itertools.count(1)
@@ -200,6 +204,12 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
                      .add_u64_counter("rebuild_cache_served")
                      .add_u64_counter("rebuild_local")
                      .add_u64_counter("rebuild_full")
+                     # of the rebuilds that read: those whose first
+                     # plan's gather did not give the shard, so that
+                     # every other holder was asked too; and the
+                     # chunks their first plans named, summed
+                     .add_u64_counter("rebuild_widened")
+                     .add_u64_counter("rebuild_planned_chunks")
                      # the PG log as keys (pglog.persist_log): keys
                      # and bytes handed to transactions, and how often
                      # a log had to be written whole (a converted

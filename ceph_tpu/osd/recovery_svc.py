@@ -374,6 +374,27 @@ class RecoveryService:
                 pg.peer_last_backfill.pop(target, None)
         seg = mine["objects"]
         end = mine["end"]           # "" == ran off the end of our space
+        shard = None
+        if pg.is_ec:
+            shard = pg.role_of(target)
+            if shard < 0:
+                # a CRUSH target being pre-seeded before a pg_temp
+                # release: its shard id is its POSITION in the raw
+                # CRUSH up set, not in the (temp) acting set
+                up, _a = self.osdmap.pg_to_up_acting_osds(pgid)
+                shard = up.index(target) if target in up else -1
+            if shard < 0:
+                self.log.warn("backfill of osd.%d: no shard position "
+                              "in %s; abandoning", target, pgid)
+                release()
+                return
+            # the frontier above already counts the whole batch as
+            # the target's: until the compare below has passed an
+            # object by, or its push is on the way, it is OWED at the
+            # target's position, and another position's rebuild of it
+            # does not plan to read it there
+            batch = [(oid, shard) for oid in seg]
+            self._rebuild_owed(pgid, batch, +1)
         # one tracked op a round: the listing above, the peer's view
         # of the range and the compare.  The objects it finds to push
         # are ops of their own (`_rebuild_op`).
@@ -399,6 +420,8 @@ class RecoveryService:
             self.log.warn("backfill of osd.%d stalled at %r; retrying",
                           target, cursor)
             trk.finish()
+            if pg.is_ec:
+                self._rebuild_owed(pgid, batch, -1)
             release()
             self.clock.timer(
                 2.0, lambda: self.queue_backfill(pgid, target,
@@ -411,20 +434,10 @@ class RecoveryService:
         trk.span_end("backfill.scan", objects=len(seg), pushed=len(todo),
                      skipped=len(seg) - len(todo))
         trk.finish()
-        shard = None
         if pg.is_ec:
-            shard = pg.role_of(target)
-            if shard < 0:
-                # a CRUSH target being pre-seeded before a pg_temp
-                # release: its shard id is its POSITION in the raw
-                # CRUSH up set, not in the (temp) acting set
-                up, _a = self.osdmap.pg_to_up_acting_osds(pgid)
-                shard = up.index(target) if target in up else -1
-            if shard < 0:
-                self.log.warn("backfill of osd.%d: no shard position "
-                              "in %s; abandoning", target, pgid)
-                release()
-                return
+            # what the compare passed by, the target holds
+            self._rebuild_owed(pgid, [
+                (oid, shard) for oid in seg.keys() - dict(todo).keys()], -1)
         for oid, ev in todo:
             state["pushed"] += 1
             self.perf.inc("backfill_objects")
@@ -439,6 +452,7 @@ class RecoveryService:
                     # sources busy (concurrent write): the re-scan
                     # below picks this object up again
                     state["failed"] = True
+                self._rebuild_owed(pgid, [(oid, shard)], -1)
             else:
                 self._push_object_inline(pg, target, oid, ev)
         for oid, tv in theirs.items():
@@ -1275,13 +1289,29 @@ class RecoveryService:
             self.log.info("ec role audit %s: %d shard rebuilds queued",
                           pgid, queued)
 
-    def _rebuild_pending(self, pgid: PgId, by: int) -> None:
+    def _rebuild_owed(self, pgid: PgId, pairs, by: int) -> None:
+        """Enter (`by` +1) or strike (-1) one owed shard file for each
+        (object, position) of `pairs` in the PG's record: what holds
+        "clean" back (`pg_repairing`) and what a rebuild's plan leaves
+        out (`rebuilds_owed`)."""
         with self.backfill_lock:
-            n = self._rebuilds_pending.get(pgid, 0) + by
-            if n > 0:
-                self._rebuilds_pending[pgid] = n
-            else:
-                self._rebuilds_pending.pop(pgid, None)
+            owed = self._rebuilds_pending.setdefault(pgid, {})
+            for oid, position in pairs:
+                at = owed.setdefault(oid, [])
+                if by > 0:
+                    at.append(position)
+                else:
+                    at.remove(position)
+                if not at:
+                    del owed[oid]
+            if not owed:
+                del self._rebuilds_pending[pgid]
+
+    def rebuilds_owed(self, pgid: PgId, oid: str) -> set[int]:
+        """The positions at which this primary still owes `oid` a
+        shard file: they do not hold it yet."""
+        with self.backfill_lock:
+            return set(self._rebuilds_pending.get(pgid, {}).get(oid, ()))
 
     def pg_repairing(self, pgid: PgId) -> str:
         """What repair this primary still owes the PG: "backfilling"
@@ -1295,7 +1325,8 @@ class RecoveryService:
     def queue_ec_rebuild(self, pgid: PgId, oid: str, version: int,
                          missing: list[tuple[int, int]],
                          attempt: int = 0, front: bool = False) -> None:
-        self._rebuild_pending(pgid, +1)
+        owed = [(oid, s) for s, _o in missing]
+        self._rebuild_owed(pgid, owed, +1)
 
         def work(release: Callable) -> None:
             def run() -> None:
@@ -1308,7 +1339,7 @@ class RecoveryService:
                         pgid, oid, version, missing, attempt)
                 finally:
                     release()
-                    self._rebuild_pending(pgid, -1)
+                    self._rebuild_owed(pgid, owed, -1)
             self.op_wq.queue(pgid, run)
 
         self._recovery.request(work, front=front)
@@ -1322,15 +1353,29 @@ class RecoveryService:
         this thread's part, with `rebuild.read` (the gather of what
         the codec's plan reads for the lost positions, and the ONE
         decode that gives their shard files: its `ec.*` phases land
-        here too) and `rebuild.encode` inside it, which re-encodes
-        nothing: it covers the CRC columns of the rebuilt files from
+        here too; its args below) and `rebuild.encode` inside it, which
+        re-encodes nothing: it covers the CRC columns of the rebuilt files from
         their own bytes, their fold and the `hinfo` (`bytes` = the
         bytes rebuilt, `positions`); a cache-served rebuild has none.
         A `rebuild.push` runs from the send to the target's ack and
         keeps the op open until then (`_ec_push_shards`).  The
         sub-reads and the push carry the trace id, so the shard OSDs'
         `sub_read` ops and the target's `push` op (its `store_apply` /
-        `wal` spans) correlate under it."""
+        `wal` spans) correlate under it.
+
+        `rebuild.read` says what was read, each arg with ONE meaning:
+        `planned`, the chunks the first plan named (0 where nothing
+        could be planned and every holder was asked at once);
+        `widened`, 1 where that plan's gather did not give the shard
+        files and a second gather of every other holder was made (a
+        source the primary took for a holder was behind); `chunks`
+        and `bytes_read`, the shard files the last gather had in hand
+        and their bytes; `path`, the size of that hand alone: `local`
+        with fewer than k chunks in it, `full` with k or more (a
+        shingle's fallback plan of k, any Reed-Solomon rebuild, and a
+        widened read that took in k), `cache` where the HBM cache
+        served and nothing was read.  Whether the plan served is
+        `widened`, never `path`."""
         trk = self.op_tracker.create(
             f"rebuild({pgid} {oid} v={version})", trace_id=trace_id,
             kind="recovery")
@@ -1374,37 +1419,44 @@ class RecoveryService:
         from .daemon import RECOVERY_QOS_CLASS
         qos = (RECOVERY_QOS_CLASS if self._qos_recovery is not None
                else None)
-        got: dict = {}
+        told: dict = {"have": {}, "planned": 0, "widened": 0}
         with optracker.span("rebuild.read") as read:
             # the lost positions' shard files from the shards the
             # codec's plan reads for THEM (k for Reed-Solomon, a local
-            # group's l for lrc, a shingle for shec): one gather, one
-            # decode, no object in between
+            # group's l for lrc, a shingle for shec) among the
+            # positions that hold the object: one gather, one decode,
+            # no object in between
             rebuilt = pg._ec_read_local(
-                oid, need_ver=need, qos=qos, got=got,
+                oid, need_ver=need, qos=qos, told=told,
                 want=[s for s, _o in missing])
+            got, planned = told["have"], told["planned"]
+            widened = told["widened"]
             local = rebuilt is not None and \
                 len(got) < pg._ec_codec().get_data_chunk_count()
             read.update(path="local" if local else "full",
-                        chunks=len(got), bytes_read=sum(got.values()))
+                        chunks=len(got), bytes_read=sum(got.values()),
+                        planned=planned, widened=widened)
         if rebuilt is None:
             # sources not all at `need` yet (write still fanning out):
             # retry with backoff rather than stranding the stale shard
             if retry and attempt < 6:
-                # pending across the wait, so the PG never looks
+                # owed across the wait, so the PG never looks
                 # recovered between two attempts
-                self._rebuild_pending(pgid, +1)
+                owed = [(oid, s) for s, _o in missing]
+                self._rebuild_owed(pgid, owed, +1)
 
                 def again() -> None:
                     self.queue_ec_rebuild(pgid, oid, need, missing,
                                           attempt + 1)
-                    self._rebuild_pending(pgid, -1)
+                    self._rebuild_owed(pgid, owed, -1)
                 self.clock.timer(0.3 * (attempt + 1), again)
             elif retry:
                 self.log.warn("cannot rebuild %s/%s: undecodable",
                               pgid, oid)
             return False
         self.perf.inc("rebuild_local" if local else "rebuild_full")
+        self.perf.inc("rebuild_widened", widened)
+        self.perf.inc("rebuild_planned_chunks", planned)
         self._ec_push_shards(pg, oid, need, missing, rebuilt)
         return True
 

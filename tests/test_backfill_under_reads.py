@@ -17,6 +17,16 @@ acked);
 a backfill round is one with `backfill.scan` (`objects`, `pushed`,
 `skipped`) and the target's `scan_range` wait inside; the counters add
 up; lrc rebuilds a lost shard from the three others of its group.
+
+PR 50 adds a shec k=8 m=4 c=3 pool on 13 OSDs (the cell
+`shec-k8m4c3-4m-backfill-rand-read` at a small size): a lost shard is
+rebuilt from its shingle's 4 chunks (8 for the two parities that span
+all the data); a rebuild's plan is made over the positions that HOLD
+the object, so it names no position whose rebuild is still owed and
+makes one gather (`rebuild.read` `planned`, `widened`; counters
+`rebuild_planned_chunks`, `rebuild_widened`); and the primary's record
+of owed (object, position) pairs is empty when the cluster is clean,
+after an interval change in the middle of a repair too.
 """
 
 import threading
@@ -27,6 +37,7 @@ import pytest
 
 from benchmark.references import lrc as lrc_reference
 from benchmark.references import reed_sol_van as rs_reference
+from benchmark.references import shec as shec_reference
 from ceph_tpu.client import RadosError
 from ceph_tpu.osd import ecutil
 from ceph_tpu.osd.pg import shard_oid
@@ -48,9 +59,14 @@ POOLS = {
         "osds": 10, "width": 8, "reference": lrc_reference,
         "profile": {"plugin": "tpu", "technique": "lrc", "k": "4",
                     "m": "2", "l": "3", "host_cutover": "1"}},
+    "shec-k8m4c3": {
+        "osds": 13, "width": 12, "reference": shec_reference,
+        "profile": {"plugin": "tpu", "technique": "shec_multiple",
+                    "k": "8", "m": "4", "c": "3", "host_cutover": "1"}},
 }
 COUNTERS = ("backfill_rounds", "backfill_objects", "rebuild_cache_served",
-            "rebuild_local", "rebuild_full", "recovery_pushes")
+            "rebuild_local", "rebuild_full", "rebuild_widened",
+            "rebuild_planned_chunks", "recovery_pushes")
 
 
 class Repaired:
@@ -64,6 +80,35 @@ def counters(cluster) -> dict:
         for name in COUNTERS:
             out[name] += block[name]
     return out
+
+
+def owed_pairs(cluster) -> dict:
+    """The primaries' records of owed (object, position) pairs, by
+    OSD, the empty ones left out."""
+    return {osd.whoami: {str(pgid): dict(owed) for pgid, owed in
+                         dict(osd._rebuilds_pending).items()}
+            for osd in cluster.osds.values() if osd._rebuilds_pending}
+
+
+class OwedWatch(threading.Thread):
+    """Samples, while a repair runs, whether the cluster says clean
+    and what the primaries still owe: clean first, so that a pair seen
+    beside "clean" was owed AFTER the cluster called itself whole."""
+
+    def __init__(self, cluster):
+        super().__init__(daemon=True, name="owed-watch")
+        self.cluster, self.samples = cluster, []
+        self.stop = threading.Event()
+
+    def run(self) -> None:
+        while not self.stop.wait(0.05):
+            clean = self.cluster.unclean_pgs() == []
+            self.samples.append((clean, owed_pairs(self.cluster)))
+
+    def end(self) -> list:
+        self.stop.set()
+        self.join(10)
+        return self.samples
 
 
 @pytest.fixture(scope="module", params=sorted(POOLS))
@@ -140,10 +185,14 @@ def repaired(request):
             victim not in cluster.leader().osdmon.osdmap
             .pg_to_up_acting_osds(p)[1] for p in pgids), 60,
             "the victim is still acting")
+        watch = OwedWatch(cluster)
+        watch.start()
         cluster.wait_for_clean(timeout=240)
         out.repairing_at_clean = [
             osd.pg_repairing(p) for osd in cluster.osds.values()
             for p in pgids]
+        out.owed_at_clean = owed_pairs(cluster)
+        out.owed_samples = watch.end()
         stop.set()
         for t in readers:
             t.join(30)
@@ -395,17 +444,104 @@ def test_the_counters_add_up(repaired):
     assert delta["recovery_pushes"] >= sum(seen.values())
 
 
+def shec_reference_chunks(lost: int, available) -> int:
+    """The chunks the plain reference's plan reads for one lost
+    position of the shec pool: the parities it takes and the data
+    chunks they touch that are in hand."""
+    matrix = shec_reference.coding_matrix(8, 4, 3)
+    parities, unknowns = shec_reference.plan([lost], available, matrix)
+    touched = set() if lost >= 8 else {lost}
+    for p in parities + ([lost - 8] if lost >= 8 else []):
+        touched |= set(np.flatnonzero(matrix[p]).tolist())
+    return len(parities) + len(touched - set(unknowns))
+
+
 def test_a_code_with_locality_repairs_from_its_group(repaired):
-    reads = [spans(d, "rebuild.read")[0]["args"]
-             for d in pushed_docs(repaired)]
-    if repaired.spec["profile"]["technique"] != "lrc":
+    r = repaired
+    reads = [spans(d, "rebuild.read")[0]["args"] for d in pushed_docs(r)]
+    technique = r.spec["profile"]["technique"]
+    if technique == "reed_sol_van":
         assert {a["path"] for a in reads} <= {"full", "cache"}
         assert all(a["chunks"] == 8 for a in reads if a["path"] == "full")
         return
     local = [a for a in reads if a["path"] == "local"]
-    assert local and repaired.delta["rebuild_local"] == len(local)
-    assert all(a["chunks"] == 3 and
-               a["bytes_read"] == 3 * OBJECT_BYTES // 4 for a in local)
+    assert local and r.delta["rebuild_local"] == len(local)
+    if technique == "lrc":
+        assert all(a["chunks"] == 3 and
+                   a["bytes_read"] == 3 * OBJECT_BYTES // 4 for a in local)
+        return
+    # shec: where the PG changed ONE position, every rebuild read what
+    # the reference's plan reads for that position among the eleven
+    # others: a shingle's 4 for positions 0-9, all 8 data chunks for
+    # the two parities that span them
+    assert [shec_reference_chunks(p, set(range(12)) - {p})
+            for p in range(12)] == [4] * 10 + [8, 8]
+    changed = {str(pgid): [i for i, (o, was) in enumerate(zip(
+        r.osdmap.pg_to_up_acting_osds(pgid)[1], r.before[pgid]))
+        if o != was] for pgid in r.pgids}
+    held = 0
+    for d in pushed_docs(r):
+        pgid = d["trace_id"].split(":")[1]
+        if len(changed[pgid]) != 1:
+            continue
+        (lost,) = {p["args"]["shard"] for p in spans(d, "rebuild.push")}
+        assert [lost] == changed[pgid]
+        read = spans(d, "rebuild.read")[0]["args"]
+        want = shec_reference_chunks(lost, set(range(12)) - {lost})
+        assert (read["chunks"], read["planned"], read["widened"]) == \
+            (want, want, 0), d
+        assert read["path"] == ("local" if want < 8 else "full")
+        assert read["bytes_read"] == want * OBJECT_BYTES // 8
+        held += 1
+    assert held
+
+
+def test_the_plan_and_widening_counters_add_up(repaired):
+    """`rebuild_widened` and the rebuilds that did not widen are the
+    rebuilds that read and pushed; `rebuild_planned_chunks` is the sum
+    of what their first plans named.  A plan names at most what the
+    code reads for an object, and a rebuild that did not widen had in
+    hand what its plan named."""
+    r = repaired
+    reads = [spans(d, "rebuild.read")[0]["args"] for d in pushed_docs(r)]
+    reads = [a for a in reads if a["path"] != "cache"]
+    assert reads
+    widened = sum(a["widened"] for a in reads)
+    assert {a["widened"] for a in reads} <= {0, 1}
+    assert r.delta["rebuild_widened"] == widened
+    assert widened + sum(1 for a in reads if not a["widened"]) == \
+        r.delta["rebuild_local"] + r.delta["rebuild_full"]
+    assert r.delta["rebuild_planned_chunks"] == \
+        sum(a["planned"] for a in reads)
+    k = int(r.spec["profile"]["k"])
+    for a in reads:
+        assert 1 <= a["planned"] <= k
+        if not a["widened"]:
+            assert a["chunks"] == a["planned"]
+    # the plan is made over the positions that hold the object: what
+    # still widens is the first rebuild of a session, planned before
+    # the role audit said which other positions are owed
+    assert widened <= len(r.pgids)
+
+
+def test_nothing_is_owed_once_the_cluster_is_clean(repaired):
+    """The primaries' record of owed (object, position) pairs and
+    `pg_repairing` say the same: while a pair is owed the cluster is
+    not clean, after the backfill sessions and the role audits it is
+    empty, and it was in use on the way."""
+    r = repaired
+    assert r.owed_at_clean == {}
+    assert not any(r.repairing_at_clean)
+    assert owed_pairs(r.cluster) == {}
+    assert any(owed for _clean, owed in r.owed_samples)
+    assert not [owed for clean, owed in r.owed_samples if clean and owed]
+    # a pair is an object of the pool at a position of the code
+    for _clean, owed in r.owed_samples:
+        for by_pg in owed.values():
+            for pairs in by_pg.values():
+                assert set(pairs) <= set(r.objects)
+                assert all(0 <= p < r.spec["width"]
+                           for at in pairs.values() for p in at)
 
 
 def test_a_rebuild_widens_past_a_planned_source_that_is_behind(repaired):
@@ -469,3 +605,171 @@ def test_a_rebuild_widens_past_a_planned_source_that_is_behind(repaired):
     assert want_crc is None or hinfo["crc"] == want_crc
     assert hinfo["shard"] == lost and hinfo["size"] == OBJECT_BYTES
     assert r.io.read(name) == r.objects[name]
+
+
+def test_a_rebuild_plans_around_a_position_still_owed(repaired):
+    """A second position of the lost one's plan (for shec: of its
+    shingle) is still owed its shard and holds nothing: the primary
+    knows, the plan is made over the positions that hold the object
+    and names neither, ONE gather serves (`widened` 0, the counter of
+    widened reads unmoved), and what lands is the reference's shard.
+    Then the owed position is rebuilt too.  (After the drill, on the
+    clean pool.)"""
+    r = repaired
+    name = sorted(r.objects)[1]
+    pgid = r.osdmap.object_to_pg(r.io.pool_id, name)
+    _up, acting = r.osdmap.pg_to_up_acting_osds(pgid)
+    primary = r.cluster.osds[acting[0]]
+    pg = primary.pgs[pgid]
+    cur = tuple(pg.pglog.objects[name])
+    width = r.spec["width"]
+    lost = 1
+    first = ecutil.minimum_shards(
+        pg._ec_codec(), [p for p in range(width) if p != lost], [lost])
+    owed = max(first)                       # a peer's, not position 0
+    assert owed not in (0, lost)
+    config = {"pool_profile": r.spec["profile"], "stripe_unit": UNIT,
+              "shards": width}
+    want = r.spec["reference"].stored(r.objects[name], config)
+    for position in (lost, owed):
+        r.cluster.osds[acting[position]].store.apply_transaction(
+            Transaction().remove(pg.cid, shard_oid(name, position)))
+    asked, real = [], primary.ec_fetch_shards
+
+    def spy(pgid_, oid_, targets, **kw):
+        asked.append(sorted(s for s, _o in targets))
+        return real(pgid_, oid_, targets, **kw)
+
+    def perf(counter: str) -> int:
+        return primary.asok.execute("perf dump")["osd"][counter]
+
+    def landed(position: int) -> None:
+        holder = r.cluster.osds[acting[position]]
+        soid = shard_oid(name, position)
+        end = time.time() + 30
+        while not holder.store.exists(pg.cid, soid):
+            assert time.time() < end, "the push never landed"
+            time.sleep(0.05)
+        want_data, want_crc = want[position]
+        assert bytes(holder.store.read(pg.cid, soid)) == want_data
+        hinfo = denc.loads(holder.store.getattr(pg.cid, soid, HINFO_KEY))
+        assert want_crc is None or hinfo["crc"] == want_crc
+
+    widened = perf("ec_read_widened"), perf("rebuild_widened")
+    primary.ec_fetch_shards = spy
+    primary._rebuild_owed(pgid, [(name, owed)], +1)
+    try:
+        assert primary.rebuilds_owed(pgid, name) == {owed}
+        assert primary.pg_repairing(pgid) == "recovering"
+        trace = f"rebuild:{pgid}:{name}:s{lost}:around"
+        assert primary._rebuild_op(trace, pgid, name, cur,
+                                   [(lost, acting[lost])], retry=False)
+    finally:
+        del primary.ec_fetch_shards
+        primary._rebuild_owed(pgid, [(name, owed)], -1)
+    assert primary.rebuilds_owed(pgid, name) == set()
+    assert len(asked) == 1 and not {lost, owed} & set(asked[0])
+    assert (perf("ec_read_widened"), perf("rebuild_widened")) == widened
+    landed(lost)
+
+    def docs() -> list:
+        # the op closes when its push is acknowledged
+        return [d for d in primary.asok.execute("dump_historic_ops")["ops"]
+                if d["trace_id"] == trace and d["kind"] == "recovery"
+                and d["description"].startswith("rebuild(")]
+
+    r.cluster._wait(docs, 30, "the rebuild's op never closed")
+    (doc,) = docs()
+    read = spans(doc, "rebuild.read")[0]["args"]
+    assert read["widened"] == 0 and read["chunks"] == read["planned"]
+    # position 0 is the primary's own file: read, not asked
+    assert read["planned"] == len(asked[0]) + 1
+    assert not {lost, owed} & set(spans(doc, "gather_wait")[-1]
+                                  ["args"]["chunks"])
+    assert primary._rebuild_op(f"rebuild:{pgid}:{name}:s{owed}:after",
+                               pgid, name, cur, [(owed, acting[owed])],
+                               retry=False)
+    landed(owed)
+    assert r.io.read(name) == r.objects[name]
+
+
+def test_no_owed_pair_outlives_an_interval_change():
+    """A second OSD is marked out in the MIDDLE of the repair of the
+    first (a backfill session running, role-audit rebuilds owed): the
+    PGs change interval under both.  While any pair is owed the
+    cluster does not call itself clean, and once it does no primary
+    owes anything: no pair of the dead interval is left behind."""
+    cluster = MiniCluster(
+        num_mons=1, num_osds=8, store_kind="memstore",
+        conf=Config({"osd_heartbeat_interval": 0.5,
+                     "osd_heartbeat_grace": 5.0,
+                     "mon_osd_min_down_reporters": 2,
+                     "osd_pg_log_max_entries": 4,
+                     "osd_backfill_scan_batch": 4})).start()
+    try:
+        rados = cluster.client()
+        rados.create_ec_pool("owed", "owed-profile", {
+            "plugin": "tpu", "technique": "reed_sol_van", "k": "4",
+            "m": "2", "host_cutover": "1", "stripe_unit": str(UNIT)},
+            pg_num=4)
+        io = rados.open_ioctx("owed")
+        end = time.time() + 60
+        while True:
+            try:
+                io.write_full("settle", b"s")
+                break
+            except RadosError:
+                assert time.time() < end
+                time.sleep(0.3)
+        io.remove_object("settle")
+        rng = np.random.default_rng(50)
+        objects = {f"obj{i:03d}": rng.integers(
+            0, 256, 16 * 1024, dtype=np.uint8).tobytes() for i in range(48)}
+        for name, data in objects.items():
+            io.write_full(name, data)
+        cluster.wait_for_clean(timeout=60)
+        assert owed_pairs(cluster) == {}
+        osdmap = cluster.leader().osdmon.osdmap
+        pgids = [p for p in osdmap.all_pgs() if p.pool == io.pool_id]
+        primaries = {osdmap.pg_to_up_acting_osds(p)[1][0] for p in pgids}
+        first, second = sorted(set(range(8)) - primaries)[:2]
+
+        def fail(victim: int) -> None:
+            cluster.kill_osd(victim)
+            cluster.mark_osd_down(victim)
+            cluster.mark_osd_out(victim)
+
+        fail(first)
+        watch = OwedWatch(cluster)
+        watch.start()
+        # in the middle of both: a session is running and the audit's
+        # rebuilds are owed
+        cluster._wait(lambda: any(
+            osd._backfills_active and osd._rebuilds_pending
+            for osd in cluster.osds.values()), 60,
+            "no repair under way to interrupt")
+        before = {p: tuple(cluster.leader().osdmon.osdmap
+                           .pg_to_up_acting_osds(p)[1]) for p in pgids}
+        fail(second)
+        cluster._wait(lambda: all(
+            second not in cluster.leader().osdmon.osdmap
+            .pg_to_up_acting_osds(p)[1] for p in pgids), 60,
+            "the second victim is still acting")
+        after = {p: tuple(cluster.leader().osdmon.osdmap
+                          .pg_to_up_acting_osds(p)[1]) for p in pgids}
+        assert any(before[p] != after[p] for p in pgids)
+        cluster.wait_for_clean(timeout=240)
+        at_clean = owed_pairs(cluster)
+        samples = watch.end()
+        assert at_clean == {}
+        assert not any(osd.pg_repairing(p)
+                       for osd in cluster.osds.values() for p in pgids)
+        assert any(owed for _clean, owed in samples)
+        assert not [owed for clean, owed in samples if clean and owed]
+        # and it stays so: what the dead interval queued has drained
+        time.sleep(1.0)
+        assert owed_pairs(cluster) == {}
+        for name, data in objects.items():
+            assert io.read(name) == data
+    finally:
+        cluster.stop()

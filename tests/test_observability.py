@@ -72,6 +72,9 @@ class TestCounterSchema:
            # (tests/test_backfill_under_reads.py holds their sums)
            "backfill_rounds", "backfill_objects", "rebuild_cache_served",
            "rebuild_local", "rebuild_full",
+           # rebuilds whose first plan's gather did not serve, and the
+           # chunks the first plans named (the same file holds them)
+           "rebuild_widened", "rebuild_planned_chunks",
            # the PG log as keys: keys and bytes handed to
            # transactions, logs that had to be written whole
            "pglog_keys_written", "pglog_bytes_written",
