@@ -14,6 +14,7 @@ import threading
 from ..mon.client import MonClient
 from ..mon.monmap import MonMap
 from ..msg import create_messenger
+from ..utils import denc
 from ..utils.bufferlist import wrap_payload
 from ..utils.config import Config
 from .objecter import Objecter, ObjecterError
@@ -210,8 +211,10 @@ class Rados:
     def perf_dump(self) -> dict:
         """The client's `perf dump`: the objecter's block (sends and
         resends by cause, kicked connections, each target's resend
-        timeout) and the messenger's counters."""
-        return {**self.objecter.perf_dump(), "msgr": self.msgr.perf.dump()}
+        timeout), the messenger's counters and which codec walk serves
+        this process (`denc.counters`)."""
+        return {**self.objecter.perf_dump(), "msgr": self.msgr.perf.dump(),
+                "denc": denc.counters()}
 
     # -- cluster admin -----------------------------------------------------
 

@@ -211,6 +211,7 @@ class Monitor(Dispatcher):
             "fsync_reorder_windows":
                 self.store.counters["fsync_reorder_windows"],
         }
+        out["denc"] = denc.counters()       # process-wide: which walk
         return out
 
     # entity helpers -------------------------------------------------------

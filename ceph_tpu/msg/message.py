@@ -23,9 +23,21 @@ The sender never copies a segment — ``encode_iov`` returns the header,
 table, payload and the segment views for a gather write — and the
 receiver scatter-reads each segment straight off the socket, so a
 payload crosses the messenger without ever being denc-copied into the
-field dict and re-joined per send.  Frames with no large fields keep
-the CTM1 layout byte-identical (the wire corpus pins it), and decode is
-magic-gated: a CTM1 peer's frames always parse.
+field dict and re-joined per send.
+
+A frame's fields are walked once each way.  Where the native tier's
+extension is there (``native.get_ext()``), ``encode_iov`` makes one
+compiled pass over the message's ``__dict__`` that encodes the payload
+and lifts the segments as it meets them, and ``decode`` one that
+builds the field dict and puts each segment where its ``_SegRef``
+decodes (native/pyext.cc ``denc_dumps_msg`` / ``denc_loads_msg``).
+Where it is not, ``_extract_segments`` + ``denc.dumps`` and
+``denc.loads`` + ``_substitute_segments`` below do the same in three
+Python walks: the same bytes and the same refusals.
+
+Frames with no large fields keep the CTM1 layout byte-identical (the
+wire corpus pins it), and decode is magic-gated: a CTM1 peer's frames
+always parse.
 """
 
 from __future__ import annotations
@@ -33,8 +45,9 @@ from __future__ import annotations
 import struct
 from typing import ClassVar
 
+from .. import native
 from ..utils import copyaudit, denc
-from ..utils.bufferlist import BufferList
+from ..utils.bufferlist import BufferList, iov_of
 
 _HDR = struct.Struct("<4sIQQ")        # magic, type, payload_len, seq
 MAGIC = b"CTM1"
@@ -155,6 +168,30 @@ def _substitute_segments(obj, segs: list):
     return obj
 
 
+def _lift_other(obj, segs: list) -> tuple:
+    """The compiled pass met a value of no exact primitive type in a
+    place segments are lifted from (a list, tuple or dict SUBCLASS, a
+    bytes subclass, a registered struct): the Python walk for that value
+    alone, then what denc makes of the result."""
+    obj = _extract_segments(obj, segs)
+    if type(obj) in (list, tuple, dict):   # what that walk rebuilt
+        return b"", obj
+    return denc._head_tail(obj)
+
+
+def _note_inline(nbytes: int) -> None:
+    copyaudit.note("msg.inline", nbytes)
+
+
+# what native/pyext.cc's message passes take besides the fields, in
+# its CTX_* order; a _SegRef's encoding ends in its index's varint
+_NATIVE_CTX = (
+    SEG_THRESHOLD, _SEG_MAX, _INLINE_AUDIT_FLOOR,
+    denc.py_dumps(_SegRef(0))[:-1], _SegRef,
+    getattr(_SegRef, "DENC_VERSION", 1), BufferList, _lift_other,
+    _note_inline, _substitute_segments)
+
+
 class MessageRegistry:
     _types: dict[int, type] = {}
 
@@ -201,15 +238,18 @@ class Message:
         they are unencodable live objects, and a trace handle leaking
         into a frame would be a cross-daemon aliasing bug, not data."""
         seg_holders: list = []
-        fields = _extract_segments(
-            {k: v for k, v in self.__dict__.items()
-             if k != "seq" and not k.startswith("_")},
-            seg_holders)
-        payload = denc.dumps(fields)
+        ext = native.get_ext()
+        if ext is not None:
+            payload = ext.denc_dumps_msg(self.__dict__, seg_holders,
+                                         _NATIVE_CTX)
+        else:
+            payload = denc.dumps(_extract_segments(
+                {k: v for k, v in self.__dict__.items()
+                 if k != "seq" and not k.startswith("_")},
+                seg_holders))
         if not seg_holders:
             return [_HDR.pack(MAGIC, self.TYPE, len(payload), seq),
                     payload]
-        from ..utils.bufferlist import iov_of
         seg_bufs: list = []
         lens: list[int] = []
         for holder in seg_holders:
@@ -272,13 +312,19 @@ class Message:
         klass = MessageRegistry.get(type_id)
         if klass is None:
             raise ValueError(f"unknown message type {type_id}")
-        fields = denc.loads(payload)
-        if not isinstance(fields, dict):
-            raise denc.DencError("message payload must be a field dict")
-        # ALWAYS walk: a frame that encodes _SegRef placeholders but
-        # carries no (or too few) segments must be rejected here, not
-        # leak placeholder objects into message fields
-        fields = _substitute_segments(fields, segments or [])
+        # a frame that encodes _SegRef placeholders but carries no (or
+        # too few) segments is rejected by either path, never leaks
+        # placeholder objects into message fields
+        ext = native.get_ext()
+        if ext is not None:
+            fields = ext.denc_loads_msg(payload, segments or (),
+                                        _NATIVE_CTX)
+        else:
+            fields = denc.loads(payload)
+            if not isinstance(fields, dict):
+                raise denc.DencError(
+                    "message payload must be a field dict")
+            fields = _substitute_segments(fields, segments or [])
         msg = klass.__new__(klass)
         msg.__dict__.update(fields)
         msg.seq = seq

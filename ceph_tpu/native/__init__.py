@@ -1,11 +1,18 @@
-"""Native host kernels: C++ CRC32C + GF(2^8)/GF(2) region math.
+"""Native host kernels: C++ CRC32C + GF(2^8)/GF(2) region math, and
+the denc codec's compiled walk.
 
 Two binding tiers, fastest first:
 
   * a CPython extension module (pyext.cc) whose per-call overhead is a
     few hundred ns — the small-op path (a 4KiB-chunk stripe encodes in
-    ~1.5us; a ctypes call alone costs more than that);
-  * a ctypes-loaded shared library as the fallback binding.
+    ~1.5us; a ctypes call alone costs more than that).  Beside the CRC
+    and GF kernels it holds the codec of every frame and every stored
+    blob: `denc_dumps` / `denc_loads` (utils/denc.py `dumps` / `loads`)
+    and the messenger's one pass a frame each way, `denc_dumps_msg` /
+    `denc_loads_msg` (msg/message.py `encode_iov` / `decode`: the
+    payload and the segment lift in one walk of the fields);
+  * a ctypes-loaded shared library as the fallback binding (kernels
+    only: the codec needs the C API, and falls back to its Python walk).
 
 Both are built on first import with one g++ invocation, cached next to
 the sources with a source+flags hash in the filename — edits (and flag

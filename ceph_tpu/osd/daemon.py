@@ -526,6 +526,10 @@ class OSDDaemon(Dispatcher, RecoveryService, ScrubService):
         dp["host_copy_bytes_per_read"] = round(
             dp["read_copy_bytes"] / reads, 1)
         out["data_path"] = dp
+        # which codec walk serves this PROCESS (utils/denc.py): whole
+        # passes by the compiled one and by the Python fallback, and
+        # the values the compiled one handed back inside its own
+        out["denc"] = denc.counters()
         # per-pool QoS: dmClock grants/misses/stalls for the op queue
         # (this daemon's shards) + the shared EC dispatch lanes
         out["qos"] = self._qos.stats()

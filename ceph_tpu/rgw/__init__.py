@@ -310,7 +310,8 @@ class RGWDaemon:
             SystemClock(), daemon="client.rgw", history_size=int(
                 getattr(conf, "osd_op_history_size", 20)))
         self.asok = AdminSocket("client.rgw")
-        self.asok.register("perf dump", lambda c: {"rgw": self.perf()})
+        self.asok.register("perf dump", lambda c: {
+            "rgw": self.perf(), "denc": denc.counters()})
         self.asok.register("dump_historic_ops",
                            lambda c: self.op_tracker.dump_historic_ops())
         gw = self

@@ -23,6 +23,16 @@ Model:
 Corrupt or truncated input raises ``DencError`` — never an arbitrary
 exception from deep inside, and never attribute access on untrusted
 objects.
+
+Two walks, one format.  ``dumps`` / ``loads`` run in the native tier's
+CPython extension (native/pyext.cc) where ``native.get_ext()`` has it,
+and in the Python ``_encode`` / ``_decode`` below where it has not: the
+same bytes, the same values, the same refusals (tests/test_denc.py
+holds each to the other).  The compiled walk serves the exact
+primitive types itself and hands every other value, for that value
+alone, back to ``_head_tail`` / ``_construct`` here, which hold the
+registry and the version logic for both.  ``counters()`` says which
+walk served.
 """
 
 from __future__ import annotations
@@ -31,6 +41,8 @@ import struct
 from typing import Any
 
 import numpy as np
+
+from .. import native
 
 
 class DencError(ValueError):
@@ -138,16 +150,31 @@ def _encode(obj: Any, out: bytearray) -> None:
         out += _uvarint(len(obj))
         for v in obj:
             _encode(v, out)
-    elif isinstance(obj, np.integer):
-        out.append(T_INT)
-        out += _uvarint(_big(int(obj)))
-    elif isinstance(obj, np.floating):
-        out.append(T_FLOAT)
-        out += _F64.pack(float(obj))
-    elif isinstance(obj, np.ndarray):
+    else:
+        head, tail = _head_tail(obj)
+        out += head
+        if tail is not _NOTHING:
+            _encode(tail, out)
+
+
+_NOTHING = object()       # _head_tail: no value follows the bytes
+
+
+def _head_tail(obj: Any) -> tuple[bytes, Any]:
+    """A value that is none of the exact primitive types, for either
+    walk: the bytes that open its encoding, and the value whose
+    encoding follows them (``_NOTHING`` where the bytes are all of it).
+    The native walk also sends an int beyond a machine word here."""
+    if type(obj) is int:
+        return bytes((T_INT,)) + _uvarint(_big(obj)), _NOTHING
+    if isinstance(obj, np.integer):
+        return b"", int(obj)
+    if isinstance(obj, np.floating):
+        return b"", float(obj)
+    if isinstance(obj, np.ndarray):
         dt = obj.dtype.str.encode()
         raw = np.ascontiguousarray(obj).tobytes()
-        out.append(T_NDARRAY)
+        out = bytearray((T_NDARRAY,))
         out += _uvarint(len(dt))
         out += dt
         out += _uvarint(obj.ndim)
@@ -155,29 +182,29 @@ def _encode(obj: Any, out: bytearray) -> None:
             out += _uvarint(d)
         out += _uvarint(len(raw))
         out += raw
+        return bytes(out), _NOTHING
+    klass = type(obj)
+    if _registry.get(klass.__name__) is not klass:
+        if isinstance(obj, dict):
+            # a plain subclass (the pg log's tracking index) is
+            # the dict it holds, and decodes as one
+            return b"", dict(obj)
+        raise DencError(
+            f"type {klass.__name__} is not denc-encodable "
+            f"(register with @denc_type)")
+    if hasattr(obj, "_denc_fields"):
+        fields = obj._denc_fields()
+    elif isinstance(obj, tuple) and hasattr(klass, "_fields"):
+        fields = dict(zip(klass._fields, obj))   # NamedTuple
     else:
-        klass = type(obj)
-        if _registry.get(klass.__name__) is not klass:
-            if isinstance(obj, dict):
-                # a plain subclass (the pg log's tracking index) is
-                # the dict it holds, and decodes as one
-                return _encode(dict(obj), out)
-            raise DencError(
-                f"type {klass.__name__} is not denc-encodable "
-                f"(register with @denc_type)")
-        if hasattr(obj, "_denc_fields"):
-            fields = obj._denc_fields()
-        elif isinstance(obj, tuple) and hasattr(klass, "_fields"):
-            fields = dict(zip(klass._fields, obj))   # NamedTuple
-        else:
-            fields = {k: v for k, v in obj.__dict__.items()
-                      if not k.startswith("_")}
-        name = klass.__name__.encode()
-        out.append(T_OBJ)
-        out += _uvarint(len(name))
-        out += name
-        out += _uvarint(getattr(klass, "DENC_VERSION", 1))
-        _encode(fields, out)
+        fields = {k: v for k, v in obj.__dict__.items()
+                  if not k.startswith("_")}
+    name = klass.__name__.encode()
+    head = bytearray((T_OBJ,))
+    head += _uvarint(len(name))
+    head += name
+    head += _uvarint(getattr(klass, "DENC_VERSION", 1))
+    return bytes(head), fields
 
 
 class _Reader:
@@ -255,73 +282,143 @@ def _decode(r: _Reader, depth: int = 0) -> Any:
         except TypeError as e:
             raise DencError(f"unhashable set member: {e}") from None
     if tag == T_NDARRAY:
-        dt = r.take(r.uvarint()).decode("ascii", "replace")
-        try:
-            dtype = np.dtype(dt)
-        except TypeError as e:
-            raise DencError(f"bad dtype {dt!r}: {e}") from None
-        if dtype.hasobject:
-            raise DencError("object dtypes are not decodable")
-        ndim = r.uvarint()
-        if ndim > 32:
-            raise DencError("too many dimensions")
-        shape = tuple(r.uvarint() for _ in range(ndim))
-        raw = r.take(r.uvarint())
-        count = int(np.prod(shape, dtype=np.int64)) if shape else 1
-        if dtype.itemsize * count != len(raw):
-            raise DencError("ndarray payload size mismatch")
-        return np.frombuffer(raw, dtype=dtype).reshape(shape).copy()
+        return _decode_ndarray(r)
     if tag == T_OBJ:
         name = r.take(r.uvarint()).decode("utf-8", "replace")
         version = r.uvarint()
         klass = _registry.get(name)
         if klass is None:
             raise DencError(f"unknown denc type {name!r}")
-        fields = _decode(r, depth + 1)
-        if not isinstance(fields, dict):
-            raise DencError(f"bad field container for {name}")
-        code_version = getattr(klass, "DENC_VERSION", 1)
-        if version > code_version:
-            raise DencError(
-                f"{name} v{version} is newer than supported v{code_version}")
-        if version < code_version:
-            upgrade = getattr(klass, "_denc_upgrade", None)
-            if upgrade is None:
-                raise DencError(
-                    f"{name} v{version} has no upgrade path to "
-                    f"v{code_version}")
-            try:
-                fields = upgrade(fields, version)
-            except TypeError as e:
-                raise DencError(
-                    f"{name}._denc_upgrade must be a "
-                    f"staticmethod/classmethod taking (fields, version): "
-                    f"{e}") from None
-            if not isinstance(fields, dict):
-                raise DencError(f"{name}._denc_upgrade returned non-dict")
-        if isinstance(klass, type) and issubclass(klass, tuple) and \
-                hasattr(klass, "_fields"):
-            try:
-                return klass(**fields)               # NamedTuple
-            except TypeError as e:
-                raise DencError(f"bad fields for {name}: {e}") from None
-        obj = klass.__new__(klass)
-        obj.__dict__.update(fields)
-        if hasattr(obj, "_denc_finish"):
-            obj._denc_finish()
-        return obj
+        return _construct(name, klass, version, _decode(r, depth + 1))
     raise DencError(f"bad tag 0x{tag:02x}")
 
 
-def dumps(obj: Any) -> bytes:
+def _decode_ndarray(r: _Reader) -> np.ndarray:
+    """What follows a T_NDARRAY tag (either walk)."""
+    dt = r.take(r.uvarint()).decode("ascii", "replace")
+    try:
+        dtype = np.dtype(dt)
+    except TypeError as e:
+        raise DencError(f"bad dtype {dt!r}: {e}") from None
+    if dtype.hasobject:
+        raise DencError("object dtypes are not decodable")
+    ndim = r.uvarint()
+    if ndim > 32:
+        raise DencError("too many dimensions")
+    shape = tuple(r.uvarint() for _ in range(ndim))
+    raw = r.take(r.uvarint())
+    count = int(np.prod(shape, dtype=np.int64)) if shape else 1
+    if dtype.itemsize * count != len(raw):
+        raise DencError("ndarray payload size mismatch")
+    return np.frombuffer(raw, dtype=dtype).reshape(shape).copy()
+
+
+def _construct(name: str, klass: type, version: int, fields: Any) -> Any:
+    """A registered struct from its decoded field container (either
+    walk): the version check, the upgrade, the instance."""
+    if not isinstance(fields, dict):
+        raise DencError(f"bad field container for {name}")
+    code_version = getattr(klass, "DENC_VERSION", 1)
+    if version > code_version:
+        raise DencError(
+            f"{name} v{version} is newer than supported v{code_version}")
+    if version < code_version:
+        upgrade = getattr(klass, "_denc_upgrade", None)
+        if upgrade is None:
+            raise DencError(
+                f"{name} v{version} has no upgrade path to "
+                f"v{code_version}")
+        try:
+            fields = upgrade(fields, version)
+        except TypeError as e:
+            raise DencError(
+                f"{name}._denc_upgrade must be a "
+                f"staticmethod/classmethod taking (fields, version): "
+                f"{e}") from None
+        if not isinstance(fields, dict):
+            raise DencError(f"{name}._denc_upgrade returned non-dict")
+    if isinstance(klass, type) and issubclass(klass, tuple) and \
+            hasattr(klass, "_fields"):
+        try:
+            return klass(**fields)               # NamedTuple
+        except TypeError as e:
+            raise DencError(f"bad fields for {name}: {e}") from None
+    obj = klass.__new__(klass)
+    obj.__dict__.update(fields)
+    if hasattr(obj, "_denc_finish"):
+        obj._denc_finish()
+    return obj
+
+
+def _native_ndarray(buf: bytes, pos: int) -> tuple[np.ndarray, int]:
+    r = _Reader(buf)
+    r.pos = pos
+    return _decode_ndarray(r), r.pos
+
+
+def _native_bigint(raw: bytes) -> int:
+    return _unzigzag(_Reader(raw).uvarint())
+
+
+def _native_hooks() -> tuple:
+    """What the compiled walk (native/pyext.cc `load_hooks`) takes
+    from here at its first call, in its order."""
+    return (DencError, _registry, _head_tail, _NOTHING, _construct,
+            _native_ndarray, _native_bigint)
+
+
+# Whole dumps / loads calls the Python walk served, process-wide (a
+# plain add under the GIL's switch interval: a lost update can only
+# under-count).  The compiled walk keeps its own: `counters`.
+python_calls = 0
+
+
+def counters() -> dict:
+    """Which walk serves this process: top-level passes each has made
+    (a message's `encode_iov` / `decode` pass counts as one), and the
+    values the compiled walk handed back to Python inside its own."""
+    ext = native.get_ext()
+    n, cb = ext.denc_counters() if ext is not None else (0, 0)
+    return {"native_calls": n, "python_calls": python_calls,
+            "value_callbacks": cb}
+
+
+def __getattr__(name: str) -> int:
+    # `denc.native_calls`, `denc.value_callbacks`: kept by the extension
+    if name in ("native_calls", "value_callbacks"):
+        return counters()[name]
+    raise AttributeError(name)
+
+
+def py_dumps(obj: Any) -> bytes:
+    """`dumps` by the Python walk: the fallback, and the tests' oracle."""
+    global python_calls
+    python_calls += 1
     out = bytearray()
     _encode(obj, out)
     return bytes(out)
 
 
-def loads(buf: bytes) -> Any:
+def py_loads(buf: bytes) -> Any:
+    """`loads` by the Python walk."""
+    global python_calls
+    python_calls += 1
     r = _Reader(bytes(buf))
     obj = _decode(r)
     if r.pos != len(r.buf):
         raise DencError(f"{len(r.buf) - r.pos} trailing bytes")
     return obj
+
+
+def dumps(obj: Any) -> bytes:
+    ext = native.get_ext()
+    if ext is None:
+        return py_dumps(obj)
+    return ext.denc_dumps(obj)
+
+
+def loads(buf: bytes) -> Any:
+    ext = native.get_ext()
+    if ext is None:
+        return py_loads(buf)
+    return ext.denc_loads(buf)

@@ -27,9 +27,26 @@ if "xla_force_host_platform_device_count" not in flags:
     ).strip()
 
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_enable_compilation_cache", False)
+
+
+@pytest.fixture(params=["native", "python"])
+def denc_walk(request, monkeypatch):
+    """Run a test under each of the codec's two walks (utils/denc.py,
+    msg/message.py): the native tier's compiled one, and the Python
+    one that serves where `native.get_ext()` has nothing (which also
+    sends the CRC and GF entry points to their own fallbacks)."""
+    from ceph_tpu import native
+    if request.param == "native":
+        if native.get_ext() is None:
+            pytest.skip("the native tier's extension cannot be built "
+                        "here (no g++, or no Python.h)")
+    else:
+        monkeypatch.setattr(native, "get_ext", lambda: None)
+    return request.param
 
 
 def pytest_configure(config):

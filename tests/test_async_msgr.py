@@ -87,7 +87,10 @@ class TestCrossStackWireIdentity:
                     # the messenger stamps the sender entity; the
                     # corpus was encoded src-less — normalize back
                     msg.src = ""
-                    got[type(msg).__name__] = msg.encode(seq=7)
+                    blob = msg.encode(seq=7)
+                    # a type's second sample rides with segments
+                    got[type(msg).__name__ + (
+                        ".ctm2" if blob[:4] == b"CTM2" else "")] = blob
                 received[ms_type] = got
             finally:
                 a.shutdown()

@@ -161,6 +161,7 @@ def make_msgr(name, conf=None):
     return m, disp
 
 
+@pytest.mark.usefixtures("denc_walk")
 class TestDataSegments:
     def test_large_fields_ride_segments(self):
         """Fields over the threshold leave the denc payload and ride

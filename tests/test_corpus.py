@@ -17,6 +17,7 @@ import os
 import sys
 
 import numpy as np
+import pytest
 
 CORPUS_PATH = os.path.join(os.path.dirname(__file__), "data",
                            "encode_corpus.json")
@@ -70,7 +71,11 @@ def build_corpus() -> dict:
     return out
 
 
+@pytest.mark.usefixtures("denc_walk")
 def test_encode_corpus_stable():
+    """Under the native tier's extension and without it (conftest's
+    `denc_walk`: the codec's Python walk, and the GF and CRC entry
+    points on their own fallbacks): the same parity bytes."""
     assert os.path.exists(CORPUS_PATH), \
         "corpus missing — run: python tests/test_corpus.py --create"
     with open(CORPUS_PATH) as f:

@@ -146,13 +146,16 @@ class TestCounterSchema:
 
     def test_client_schema_complete(self, cluster, io):
         """The client's `perf dump`: the objecter block's counters plus
-        the ops in flight and each target's resend timeout, and the
-        messenger's set; healthy I/O moves sends and nothing else."""
+        the ops in flight and each target's resend timeout, the
+        messenger's set, and which codec walk serves the process;
+        healthy I/O moves sends and nothing else."""
         rados = io.rados
         assert set(rados.objecter.perf._schema) == self.OBJECTER
         io.read("warm")
         dump = rados.perf_dump()
-        assert set(dump) == {"objecter", "msgr"}
+        assert set(dump) == {"objecter", "msgr", "denc"}
+        assert set(dump["denc"]) == {"native_calls", "python_calls",
+                                     "value_callbacks"}
         assert set(dump["msgr"]) == self.MSGR
         obj = dump["objecter"]
         assert set(obj) == self.OBJECTER | {"ops_in_flight",
